@@ -417,6 +417,8 @@ DECLARED_KEYS: dict[str, str] = {
     "CHAT_TEMPLATE": "chat template style",
     "CHAT_TEMPLATE_JINJA": "jinja template path override",
     "CHAT_TEMPLATE_OPENER": "assistant-turn opener override",
+    # persistent XLA compile cache (tpu/device.py configure_compile_cache)
+    "JAX_COMPILATION_CACHE_DIR": "compile cache dir (unset = <checkout>/.jax_cache)",
     # native extension loader
     "GOFR_NATIVE_LIB": "prebuilt native library path",
     "GOFR_NATIVE_CACHE": "native build cache dir",

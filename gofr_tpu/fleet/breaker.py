@@ -5,7 +5,7 @@ The breaker answers a different question than the health prober
 (``fleet/replica.py``): the prober asks "does the replica SAY it is
 ready", the breaker asks "did it actually SERVE when we last tried".
 A replica can pass readiness probes while failing real requests (a
-wedged device tunnel still answers host-side HTTP), so rotation
+replica whose device runtime hangs still answers host-side HTTP), so rotation
 membership requires both signals.
 
 States and transitions (the classic three-state machine):

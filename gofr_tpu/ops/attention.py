@@ -12,11 +12,14 @@ Layouts: q [B, Sq, Hq, D]; k, v [B, Skv, Hkv, D]; Hq % Hkv == 0.
 — the structured form of a padding mask, supported by both paths.
 ``mask`` is an arbitrary boolean mask ([B, Skv] or [B, Sq, Skv]); only the
 XLA path supports it.
+``mesh`` names the serving mesh the surrounding jit is partitioned over:
+GSPMD cannot partition a Mosaic kernel, so the Pallas path then runs under
+``shard_map`` (heads over ``tp``, batch rows over ``dp``/``fsdp``).
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +37,7 @@ def attention(
     kv_lens: Optional[jnp.ndarray] = None,
     scale: Optional[float] = None,
     impl: str = "auto",
+    mesh: Optional[Any] = None,
 ) -> jnp.ndarray:
     if k.dtype != q.dtype:
         # low-precision KV cache (float8_e4m3fn via cfg.kv_dtype): upcast
@@ -53,6 +57,8 @@ def attention(
             )
         from gofr_tpu.ops.flash import flash_attention
 
+        if mesh is not None:
+            return _sharded_flash(q, k, v, causal, q_offset, kv_lens, scale, mesh)
         return flash_attention(
             q, k, v, causal=causal, q_offset=q_offset, kv_lens=kv_lens, scale=scale
         )
@@ -65,6 +71,42 @@ def attention(
         else:
             mask = jnp.logical_and(mask, len_mask[:, None, :])
     return _xla_attention(q, k, v, causal, q_offset, mask, scale)
+
+
+def _sharded_flash(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    causal: bool,
+    q_offset: int | jnp.ndarray,
+    kv_lens: Optional[jnp.ndarray],
+    scale: Optional[float],
+    mesh: Any,
+) -> jnp.ndarray:
+    """The flash kernel under a serving mesh. Mosaic lowering refuses a
+    kernel inside a GSPMD-partitioned jit ("cannot be automatically
+    partitioned"), so the kernel runs once per shard: batch rows over
+    (dp, fsdp), heads over tp. Query and KV heads split alike, so every
+    shard keeps whole GQA groups, and attention mixes neither axis — no
+    collective is needed."""
+    from jax.sharding import PartitionSpec as P
+
+    from gofr_tpu.ops.flash import _normalize_scalars, flash_attention
+
+    offsets, lens = _normalize_scalars(q, k, q_offset, kv_lens)
+    rows = P(("dp", "fsdp"))
+    heads = P(("dp", "fsdp"), None, "tp", None)
+
+    def per_shard(q_, k_, v_, offsets_, lens_):
+        return flash_attention(
+            q_, k_, v_, causal=causal, q_offset=offsets_, kv_lens=lens_,
+            scale=scale,
+        )
+
+    return jax.shard_map(
+        per_shard, mesh=mesh, in_specs=(heads, heads, heads, rows, rows),
+        out_specs=heads, check_vma=False,
+    )(q, k, v, offsets, lens)
 
 
 def _pallas_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:

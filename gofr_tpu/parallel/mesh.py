@@ -61,15 +61,10 @@ def make_mesh(
         raise ValueError(f"mesh shape {shape} needs {total} devices, have {len(devices)}")
     # Auto axes: GSPMD owns propagation and inserts collectives freely
     # (jax 0.9 defaults some paths to explicit sharding-in-types, which
-    # rejects mixed-axis contractions instead of resolving them). Older
-    # jax (< 0.5) predates AxisType — its meshes are Auto by definition,
-    # so the plain two-argument call is the same semantics.
-    axis_type = getattr(
-        getattr(jax.sharding, "AxisType", None), "Auto", None
+    # rejects mixed-axis contractions instead of resolving them)
+    return jax.make_mesh(
+        sizes, AXES, (jax.sharding.AxisType.Auto,) * len(AXES), devices=devices
     )
-    if axis_type is None:
-        return jax.make_mesh(sizes, AXES, devices=devices)
-    return jax.make_mesh(sizes, AXES, (axis_type,) * len(AXES), devices=devices)
 
 
 def axis_size(mesh: Mesh, axis: str) -> int:

@@ -2,15 +2,13 @@
 
 PR 1 and PR 3 made the LIVE process explainable (`/admin/requests`,
 `/admin/engine`, `/admin/dispatches`) — but every one of those surfaces
-dies with the process, and three bench rounds in a row (r03–r05) ended
-in device wedges whose evidence evaporated exactly that way. This
-module is the flight recorder's crash-survivable twin: when the engine
+dies with the process, and the evidence of a device wedge evaporates
+with it. This module is the flight recorder's crash-survivable twin: when the engine
 wedges, the process crashes, or an operator asks, the ENTIRE
 observability state is serialized into one atomic
 ``postmortem-<ts>.json`` bundle under ``POSTMORTEM_DIR`` — readable
-after SIGKILL, harvestable by ``bench.py``/``tools/tunnel_watch.py``
-into the round's ``hw/rNN/`` evidence directory, pretty-printed by
-``tools/postmortem_view.py``.
+after SIGKILL, harvestable by ``bench.py`` (``BENCH_POSTMORTEM_OUT``),
+pretty-printed by ``tools/postmortem_view.py``.
 
 Bundle contents (schema ``gofr-postmortem/1``):
 

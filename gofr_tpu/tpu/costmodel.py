@@ -297,12 +297,9 @@ class CostModel:
         flops = bytes_accessed = 0.0
         peak_memory = 0
         try:
-            cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
-            if isinstance(cost, dict):
-                flops = float(cost.get("flops") or 0.0)
-                bytes_accessed = float(cost.get("bytes accessed") or 0.0)
+            cost = compiled.cost_analysis()  # one dict on the installed jax
+            flops = float(cost.get("flops") or 0.0)
+            bytes_accessed = float(cost.get("bytes accessed") or 0.0)
         except Exception as exc:
             if self.logger is not None:
                 self.logger.debugf(

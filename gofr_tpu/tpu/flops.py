@@ -7,9 +7,10 @@ per token; attention FLOPs and norms are ignored, which slightly
 *under*-counts — the reported MFU is a floor, never inflated).
 
 ``device_peak_flops`` maps PJRT device kinds to published per-chip bf16
-peaks. Matmuls run in bf16 even for int8 weight-only checkpoints
-(models/quant.py dequantizes into the bf16 MXU path), so the bf16 peak is
-the correct denominator either way.
+peaks (unknown TPU kind: error; non-TPU platform: no peak, so every
+utilization reads 0.0). Matmuls run in bf16 even for int8 weight-only
+checkpoints (models/quant.py dequantizes into the bf16 MXU path), so the
+bf16 peak is the correct denominator either way.
 """
 
 from __future__ import annotations
@@ -51,44 +52,42 @@ _HBM_BW: tuple[tuple[str, float], ...] = (
 
 
 def _lookup(
-    table: tuple[tuple[str, float], ...],
-    device_kind: str,
-    platform: str,
-    tpu_default: float,
-    other_default: float,
+    table: tuple[tuple[str, float], ...], device_kind: str, platform: str
 ) -> float:
     """Shared device-kind table scan for the peak FLOP/s and HBM-bandwidth
-    lookups: ordered substring match, unknown-TPU fallback, non-TPU nominal."""
+    lookups: ordered substring match. A TPU kind missing from the table
+    is an error, never a default — a utilization against the wrong peak
+    is a wrong number under a trusted name. Off-TPU there is no peak
+    (0.0), so ``mfu``/``mbu`` return 0.0 and no CPU run exports a
+    utilization."""
     kind = (device_kind or "").lower()
-    if platform == "tpu" or "tpu" in kind:
-        for needle, value in table:
-            if needle in kind:
-                return value
-        return tpu_default
-    return other_default
+    if platform != "tpu" and "tpu" not in kind:
+        return 0.0
+    for needle, value in table:
+        if needle in kind:
+            return value
+    raise ValueError(
+        f"unknown TPU device_kind {device_kind!r}: add its published "
+        "per-chip peaks to gofr_tpu/tpu/flops.py"
+    )
 
 
 def device_peak_flops(device_kind: str, platform: str, quant: str = "") -> float:
-    """Per-chip peak for the device kind; CPU falls back to a nominal
-    100 GFLOP/s so MFU math never divides by zero in tests (CPU MFU is not a
-    meaningful number and is labeled by platform in the metrics).
-    Unknown TPU kinds assume v5e-class.
+    """Per-chip bf16 peak for the device kind; 0.0 off-TPU; raises
+    ``ValueError`` on a TPU kind the table does not hold.
 
     ``quant="w8a8"`` returns the int8 peak: every shipped TPU generation's
     MXU runs int8 at 2x its bf16 rate, and an MFU gauge fed the bf16 peak
     would read 2x too high under w8a8. THE single home of that factor —
     the serving gauge and the profiler must agree."""
-    peak = _lookup(_PEAKS, device_kind, platform, 197e12, 100e9)
-    if quant == "w8a8" and (platform == "tpu" or "tpu" in (device_kind or "").lower()):
-        peak *= 2.0
-    return peak
+    peak = _lookup(_PEAKS, device_kind, platform)
+    return peak * 2.0 if quant == "w8a8" else peak
 
 
 def device_peak_hbm_bw(device_kind: str, platform: str) -> float:
-    """Per-chip HBM bandwidth for the device kind; CPU falls back to a
-    nominal 50 GB/s so MBU math never divides by zero in tests.
-    Unknown TPU kinds assume v5e-class."""
-    return _lookup(_HBM_BW, device_kind, platform, 819e9, 50e9)
+    """Per-chip HBM bandwidth for the device kind; 0.0 off-TPU; raises
+    ``ValueError`` on a TPU kind the table does not hold."""
+    return _lookup(_HBM_BW, device_kind, platform)
 
 
 def tree_bytes(tree: Any) -> int:

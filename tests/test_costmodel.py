@@ -110,7 +110,7 @@ def test_harvest_defensive_against_backend_quirks():
 
     class _Compiled:
         def cost_analysis(self):
-            return [{"flops": 3e9, "bytes accessed": 2e6}]  # list form
+            return {"flops": 3e9, "bytes accessed": 2e6}
 
         def memory_analysis(self):
             class _M:
@@ -140,12 +140,16 @@ def test_calibration_provenance_profile_vs_nominal(tmp_path):
     assert cm.calibration["source"] == "profile"
     assert cm.calibration["matched"] == "cpu"
     assert cm.eff_flops and cm.eff_bw
-    # unknown kind + missing profile: labeled nominal fallback, never a
-    # silent zero or a boot failure
+    # missing profile: labeled nominal fallback off the published peaks,
+    # never a silent zero or a boot failure
     cm2 = _model(profile_path=str(tmp_path / "missing.json"))
-    cm2.calibrate("warp drive", "tpu")
+    cm2.calibrate("TPU v5 lite", "tpu")
     assert cm2.calibration["source"] == "nominal"
     assert cm2.eff_flops and cm2.eff_bw
+    # a TPU kind with no published peak is an error (the device probe
+    # raises the same one first), never a v5e-class guess
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        cm2.calibrate("warp drive", "tpu")
     # corrupt profile degrades the same way
     bad = tmp_path / "bad.json"
     bad.write_text("{not json")

@@ -338,19 +338,26 @@ def test_stop_tokens_in_stream(pooled):
     assert got == full[: full.index(stop_tok)]
 
 
-def test_pooled_decode_sets_mbu_gauge(pooled):
+def test_pooled_decode_sets_mbu_gauge_only_where_a_peak_exists(
+    pooled, monkeypatch
+):
     # decode is bandwidth-bound; the pool maintains an MBU gauge (bytes
-    # streamed per step / time / peak bw) next to the MFU one
+    # streamed per step / time / peak bw) next to the MFU one — but only
+    # against a real peak: the CPU has none, so it exports no utilization
+    def mbu_line():
+        return next(
+            (ln for ln in pooled.metrics.expose().splitlines()
+             if ln.startswith('gofr_tpu_mbu{model="tiny",op="decode"}')),
+            None,
+        )
+
     pooled.generate([1, 2, 3], max_new_tokens=6)
-    text = pooled.metrics.expose()
-    line = next(
-        (ln for ln in text.splitlines()
-         if ln.startswith('gofr_tpu_mbu{model="tiny",op="decode"}')),
-        None,
-    )
-    assert line is not None, text
-    assert float(line.rsplit(" ", 1)[1]) > 0.0
+    assert mbu_line() is None
     assert pooled.decode_pool._bytes_per_step > 0
+    # the same accounting against v5e's published bandwidth
+    monkeypatch.setattr(pooled.decode_pool, "_peak_bw", 819e9)
+    pooled.generate([1, 2, 3], max_new_tokens=6)
+    assert float(mbu_line().rsplit(" ", 1)[1]) > 0.0
 
 
 def test_slot_sampling_knobs_reset_on_free(pooled):

@@ -146,6 +146,35 @@ def test_flash_pallas_impl_via_attention():
     _assert_close(got, want)
 
 
+def test_flash_under_a_serving_mesh_runs_per_shard():
+    # GSPMD cannot partition a Mosaic kernel (TPU lowering refuses it), so
+    # under a serving mesh attention shard_maps the kernel: heads over tp,
+    # rows over dp. GQA 4q/2kv on tp=2 keeps one whole group per shard;
+    # ragged offsets shard with their rows.
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from gofr_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    b, skv, d = 2, 64, 16
+    q = _rand(36, (b, 8, 4, d))
+    k, v = _rand(37, (b, skv, 2, d)), _rand(38, (b, skv, 2, d))
+    offsets = jnp.array([10, 30], jnp.int32)
+    heads = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+    rows = NamedSharding(mesh, P(("dp", "fsdp")))
+    fn = jax.jit(lambda q_, k_, v_, o_: attention(
+        q_, k_, v_, causal=True, q_offset=o_, kv_lens=o_ + 8,
+        impl="pallas", mesh=mesh,
+    ))
+    got = fn(jax.device_put(q, heads), jax.device_put(k, heads),
+             jax.device_put(v, heads), jax.device_put(offsets, rows))
+    assert got.sharding.is_equivalent_to(heads, got.ndim)
+    want = attention(
+        q, k, v, causal=True, q_offset=offsets, kv_lens=offsets + 8, impl="xla"
+    )
+    _assert_close(got, want)
+
+
 def test_flash_decode_sq1():
     # sq=1 decode shape: padded q block, KV loop bounded by kv_lens
     b, skv, h, d = 2, 64, 2, 16

@@ -1,4 +1,4 @@
-"""Golden-logits checkpoint fidelity (VERDICT r04 item 10).
+"""Golden-logits checkpoint fidelity.
 
 A REAL HF checkpoint — a tiny random-weight ``LlamaForCausalLM`` written
 by ``transformers.save_pretrained``, the actual ecosystem writer, NOT

@@ -71,3 +71,25 @@ def test_parse_is_the_single_grammar():
     assert _parse_mesh_request("2x4") is None  # TPU VM physical grid form
     with pytest.raises(ValueError, match="malformed"):
         _parse_mesh_request("tp=")
+
+
+def test_seeded_init_places_weights_on_the_mesh_as_created():
+    """Building the model whole and sharding it afterwards piles it on one
+    device first; under a mesh the seeded init places each weight as it is
+    made — same values, same layout as build-then-shard."""
+    import jax
+    import numpy as np
+
+    from gofr_tpu.models.llama import TINY
+    from gofr_tpu.models.transformer import init_transformer
+    from gofr_tpu.parallel.mesh import make_mesh
+    from gofr_tpu.parallel.sharding import shard_params
+
+    mesh = make_mesh({"fsdp": 2, "tp": 2}, devices=jax.devices()[:4])
+    placed = init_transformer(jax.random.key(0), TINY, quantize="int8", mesh=mesh)
+    whole = shard_params(
+        init_transformer(jax.random.key(0), TINY, quantize="int8"), mesh
+    )
+    for got, want in zip(jax.tree.leaves(placed), jax.tree.leaves(whole)):
+        assert got.sharding.is_equivalent_to(want.sharding, got.ndim)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

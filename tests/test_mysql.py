@@ -225,7 +225,7 @@ def test_container_degrades_on_bad_mysql(monkeypatch):
     c.close()
 
 
-# -- caching_sha2_password (MySQL 8 default; VERDICT r03 item 4) -------------
+# -- caching_sha2_password (MySQL 8 default) ---------------------------------
 
 def test_sha2_fast_auth_is_the_default():
     """The fixture server advertises caching_sha2_password (stock MySQL 8),

@@ -13,9 +13,9 @@ per-device-kind *effective* coefficients shipped in the committed
           assert the committed profile row reproduces within tolerance
           (a drifted fit means someone edited one side only)
   synth   regenerate the committed ``hw/r02/dispatch_records.json``
-          deterministically from the r02 bench summary (BENCH_r02.json
-          kept no raw dispatch timeline, so the committed calibration
-          window is derived: roofline-consistent dispatch durations for
+          deterministically from the r02 bench summary (that record,
+          removed in PR 21, kept no raw dispatch timeline, so the
+          committed calibration window is derived: roofline-consistent dispatch durations for
           the r02 serving shape, seeded noise — provenance in-band)
 
 Fit procedure (deterministic, no solver): each record is classified
@@ -44,7 +44,7 @@ DEFAULT_PROFILE = os.path.join(REPO, "gofr_tpu", "tpu", "cost_profile.json")
 DEFAULT_RECORDS = os.path.join(REPO, "hw", "r02", "dispatch_records.json")
 
 # -- r02 synthesis constants --------------------------------------------------
-# BENCH_r02.json: model=small, prompt_len=48, clients=8 on a v5e-class
+# the r02 bench summary: model=small, prompt_len=48, clients=8 on a v5e-class
 # chip. The "true" efficiencies the synthesized window encodes — chosen
 # inside the published envelope (prefill compute-bound at ~0.35 of bf16
 # peak, decode streaming at ~0.55 of HBM peak) and reproduced by --fit.
@@ -251,9 +251,9 @@ def synth(out_path: str) -> dict[str, Any]:
         "device_kind": SYNTH_DEVICE_KIND,
         "platform": "tpu",
         "derived_from": (
-            "BENCH_r02.json summary (model=small, prompt_len=48, "
-            "clients=8) — r02 kept no raw dispatch timeline; durations "
-            "are roofline-consistent with seeded noise "
+            "the r02 bench summary (record removed in PR 21; model=small, "
+            "prompt_len=48, clients=8) — r02 kept no raw dispatch "
+            "timeline; durations are roofline-consistent with seeded noise "
             f"(tools/costcal.py --synth, seed {SYNTH_SEED})"
         ),
         "records": records,

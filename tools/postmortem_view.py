@@ -1,7 +1,7 @@
 """Pretty-print a postmortem black-box bundle (gofr_tpu/postmortem.py).
 
     python tools/postmortem_view.py                      # newest bundle in ./postmortems
-    python tools/postmortem_view.py hw/r05               # newest bundle in a dir
+    python tools/postmortem_view.py postmortems          # newest bundle in a dir
     python tools/postmortem_view.py postmortem-...json   # a specific bundle
     python tools/postmortem_view.py ... --json           # machine-readable digest
 
