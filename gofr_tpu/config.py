@@ -424,7 +424,6 @@ DECLARED_KEYS: dict[str, str] = {
     "GOFR_NATIVE_CACHE": "native build cache dir",
     "GOFR_NATIVE_DISABLE": "force the pure-python fallback",
     # correctness tooling (devtools/sanitizer.py + tests/conftest.py)
-    "GOFR_POOL_DEBUG": "decode-pool debug logging",
     "GOFR_SANITIZE": "runtime concurrency sanitizer",
     "GOFR_SANITIZE_ALL": "track non-project locks too",
     "GOFR_SANITIZE_HOLD_MS": "lock hold-time warning threshold",
@@ -487,7 +486,7 @@ def get_env(key: str, default: Optional[str] = None) -> Optional[str]:
 
 def env_flag(key: str) -> bool:
     """True when ``key`` is set to ``1`` — the framework's debug-toggle
-    idiom (``GOFR_POOL_DEBUG``, ``GOFR_SANITIZE``, ...)."""
+    idiom (``GOFR_SANITIZE``, ``GOFR_NATIVE_DISABLE``, ...)."""
     return os.environ.get(key, "") == "1"
 
 

@@ -67,6 +67,9 @@ async def _sse_iter(stream: Stream, executor: Any = None) -> AsyncIterator[bytes
                         next_id += 1
                 else:
                     yield _to_bytes(item)
+                # resumed: the server wrote that frame and asked for more
+                if stream.on_write is not None:
+                    stream.on_write()
         else:
             # Sync generators (e.g. blocking token decode) must not stall the
             # event loop between yields; pull each item on a worker thread —
@@ -89,6 +92,9 @@ async def _sse_iter(stream: Stream, executor: Any = None) -> AsyncIterator[bytes
                         next_id += 1
                 else:
                     yield _to_bytes(item)
+                # resumed: the server wrote that frame and asked for more
+                if stream.on_write is not None:
+                    stream.on_write()
         completed = True
     finally:
         if not completed and stream.on_abort is not None:

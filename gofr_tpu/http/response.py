@@ -62,7 +62,12 @@ class Stream:
     directly (never through the events generator, which may be
     suspended mid-``next`` on a pool thread): handlers use it to trip
     the generation's stop event so an abandoned stream frees its
-    decode slot and paged-KV blocks within one chunk."""
+    decode slot and paged-KV blocks within one chunk.
+
+    ``on_write`` (optional callable) fires each time a frame was handed
+    to the socket — the server's write of the previous frame returned
+    and it asked for the next. The flight recorder uses it to mark when
+    the first token's frame left (``FlightRecord.t_first_frame``)."""
 
     events: Union[Iterator[Any], AsyncIterator[Any]]
     sse: bool = True
@@ -70,3 +75,4 @@ class Stream:
     ids: bool = False
     id_offset: int = 0
     on_abort: Optional[Any] = None
+    on_write: Optional[Any] = None

@@ -214,21 +214,27 @@ def _block(
     per-batch cache at ``starts`` [B] and attends the full cache window;
     returns (out, (k_cache, v_cache), aux).
     """
+    # the named scopes are names only (HLO op metadata: a device
+    # operation in a profiler trace then says which of these lines it
+    # came from); they change no program, shape or module name
     b, s, _ = x.shape
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = _mm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = _mm(h, p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = _mm(h, p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, freqs, positions)
-    k = apply_rope(k, freqs, positions)
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        q = _mm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+        k = _mm(h, p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        v = _mm(h, p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    with jax.named_scope("attn.rope"):
+        q = apply_rope(q, freqs, positions)
+        k = apply_rope(k, freqs, positions)
 
     if kv_cache is None:
-        if attn_fn is not None:
-            attn = attn_fn(q, k, v)
-        else:
-            attn = attention(
-                q, k, v, causal=True, impl=cfg.attn_impl, mesh=cfg.mesh
-            )
+        with jax.named_scope("attn.flash"):
+            if attn_fn is not None:
+                attn = attn_fn(q, k, v)
+            else:
+                attn = attention(
+                    q, k, v, causal=True, impl=cfg.attn_impl, mesh=cfg.mesh
+                )
         merged = (k, v)
     else:
         k_cache, v_cache = kv_cache
@@ -236,18 +242,22 @@ def _block(
         def merge(cache_b, new_b, start_b):
             return jax.lax.dynamic_update_slice(cache_b, new_b, (start_b, 0, 0))
 
-        k_cache = jax.vmap(merge)(k_cache, k.astype(k_cache.dtype), starts)
-        v_cache = jax.vmap(merge)(v_cache, v.astype(v_cache.dtype), starts)
-        attn = attention(
-            q, k_cache, v_cache, causal=True, q_offset=starts,
-            kv_lens=kv_lens, impl=cfg.attn_impl, mesh=cfg.mesh,
-        )
+        with jax.named_scope("attn.kv_update"):
+            k_cache = jax.vmap(merge)(k_cache, k.astype(k_cache.dtype), starts)
+            v_cache = jax.vmap(merge)(v_cache, v.astype(v_cache.dtype), starts)
+        with jax.named_scope("attn.flash"):
+            attn = attention(
+                q, k_cache, v_cache, causal=True, q_offset=starts,
+                kv_lens=kv_lens, impl=cfg.attn_impl, mesh=cfg.mesh,
+            )
         merged = (k_cache, v_cache)
 
-    x = x + _mm(attn.reshape(b, s, cfg.dim), p["wo"])
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    y, aux = (mlp_fn or _default_mlp)(p, h)
-    x = x + y
+    with jax.named_scope("attn.out"):
+        x = x + _mm(attn.reshape(b, s, cfg.dim), p["wo"])
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+        y, aux = (mlp_fn or _default_mlp)(p, h)
+        x = x + y
     return x, merged, aux
 
 
@@ -259,15 +269,17 @@ def transformer_forward(
     b, s = tokens.shape
     freqs = jnp.asarray(_cached_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta))
     positions = jnp.arange(s)
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
 
     def body(carry, layer_params):
         y, _, _ = _block(cfg, layer_params, carry, freqs, positions)
         return y, None
 
     x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["norm_f"], cfg.norm_eps)
-    return _mm(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["norm_f"], cfg.norm_eps)
+        return _mm(x, params["lm_head"]).astype(jnp.float32)
 
 
 # -- KV-cached ragged-batch serving path -------------------------------------
@@ -305,7 +317,8 @@ def _run_cached(
     starts = cache["lengths"]  # [B]
     freqs = jnp.asarray(_cached_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta))
     positions = starts[:, None] + jnp.arange(s)[None, :]  # [B, S]
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
     written = starts + s  # [B]
 
     def body(carry, inputs):
@@ -319,7 +332,9 @@ def _run_cached(
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"])
     )
-    return rms_norm(x, params["norm_f"], cfg.norm_eps), k_new, v_new, starts
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["norm_f"], cfg.norm_eps)
+    return x, k_new, v_new, starts
 
 
 def _forward_with_cache(
@@ -337,10 +352,14 @@ def _forward_with_cache(
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
     x, k_new, v_new, starts = _run_cached(params, tokens, cache, cfg)
-    # gather each request's last REAL position (pad-aware bucketed prefill)
-    last_idx = jnp.clip(lengths - 1, 0, s - 1)  # [B]
-    x_last = jnp.take_along_axis(x, last_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = _mm(x_last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        # gather each request's last REAL position (pad-aware bucketed
+        # prefill)
+        last_idx = jnp.clip(lengths - 1, 0, s - 1)  # [B]
+        x_last = jnp.take_along_axis(
+            x, last_idx[:, None, None].astype(jnp.int32), axis=1
+        )[:, 0]
+        logits = _mm(x_last, params["lm_head"]).astype(jnp.float32)
     new_cache = {"k": k_new, "v": v_new, "lengths": starts + lengths}
     return logits, new_cache
 
@@ -568,18 +587,21 @@ def decode_chunk(
         else:
             tok, c, k, pres, cnt = carry
         logits, c = decode_step(params, tok, c, cfg)
-        k, sub = jax.random.split(k)
-        sample_in = (
-            logits if presence is None
-            else apply_penalties(
-                logits, pres, repetition_penalty, cnt,
-                presence_penalty, frequency_penalty, bias,
+        with jax.named_scope("sample"):
+            k, sub = jax.random.split(k)
+            sample_in = (
+                logits if presence is None
+                else apply_penalties(
+                    logits, pres, repetition_penalty, cnt,
+                    presence_penalty, frequency_penalty, bias,
+                )
             )
-        )
-        nxt = sample_logits(sample_in, sub, temperature, top_k, top_p, min_p)
-        outs = nxt
-        if with_logprobs:
-            outs = (nxt, *_lp_outputs(logits, nxt))
+            nxt = sample_logits(
+                sample_in, sub, temperature, top_k, top_p, min_p
+            )
+            outs = nxt
+            if with_logprobs:
+                outs = (nxt, *_lp_outputs(logits, nxt))
         if presence is None:
             return (nxt[:, None], c, k), outs
         pres = update_presence(pres, nxt)
@@ -679,10 +701,19 @@ def decode_chunk_pool(
     def body(carry, _):
         tok, c, k = carry
         logits, c = decode_step(params, tok, c, cfg)
-        k, s = jax.random.split(k)
-        nxt = sample_logits_rows(logits, s, temperature, top_k, top_p, min_p)
-        lp, tv, ti = _lp_outputs(logits, nxt)
-        return (nxt[:, None], c, k), (nxt, lp, tv, ti)
+        with jax.named_scope("sample"):
+            k, s = jax.random.split(k)
+            nxt = sample_logits_rows(
+                logits, s, temperature, top_k, top_p, min_p
+            )
+            lp, tv, ti = _lp_outputs(logits, nxt)
+        # the per-step hand-over: this step's whole pool cache is the
+        # next step's input (the scan's carry). The whole-cache copies
+        # the compiler puts in at this loop carry the loop's own scope,
+        # not this one (PERF.md section 5).
+        with jax.named_scope("pool.cache_handover"):
+            carry = (nxt[:, None], c, k)
+        return carry, (nxt, lp, tv, ti)
 
     (tok, cache, _), (toks, lps, tvals, tids) = jax.lax.scan(
         body, (token, cache, sub), None, length=n_steps
@@ -768,13 +799,18 @@ def decode_chunk_pool_penalized(
     def body(carry, _):
         tok, c, k, pres, cnt = carry
         logits, c = decode_step(params, tok, c, cfg)
-        k, s = jax.random.split(k)
-        penalized = apply_penalties(logits, pres, rep, cnt, pp, fp, bias)
-        nxt = sample_logits_rows(penalized, s, temperature, top_k, top_p, min_p)
-        lp, tv, ti = _lp_outputs(logits, nxt)
-        pres = update_presence(pres, nxt)
-        cnt = update_counts(cnt, nxt)
-        return (nxt[:, None], c, k, pres, cnt), (nxt, lp, tv, ti)
+        with jax.named_scope("sample"):
+            k, s = jax.random.split(k)
+            penalized = apply_penalties(logits, pres, rep, cnt, pp, fp, bias)
+            nxt = sample_logits_rows(
+                penalized, s, temperature, top_k, top_p, min_p
+            )
+            lp, tv, ti = _lp_outputs(logits, nxt)
+            pres = update_presence(pres, nxt)
+            cnt = update_counts(cnt, nxt)
+        with jax.named_scope("pool.cache_handover"):
+            carry = (nxt[:, None], c, k, pres, cnt)
+        return carry, (nxt, lp, tv, ti)
 
     (tok, cache, _, presence, counts), (toks, lps, tvals, tids) = jax.lax.scan(
         body, (token, cache, sub, presence, counts), None, length=n_steps

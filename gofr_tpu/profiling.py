@@ -11,6 +11,14 @@ regression can be traced without redeploying.
 Per-batch device time is additionally recorded as a span tag on every
 dispatched batch (tpu/device.py ``tpu-batch`` spans) — the always-on,
 cheap signal; full traces are the on-demand deep dive.
+
+``phase`` puts the program's own phase boundaries on both clocks at one
+line of code: a ``jax.profiler.TraceAnnotation`` (the ``/host:CPU`` plane
+of the same ``.xplane.pb`` the device's ``XLA Ops`` land in) and the
+``perf_counter`` marks of the DispatchRecord / FlightRecord that the
+phase belongs to. The names are LEAVES — no ``phase`` encloses another —
+because a trace reducer that attributes a device gap to the host event
+covering most of it would hand every gap to an enclosing span.
 """
 
 from __future__ import annotations
@@ -20,6 +28,67 @@ import tempfile
 import threading
 import time
 from typing import Any, Optional
+
+# the phase vocabulary (PERF.md section 3 says which record field and
+# which benchmark metric reads each)
+BATCHER_COLLECT = "gofr.batcher.collect"
+PREFILL_ISSUE = "gofr.prefill.issue"
+PREFILL_FETCH_WAIT = "gofr.prefill.fetch_wait"
+POOL_ISSUE = "gofr.pool.issue"
+POOL_FETCH_WAIT = "gofr.pool.fetch_wait"
+POOL_DELIVER = "gofr.pool.deliver"
+POOL_WAIT_WORK = "gofr.pool.wait_work"
+SOLO_ISSUE = "gofr.solo.issue"
+SOLO_FETCH_WAIT = "gofr.solo.fetch_wait"
+SSE_FIRST_FRAME = "gofr.sse.first_frame"
+
+def _annotation(name: str, dispatch_id: Optional[int]) -> Any:
+    """The profiler's host-plane event for one phase. With no profiler
+    session its enter/exit are a flag check inside jaxlib."""
+    from jax.profiler import TraceAnnotation  # not at import: jax is heavy
+
+    if dispatch_id is None:
+        return TraceAnnotation(name)
+    return TraceAnnotation(name, dispatch_id=dispatch_id)
+
+
+class phase:
+    """``with phase(NAME, record, start="t_x", end="t_y"):`` — one phase
+    of a dispatch or a request. Opens the profiler annotation ``NAME``
+    (tagged with the record's ``dispatch_id`` when it has one) and stamps
+    the record's set-once ``perf_counter`` marks at the same two program
+    points, so the record's split and the trace's spans cannot drift
+    apart. ``record`` may be None (no timeline wired): annotation only."""
+
+    __slots__ = ("_record", "_end", "_ann")
+
+    def __init__(self, name: str, record: Any = None,
+                 start: Optional[str] = None, end: Optional[str] = None):
+        self._record = record
+        self._end = end
+        self._ann = _annotation(name, getattr(record, "dispatch_id", None))
+        if start is not None:
+            _stamp(record, start)
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._end is not None:
+            _stamp(self._record, self._end)
+        return False
+
+
+def between(a: Optional[float], b: Optional[float]) -> Optional[float]:
+    """Seconds from mark ``a`` to mark ``b``; None while either is unset."""
+    return None if a is None or b is None else b - a
+
+
+def _stamp(record: Any, mark: str) -> None:
+    if record is not None and getattr(record, mark) is None:
+        setattr(record, mark, time.perf_counter())
 
 
 class Profiler:

@@ -49,6 +49,8 @@ import weakref
 from collections import deque
 from typing import Any, Optional
 
+from gofr_tpu.profiling import SSE_FIRST_FRAME, between, phase
+
 # process identity, regenerated on every interpreter start: the fleet
 # prober compares it across probes to tell "the same process recovered"
 # from "a NEW process answers at this address" — the supervisor-restart
@@ -260,6 +262,7 @@ class FlightRecord:
         "tenant", "deadline_s", "priority", "shed_stage",
         "wall_start", "t_start", "t_enqueue", "t_dispatch",
         "t_first_token", "t_last_token", "t_done", "wall_done", "_lock",
+        "t_pool_admit", "t_first_frame",
         # the recorder's in-flight index holds records WEAKLY (an
         # abandoned record must vanish with its request, not leak)
         "__weakref__",
@@ -347,6 +350,13 @@ class FlightRecord:
         self.t_enqueue: Optional[float] = None
         self.t_dispatch: Optional[float] = None
         self.t_first_token: Optional[float] = None
+        # the decode pool gave this request a slot, or refused it (the
+        # request then decodes solo); unset on paths that never asked
+        self.t_pool_admit: Optional[float] = None
+        # the first token's frame was handed to the socket (streams only:
+        # the responder's write returned) — t_first_token is when the
+        # token existed on the host
+        self.t_first_frame: Optional[float] = None
         self.t_last_token: Optional[float] = None
         self.t_done: Optional[float] = None
         self.wall_done: Optional[float] = None
@@ -367,6 +377,10 @@ class FlightRecord:
     def mark_first_token(self) -> None:
         if self.t_first_token is None:
             self.t_first_token = time.perf_counter()
+
+    def mark_pool_admit(self) -> None:
+        if self.t_pool_admit is None:
+            self.t_pool_admit = time.perf_counter()
 
     def mark_pooled(self, cohort: int) -> None:
         """Decode joined the continuous-batching pool with ``cohort``
@@ -496,6 +510,14 @@ class FlightRecord:
         return self.t_first_token - self.t_start
 
     @property
+    def prefill(self) -> Optional[float]:
+        """Dispatch -> first token on the host, less the scheduler's
+        defer (which t_dispatch precedes): the prefill dispatch itself,
+        device queue included, and the wake-up of the request thread."""
+        span = between(self.t_dispatch, self.t_first_token)
+        return None if span is None else span - self.sched_defer_s
+
+    @property
     def tpot(self) -> Optional[float]:
         """Mean time per output token AFTER the first (decode cadence)."""
         if (
@@ -564,6 +586,14 @@ class FlightRecord:
             "first_token_ts": _offset(self.t_first_token),
             "done_ts": self.wall_done,
             "queue_wait_s": self.queue_wait,
+            # the server-side TTFT, partitioned (each term from marks on
+            # this one clock): parse_s + queue_wait_s + sched_defer_s +
+            # prefill_s + first_frame_s = server_ttft_s on a stream
+            "parse_s": between(self.t_start, self.t_enqueue),
+            "prefill_s": self.prefill,
+            "first_frame_s": between(self.t_first_token, self.t_first_frame),
+            "pool_admit_s": between(self.t_first_token, self.t_pool_admit),
+            "server_ttft_s": between(self.t_start, self.t_first_frame),
             "ttft_s": self.ttft,
             "tpot_s": self.tpot,
             "duration_s": self.duration,
@@ -1252,7 +1282,15 @@ class FlightRecorder:
             else:
                 self.finish(record)
 
+        def frame_written() -> None:
+            # the responder calls this once a frame's write returned; the
+            # first one after the first token existed carried that token
+            if record.t_first_frame is None and record.t_first_token is not None:
+                with phase(SSE_FIRST_FRAME, record, end="t_first_frame"):
+                    pass  # an instant on both clocks: the frame has left
+
         result.events = guarded()
+        result.on_write = frame_written
         return result
 
     # -- read side (admin API / postmortem) ----------------------------------

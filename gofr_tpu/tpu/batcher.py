@@ -39,6 +39,7 @@ import numpy as np
 
 from gofr_tpu.deadline import current_deadline, deadline_exceeded_counter
 from gofr_tpu.errors import DeadlineExceeded, TooManyRequestsError
+from gofr_tpu.profiling import BATCHER_COLLECT, phase
 from gofr_tpu.telemetry import current_record
 from gofr_tpu.tpu.introspect import activate_dispatch
 from gofr_tpu.tracing import current_span, get_tracer
@@ -210,24 +211,28 @@ class DynamicBatcher:
             batch = [first]
             deadline = first.arrival + self.timeout_s
             closing = False
-            while len(batch) < self.max_batch:
-                if pending:
-                    item = pending.popleft()
+            # the batch window on the profiler's clock: first item in
+            # hand -> cohort complete (the idle wait for a first item is
+            # not a phase of any request)
+            with phase(BATCHER_COLLECT):
+                while len(batch) < self.max_batch:
+                    if pending:
+                        item = pending.popleft()
+                        if self._viable(item):
+                            batch.append(item)
+                        continue
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        item = self._queue.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if item is None:
+                        closing = True
+                        break
                     if self._viable(item):
                         batch.append(item)
-                    continue
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    item = self._queue.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if item is None:
-                    closing = True
-                    break
-                if self._viable(item):
-                    batch.append(item)
             # final sweep BEFORE cohort formation: an item can expire (or
             # its caller vanish) during the drain wait above — expired
             # items must never consume cohort slots or padded tokens

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from gofr_tpu.config import env_flag
 from gofr_tpu.deadline import (
     cancellations_counter,
     current_deadline,
@@ -55,6 +54,13 @@ from gofr_tpu.deadline import (
     pool_reject_counter,
 )
 from gofr_tpu.errors import DeadlineExceeded
+from gofr_tpu.profiling import (
+    POOL_DELIVER,
+    POOL_FETCH_WAIT,
+    POOL_ISSUE,
+    POOL_WAIT_WORK,
+    phase,
+)
 from gofr_tpu.telemetry import current_journal_entry, current_record
 
 DONE = object()  # end-of-stream marker on a slot's token queue
@@ -69,11 +75,6 @@ DEADLINE = object()
 # depth is wasted lockstep steps for slots freed mid-pipeline. The
 # default of 3 is unmeasured on this machine (ROADMAP S3).
 PIPELINE_DEPTH = 3
-
-# GOFR_POOL_DEBUG=1: per-chunk dispatch/fetch/deliver timings on stderr —
-# the first tool to reach for when pooled tok/s diverges from the raw
-# decode-chunk capability
-_POOL_DEBUG = env_flag("GOFR_POOL_DEBUG")
 
 
 class PoolFailure:
@@ -203,6 +204,11 @@ class DecodePool:
         self._timeline = timeline
         self._watchdog = watchdog
         self._in_flight_chunks: deque = deque()  # replaced by the worker
+        # dispatches this pool issued and has not yet fetched — a plain
+        # int beside the deque, written by the worker alone, read without
+        # the lock by whoever issues a program of its own (a prefill, a
+        # solo chunk) to record what it queued behind on the device
+        self.chunks_in_flight = 0
         # the record of a chunk BETWEEN begin() and its in_flight.append
         # (the jitted dispatch can raise in that window) — swept by
         # _abandon_in_flight like the appended ones
@@ -380,10 +386,9 @@ class DecodePool:
             if metrics is not None
             else None
         )
-        # submit rejections by reason: solo-decode fallbacks were only
-        # diagnosable via GOFR_POOL_DEBUG stderr — in production this
-        # counter (and the FlightRecord's pool_reject_reason) says WHY a
-        # stream missed the pool
+        # submit rejections by reason: this counter (and the
+        # FlightRecord's pool_reject_reason) says WHY a stream missed the
+        # pool and decoded solo
         self._reject_counter = (
             pool_reject_counter(metrics)
             if metrics is not None
@@ -908,6 +913,7 @@ class DecodePool:
         flight as errored — a phantom 'running' decode chunk with
         ever-growing duration would misdirect the exact wedged-device
         diagnosis the timeline exists to provide."""
+        self.chunks_in_flight = 0  # whatever was in flight is not fetched
         if self._timeline is None:
             return
         if self._pending_chunk_drec is not None:
@@ -959,7 +965,8 @@ class DecodePool:
         while True:
             with self._work:
                 while not self._active and not in_flight and not self._closed:
-                    self._work.wait()
+                    with phase(POOL_WAIT_WORK):  # parked: no live slot
+                        self._work.wait()
                 if self._closed:
                     # closing mid-stream is an ERROR for waiters, never a
                     # silently-truncated "ok" result; un-fetched chunks'
@@ -1030,6 +1037,7 @@ class DecodePool:
                 "decode_chunk", batch_size=len(records),
             )
             drec.mark_running()
+            drec.chunks_ahead = self.chunks_in_flight  # depth reached
             for _, req in records:
                 if req is not None and req.record is not None:
                     req.record.note_dispatch_id(drec.dispatch_id)
@@ -1037,7 +1045,10 @@ class DecodePool:
             # leak this record as running forever
             self._pending_chunk_drec = drec
         dispatch_start = _perf_counter()
-        toks_dev, lps_dev, tvals_dev, tids_dev = self._run_executable(records)
+        with phase(POOL_ISSUE, drec, end="t_issued"):
+            toks_dev, lps_dev, tvals_dev, tids_dev = self._run_executable(
+                records
+            )
         # start the D2H copy NOW: the transfer begins the moment the
         # chunk's compute finishes, so the blocking fetch later waits on
         # an already-in-flight copy and the per-chunk fetches OVERLAP
@@ -1061,6 +1072,7 @@ class DecodePool:
              dispatch_start, drec)
         )
         self._pending_chunk_drec = None  # owned by in_flight now
+        self.chunks_in_flight += 1
         if self._sched is not None:
             # decode keeps its cadence; prefill chunks take the gaps
             # between these notes
@@ -1215,6 +1227,7 @@ class DecodePool:
         )
         next_dev.copy_to_host_async()
         self._pending_chunk_drec = None
+        self.chunks_in_flight += 1
         if self._sched is not None:
             self._sched.note_decode_chunk(len(records))
         return records, drafts, next_dev, width, dispatch_start, drec
@@ -1234,6 +1247,7 @@ class DecodePool:
         try:
             with watch:
                 next_ids = np.asarray(next_dev)
+            self.chunks_in_flight -= 1
             fetch_done = _perf_counter()
             # depth-1 dispatch: the span IS the inter-delivery interval
             elapsed = fetch_done - max(dispatch_start, last_fetch_done)
@@ -1415,7 +1429,6 @@ class DecodePool:
         call uses as its throughput-denominator anchor."""
         (records, toks_dev, lps_dev, tvals_dev, tids_dev,
          dispatch_start, drec) = in_flight.popleft()
-        fetch_start = _perf_counter()
         # the blocking host fetch is WHERE a wedged device manifests:
         # it runs under the stall watchdog's deadline so a hang flips
         # the engine state instead of silently parking this worker
@@ -1426,7 +1439,11 @@ class DecodePool:
             if self._watchdog is not None else contextlib.nullcontext()
         )
         try:
-            with watch:
+            # fetch wait: the host blocked on this chunk — the device
+            # queue ahead of it, its compute, the D2H copy
+            with watch, phase(
+                POOL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"
+            ):
                 toks = np.asarray(toks_dev)
                 lps = np.asarray(lps_dev)
                 tvals = (
@@ -1435,6 +1452,7 @@ class DecodePool:
                 tids = (
                     np.asarray(tids_dev) if tids_dev is not None else None
                 )
+            self.chunks_in_flight -= 1
             fetch_done = _perf_counter()
             # throughput denominator: the interval between consecutive
             # deliveries at steady state (dispatch->fetch spans ~2 chunk
@@ -1448,7 +1466,12 @@ class DecodePool:
                 fetch_done - max(dispatch_start, last_fetch_done),
                 span / self.pipeline_depth,
             )
-            with self._work:
+            if drec is not None:
+                # the interval between deliveries: one chunk's device
+                # time while the pipeline is full, plus whatever else
+                # the device ran in between (a prefill, solo chunks)
+                drec.cadence_s = dispatch_elapsed
+            with phase(POOL_DELIVER, drec), self._work:
                 self._deliver(records, toks, lps, tvals, tids,
                               dispatch_elapsed, drec)
         except BaseException:
@@ -1465,16 +1488,6 @@ class DecodePool:
                 for _, req in records:
                     if req is not None and req.record is not None:
                         req.record.note_anomaly(drec.dispatch_id)
-        if _POOL_DEBUG:
-            import sys
-
-            print(
-                f"[pool] chunk active={len(records)} "
-                f"dispatch->fetch {dispatch_elapsed*1e3:.0f}ms "
-                f"fetch-wait {(fetch_done-fetch_start)*1e3:.0f}ms "
-                f"deliver {(_perf_counter()-fetch_done)*1e3:.0f}ms",
-                file=sys.stderr, flush=True,
-            )
         return fetch_done
 
     def _deliver(self, records: list, toks: np.ndarray, lps: np.ndarray,

@@ -123,6 +123,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from gofr_tpu.datasource.health import DOWN, UP, Health
+from gofr_tpu.profiling import (
+    PREFILL_FETCH_WAIT,
+    PREFILL_ISSUE,
+    SOLO_FETCH_WAIT,
+    SOLO_ISSUE,
+    phase,
+)
 from gofr_tpu.telemetry import current_record as telemetry_record
 from gofr_tpu.tpu.batcher import (
     DynamicBatcher,
@@ -153,6 +160,14 @@ WATCHDOG_AUTO_TIMEOUT_S = 120.0
 # nullcontext is stateless/reentrant: one shared instance serves every
 # unwatched dispatch without a per-call allocation
 _NULLCTX = contextlib.nullcontext()
+
+
+def _chunks_ahead(runner: Any) -> int:
+    """Pool dispatches issued and not yet fetched, as the runner is about
+    to issue one of its own: what that dispatch queues behind on the
+    device (``DispatchRecord.chunks_ahead``). A plain int read, no lock."""
+    pool = runner.decode_pool
+    return pool.chunks_in_flight if pool is not None else 0
 
 
 def configure_compile_cache() -> str:
@@ -1196,6 +1211,7 @@ class TPUDevice:
                     if self._spec_pooled and self._spec_ngram else None
                 ),
             )
+            self.runner.decode_pool = self.decode_pool
             if self._spec_pooled and not self._spec_ngram:
                 self.logger.warnf(
                     "SPEC_POOLED=on is inert for the decode pool: "
@@ -2789,6 +2805,9 @@ class _EchoRunner:
     # ``resume_from`` (its decode is position-indexed), the compile-free
     # analogue of the transformer's teacher-forced prefill
     supports_resume = True
+    # the device hands the runner its decode pool (echo has none), so a
+    # prefill can record how many pool chunks it was issued behind
+    decode_pool: Any = None
 
     def __init__(self, max_batch: int = 8, step_ms: float = 0.0,
                  mesh_axes: Optional[dict] = None, metrics: Any = None):
@@ -2891,12 +2910,19 @@ class _EchoRunner:
         return ids
 
     def run_batch(self, payloads: list[np.ndarray]) -> list[dict]:
-        if self.stall_hook is not None:
-            self.stall_hook()
-        if self._closed:
-            raise RuntimeError("echo runner closed (engine recovering)")
-        if self.step_s:
-            time.sleep(self.step_s)
+        # the same phase marks as the transformer runner's run_batch, so
+        # the record's split is exercisable compile-free
+        drec = current_dispatch()
+        with phase(PREFILL_ISSUE, drec, end="t_issued"):
+            if self.stall_hook is not None:
+                self.stall_hook()
+            if self._closed:
+                raise RuntimeError("echo runner closed (engine recovering)")
+            if drec is not None:
+                drec.chunks_ahead = _chunks_ahead(self)
+        with phase(PREFILL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"):
+            if self.step_s:
+                time.sleep(self.step_s)
         return [
             {"next_token": int(ids[0]), "length": int(ids.size)}
             for ids in payloads
@@ -3303,6 +3329,10 @@ class _TransformerRunner:
     # bucket (prepare() keeps the LAST tokens). MODEL_BUCKETS restricts
     # this when a deployment only serves shorter prompts.
     SEQ_BUCKETS = (64, 128, 256, 512, 1024, 2048, 4096, 8192)
+    # the device hands the runner its decode pool once built, so a
+    # prefill or solo chunk can record how many pool chunks it was
+    # issued behind (DispatchRecord.chunks_ahead)
+    decode_pool: Any = None
 
     def __init__(
         self,
@@ -3654,7 +3684,9 @@ class _TransformerRunner:
         # int32 next-token ids, never the [B, V] logits
         def _prefill_fn(p, t, c, l):
             logits, new_cache = prefill(p, t, c, cfg, l)
-            return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
+            with jax.named_scope("sample"):
+                next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, next_ids, new_cache
 
         self._prefill = jax.jit(_prefill_fn)
         self._decode = jax.jit(lambda p, t, c: decode_step(p, t, c, cfg))
@@ -3795,25 +3827,35 @@ class _TransformerRunner:
         sequence bucket, all warmed at startup — no compile on the serving
         path (north star: p50 TTFT < 200ms)."""
         n = len(payloads)
-        # prompts longer than the largest bucket keep their LAST tokens
-        # (consistent with prepare(): recency wins for next-token prediction)
-        bucket = self._bucket_for(max(int(p.size) for p in payloads))
-        bsz = next_pow2(max(len(payloads), self.max_batch))
-        self._note_exec(("prefill", bucket, bsz))
-        tokens, lengths = pack_token_rows(payloads, bsz, bucket)
-        full_lengths = np.maximum(lengths, 1)  # padded rows need length>=1
-        cache = self._zero_cache(bsz)
-        tokens_dev, lengths_dev = jnp.asarray(tokens), jnp.asarray(full_lengths)
-        if self._token_sharding is not None:
-            tokens_dev = jax.device_put(tokens_dev, self._token_sharding)
-            lengths_dev = jax.device_put(lengths_dev, self._row_sharding)
-        logits, next_ids, cache = self._prefill(
-            self.params, tokens_dev, cache, lengths_dev
-        )
+        drec = current_dispatch()  # the batcher activated this dispatch
+        # issue: host preparation (pack, zero cache, H2D) and the enqueue
+        # of the program; the jitted call returns before the device ran it
+        with phase(PREFILL_ISSUE, drec, end="t_issued"):
+            # prompts longer than the largest bucket keep their LAST tokens
+            # (consistent with prepare(): recency wins for next-token
+            # prediction)
+            bucket = self._bucket_for(max(int(p.size) for p in payloads))
+            bsz = next_pow2(max(len(payloads), self.max_batch))
+            self._note_exec(("prefill", bucket, bsz))
+            tokens, lengths = pack_token_rows(payloads, bsz, bucket)
+            full_lengths = np.maximum(lengths, 1)  # padded rows need length>=1
+            cache = self._zero_cache(bsz)
+            tokens_dev, lengths_dev = jnp.asarray(tokens), jnp.asarray(full_lengths)
+            if self._token_sharding is not None:
+                tokens_dev = jax.device_put(tokens_dev, self._token_sharding)
+                lengths_dev = jax.device_put(lengths_dev, self._row_sharding)
+            if drec is not None:
+                drec.chunks_ahead = _chunks_ahead(self)
+            logits, next_ids, cache = self._prefill(
+                self.params, tokens_dev, cache, lengths_dev
+            )
         # ONE tiny fetch ([bsz] int32) synchronizes the batch; logits stay
         # on device (row views fetch lazily if a handler reads them) and
-        # cache rows slice lazily (only generate() needs them)
-        next_ids = np.asarray(next_ids)
+        # cache rows slice lazily (only generate() needs them). The wait
+        # holds the device queue ahead of the program, its compute and
+        # the D2H copy.
+        with phase(PREFILL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"):
+            next_ids = np.asarray(next_ids)
         return [
             _PrefillState(
                 cache, logits, i,
@@ -4013,14 +4055,13 @@ class _TransformerRunner:
                     adapter=adapter, want_kv=seed_kv,
                     spec_ctx=ids if pool_spec else None,
                 )
-            except (queue_mod.Full, RuntimeError) as exc:
-                from gofr_tpu.tpu.decode_pool import _POOL_DEBUG
-
-                if _POOL_DEBUG:
-                    import sys as _sys
-
-                    print(f"[pool] submit fallback: {exc!r}", file=_sys.stderr, flush=True)
-                slot_q = None  # pool saturated/closed -> solo decode below
+            except (queue_mod.Full, RuntimeError):
+                # pool saturated/closed -> solo decode below (the reason
+                # is on the FlightRecord and gofr_tpu_pool_reject_total)
+                slot_q = None
+            record = telemetry_record()
+            if record is not None:
+                record.mark_pool_admit()  # a slot, or the refusal
             if slot_q is not None:
                 state = None
                 kv_row = self._consume_pool(
@@ -4085,86 +4126,118 @@ class _TransformerRunner:
         mp = sampler.min_p
         pen = sampler.repetition_penalty
         ppen, fpen = sampler.presence_penalty, sampler.frequency_penalty
-        pending: "deque" = deque()  # (toks_dev, n_steps)
+        pending: "deque" = deque()  # (toks_dev, ..., n_steps, drec)
         token_dev = jnp.asarray([[token]], jnp.int32)
         steps_in_flight = 0
         stopped = False
-        while not stopped:
-            while (
-                not (stop is not None and stop.is_set())
-                and len(pending) < 2
-                and steps_in_flight < max_new_tokens - len(out)
-                and cache_len + steps_in_flight < max_len
-            ):
-                # always run the WARMED full chunk unless the cache
-                # boundary forces a short one — a max_new_tokens remainder
-                # must not compile a fresh scan length mid-request;
-                # surplus sampled tokens are simply discarded
-                n = min(self.decode_chunk_size, max_len - cache_len - steps_in_flight)
-                key = self._greedy_key if sampler.greedy else sampler.take_key()
-                fn = self._chunk_fns[(presence is not None, logprobs)]
-                # jit caches per (variant, scan length): a first use of
-                # an opt-in variant or remainder length compiles here
-                self._note_exec(
-                    ("decode_chunk", presence is not None, logprobs, n)
+        # one DispatchRecord (kind decode_solo) per chunk: these chunks
+        # share the device with the pool's, and without a record their
+        # time shows in no program record at all
+        timeline = self.timeline
+        record = telemetry_record()
+        issued = fetched = None  # the records being issued / delivered
+        left_open = "error"  # how records a raise leaves open are closed
+        try:
+            while not stopped:
+                while (
+                    not (stop is not None and stop.is_set())
+                    and len(pending) < 2
+                    and steps_in_flight < max_new_tokens - len(out)
+                    and cache_len + steps_in_flight < max_len
+                ):
+                    # always run the WARMED full chunk unless the cache
+                    # boundary forces a short one — a max_new_tokens remainder
+                    # must not compile a fresh scan length mid-request;
+                    # surplus sampled tokens are simply discarded
+                    n = min(self.decode_chunk_size, max_len - cache_len - steps_in_flight)
+                    key = self._greedy_key if sampler.greedy else sampler.take_key()
+                    fn = self._chunk_fns[(presence is not None, logprobs)]
+                    # jit caches per (variant, scan length): a first use of
+                    # an opt-in variant or remainder length compiles here
+                    self._note_exec(
+                        ("decode_chunk", presence is not None, logprobs, n)
+                    )
+                    if timeline is not None:
+                        issued = timeline.begin(
+                            "decode_solo", batch_size=1, tokens=n,
+                        )
+                        issued.chunks_ahead = _chunks_ahead(self)
+                        if record is not None:
+                            record.note_dispatch_id(issued.dispatch_id)
+                    with phase(SOLO_ISSUE, issued, end="t_issued"):
+                        if presence is None:
+                            result = fn(prm, token_dev, cache, key, temp,
+                                        tk, tp, mp, n)
+                        else:
+                            result = fn(prm, token_dev, cache, key, temp,
+                                        tk, tp, mp, presence, pen, counts,
+                                        ppen, fpen, bias_row, n)
+                    toks_dev, cache = result[0], result[1]
+                    rest = list(result[2:])
+                    if presence is not None:
+                        presence = rest.pop(0)
+                        counts = rest.pop(0)
+                    if logprobs:
+                        lps_dev, tvals_dev, tids_dev = rest[:3]
+                    else:
+                        lps_dev = tvals_dev = tids_dev = None
+                    token_dev = toks_dev[:, -1:]
+                    pending.append(
+                        (toks_dev, lps_dev, tvals_dev, tids_dev, n, issued)
+                    )
+                    steps_in_flight += n
+                if not pending:
+                    break
+                toks_dev, lps_dev, tvals_dev, tids_dev, n, fetched = (
+                    pending.popleft()
                 )
-                if presence is None:
-                    result = fn(prm, token_dev, cache, key, temp,
-                                tk, tp, mp, n)
-                else:
-                    result = fn(prm, token_dev, cache, key, temp,
-                                tk, tp, mp, presence, pen, counts,
-                                ppen, fpen, bias_row, n)
-                toks_dev, cache = result[0], result[1]
-                rest = list(result[2:])
-                if presence is not None:
-                    presence = rest.pop(0)
-                    counts = rest.pop(0)
-                if logprobs:
-                    lps_dev, tvals_dev, tids_dev = rest[:3]
-                else:
-                    lps_dev = tvals_dev = tids_dev = None
-                token_dev = toks_dev[:, -1:]
-                pending.append((toks_dev, lps_dev, tvals_dev, tids_dev, n))
-                steps_in_flight += n
-            if not pending:
-                break
-            toks_dev, lps_dev, tvals_dev, tids_dev, n = pending.popleft()
-            chunk = [int(t) for t in np.asarray(toks_dev)[0]]
-            chunk_lps = (
-                [float(x) for x in np.asarray(lps_dev)[0]]
-                if lps_dev is not None else None
-            )
-            chunk_tops = None
-            if top_logprobs:
-                tv = np.asarray(tvals_dev)[0]
-                ti = np.asarray(tids_dev)[0]
-                chunk_tops = [
-                    [(int(ti[j, m]), float(tv[j, m]))
-                     for m in range(ti.shape[-1])]
-                    for j in range(ti.shape[0])
-                ]
-            steps_in_flight -= n
-            cache_len += n
-            if deadline is not None and deadline.expired():
-                self._shed_solo_decode(deadline, len(out))
-            take = min(n, max_new_tokens - len(out))
-            for j, t in enumerate(chunk[:take]):
-                if t in stop_tokens:
+                with phase(
+                    SOLO_FETCH_WAIT, fetched, start="t_fetch", end="t_fetched"
+                ):
+                    chunk = [int(t) for t in np.asarray(toks_dev)[0]]
+                    chunk_lps = (
+                        [float(x) for x in np.asarray(lps_dev)[0]]
+                        if lps_dev is not None else None
+                    )
+                    chunk_tops = None
+                    if top_logprobs:
+                        tv = np.asarray(tvals_dev)[0]
+                        ti = np.asarray(tids_dev)[0]
+                        chunk_tops = [
+                            [(int(ti[j, m]), float(tv[j, m]))
+                             for m in range(ti.shape[-1])]
+                            for j in range(ti.shape[0])
+                        ]
+                steps_in_flight -= n
+                cache_len += n
+                if deadline is not None and deadline.expired():
+                    self._shed_solo_decode(deadline, len(out))
+                take = min(n, max_new_tokens - len(out))
+                for j, t in enumerate(chunk[:take]):
+                    if t in stop_tokens:
+                        stopped = True
+                        break
+                    out.append(t)
+                    if chunk_lps is not None:
+                        lps.append(chunk_lps[j])
+                    if chunk_tops is not None:
+                        tops.append(chunk_tops[j])
+                    if on_token:
+                        on_token((t, chunk_lps[j]) if logprobs else t)
+                    if stop is not None and stop.is_set():
+                        stopped = True  # on_token may set stop mid-burst
+                        break
+                if len(out) >= max_new_tokens:
                     stopped = True
-                    break
-                out.append(t)
-                if chunk_lps is not None:
-                    lps.append(chunk_lps[j])
-                if chunk_tops is not None:
-                    tops.append(chunk_tops[j])
-                if on_token:
-                    on_token((t, chunk_lps[j]) if logprobs else t)
-                if stop is not None and stop.is_set():
-                    stopped = True  # on_token may set stop mid-burst
-                    break
-            if len(out) >= max_new_tokens:
-                stopped = True
+                if timeline is not None and fetched is not None:
+                    timeline.finish(fetched)  # tokens handed on: delivered
+            left_open = "abandoned"  # the speculative chunk past a stop
+        finally:
+            if timeline is not None:
+                # finish() is idempotent: delivered chunks stay "ok"
+                for drec in [issued, fetched] + [e[-1] for e in pending]:
+                    if drec is not None:
+                        timeline.finish(drec, status=left_open)
         return cache
 
     def _shed_solo_decode(self, deadline: Any, emitted: int) -> None:
@@ -4251,11 +4324,13 @@ class _TransformerRunner:
                         "prefill_chunk", bucket=bucket, batch_size=1,
                         tokens=size,
                     )
+                    drec.chunks_ahead = _chunks_ahead(self)
                     if record is not None:
                         record.note_dispatch_id(drec.dispatch_id)
-                logits, next_ids, cache = self._prefill(
-                    prm, tokens, cache, lengths
-                )
+                with phase(PREFILL_ISSUE, drec, end="t_issued"):
+                    logits, next_ids, cache = self._prefill(
+                        prm, tokens, cache, lengths
+                    )
                 if record is not None:
                     record.note_prefill_chunk(bucket=bucket)
                 total += size
@@ -4269,7 +4344,9 @@ class _TransformerRunner:
                 )
                 if self.watchdog is not None else _NULLCTX
             )
-            with watch:
+            with watch, phase(
+                PREFILL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"
+            ):
                 next_token = int(np.asarray(next_ids)[0])
         except BaseException:
             # a raising slice dispatch (or fetch) must not leak the open

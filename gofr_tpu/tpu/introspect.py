@@ -11,8 +11,9 @@ This module gives the serving engine that layer:
 - ``DispatchTimeline``: every device dispatch (batched prefill, chunked
   prefill slice, pooled decode chunk, warmup compile, device probe) gets
   a monotonic ``dispatch_id`` and a ``DispatchRecord`` — kind, bucket,
-  batch size, padded tokens, queued/running/done marks, per-dispatch
-  MFU/MBU — in a bounded ring exposed at ``GET /admin/dispatches``.
+  batch size, padded tokens, queued/running/done marks and the split of
+  running -> done into issue / in flight / fetch wait / deliver,
+  per-dispatch MFU/MBU — in a bounded ring exposed at ``GET /admin/dispatches``.
   FlightRecords carry the dispatch ids they rode
   (``FlightRecord.note_dispatch_id``), so a slow request in
   ``/admin/requests`` links directly to the dispatches that made it slow.
@@ -50,10 +51,13 @@ import traceback
 from collections import deque
 from typing import Any, Iterator, Optional
 
+from gofr_tpu.profiling import between
+
 DISPATCH_KINDS = (
     "prefill",          # one batched prefill dispatch (DynamicBatcher)
     "prefill_chunk",    # one bounded-compute chunked-prefill slice
     "decode_chunk",     # one pooled decode chunk (DecodePool)
+    "decode_solo",      # one chunk of a solo decode beside the pool
     "warmup_compile",   # one boot-time warmup compile stage
     "device_probe",     # the first jax.devices() touch of the runtime
 )
@@ -97,6 +101,7 @@ class DispatchRecord:
         "tokens", "detail", "status", "wall_start", "t_queued", "t_running",
         "t_done", "mfu", "mbu", "predicted_ms", "residual_ratio",
         "cost_source", "anomaly",
+        "t_issued", "t_fetch", "t_fetched", "cadence_s", "chunks_ahead",
     )
 
     def __init__(
@@ -136,6 +141,22 @@ class DispatchRecord:
         self.residual_ratio: Optional[float] = None
         self.cost_source: Optional[str] = None
         self.anomaly: Optional[str] = None
+        # where the running -> done time went (set-once marks stamped by
+        # profiling.phase at the lines that do the work): the jitted
+        # call returned, the host began to fetch the result, the fetch
+        # returned. With t_running and t_done they split duration into
+        # issue / in_flight / fetch_wait / deliver.
+        self.t_issued: Optional[float] = None
+        self.t_fetch: Optional[float] = None
+        self.t_fetched: Optional[float] = None
+        # decode chunks: the pool's inter-delivery interval, its own
+        # estimate of one chunk's device time with the pipeline full
+        # (it holds other programs' time between two deliveries too)
+        self.cadence_s: Optional[float] = None
+        # pool dispatches issued and not yet fetched when this one was
+        # issued: what a prefill queued behind on the device, or the
+        # pipeline depth a decode chunk actually reached
+        self.chunks_ahead: Optional[int] = None
 
     def mark_running(self) -> None:
         """Device execution begins (after any scheduler-interleave wait)."""
@@ -167,6 +188,12 @@ class DispatchRecord:
             "start_ts": self.wall_start,
             "queue_wait_s": self.queue_wait,
             "duration_s": self.duration,
+            "issue_s": between(self.t_running, self.t_issued),
+            "in_flight_s": between(self.t_issued, self.t_fetch),
+            "fetch_wait_s": between(self.t_fetch, self.t_fetched),
+            "deliver_s": between(self.t_fetched, self.t_done),
+            "cadence_s": self.cadence_s,
+            "chunks_ahead": self.chunks_ahead,
             "mfu": self.mfu,
             "mbu": self.mbu,
             "predicted_ms": self.predicted_ms,

@@ -61,8 +61,8 @@ def test_pool_enabled_by_default():
 
 
 def test_submit_rejection_reason_is_counted():
-    """A solo-decode fallback must be diagnosable without
-    GOFR_POOL_DEBUG: the reject reason lands on
+    """A solo-decode fallback must be diagnosable from the metrics:
+    the reject reason lands on
     gofr_tpu_pool_reject_total{reason=...}. DECODE_POOL_PENALTIES=off
     rejects penalized submits deterministically."""
     dev, old = _device(DECODE_POOL_PENALTIES="off")

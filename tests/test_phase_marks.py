@@ -1,0 +1,338 @@
+"""Phase marks inside a dispatch and a request (gofr_tpu/profiling.py
+``phase``): the DispatchRecord's split of running -> done into issue / in
+flight / fetch wait / deliver, ``chunks_ahead``, the ``decode_solo`` kind,
+the FlightRecord's partition of the server-side TTFT, and the rule that the
+profiler annotations are leaves. The request path runs on the no-JAX echo
+model over HTTP; the decode pool and the solo fallback on the tiny
+transformer (ONE compiled bucket, two slots: a few seconds of CPU compiles,
+the price of keeping the pool's marks in tier-1)."""
+
+import json
+import os
+import socket
+import threading
+import time
+import urllib.request
+from types import SimpleNamespace
+
+import pytest
+
+from gofr_tpu import profiling
+from gofr_tpu.config import EnvConfig
+from gofr_tpu.logging import Level
+from gofr_tpu.metrics import Registry
+from gofr_tpu.telemetry import FlightRecorder, activate_record
+from gofr_tpu.testutil import MockLogger
+from gofr_tpu.tpu.device import new_device
+from gofr_tpu.tpu.introspect import DispatchTimeline
+
+PHASES = ("issue_s", "in_flight_s", "fetch_wait_s", "deliver_s")
+ALL_NAMES = {
+    profiling.BATCHER_COLLECT, profiling.PREFILL_ISSUE, profiling.PREFILL_FETCH_WAIT,
+    profiling.POOL_ISSUE, profiling.POOL_FETCH_WAIT, profiling.POOL_DELIVER,
+    profiling.POOL_WAIT_WORK, profiling.SOLO_ISSUE, profiling.SOLO_FETCH_WAIT,
+    profiling.SSE_FIRST_FRAME,
+}
+
+
+def assert_marks_in_order(record: dict) -> None:
+    assert record["status"] == "ok", record
+    for name in PHASES:
+        assert record[name] is not None and record[name] >= 0, (name, record)
+    assert sum(record[name] for name in PHASES) == pytest.approx(
+        record["duration_s"], abs=1e-3)
+
+
+class RecordingAnnotations:
+    """Stands in for ``profiling._annotation``: records every name opened
+    and fails the opener if another phase is open on its thread."""
+
+    def __init__(self):
+        self.names: set[str] = set()
+        self.nested: list[tuple[str, str]] = []
+        self._open = threading.local()
+
+    def __call__(self, name, dispatch_id):
+        outer = self
+
+        class _Ann:
+            def __enter__(self):
+                stack = outer._open.__dict__.setdefault("stack", [])
+                if stack:
+                    outer.nested.append((stack[-1], name))
+                stack.append(name)
+                outer.names.add(name)
+
+            def __exit__(self, *exc):
+                outer._open.stack.pop()
+
+        return _Ann()
+
+
+# -- the helper ---------------------------------------------------------------
+
+def test_phase_stamps_set_once_marks_and_takes_no_record():
+    timeline = DispatchTimeline(capacity=4)
+    rec = timeline.begin("decode_chunk", batch_size=1)
+    with profiling.phase(profiling.POOL_ISSUE, rec, end="t_issued"):
+        pass
+    first = rec.t_issued
+    assert first is not None and first >= rec.t_running
+    with profiling.phase(profiling.POOL_ISSUE, rec, end="t_issued"):
+        pass
+    assert rec.t_issued == first  # set once
+    with profiling.phase(profiling.POOL_FETCH_WAIT, rec, start="t_fetch", end="t_fetched"):
+        time.sleep(0.002)
+    timeline.finish(rec)
+    out = rec.to_dict()
+    assert out["fetch_wait_s"] >= 0.002
+    assert_marks_in_order(out)
+    with profiling.phase(profiling.POOL_WAIT_WORK, None, start="t_fetch", end="t_fetched"):
+        pass  # no record: the annotation alone
+
+
+def test_unmarked_record_reports_no_split():
+    timeline = DispatchTimeline(capacity=4)
+    rec = timeline.begin("warmup_compile")
+    timeline.finish(rec)
+    out = rec.to_dict()
+    assert out["duration_s"] is not None
+    assert all(out[name] is None for name in PHASES[:3])
+    assert out["cadence_s"] is None and out["chunks_ahead"] is None
+
+
+# -- the request path: echo model over HTTP -------------------------------------
+
+@pytest.fixture(scope="module")
+def echo_app(tmp_path_factory):
+    import gofr_tpu
+    from gofr_tpu.openai_compat import register_openai_routes
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    env = {"HTTP_PORT": str(port), "LOG_LEVEL": "FATAL", "MODEL_NAME": "echo",
+           "TOKENIZER": "byte", "BATCH_MAX_SIZE": "4", "BATCH_TIMEOUT_MS": "1",
+           "ECHO_STEP_MS": "2", "FLIGHT_SLOW_MS": "60000"}
+    saved = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    cwd = os.getcwd()
+    os.chdir(tmp_path_factory.mktemp("phase_marks"))
+    try:
+        app = gofr_tpu.new()
+    finally:
+        os.chdir(cwd)
+        for k, v in saved.items():
+            os.environ.pop(k, None) if v is None else os.environ.__setitem__(k, v)
+    register_openai_routes(app)
+    app.start()
+    yield app, f"http://127.0.0.1:{port}"
+    app.shutdown()
+
+
+def _complete(base, stream, prompt="hello there"):
+    req = urllib.request.Request(
+        base + "/v1/completions",
+        data=json.dumps({"model": "echo", "prompt": prompt, "max_tokens": 4,
+                         "stream": stream}).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=30) as resp:
+        resp.read()
+        return resp.headers["X-Correlation-ID"]
+
+
+def _flight(app, trace_id):
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline:  # a stream's record closes behind its last frame
+        for record in app.container.telemetry.records(limit=200):
+            if record["trace_id"] == trace_id and record["status"] == "ok":
+                return record
+        time.sleep(0.01)
+    raise AssertionError(f"no finished flight record for {trace_id}")
+
+
+def test_streamed_request_partitions_its_server_side_ttft(echo_app):
+    app, base = echo_app
+    flight = _flight(app, _complete(base, stream=True))
+    parts = ("parse_s", "queue_wait_s", "prefill_s", "first_frame_s")
+    for name in parts:
+        assert flight[name] is not None and flight[name] >= 0, (name, flight)
+    total = sum(flight[name] for name in parts) + (flight["sched_defer_s"] or 0.0)
+    assert total == pytest.approx(flight["server_ttft_s"], abs=1e-9)
+    assert flight["server_ttft_s"] >= flight["ttft_s"]  # the frame left after the token existed
+    assert flight["prefill_s"] >= 0.002  # ECHO_STEP_MS inside the dispatch
+
+
+def test_non_streamed_request_has_no_first_frame(echo_app):
+    app, base = echo_app
+    flight = _flight(app, _complete(base, stream=False))
+    assert flight["first_frame_s"] is None and flight["server_ttft_s"] is None
+    assert flight["parse_s"] >= 0 and flight["prefill_s"] >= 0
+    assert flight["pool_admit_s"] is None  # the echo runner has no pool to ask
+
+
+def test_prefill_record_splits_its_duration(echo_app):
+    app, base = echo_app
+    flight = _flight(app, _complete(base, stream=False, prompt="split me"))
+    records = {r["dispatch_id"]: r
+               for r in app.container.tpu.timeline.records(limit=500, kind="prefill")}
+    mine = [records[i] for i in flight["dispatch_ids"]]
+    assert mine
+    for record in mine:
+        assert_marks_in_order(record)
+        assert record["fetch_wait_s"] >= 0.002  # the echo runner's step is its "device"
+        assert record["chunks_ahead"] == 0 and record["cadence_s"] is None
+
+
+def test_prefill_records_the_pool_chunks_it_was_issued_behind(echo_app):
+    """The echo runner has no pool; a stand-in with k chunks in flight,
+    swapped in by the stall hook as the dispatch starts, must read back."""
+    app, base = echo_app
+    runner = app.container.tpu.runner
+    runner.stall_hook = lambda: setattr(
+        runner, "decode_pool", SimpleNamespace(chunks_in_flight=2))
+    try:
+        flight = _flight(app, _complete(base, stream=False, prompt="behind two"))
+    finally:
+        runner.stall_hook = None
+        runner.decode_pool = None
+    records = {r["dispatch_id"]: r
+               for r in app.container.tpu.timeline.records(limit=500, kind="prefill")}
+    assert [records[i]["chunks_ahead"] for i in flight["dispatch_ids"]] == [2]
+
+
+def test_request_path_annotations_are_leaves(echo_app, monkeypatch):
+    app, base = echo_app
+    stub = RecordingAnnotations()
+    monkeypatch.setattr(profiling, "_annotation", stub)
+    _flight(app, _complete(base, stream=True, prompt="leaves"))
+    assert not stub.nested
+    assert {profiling.BATCHER_COLLECT, profiling.PREFILL_ISSUE,
+            profiling.PREFILL_FETCH_WAIT, profiling.SSE_FIRST_FRAME} <= stub.names
+    assert stub.names <= ALL_NAMES
+
+
+# -- the decode pool and the solo fallback: tiny transformer ----------------------
+
+_TINY = {
+    "MODEL_NAME": "tiny", "BATCH_MAX_SIZE": "2", "BATCH_TIMEOUT_MS": "1",
+    "MODEL_BUCKETS": "64", "DECODE_SLOTS": "2", "DECODE_CHUNK": "4", "PREFIX_CACHE": "0",
+}
+
+
+@pytest.fixture(scope="module")
+def held_pool():
+    """A two-slot pool whose worker is held at its first fetch with both
+    slots taken and the pipeline full, a prefill and a refused request
+    served meanwhile, then let go: (device, depth, prefill flight, refused
+    flight, the annotation names seen, nested pairs)."""
+    old = {k: os.environ.get(k) for k in _TINY}
+    os.environ.update(_TINY)
+    try:
+        dev = new_device(EnvConfig(), MockLogger(Level.INFO), Registry())
+    finally:
+        for k, v in old.items():
+            os.environ.pop(k, None) if v is None else os.environ.__setitem__(k, v)
+    dev.wait_ready(300.0)
+    pool = dev.decode_pool
+    depth = pool.pipeline_depth
+    stub = RecordingAnnotations()
+    real_annotation, profiling._annotation = profiling._annotation, stub
+    gate = threading.Event()
+    real_fetch = pool._fetch_and_deliver
+
+    def held_fetch(in_flight, last_fetch_done):
+        gate.wait(60.0)
+        return real_fetch(in_flight, last_fetch_done)
+
+    pool._fetch_and_deliver = held_fetch
+    recorder = FlightRecorder()
+
+    def serve(prompt, n, out):
+        record = recorder.start(model="tiny", endpoint="/t")
+        try:
+            dev.generate(prompt, max_new_tokens=n)
+        finally:
+            recorder.finish(record)
+            activate_record(None)
+        out.append(record.to_dict())
+
+    riders: list[dict] = []
+    threads = [threading.Thread(target=serve, args=([3 + i, 1, 4, 1, 5], 21, riders))
+               for i in range(2)]
+    try:
+        for thread in threads:
+            thread.start()
+        deadline = time.monotonic() + 60.0
+        while time.monotonic() < deadline and not (
+            pool.chunks_in_flight == depth and len(pool._active) == 2
+        ):
+            time.sleep(0.005)
+        assert pool.chunks_in_flight == depth and len(pool._active) == 2
+        prefill_only: list[dict] = []
+        serve([9, 8, 7], 1, prefill_only)  # one token: prefill, never the pool
+        refused: list[dict] = []
+        serve([2, 7, 1, 8], 9, refused)  # no free slot: decodes solo
+    finally:
+        gate.set()
+        for thread in threads:
+            thread.join(60.0)
+        pool._fetch_and_deliver = real_fetch
+    assert not any(thread.is_alive() for thread in threads) and len(riders) == 2
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline and profiling.POOL_WAIT_WORK not in stub.names:
+        time.sleep(0.005)  # the worker parks once its last slot is free
+    profiling._annotation = real_annotation
+    yield SimpleNamespace(dev=dev, depth=depth, prefill_only=prefill_only[0],
+                          refused=refused[0], riders=riders, stub=stub)
+    dev.close()
+
+
+def _records(dev, kind):
+    return {r["dispatch_id"]: r for r in dev.timeline.records(limit=2000, kind=kind)}
+
+
+def test_pool_chunks_carry_marks_cadence_and_depth(held_pool):
+    chunks = list(_records(held_pool.dev, "decode_chunk").values())
+    assert len(chunks) >= held_pool.depth
+    for record in chunks:
+        assert_marks_in_order(record)
+        assert record["cadence_s"] is not None and record["cadence_s"] > 0
+        assert 0 <= record["chunks_ahead"] < held_pool.depth
+    # the held worker filled its pipeline one chunk at a time
+    first = sorted(chunks, key=lambda r: r["dispatch_id"])[: held_pool.depth]
+    assert [r["chunks_ahead"] for r in first] == list(range(held_pool.depth))
+    assert held_pool.dev.decode_pool.chunks_in_flight == 0
+
+
+def test_prefill_issued_behind_k_pool_chunks_records_k(held_pool):
+    prefills = _records(held_pool.dev, "prefill")
+    mine = [prefills[i] for i in held_pool.prefill_only["dispatch_ids"]]
+    assert [r["chunks_ahead"] for r in mine] == [held_pool.depth]
+    assert_marks_in_order(mine[0])
+    assert held_pool.prefill_only["pool_admit_s"] is None  # never asked the pool
+
+
+def test_refused_request_leaves_decode_solo_records_on_its_flight(held_pool):
+    flight = held_pool.refused
+    assert flight["pool_reject_reason"] == "no_free_slots"
+    assert flight["pool_admit_s"] is not None and flight["pool_admit_s"] >= 0
+    solo = _records(held_pool.dev, "decode_solo")
+    mine = [solo[i] for i in flight["dispatch_ids"] if i in solo]
+    assert len(mine) == 2  # 8 tokens after the first, chunks of 4
+    for record in mine:
+        assert_marks_in_order(record)
+        assert record["batch_size"] == 1 and record["tokens"] == 4
+        assert record["chunks_ahead"] == held_pool.depth
+        assert record["predicted_ms"] is None  # the cost model is not taught the kind
+    # no solo record left running or abandoned behind the finished request
+    assert all(r["status"] == "ok" for r in solo.values())
+    for rider in held_pool.riders:  # the pooled riders admitted, and have no solo records
+        assert rider["pool_reject_reason"] is None and rider["pool_admit_s"] >= 0
+        assert not set(rider["dispatch_ids"]) & set(solo)
+
+
+def test_pool_and_solo_annotations_are_leaves(held_pool):
+    assert not held_pool.stub.nested
+    assert held_pool.stub.names == ALL_NAMES - {profiling.SSE_FIRST_FRAME}

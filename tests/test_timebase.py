@@ -414,22 +414,33 @@ def _wait_snapshots(app, n=2, timeout=10.0):
     )
 
 
+def _wait_series(base, path, timeout=10.0):
+    """Poll a timeseries query until it serves a series: the request that
+    makes the series is counted behind its response, and only a snapshot
+    taken after that holds it — however many snapshots came before."""
+    deadline = time.monotonic() + timeout
+    while True:
+        out = _get(base, path)
+        if out["series"] or time.monotonic() >= deadline:
+            return out
+        time.sleep(0.02)
+
+
 def test_timeseries_endpoint_serves_series_and_rates(echo_app):
     app, base, _ = echo_app
     _post(base, {"messages": [{"role": "user", "content": "hi"}],
                  "max_tokens": 2, "temperature": 0})
     _wait_snapshots(app, n=2)
+    chat = ("/admin/timeseries?metric=gofr_http_requests_total"
+            "&labels=path:/v1/chat/completions")
+    _wait_series(base, chat)  # a snapshot that has counted the request above
     out = _get(base, "/admin/timeseries?metric=gofr_http_requests_total")
     assert out["kind"] == "counter"
     assert out["series"], "no series for a counter that was incremented"
     assert all(len(s["points"]) >= 1 for s in out["series"])
     assert out["timebase"]["snapshots"] >= 2
     # labels filter narrows to the chat route
-    filtered = _get(
-        base,
-        "/admin/timeseries?metric=gofr_http_requests_total"
-        "&labels=path:/v1/chat/completions",
-    )
+    filtered = _get(base, chat)
     assert filtered["series"]
     assert all(
         s["labels"]["path"] == "/v1/chat/completions"
