@@ -192,6 +192,26 @@ def _default_mlp(p: dict, h: jnp.ndarray) -> tuple[jnp.ndarray, dict]:
     return _mm(gated, p["w_down"]), {}
 
 
+def _write_kv(
+    stack: jnp.ndarray, new: jnp.ndarray, layer: jnp.ndarray,
+    starts: jnp.ndarray,
+) -> jnp.ndarray:
+    """Write ``new`` [B, s, kv_heads, head_dim] into the stacked cache
+    [L, B, max_seq, kv_heads, head_dim] at [layer, row, starts[row]:+s].
+
+    One ``dynamic_update_slice`` per row, each of this call's s tokens
+    alone: the stack is updated in place and only B·s·kv_heads·head_dim
+    elements move. (One batched-index update would be a scatter, which a
+    backend may widen to the whole operand.) Starts clamp as every
+    dynamic_update_slice does, so a full row overwrites its own tail."""
+    new = new.astype(stack.dtype)
+    for row in range(new.shape[0]):
+        stack = jax.lax.dynamic_update_slice(
+            stack, new[row][None, None], (layer, row, starts[row], 0, 0)
+        )
+    return stack
+
+
 def _block(
     cfg: TransformerConfig,
     p: dict,
@@ -199,6 +219,7 @@ def _block(
     freqs: jnp.ndarray,
     positions: jnp.ndarray,
     kv_cache: Optional[tuple[jnp.ndarray, jnp.ndarray]] = None,
+    layer: Optional[jnp.ndarray] = None,
     starts: Optional[jnp.ndarray] = None,
     kv_lens: Optional[jnp.ndarray] = None,
     attn_fn: Optional[Any] = None,
@@ -210,9 +231,13 @@ def _block(
     ``mlp_fn`` returning (out, aux_losses)).
 
     Without cache: attention over this call's keys (via ``attn_fn`` when
-    given), returns (out, (k, v), aux). With cache: merges k/v into the
-    per-batch cache at ``starts`` [B] and attends the full cache window;
-    returns (out, (k_cache, v_cache), aux).
+    given), returns (out, (k, v), aux). With cache: ``kv_cache`` is the
+    WHOLE stacked cache (k, v), each [L, B, max_seq, kv_heads, head_dim],
+    and ``layer`` this block's index into it. This call's k/v are written
+    at [layer, row, starts[row] : starts[row] + s] and nothing else of
+    the stacks is touched; attention reads its layer out of the stack over
+    the full cache window. Returns (out, (k_stack, v_stack), aux): the
+    buffers that came in, so the caller's loops carry them in place.
     """
     # the named scopes are names only (HLO op metadata: a device
     # operation in a profiler trace then says which of these lines it
@@ -237,20 +262,17 @@ def _block(
                 )
         merged = (k, v)
     else:
-        k_cache, v_cache = kv_cache
-
-        def merge(cache_b, new_b, start_b):
-            return jax.lax.dynamic_update_slice(cache_b, new_b, (start_b, 0, 0))
-
+        k_stack, v_stack = kv_cache
         with jax.named_scope("attn.kv_update"):
-            k_cache = jax.vmap(merge)(k_cache, k.astype(k_cache.dtype), starts)
-            v_cache = jax.vmap(merge)(v_cache, v.astype(v_cache.dtype), starts)
+            k_stack = _write_kv(k_stack, k, layer, starts)
+            v_stack = _write_kv(v_stack, v, layer, starts)
         with jax.named_scope("attn.flash"):
             attn = attention(
-                q, k_cache, v_cache, causal=True, q_offset=starts,
+                q, k_stack, v_stack, causal=True, q_offset=starts,
                 kv_lens=kv_lens, impl=cfg.attn_impl, mesh=cfg.mesh,
+                layer=layer,
             )
-        merged = (k_cache, v_cache)
+        merged = (k_stack, v_stack)
 
     with jax.named_scope("attn.out"):
         x = x + _mm(attn.reshape(b, s, cfg.dim), p["wo"])
@@ -287,7 +309,13 @@ def transformer_forward(
 def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -> dict:
     """Cache layout [n_layers, B, max_seq, n_kv_heads, head_dim] with
     per-request ``lengths`` [B]. ``max_seq`` must not exceed cfg.max_seq
-    (the RoPE table bounds valid positions)."""
+    (the RoPE table bounds valid positions).
+
+    The two stacks are ONE buffer each for as long as a program runs:
+    every cached forward carries them through its layer loop (and a chunk
+    through its step loop), writes a token's k/v at [layer, row, position]
+    and reads a layer at a time out of the stack (``_run_cached``). A
+    caller that donates the cache gets the same buffer back."""
     max_seq = max_seq or cfg.max_seq
     if max_seq > cfg.max_seq:
         raise ValueError(
@@ -308,7 +336,8 @@ def _run_cached(
     """Shared cached-forward body (prefill, decode, and the speculative
     verify all run THIS): ``tokens`` [B, S] starting at per-request
     ``cache['lengths']``. Returns the final-norm hidden states [B, S, D],
-    the updated k/v stacks, and ``starts`` [B].
+    the k/v stacks — the buffers that came in, with this call's tokens
+    written into them — and ``starts`` [B].
 
     Keys valid for query j of request b: cache positions <= starts_b + j
     (causal handles the per-query bound; kv_lens bounds the written region
@@ -321,16 +350,22 @@ def _run_cached(
         x = params["embed"][tokens]
     written = starts + s  # [B]
 
+    # the stacks ride the layer loop's CARRY: a scan's ys is a fresh
+    # buffer, so stacks passed as xs/ys are copied slab by slab every
+    # call, and whole at the carry of any loop around this one
     def body(carry, inputs):
-        layer_params, k_cache, v_cache = inputs
-        y, (k_cache, v_cache), _ = _block(
-            cfg, layer_params, carry, freqs, positions,
-            kv_cache=(k_cache, v_cache), starts=starts, kv_lens=written,
+        x, k_stack, v_stack = carry
+        layer_params, layer = inputs
+        y, (k_stack, v_stack), _ = _block(
+            cfg, layer_params, x, freqs, positions,
+            kv_cache=(k_stack, v_stack), layer=layer, starts=starts,
+            kv_lens=written,
         )
-        return y, (k_cache, v_cache)
+        return (y, k_stack, v_stack), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
+    (x, k_new, v_new), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
     )
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["norm_f"], cfg.norm_eps)
@@ -707,13 +742,9 @@ def decode_chunk_pool(
                 logits, s, temperature, top_k, top_p, min_p
             )
             lp, tv, ti = _lp_outputs(logits, nxt)
-        # the per-step hand-over: this step's whole pool cache is the
-        # next step's input (the scan's carry). The whole-cache copies
-        # the compiler puts in at this loop carry the loop's own scope,
-        # not this one (PERF.md section 5).
-        with jax.named_scope("pool.cache_handover"):
-            carry = (nxt[:, None], c, k)
-        return carry, (nxt, lp, tv, ti)
+        # the cache is this loop's carry and the layer loop's too
+        # (_run_cached): the step hands on the buffer it was given
+        return (nxt[:, None], c, k), (nxt, lp, tv, ti)
 
     (tok, cache, _), (toks, lps, tvals, tids) = jax.lax.scan(
         body, (token, cache, sub), None, length=n_steps
@@ -808,9 +839,7 @@ def decode_chunk_pool_penalized(
             lp, tv, ti = _lp_outputs(logits, nxt)
             pres = update_presence(pres, nxt)
             cnt = update_counts(cnt, nxt)
-        with jax.named_scope("pool.cache_handover"):
-            carry = (nxt[:, None], c, k, pres, cnt)
-        return carry, (nxt, lp, tv, ti)
+        return (nxt[:, None], c, k, pres, cnt), (nxt, lp, tv, ti)
 
     (tok, cache, _, presence, counts), (toks, lps, tvals, tids) = jax.lax.scan(
         body, (token, cache, sub, presence, counts), None, length=n_steps

@@ -6,7 +6,10 @@
   runs in interpret mode on non-TPU backends so tests cover the kernel.
 - ``impl="auto"``: pallas on TPU when shapes are tile-friendly, else XLA.
 
-Layouts: q [B, Sq, Hq, D]; k, v [B, Skv, Hkv, D]; Hq % Hkv == 0.
+Layouts: q [B, Sq, Hq, D]; k, v [B, Skv, Hkv, D]; Hq % Hkv == 0. With
+``layer`` (an int32 scalar) k and v are the whole stacked KV cache
+[L, B, Skv, Hkv, D] and attention reads that layer of it: the Pallas
+kernel indexes the stack itself, the XLA path slices it.
 ``q_offset`` positions the query block absolutely (decode: cache length).
 ``kv_lens`` [B] bounds the valid key prefix (padded/unwritten cache tail)
 — the structured form of a padding mask, supported by both paths.
@@ -38,18 +41,25 @@ def attention(
     scale: Optional[float] = None,
     impl: str = "auto",
     mesh: Optional[Any] = None,
+    layer: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    if k.dtype != q.dtype:
-        # low-precision KV cache (float8_e4m3fn via cfg.kv_dtype): upcast
-        # at the attention boundary — capacity is the win (2x tokens per
-        # HBM byte); a fused low-precision cache read in the kernel is the
-        # follow-on traffic optimization
-        k = k.astype(q.dtype)
-        v = v.astype(q.dtype)
     if impl == "auto":
         # arbitrary masks stay on the XLA path (kv_lens is fine: the flash
         # kernel bounds its KV loop with it)
         impl = "pallas" if (mask is None and _pallas_ok(q, k)) else "xla"
+    if layer is not None and (impl != "pallas" or k.dtype != q.dtype):
+        # the XLA path, and a low-precision cache on either path, read
+        # their layer by a slice the compiler is free to fuse
+        k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+        v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+        layer = None
+    if k.dtype != q.dtype:
+        # low-precision KV cache (float8_e4m3fn via cfg.kv_dtype): upcast
+        # at the attention boundary, one layer at a time — capacity is the
+        # win (2x tokens per HBM byte); a fused low-precision cache read
+        # in the kernel is the follow-on traffic optimization
+        k = k.astype(q.dtype)
+        v = v.astype(q.dtype)
     if impl == "pallas":
         if mask is not None:
             raise NotImplementedError(
@@ -58,9 +68,12 @@ def attention(
         from gofr_tpu.ops.flash import flash_attention
 
         if mesh is not None:
-            return _sharded_flash(q, k, v, causal, q_offset, kv_lens, scale, mesh)
+            return _sharded_flash(
+                q, k, v, causal, q_offset, kv_lens, scale, mesh, layer
+            )
         return flash_attention(
-            q, k, v, causal=causal, q_offset=q_offset, kv_lens=kv_lens, scale=scale
+            q, k, v, causal=causal, q_offset=q_offset, kv_lens=kv_lens,
+            scale=scale, layer=layer,
         )
     if kv_lens is not None:
         len_mask = jnp.arange(k.shape[1])[None, :] < kv_lens[:, None]  # [B, Skv]
@@ -82,13 +95,15 @@ def _sharded_flash(
     kv_lens: Optional[jnp.ndarray],
     scale: Optional[float],
     mesh: Any,
+    layer: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """The flash kernel under a serving mesh. Mosaic lowering refuses a
     kernel inside a GSPMD-partitioned jit ("cannot be automatically
     partitioned"), so the kernel runs once per shard: batch rows over
     (dp, fsdp), heads over tp. Query and KV heads split alike, so every
     shard keeps whole GQA groups, and attention mixes neither axis — no
-    collective is needed."""
+    collective is needed. Stacked k/v (``layer`` given) carry the layer
+    axis in front, never sharded; the index itself is replicated."""
     from jax.sharding import PartitionSpec as P
 
     from gofr_tpu.ops.flash import _normalize_scalars, flash_attention
@@ -96,24 +111,28 @@ def _sharded_flash(
     offsets, lens = _normalize_scalars(q, k, q_offset, kv_lens)
     rows = P(("dp", "fsdp"))
     heads = P(("dp", "fsdp"), None, "tp", None)
+    kv_heads, index = (heads, None) if layer is None else (P(None, *heads), P())
 
-    def per_shard(q_, k_, v_, offsets_, lens_):
+    def per_shard(q_, k_, v_, offsets_, lens_, layer_):
         return flash_attention(
             q_, k_, v_, causal=causal, q_offset=offsets_, kv_lens=lens_,
-            scale=scale,
+            scale=scale, layer=layer_,
         )
 
     return jax.shard_map(
-        per_shard, mesh=mesh, in_specs=(heads, heads, heads, rows, rows),
+        per_shard, mesh=mesh,
+        in_specs=(heads, kv_heads, kv_heads, rows, rows, index),
         out_specs=heads, check_vma=False,
-    )(q, k, v, offsets, lens)
+    )(q, k, v, offsets, lens, layer)
 
 
 def _pallas_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
+    """``k`` [B, Skv, Hkv, D], or the stacked cache with a layer axis in
+    front: only Skv is read."""
     if jax.default_backend() not in ("tpu",):
         return False
     b, sq, hq, d = q.shape
-    skv = k.shape[1]
+    skv = k.shape[-3]
     if sq == 1 and skv < 2048:
         # short-cache decode: per-layer kernel launch overhead outweighs
         # the bounded-KV-loop win (measured on llama3-8b int8, 512-slot

@@ -11,7 +11,9 @@ softmax in VMEM):
 - K/V for one (batch, kv-head) live whole in VMEM (max_seq 8192 × 128 in
   bf16 = 2 MiB each, well under the ~16 MiB budget); Q is tiled ``block_q``
   rows at a time. GQA maps query head → kv head in the BlockSpec index map,
-  so repeated KV heads are never materialized.
+  so repeated KV heads are never materialized. The forward kernel reads
+  K/V out of a stack [L, B, Skv, Hkv, D] at a layer index (a third
+  scalar-prefetch value): the serving path hands it the whole KV cache.
 - per-batch scalars (``q_offset`` for ragged decode positions, ``kv_lens``
   bounding the valid cache prefix) ride scalar prefetch
   (``PrefetchScalarGridSpec``) — available before the body for the
@@ -55,9 +57,10 @@ DEFAULT_BLOCK_KV = 128
 def _kernel(
     offs_ref,  # [B] int32 scalar-prefetch: absolute position of q row 0
     lens_ref,  # [B] int32 scalar-prefetch: valid KV prefix length
+    layer_ref,  # [1] int32 scalar-prefetch: read by the K/V index maps only
     q_ref,  # [1, 1, block_q, D]
-    k_ref,  # [1, 1, Skv_pad, D]
-    v_ref,  # [1, 1, Skv_pad, D]
+    k_ref,  # [1, 1, 1, Skv_pad, D]: one (layer, row, kv head) of the stack
+    v_ref,  # [1, 1, 1, Skv_pad, D]
     out_ref,  # [1, 1, block_q, D]
     lse_ref,  # [1, 1, block_q, 1] f32: per-row logsumexp (backward
     # residual). The trailing singleton is a TPU tiling requirement: the
@@ -72,6 +75,7 @@ def _kernel(
     block_kv: int,
     num_kv_blocks: int,
 ):
+    del layer_ref
     b = pl.program_id(0)
     qi = pl.program_id(2)
 
@@ -99,8 +103,8 @@ def _kernel(
 
     def body(j, carry):
         m_prev, l_prev, acc_prev = carry
-        kb = k_ref[0, 0, pl.ds(j * block_kv, block_kv), :]  # [block_kv, D]
-        vb = v_ref[0, 0, pl.ds(j * block_kv, block_kv), :]
+        kb = k_ref[0, 0, 0, pl.ds(j * block_kv, block_kv), :]  # [block_kv, D]
+        vb = v_ref[0, 0, 0, pl.ds(j * block_kv, block_kv), :]
 
         s = jax.lax.dot_general(
             qb,
@@ -160,20 +164,36 @@ def _flash_fwd_impl(
     v: jnp.ndarray,
     offsets: jnp.ndarray,
     kv_lens: jnp.ndarray,
+    layer: jnp.ndarray,
     causal: bool,
     scale: float,
     block_q: int,
     block_kv: int,
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``k``, ``v`` [L, B, Skv, Hkv, D] are the stacked KV cache, read at
+    ``layer`` [1] int32 by the kernel's own BlockSpecs (an unstacked k/v
+    comes in as a stack of one).
+
+    The kernel takes the stack as its transpose [L, B, Hkv, Skv, D] and
+    picks the (Skv, D) block of (layer, row, kv head) out of it. Written
+    as a transpose of the whole stack, it costs none inside a program's
+    loops: the compiler gives the loop-carried cache that physical layout
+    ({4,2,3,1,0}) and the transpose becomes a bitcast, so no slice of the
+    layer and no copy of K or V stands in front of the kernel. The program
+    pays one relayout of the cache where it enters and one where it leaves
+    (a chunk, not a step or a layer). The row-major "free" view [L, B,
+    Skv, Hkv·D] is not free under (8, 128) tiling: compiled for the v5e it
+    is a reshape of the whole stack in every layer."""
     b, sq, hq, d = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    n_layers, _, skv, hkv, _ = k.shape
     groups = hq // hkv
 
-    # [B, H, S, D] layout: the kernel tiles (sublane=seq, lane=head_dim)
+    # q and the output in [B, H, S, D]: the kernel tiles (sublane=seq,
+    # lane=head_dim)
     qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    kt = jnp.swapaxes(k, 2, 3)  # [L, B, Hkv, Skv, D]
+    vt = jnp.swapaxes(v, 2, 3)
 
     # sublane floor 16 covers the bf16 min tile (f32 needs only 8); sq=1
     # decode pads its q block rather than falling back to XLA
@@ -182,26 +202,25 @@ def _flash_fwd_impl(
     sq_pad = pl.cdiv(sq, block_q) * block_q
     skv_pad = pl.cdiv(skv, block_kv) * block_kv
     qt = _pad_axis(qt, 2, sq_pad)
-    kt = _pad_axis(kt, 2, skv_pad)
-    vt = _pad_axis(vt, 2, skv_pad)
+    # a no-op for a cache (its length is a multiple of the block): padding
+    # a stack would copy it
+    kt = _pad_axis(kt, 3, skv_pad)
+    vt = _pad_axis(vt, 3, skv_pad)
     num_q_blocks = sq_pad // block_q
     num_kv_blocks = skv_pad // block_kv
 
+    def kv_block(bi, h, qi, offs, lens, lay):
+        return (lay[0], bi, h // groups, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hq, num_q_blocks),
         in_specs=[
             pl.BlockSpec(
                 (1, 1, block_q, d), lambda bi, h, qi, *_: (bi, h, qi, 0)
             ),
-            pl.BlockSpec(
-                (1, 1, skv_pad, d),
-                lambda bi, h, qi, *_, g=groups: (bi, h // g, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, skv_pad, d),
-                lambda bi, h, qi, *_, g=groups: (bi, h // g, 0, 0),
-            ),
+            pl.BlockSpec((1, 1, 1, skv_pad, d), kv_block),
+            pl.BlockSpec((1, 1, 1, skv_pad, d), kv_block),
         ],
         out_specs=[
             pl.BlockSpec(
@@ -231,10 +250,12 @@ def _flash_fwd_impl(
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=4 * b * hq * sq * skv * d,
-            bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
+            bytes_accessed=(
+                q.size + (k.size + v.size) // n_layers
+            ) * q.dtype.itemsize,
             transcendentals=b * hq * sq * skv,
         ),
-    )(offsets, kv_lens, qt, kt, vt)
+    )(offsets, kv_lens, layer, qt, kt, vt)
     return jnp.swapaxes(out[:, :, :sq, :], 1, 2), lse[:, :, :sq, 0]
 
 
@@ -548,7 +569,7 @@ def _normalize_scalars(
     q_offset: int | jnp.ndarray,
     kv_lens: Optional[jnp.ndarray],
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    b, skv = q.shape[0], k.shape[1]
+    b, skv = q.shape[0], k.shape[-3]  # k may carry a layer axis in front
     offsets = jnp.asarray(q_offset, jnp.int32)
     if offsets.ndim == 0:
         offsets = jnp.full((b,), offsets, jnp.int32)
@@ -617,16 +638,20 @@ def _blockwise_reference(q, k, v, offsets, kv_lens, causal, scale,
 FUSED_BWD = True
 
 
+_LAYER_0 = np.zeros((1,), np.int32)  # an unstacked k/v is a stack of one
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash(q, k, v, offsets, kv_lens, causal, scale, block_q, block_kv, interpret):
-    return _flash_fwd_impl(
+    return _flash_fwd(
         q, k, v, offsets, kv_lens, causal, scale, block_q, block_kv, interpret
     )[0]
 
 
 def _flash_fwd(q, k, v, offsets, kv_lens, causal, scale, block_q, block_kv, interpret):
     out, lse = _flash_fwd_impl(
-        q, k, v, offsets, kv_lens, causal, scale, block_q, block_kv, interpret
+        q, k[None], v[None], offsets, kv_lens, _LAYER_0,
+        causal, scale, block_q, block_kv, interpret,
     )
     return out, (q, k, v, offsets, kv_lens, out, lse)
 
@@ -671,6 +696,7 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
     interpret: Optional[bool] = None,
+    layer: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Flash attention. q [B, Sq, Hq, D]; k, v [B, Skv, Hkv, D].
 
@@ -679,12 +705,22 @@ def flash_attention(
     (padded/unwritten cache tail is masked). Differentiable via the fused
     backward kernels (gradients flow to q, k, v; not to the position
     scalars).
+
+    ``layer`` (int32 scalar): k, v are the stacked KV cache [L, B, Skv,
+    Hkv, D] and the kernel reads that layer of it (the serving path: no
+    gradient is defined through the stack).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     offsets, lens = _normalize_scalars(q, k, q_offset, kv_lens)
-    return _flash(
-        q, k, v, offsets, lens, causal, float(scale), block_q, block_kv, interpret
-    )
+    if layer is None:
+        return _flash(
+            q, k, v, offsets, lens, causal, float(scale), block_q, block_kv,
+            interpret,
+        )
+    return _flash_fwd_impl(
+        q, k, v, offsets, lens, jnp.asarray(layer, jnp.int32).reshape(1),
+        causal, float(scale), block_q, block_kv, interpret,
+    )[0]

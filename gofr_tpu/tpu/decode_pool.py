@@ -279,7 +279,10 @@ class DecodePool:
         self._last_tokens = self._replicate(jnp.zeros((n_slots, 1), jnp.int32))
         # donate the cache through both ops: the pool cache is the largest
         # live buffer and must be updated in place, not copied per chunk.
-        # The key also donates (it threads through every chunk).
+        # Inside the chunk the step loop and the layer loop carry that
+        # same buffer (models/transformer.py::_run_cached), so no step
+        # copies it either. The key also donates (it threads through
+        # every chunk).
         self._decode = jax.jit(
             lambda p, t, c, key, temp, tk, tp, mp: decode_chunk_pool(
                 p, t, c, cfg, chunk, key, temp, tk, tp, mp

@@ -1,0 +1,116 @@
+"""Compile the cached forward for a described TPU v5e (no chip attached)
+and read what the chip's compiler made of the KV cache: inside the
+program's loops nothing copies, reshapes or slices it; the Pallas kernel
+reads the loop-carried buffer itself. What the CPU backend and interpret
+mode cannot show. A compile is not a run: no time is read here.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU's library, and every xdist worker
+imports every test file."""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from gofr_tpu.models import transformer as T
+
+# InternLM2-1.8B's widths (benchmark/configs) with two layers and a small
+# vocabulary (the sampler is most of the compile): what the
+# compiler does to the cache depends on neither
+CFG = T.TransformerConfig(
+    vocab_size=4096, dim=2048, n_layers=2, n_heads=16, n_kv_heads=8,
+    hidden_dim=8192, max_seq=2048, rope_theta=1e6,
+)
+SLOTS = 4
+_MOVERS = ("copy", "reshape", "transpose", "dynamic-slice", "fusion")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_on_tpu(monkeypatch):
+    """``attention()`` asks the default backend whether the Pallas path
+    runs and whether to interpret it: answer as the chip would. The
+    server's matmul precision, not the ``highest`` that conftest.py sets
+    for CPU numerics (Mosaic takes no fp32 contraction of bf16)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
+    )
+
+
+def _compiled(fn, donate, one_chip, *trees):
+    args = [_shapes(jax.eval_shape(lambda t=t: t() if callable(t) else t), one_chip)
+            for t in trees]
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+
+
+def _cache_movers(hlo: str, batch: int) -> dict[str, list[str]]:
+    """computation name -> the instructions in it that write a result of
+    the whole cache's or one layer slab's shape by moving data."""
+    slab = f"{batch},{CFG.max_seq},{CFG.n_kv_heads},{CFG.head_dim}"
+    shapes = (f"bf16[{CFG.n_layers},{slab}]", f"bf16[1,{slab}]", f"bf16[{slab}]")
+    found: dict[str, list[str]] = {}
+    computation = ""
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            computation = "ENTRY" if head.group(1) else head.group(2)
+            continue
+        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
+        if match and match.group(2) in shapes and match.group(3) in _MOVERS:
+            found.setdefault(computation, []).append(match.group(1))
+    return found
+
+
+def _abstract_params():
+    return lambda: T.init_transformer(jax.random.key(0), CFG)
+
+
+def test_pooled_chunk_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu):
+    hlo = _compiled(
+        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
+            p, t, c, CFG, 8, key, temp, tk, tp, mp),
+        (2, 3), one_chip,
+        _abstract_params(), jnp.zeros((SLOTS, 1), jnp.int32),
+        lambda: T.init_cache(CFG, SLOTS), lambda: jax.random.key(0),
+        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
+        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.float32),
+    )
+    assert "tpu_custom_call" in hlo  # the Mosaic kernel, not interpret mode
+    movers = _cache_movers(hlo, SLOTS)
+    # K and V are relaid into the layout the kernel reads once where the
+    # chunk begins and once where it ends: a chunk, not a step or a layer
+    assert set(movers) <= {"ENTRY"}, movers
+    assert len(movers.get("ENTRY", [])) <= 4, movers
+
+
+def test_prefill_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu):
+    rows = 2
+    hlo = _compiled(
+        lambda p, t, c, l: T.prefill(p, t, c, CFG, l), (2,), one_chip,
+        _abstract_params(), jnp.zeros((rows, 512), jnp.int32),
+        lambda: T.init_cache(CFG, rows), jnp.zeros((rows,), jnp.int32),
+    )
+    assert "tpu_custom_call" in hlo
+    movers = _cache_movers(hlo, rows)
+    assert set(movers) <= {"ENTRY"}, movers
+    assert len(movers.get("ENTRY", [])) <= 4, movers

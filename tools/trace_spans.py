@@ -2,7 +2,7 @@
 profiler trace, by hand, on a trace a benchmark run kept:
 
     BENCH_KEEP_TRACE=1 python -m benchmark.run --workload <cell> --seed 7 --seconds 51 --trace 1
-    python tools/trace_spans.py benchmark/out/<cell>.7.1.trace [--ops 10] [--json]
+    python tools/trace_spans.py benchmark/out/<cell>.7.1.trace [--ops 10] [--shape S] [--json]
 
 The host plane (``/host:CPU``) holds the ``gofr.*`` annotations of
 gofr_tpu/profiling.py, each tagged with its ``dispatch_id``; the device
@@ -14,7 +14,9 @@ began: a fetch that blocked returns right behind its program), the wait
 from the end of the issue to the start of the run, and the pooled decode
 chunks the device finished in that wait. ``--ops`` also lists the device
 operations that took most time, each with the scope (``jax.named_scope``
-path) and the line of source the compiler recorded for it.
+path) and the line of source the compiler recorded for it; ``--shape
+bf16[32,6,2048,8,128]`` lists the operations that write a result of that
+shape (the compiler's own copies carry no scope: their shape finds them).
 
 Reads the trace with ``benchmark.trace_reduce`` and ``jax.profiler.ProfileData``
 alone; nothing here imports a TPU library or tensorflow. The scope and the
@@ -28,6 +30,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import statistics
 import sys
 from typing import Any, Iterator, Optional
@@ -198,33 +201,59 @@ def op_metadata(raw: bytes) -> dict[str, dict[str, str]]:
     return {}
 
 
-def op_scopes(data: Any, top: int, metadata: dict[str, dict[str, str]]) -> list[dict]:
+_SHAPE = re.compile(r"[a-z]+\d*\[[\d,]*\]")
+
+
+def result_shapes(text: str) -> list[str]:
+    """The result shapes of an HLO instruction's text, layouts left out:
+    ``%copy.2 = bf16[8,128]{1,0} copy(...)`` -> ``["bf16[8,128]"]``; a
+    tuple-shaped result gives one shape for each element."""
+    _, sep, rest = text.partition(" = ")
+    if not sep:
+        return []
+    found = TR._OPCODE.search(rest)
+    return _SHAPE.findall(rest[:found.start() + 1] if found else rest)
+
+
+def op_scopes(data: Any, top: int, metadata: dict[str, dict[str, str]],
+              shapes: tuple[str, ...] = ()) -> list[dict]:
     """The ``top`` device operations by time (the labels of
-    ``trace_reduce``'s ``device_ops``), each with its scope and source."""
+    ``trace_reduce``'s ``device_ops``), each with its scope, its source and
+    the number of times it ran. With ``shapes``, only the operations one of
+    whose results has one of those shapes (``bf16[32,6,2048,8,128]``): an
+    operation no ``named_scope`` reaches is found by what it writes; ``top``
+    0 then lists them all."""
     line = device_lines(data).get(TR.OPS_LINE)
-    seconds: dict[str, float] = {}  # by instruction text: a million events, some hundred texts
+    totals: dict[str, list] = {}  # by instruction text: a million events, some hundred texts
     for ev in (line.events if line is not None else []):
-        seconds[ev.name] = seconds.get(ev.name, 0.0) + ev.duration_ns * 1e-9
+        slot = totals.setdefault(ev.name, [0.0, 0])
+        slot[0] += ev.duration_ns * 1e-9
+        slot[1] += 1
     by_op: dict[str, dict] = {}
-    for text, total in seconds.items():
+    for text, (total, count) in totals.items():
         label, opcode = TR.short_op(text)
         if opcode in TR.CONTAINERS:
             continue
+        if shapes and not set(shapes) & set(result_shapes(text)):
+            continue
         stats = metadata.get(text, {})
-        slot = by_op.setdefault(label, {"op": label, "seconds": 0.0,
+        slot = by_op.setdefault(label, {"op": label, "seconds": 0.0, "count": 0,
                                         "scope": stats.get("tf_op", ""),
                                         "source": stats.get("source", "")})
         slot["seconds"] += total
-    return sorted(by_op.values(), key=lambda s: -s["seconds"])[:top]
+        slot["count"] += count
+    ranked = sorted(by_op.values(), key=lambda s: -s["seconds"])
+    return ranked[:top] if top else ranked
 
 
 def _median(values: list[float]) -> Optional[float]:
     return statistics.median(values) if values else None
 
 
-def summary(data: Any, ops: int = 0, raw: bytes = b"") -> dict:
-    """The whole join; with ``ops``, ``raw`` is the serialized XSpace that
-    ``data`` was read from (for the operations' scopes)."""
+def summary(data: Any, ops: int = 0, raw: bytes = b"",
+            shapes: tuple[str, ...] = ()) -> dict:
+    """The whole join; with ``ops`` or ``shapes``, ``raw`` is the serialized
+    XSpace that ``data`` was read from (for the operations' scopes)."""
     runs = program_runs(data)
     rows = join(data, runs)
     out: dict[str, Any] = {
@@ -252,8 +281,8 @@ def summary(data: Any, ops: int = 0, raw: bytes = b"") -> dict:
             "chunks_in_wait_counts": dict(sorted(counts.items())),
             "rows": kind_rows,
         }
-    if ops:
-        out["ops"] = op_scopes(data, ops, op_metadata(raw))
+    if ops or shapes:
+        out["ops"] = op_scopes(data, ops, op_metadata(raw), shapes)
     return out
 
 
@@ -261,14 +290,17 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace", help="a kept trace directory or an .xplane.pb file")
     ap.add_argument("--ops", type=int, default=0, help="also list this many device operations")
+    ap.add_argument("--shape", action="append", default=[], metavar="TYPE[DIMS]",
+                    help="list only the operations with a result of this shape, e.g. "
+                         "bf16[32,6,2048,8,128] (repeatable; all of them unless --ops limits)")
     ap.add_argument("--json", action="store_true", help="print the whole join as JSON")
     args = ap.parse_args()
     path = args.trace if args.trace.endswith(".pb") else TR.find_xplane(args.trace)
     raw = b""
-    if args.ops:
+    if args.ops or args.shape:
         with open(path, "rb") as fh:
             raw = fh.read()
-    out = summary(TR.load(path), args.ops, raw)
+    out = summary(TR.load(path), args.ops, raw, tuple(args.shape))
     if args.json:
         print(json.dumps(out))
         return 0
@@ -284,7 +316,9 @@ def main() -> int:
               f"returned {k['after_run_ms_p50']:.2f}; pooled chunks finished in the wait: mean "
               f"{k['chunks_in_wait_mean']:.2f}, counts {k['chunks_in_wait_counts']}")
     for op in out.get("ops", []):
-        print(f"op {op['seconds']:.4f} s {op['op']} <- {op['scope']} {op['source']}")
+        print(f"op {op['seconds']:.4f} s x{op['count']} {op['op']} <- {op['scope']} {op['source']}")
+    if args.shape and not out["ops"]:
+        print(f"no device operation has a result of shape {' or '.join(args.shape)}")
     return 0
 
 
