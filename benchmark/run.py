@@ -4,8 +4,9 @@
 
 One process that holds the chip: it checks that the device is a TPU in the
 peaks table (there is no CPU fallback; ``--rehearse`` is the separately
-named CPU rehearsal the tests use, and it prints no metric), registers the
-cell's configuration, makes the weights from the seed, boots the server
+named CPU rehearsal the tests use, and it prints no metric), has the
+configuration's architecture (``benchmark/architectures/<name>.py``) register
+it and make the weights from the seed, boots the server
 in-process the way ``chip_smoke.py`` does with only the cell's buckets and
 rows warmed, starts the load generator as a child process that never
 imports JAX, lets it offer a short ramp of the schedule and then the window,
@@ -70,6 +71,8 @@ class Run:
 
     def __init__(self) -> None:
         self.manifest = self.cell = self.cfg = self.mix = self.load = None
+        self.arch = None  # the configuration's architecture module (spec.py)
+        self.log = log
         self.seed = 0
         self.seconds = 0.0
         self.trace_on = False
@@ -112,48 +115,6 @@ def free_port() -> int:
     with socket.socket() as sock:
         sock.bind(("127.0.0.1", 0))
         return sock.getsockname()[1]
-
-
-def register_config(run: Run) -> str:
-    """The configuration's published sizes as a ``TransformerConfig`` in the
-    program's table, and the seeded weights in place of the program's own
-    seeded init: the two seams the benchmark has into the program."""
-    import jax.numpy as jnp
-
-    import gofr_tpu.models.transformer as T
-    from benchmark import weights as W
-    from gofr_tpu.models.llama import CONFIGS
-
-    cfg, sz = run.cfg, run.sizes
-    name = cfg["_name"]
-    CONFIGS[name] = T.TransformerConfig(
-        vocab_size=sz["vocab"], dim=sz["dim"], n_layers=sz["layers"],
-        n_heads=sz["heads"], n_kv_heads=sz["kv_heads"], hidden_dim=sz["ffn"],
-        max_seq=cfg["max_position_embeddings"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), dtype=jnp.dtype(sz["dtype"]),
-    )
-    if CONFIGS[name].head_dim != sz["head_dim"]:
-        raise RunFailure(3, "the program derives head_dim as hidden/heads; "
-                         f"{name} states {sz['head_dim']}")
-
-    def seeded(key, model_cfg, quantize=False, mesh=None):
-        if (quantize or "") != sz["quant"]:
-            raise RunFailure(3, f"MODEL_QUANT {quantize!r} but the configuration "
-                             f"serves {sz['quant']!r}")
-        start = time.monotonic()
-        params = W.make_params(run.seed, sz)
-        if mesh is not None:
-            from gofr_tpu.parallel.sharding import shard_params
-
-            params = shard_params(params, mesh)
-        import jax
-
-        jax.block_until_ready(params)
-        log(f"weights from seed {run.seed}: {time.monotonic() - start:.2f}s")
-        return params
-
-    T.init_transformer = seeded
-    return name
 
 
 def boot(run: Run, deadline: float):
@@ -398,7 +359,7 @@ def log_dispatches(run: Run) -> None:
 def check_correct(run: Run, schedule_requests: dict, control: str | None) -> tuple[bool, list[dict]]:
     """Every request the window finished, every token it was served, against
     the plain reference. Prints each number beside its limit."""
-    from benchmark import reference
+    from benchmark.reference import served_gaps
 
     check = dict(run.mix["check"], **run.load["check"])
     limits = {"served_gap_mean": float(check["served_gap_mean_limit"]),
@@ -409,8 +370,8 @@ def check_correct(run: Run, schedule_requests: dict, control: str | None) -> tup
         return False, [{"name": n, "value": None, "limit": v} for n, v in limits.items()]
     pairs = [(schedule_requests[r["id"]]["prompt"], r["tokens"]) for r in done]
     start = time.monotonic()
-    got = reference.served_gaps(run.seed, run.cfg, pairs, check["widths"], check["rows"],
-                                check["scored"], control=control)
+    got = served_gaps(run.arch.logits_at, run.seed, run.cfg, pairs, check["widths"],
+                      check["rows"], check["scored"], control=control)
     took = time.monotonic() - start
     worst = done[int(got["sample"][int(got["gaps"].argmax())])]
     log(f"check: {got['gaps'].size} served tokens of {len(done)} requests, {got['agree']:.4f} are "
@@ -452,8 +413,8 @@ def prepare(args):
     check, configuration and weights registered. -> (run, devices,
     compiled, deadline, model); ``compiled`` grows by one name per XLA
     compile (or cache load) in this process."""
-    rehearse = args.rehearse
-    manifest = spec.load_manifest(REHEARSAL_MANIFEST if rehearse else None)
+    rehearse = bool(args.rehearse)
+    manifest = spec.load_manifest(args.rehearse)
     run = Run()
     run.manifest, run.seed, run.seconds = manifest, args.seed, float(args.seconds)
     run.trace_on = bool(args.trace)
@@ -511,16 +472,15 @@ def prepare(args):
     log(f"device: {platform} / {kind} x{len(devices)}; cache {cache_dir} "
         f"({'cold' if cold else 'warm'})")
 
-    from benchmark import weights as W
-
-    run.sizes = W.sizes_of(run.cfg)
+    run.arch = spec.load_architecture(manifest, run.cfg)
+    run.sizes = run.arch.sizes_of(run.cfg)
     serving = run.cfg["serving"]
     run.server_env = dict(serving["env"])
     run.server_env.update(run.load.get("env", {}))
     for pair in args.env or ():  # a control run's override, e.g. MODEL_KV_DTYPE=f8
         key, _, value = pair.partition("=")
         run.server_env[key] = value
-    model = register_config(run)
+    model = run.arch.register(run)
     run.server_env["MODEL_NAME"] = model
     run.server_env["MODEL_QUANT"] = serving["quant"]
     return run, devices, compiled, deadline, model
@@ -528,7 +488,7 @@ def prepare(args):
 
 def execute(args, out) -> int:
     run, devices, compiled, deadline, model = prepare(args)
-    rehearse = args.rehearse
+    rehearse = bool(args.rehearse)
     schedule = build_schedule(run.mix, run.load, run.sizes["vocab"], run.seed, run.seconds)
     requests_by_id = {r["id"]: r for r in schedule["requests"]}
 
@@ -571,7 +531,7 @@ def execute(args, out) -> int:
             "metrics": {} if rehearse else read_metrics(
                 run, "per_layer" if run.trace_on else "end_to_end"),
             "device": dict(run.device),
-            "check": numbers, "seed": run.seed, "workload": run.cell["name"],
+            "seed": run.seed, "workload": run.cell["name"],
         }
         if rehearse:
             result["rehearse"] = True
@@ -584,6 +544,7 @@ def execute(args, out) -> int:
             result["device"]["window_s"] = run.trace["window_s"]
             result["breakdown"] = {"device_ops": run.trace["device_ops"],
                                    "idle_gaps": run.trace["idle_gaps"]}
+        result["check"] = numbers  # each number compared beside its limit, last on the line
         print(json.dumps(result), file=out, flush=True)
     finally:
         if not stopped:
@@ -600,8 +561,10 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--rehearse", action="store_true",
-                    help="CPU rehearsal of the control flow on the rehearsal manifest")
+    ap.add_argument("--rehearse", nargs="?", const=REHEARSAL_MANIFEST, default=None,
+                    metavar="MANIFEST",
+                    help="CPU rehearsal of the control flow on the rehearsal manifest "
+                         "(or a copy of it with files added)")
     ap.add_argument("--control", default=None,
                     help="also read the reference computed in this lower precision")
     ap.add_argument("--env", action="append",

@@ -32,7 +32,7 @@ def main() -> int:
     ap.add_argument("--rates", required=True)
     ap.add_argument("--env", action="append")
     args = ap.parse_args()
-    args.rehearse, args.trace, args.control = False, 0, None
+    args.rehearse, args.trace, args.control = None, 0, None
     args.limit_s = 3000.0  # many windows in one process
     out = os.fdopen(os.dup(1), "w")
     os.dup2(2, 1)
