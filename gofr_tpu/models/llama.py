@@ -45,6 +45,21 @@ TINY = TransformerConfig(
     attn_impl="xla",
 )
 
+# TINY with power-retention layers (a state per row, no K/V): CPU tests
+TINY_RETENTION = TransformerConfig(
+    vocab_size=256,
+    dim=64,
+    n_layers=2,
+    n_heads=4,
+    n_kv_heads=2,
+    hidden_dim=128,
+    max_seq=128,
+    rope_theta=10000.0,
+    dtype=jnp.float32,
+    attn_impl="xla",
+    attn_kind="retention",
+)
+
 # Small-but-realistic single-chip bench model (fits v5e-1 in bf16 and
 # exercises the same kernels/shapes class as 8B)
 SMALL = TransformerConfig(
@@ -60,6 +75,7 @@ SMALL = TransformerConfig(
 
 CONFIGS: dict[str, TransformerConfig] = {
     "tiny": TINY,
+    "tiny-retention": TINY_RETENTION,
     "small": SMALL,
     "llama3-8b": LLAMA3_8B,
     "llama3-70b": LLAMA3_70B,

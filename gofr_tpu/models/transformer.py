@@ -53,6 +53,12 @@ class TransformerConfig:
     # single device). Only attention reads it: a Mosaic kernel must be
     # shard_mapped, GSPMD cannot partition it (ops/attention.py).
     mesh: Any = None
+    # what a layer attends with. "softmax": causal softmax attention over a
+    # K/V cache that grows by the token. "retention": power retention
+    # (ops/retention.py): RMSNorm over each head of q and k, a gate per kv
+    # head (leaves q_norm, k_norm, w_g), and a cache that is a fixed-size
+    # state per row, float32 unless kv_dtype says otherwise.
+    attn_kind: str = "softmax"
 
     @property
     def head_dim(self) -> int:
@@ -60,6 +66,8 @@ class TransformerConfig:
 
     @property
     def cache_dtype(self) -> Any:
+        if self.attn_kind == "retention":
+            return self.kv_dtype or jnp.float32
         return self.kv_dtype or self.dtype
 
 
@@ -152,6 +160,17 @@ def init_transformer(
     })
     kv_dim = cfg.n_kv_heads * cfg.head_dim
 
+    def retention_leaves(i: int) -> dict:
+        # keys of their own, so the dense leaves' values stay what they were
+        k = jax.random.fold_in(jax.random.fold_in(key, n_keys), i)
+        return {
+            "q_norm": jnp.ones((cfg.head_dim,), cfg.dtype),
+            "k_norm": jnp.ones((cfg.head_dim,), cfg.dtype),
+            # the gate stays dense (dim x n_kv_heads: a few KB a layer)
+            "w_g": (jax.random.truncated_normal(k, -3, 3, (cfg.dim, cfg.n_kv_heads))
+                    * (cfg.dim ** -0.5)).astype(cfg.dtype),
+        }
+
     def make_layer() -> dict:
         return {
             "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
@@ -174,7 +193,10 @@ def init_transformer(
     # (Quantized {"q","scale"} dicts thread per-field through the tree maps.)
     stacked = None
     for i in range(cfg.n_layers):
-        layer = put(make_layer())
+        layer = make_layer()
+        if cfg.attn_kind == "retention":
+            layer.update(retention_leaves(i))
+        layer = put(layer)
         if stacked is None:
             stacked = jax.tree.map(stack_like, layer)
         stacked = jax.tree.map(
@@ -224,6 +246,8 @@ def _block(
     kv_lens: Optional[jnp.ndarray] = None,
     attn_fn: Optional[Any] = None,
     mlp_fn: Optional[Any] = None,
+    valid: Optional[jnp.ndarray] = None,
+    live: Optional[jnp.ndarray] = None,
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray], dict]:
     """One decoder block — the single implementation shared by the
     no-cache forward, the cached prefill/decode path, the sequence-parallel
@@ -238,6 +262,13 @@ def _block(
     the stacks is touched; attention reads its layer out of the stack over
     the full cache window. Returns (out, (k_stack, v_stack), aux): the
     buffers that came in, so the caller's loops carry them in place.
+
+    ``cfg.attn_kind == "retention"``: ``kv_cache`` is the stacked state
+    (S, z) instead (ops/retention.py) and ``valid`` [B, S] says which of
+    this call's tokens are real: a state has no "past the length" for
+    bucket padding to be dead in, so a pad token must not enter it.
+    ``live`` [B] says which rows hold a request (the decode pool's slots):
+    the one-token step moves no state for the others.
     """
     # the named scopes are names only (HLO op metadata: a device
     # operation in a profiler trace then says which of these lines it
@@ -248,11 +279,34 @@ def _block(
         q = _mm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
         k = _mm(h, p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
         v = _mm(h, p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.attn_kind == "retention":
+        with jax.named_scope("attn.qk_norm"):
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     with jax.named_scope("attn.rope"):
         q = apply_rope(q, freqs, positions)
         k = apply_rope(k, freqs, positions)
 
-    if kv_cache is None:
+    if cfg.attn_kind == "retention":
+        from gofr_tpu.ops import retention
+
+        with jax.named_scope("attn.gate"):
+            log_g = jax.nn.log_sigmoid(jnp.einsum(
+                "bsd,dh->bsh", h.astype(jnp.float32), p["w_g"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            ))
+        if kv_cache is None:
+            with jax.named_scope("attn.retention.chunk"):
+                attn = retention.retention_attention(q, k, v, log_g, valid)
+            merged = (k, v)
+        else:
+            attn, s_stack, z_stack = retention.retention_cached(
+                q, k, v, log_g, *kv_cache, layer, valid=valid,
+                impl=cfg.attn_impl, live=live,
+            )
+            merged = (s_stack, z_stack)
+        attn = attn.astype(x.dtype)
+    elif kv_cache is None:
         with jax.named_scope("attn.flash"):
             if attn_fn is not None:
                 attn = attn_fn(q, k, v)
@@ -322,6 +376,16 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
             f"cache max_seq {max_seq} exceeds config max_seq {cfg.max_seq} "
             "(RoPE table bound)"
         )
+    if cfg.attn_kind == "retention":
+        # a state per row, whatever the context: no length axis
+        from gofr_tpu.ops.retention import init_state
+
+        s, z = init_state(batch, cfg.n_kv_heads, cfg.head_dim, cfg.cache_dtype,
+                          layers=cfg.n_layers)
+        # ``live``: the rows that hold a request. The decode pool keeps it
+        # to its active slots; every row of a prefill's cache is live.
+        return {"s": s, "z": z, "lengths": jnp.zeros((batch,), jnp.int32),
+                "live": jnp.ones((batch,), jnp.int32)}
     shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, cfg.cache_dtype),
@@ -330,18 +394,29 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
     }
 
 
+def cache_leaves(cache: dict) -> tuple[str, ...]:
+    """The names of a cache's device state: ``k`` and ``v``, or a retention
+    model's ``s`` and ``z``; every one has the row (slot) axis second. The
+    per-row vectors ride beside them: ``lengths`` [B], and for a state
+    ``live`` [B]."""
+    return tuple(sorted(name for name, leaf in cache.items() if leaf.ndim > 1))
+
+
 def _run_cached(
-    params: dict, tokens: jnp.ndarray, cache: dict, cfg: TransformerConfig
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    params: dict, tokens: jnp.ndarray, cache: dict, cfg: TransformerConfig,
+    lengths: Optional[jnp.ndarray] = None,
+) -> tuple[jnp.ndarray, dict, jnp.ndarray]:
     """Shared cached-forward body (prefill, decode, and the speculative
     verify all run THIS): ``tokens`` [B, S] starting at per-request
     ``cache['lengths']``. Returns the final-norm hidden states [B, S, D],
-    the k/v stacks — the buffers that came in, with this call's tokens
-    written into them — and ``starts`` [B].
+    the cache's stacks by name — the buffers that came in, with this
+    call's tokens written into them — and ``starts`` [B].
 
     Keys valid for query j of request b: cache positions <= starts_b + j
     (causal handles the per-query bound; kv_lens bounds the written region
-    so never-written cache slots are excluded)."""
+    so never-written cache slots are excluded). A retention state has no
+    such region: there ``lengths`` (this call's real tokens per row) keeps
+    bucket padding out of the state."""
     b, s = tokens.shape
     starts = cache["lengths"]  # [B]
     freqs = jnp.asarray(_cached_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta))
@@ -349,27 +424,31 @@ def _run_cached(
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
     written = starts + s  # [B]
+    valid = None
+    if cfg.attn_kind == "retention" and lengths is not None:
+        valid = jnp.arange(s)[None, :] < lengths[:, None]
+    names = cache_leaves(cache)
 
     # the stacks ride the layer loop's CARRY: a scan's ys is a fresh
     # buffer, so stacks passed as xs/ys are copied slab by slab every
     # call, and whole at the carry of any loop around this one
     def body(carry, inputs):
-        x, k_stack, v_stack = carry
+        x, stacks = carry
         layer_params, layer = inputs
-        y, (k_stack, v_stack), _ = _block(
+        y, stacks, _ = _block(
             cfg, layer_params, x, freqs, positions,
-            kv_cache=(k_stack, v_stack), layer=layer, starts=starts,
-            kv_lens=written,
+            kv_cache=stacks, layer=layer, starts=starts,
+            kv_lens=written, valid=valid, live=cache.get("live"),
         )
-        return (y, k_stack, v_stack), None
+        return (y, stacks), None
 
-    (x, k_new, v_new), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
+    (x, stacks), _ = jax.lax.scan(
+        body, (x, tuple(cache[name] for name in names)),
         (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
     )
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["norm_f"], cfg.norm_eps)
-    return x, k_new, v_new, starts
+    return x, dict(zip(names, stacks)), starts
 
 
 def _forward_with_cache(
@@ -386,7 +465,9 @@ def _forward_with_cache(
     b, s = tokens.shape
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
-    x, k_new, v_new, starts = _run_cached(params, tokens, cache, cfg)
+    x, stacks, starts = _run_cached(
+        params, tokens, cache, cfg, lengths if s > 1 else None
+    )
     with jax.named_scope("lm_head"):
         # gather each request's last REAL position (pad-aware bucketed
         # prefill)
@@ -395,7 +476,7 @@ def _forward_with_cache(
             x, last_idx[:, None, None].astype(jnp.int32), axis=1
         )[:, 0]
         logits = _mm(x_last, params["lm_head"]).astype(jnp.float32)
-    new_cache = {"k": k_new, "v": v_new, "lengths": starts + lengths}
+    new_cache = {**cache, **stacks, "lengths": starts + lengths}
     return logits, new_cache
 
 
@@ -445,10 +526,10 @@ def verify_chunk(
     shapes, so near-tie logits can in principle break exact greedy
     equality on low-precision checkpoints."""
     s = tokens.shape[1]
-    x, k_new, v_new, starts = _run_cached(params, tokens, cache, cfg)
+    x, stacks, starts = _run_cached(params, tokens, cache, cfg)
     logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, S, V]
     next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    new_cache = {"k": k_new, "v": v_new, "lengths": starts + s}
+    new_cache = {**cache, **stacks, "lengths": starts + s}
     return next_ids, new_cache
 
 
@@ -485,7 +566,7 @@ def verify_chunk_sampled(
 
     b, s = tokens.shape
     k_drafts = s - 1
-    x, k_new, v_new, starts = _run_cached(params, tokens, cache, cfg)
+    x, stacks, starts = _run_cached(params, tokens, cache, cfg)
     logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, S, V]
     v = logits.shape[-1]
     p = warped_probs(
@@ -521,7 +602,7 @@ def verify_chunk_sampled(
         pos < n_acc[:, None], draft_pad,
         jnp.where(pos == n_acc[:, None], corr[:, None], 0),
     )
-    new_cache = {"k": k_new, "v": v_new, "lengths": starts + s}
+    new_cache = {**cache, **stacks, "lengths": starts + s}
     return emitted, n_acc, key, new_cache
 
 

@@ -38,6 +38,7 @@ POOL_ISSUE = "gofr.pool.issue"
 POOL_FETCH_WAIT = "gofr.pool.fetch_wait"
 POOL_DELIVER = "gofr.pool.deliver"
 POOL_WAIT_WORK = "gofr.pool.wait_work"
+POOL_STATE_INSERT = "gofr.pool.state_insert"
 SOLO_ISSUE = "gofr.solo.issue"
 SOLO_FETCH_WAIT = "gofr.solo.fetch_wait"
 SSE_FIRST_FRAME = "gofr.sse.first_frame"

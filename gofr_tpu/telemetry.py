@@ -263,6 +263,7 @@ class FlightRecord:
         "wall_start", "t_start", "t_enqueue", "t_dispatch",
         "t_first_token", "t_last_token", "t_done", "wall_done", "_lock",
         "t_pool_admit", "t_first_frame",
+        "t_state_insert", "t_state_inserted",
         # the recorder's in-flight index holds records WEAKLY (an
         # abandoned record must vanish with its request, not leak)
         "__weakref__",
@@ -357,6 +358,12 @@ class FlightRecord:
         # the responder's write returned) — t_first_token is when the
         # token existed on the host
         self.t_first_frame: Optional[float] = None
+        # the pool moved this request's prefilled row (its K/V, or a
+        # retention model's state) into the slot: profiling.phase
+        # POOL_STATE_INSERT stamps both (host time of the enqueue; the
+        # copy's device time is the trace's)
+        self.t_state_insert: Optional[float] = None
+        self.t_state_inserted: Optional[float] = None
         self.t_last_token: Optional[float] = None
         self.t_done: Optional[float] = None
         self.wall_done: Optional[float] = None
@@ -593,6 +600,7 @@ class FlightRecord:
             "prefill_s": self.prefill,
             "first_frame_s": between(self.t_first_token, self.t_first_frame),
             "pool_admit_s": between(self.t_first_token, self.t_pool_admit),
+            "state_insert_s": between(self.t_state_insert, self.t_state_inserted),
             "server_ttft_s": between(self.t_start, self.t_first_frame),
             "ttft_s": self.ttft,
             "tpot_s": self.tpot,

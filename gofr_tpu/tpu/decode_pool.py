@@ -58,6 +58,7 @@ from gofr_tpu.profiling import (
     POOL_DELIVER,
     POOL_FETCH_WAIT,
     POOL_ISSUE,
+    POOL_STATE_INSERT,
     POOL_WAIT_WORK,
     phase,
 )
@@ -251,6 +252,12 @@ class DecodePool:
         # written in from prefill already live on the same mesh
         self._cache_shardings = cache_shardings
         self.cache = self._place(init_cache(cfg, n_slots))
+        # one row of one layer of a retention state (S and z), in bytes:
+        # what a live row reads and writes a layer a step; 0 for K/V
+        self._state_row_bytes = sum(
+            leaf.nbytes for name, leaf in self.cache.items() if name in ("s", "z")
+        ) // (cfg.n_layers * n_slots)
+        self._live_mask: Optional[tuple] = None  # what cache["live"] holds
         self._n_params = n_params
         self._peak = peak_flops
         self._model = model
@@ -294,11 +301,13 @@ class DecodePool:
             ),
         )
 
+        # a cache is K and V, or a retention model's S and z: every stack
+        # has its slot axis second, ``lengths`` [slots] has it first
         def write_slot(pool: dict, row: dict, i) -> dict:
             return {
-                "k": jax.lax.dynamic_update_slice_in_dim(pool["k"], row["k"], i, axis=1),
-                "v": jax.lax.dynamic_update_slice_in_dim(pool["v"], row["v"], i, axis=1),
-                "lengths": jax.lax.dynamic_update_slice(pool["lengths"], row["lengths"], (i,)),
+                name: jax.lax.dynamic_update_slice_in_dim(
+                    leaf, row[name], i, axis=0 if leaf.ndim == 1 else 1)
+                for name, leaf in pool.items()
             }
 
         self._write_slot = jax.jit(
@@ -315,9 +324,9 @@ class DecodePool:
             # COPY, not a view: the pool cache is donated into every later
             # chunk dispatch; a handed-back row must own its buffers
             return {
-                "k": jnp.copy(jax.lax.dynamic_slice_in_dim(pool["k"], i, 1, axis=1)),
-                "v": jnp.copy(jax.lax.dynamic_slice_in_dim(pool["v"], i, 1, axis=1)),
-                "lengths": jnp.copy(jax.lax.dynamic_slice(pool["lengths"], (i,), (1,))),
+                name: jnp.copy(jax.lax.dynamic_slice_in_dim(
+                    leaf, i, 1, axis=0 if leaf.ndim == 1 else 1))
+                for name, leaf in pool.items()
             }
 
         self._read_slot = jax.jit(read_slot)
@@ -372,7 +381,10 @@ class DecodePool:
         self._last_tokens.block_until_ready()
         if spec is not None:
             self._warm_spec()
-        self.cache = self._place(init_cache(cfg, n_slots))  # reset the warmup writes
+        # reset the warmup writes; the old cache goes first, so that two
+        # never stand side by side (a retention state is 3.3 GB at 12 slots)
+        self.cache = None
+        self.cache = self._place(init_cache(cfg, n_slots))
         self._last_tokens = self._replicate(jnp.zeros((n_slots, 1), jnp.int32))
         if penalties == "eager":
             self._enable_penalties()
@@ -434,7 +446,7 @@ class DecodePool:
             # SET only where a peak exists (a TPU kind in the flops.py
             # table): no other platform exports a utilization.
             self._bytes_per_step = tree_bytes(params) + tree_bytes(
-                {"k": self.cache["k"], "v": self.cache["v"]}
+                {k: v for k, v in self.cache.items() if v.ndim > 1}
             )
             self._mbu_gauge = metrics.gauge(
                 "gofr_tpu_mbu",
@@ -747,7 +759,9 @@ class DecodePool:
             # after any in-flight chunk (their inputs are its outputs), so
             # the new request's first real decode lands in the next
             # dispatched chunk
-            self.cache = self._write_slot(self.cache, row_cache, slot.index)
+            with phase(POOL_STATE_INSERT, record, start="t_state_insert",
+                       end="t_state_inserted"):
+                self.cache = self._write_slot(self.cache, row_cache, slot.index)
             self._last_tokens = self._write_token(
                 self._last_tokens, jnp.asarray([[first_token]], jnp.int32), slot.index
             )
@@ -1031,6 +1045,14 @@ class DecodePool:
             self._top_ps_dev = jnp.asarray(self._top_ps)
             self._min_ps_dev = jnp.asarray(self._min_ps)
             self._sampling_dirty = False
+        if "live" in self.cache:
+            # a cache that is a state per slot: the step moves no state for a
+            # slot that holds no request (ops/retention.py), so say which do
+            live = tuple(int(i in self._active) for i in range(self.n_slots))
+            if live != self._live_mask:
+                self._live_mask = live
+                self.cache = {**self.cache, "live": self._replicate(
+                    jnp.asarray(live, jnp.int32))}
         drec = None
         if self._timeline is not None:
             # dispatch timeline: one record per chunk; every active
@@ -1041,6 +1063,11 @@ class DecodePool:
             )
             drec.mark_running()
             drec.chunks_ahead = self.chunks_in_flight  # depth reached
+            if self._state_row_bytes:
+                drec.state_bytes = (
+                    len(records) * self.cfg.n_layers * 2
+                    * self._state_row_bytes * self.chunk
+                )
             for _, req in records:
                 if req is not None and req.record is not None:
                     req.record.note_dispatch_id(drec.dispatch_id)
@@ -1105,7 +1132,7 @@ class DecodePool:
             ),
         )
         self._write_lengths = jax.jit(
-            lambda c, l: {"k": c["k"], "v": c["v"], "lengths": l},
+            lambda c, l: {**c, "lengths": l},
             donate_argnums=(0,),
             out_shardings=(
                 dict(cache_shardings) if repl is not None else None

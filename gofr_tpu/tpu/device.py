@@ -10,7 +10,9 @@ Config keys (SURVEY.md §2 #22 TPU-native additions):
   weight-only quantized serving — decode streams the whole weight set per
   step, so packed weights raise its throughput ceiling 2x / ~4x over bf16
 - ``MODEL_KV_DTYPE``: "f8" stores the KV cache in float8_e4m3fn (2x
-  context length or decode slots per HBM byte, small accuracy cost)
+  context length or decode slots per HBM byte, small accuracy cost); a
+  model whose cache is a retention state holds it in float32 when unset
+  and in bfloat16 with "bf16", and refuses f8
 - ``MODEL_ATTN_IMPL``: auto (default) | xla | pallas — forces the
   attention implementation (ops/attention.py); "auto" picks the Pallas
   flash kernel when shapes are tile-friendly and profitable
@@ -614,8 +616,12 @@ class TPUDevice:
             )
         self._attn_impl = attn_raw or None
         kv_raw = config.get_or_default("MODEL_KV_DTYPE", "").strip().lower()
-        if kv_raw in ("", "bf16", "bfloat16"):
+        if kv_raw == "":
             self._kv_dtype = None
+        elif kv_raw in ("bf16", "bfloat16"):
+            # a K/V cache's default; a retention state's is float32, so
+            # there bf16 is a stated (lower) type (_TransformerRunner)
+            self._kv_dtype = jnp.bfloat16
         elif kv_raw in ("f8", "fp8", "float8", "float8_e4m3fn"):
             self._kv_dtype = jnp.float8_e4m3fn
         else:
@@ -802,6 +808,7 @@ class TPUDevice:
                 f"FLEET_ROLE '{self.role}' not supported — use prefill, "
                 "decode, or mixed"
             )
+        self._refuse_what_a_state_cannot_do(config)
         self._pool_enabled = config.get_or_default("DECODE_POOL", "on") != "off"
         self._pool_slots = int(config.get_or_default("DECODE_SLOTS", str(self.max_batch)))
         from gofr_tpu.tpu.decode_pool import PIPELINE_DEPTH
@@ -1087,6 +1094,46 @@ class TPUDevice:
             )
         if self._boot_error is not None:
             raise RuntimeError("TPU boot failed") from self._boot_error
+
+    def _refuse_what_a_state_cannot_do(self, config: Any) -> None:
+        """A model whose cache is a fixed-size state per row (attention
+        kind "retention") has no K/V rows to share by prefix, roll back by
+        length, hold in float8, send over the wire or shard by the head
+        axis of ``cache_specs``. Each such setting is refused here, by
+        name, at boot: none of them may give a wrong answer instead."""
+        from gofr_tpu.models.llama import CONFIGS
+
+        cfg = CONFIGS.get(self.model_name)
+        if getattr(cfg, "attn_kind", "softmax") != "retention":
+            return
+        stated = (config.get("KV_TRANSFER") or "").strip().lower()
+        blocks = "the paged arena holds K/V blocks"
+        rollback = "speculation rolls a cache back by length; a state has no length"
+        wire = "the wire format carries K/V blocks"
+        refused = {  # setting -> (is it on, why a state cannot serve it)
+            "PREFIX_CACHE": (self._prefix_cache_size > 0,
+                             "prefix sharing aliases K/V rows; a state would need snapshots"),
+            "KV_BLOCKS": (self._kv_paged and self._kv_blocks_cfg > 0, blocks),
+            "KV_HBM_BUDGET_MB": (self._kv_paged and self._kv_budget_mb > 0, blocks),
+            "DRAFT_MODEL_NAME": (bool(self._draft_name), rollback),
+            "SPEC_POOLED": (self._spec_pooled, rollback),
+            "MODEL_KV_DTYPE": (self._kv_dtype == jnp.float8_e4m3fn,
+                               "f8 is a K/V type; a state takes float32 (unset) or bf16"),
+            "KV_TRANSFER": (stated not in ("", "off"), wire),
+            "KV_TRANSFER_TRUST_HINT": (self.kv_hint_trusted, wire),
+            "FLEET_ROLE": (self.role != "mixed",
+                           "prefill/decode disaggregation moves K/V over the wire"),
+            "TPU_MESH": (_parse_mesh_request(self._mesh_request) is not None,
+                         "the state is not yet sharded over a mesh (by kv head under tp)"),
+        }
+        for name, (on, why) in refused.items():
+            if on:
+                raise ValueError(
+                    f"{name} is not supported for MODEL_NAME '{self.model_name}', "
+                    f"whose cache is a retention state: {why}"
+                )
+        # unset, KV transfer is armed by default; here there is nothing to send
+        self.kv_transfer_enabled = False
 
     def _build_stack(self) -> None:
         """Construct (or reconstruct, on reinit) runner + pool + batcher."""
@@ -3391,7 +3438,11 @@ class _TransformerRunner:
             # int8 only with a smaller KV allocation than the model's full
             # context (MODEL_MAX_SEQ config key)
             overrides["max_seq"] = max_seq
-        if kv_dtype is not None:
+        retention = self.cfg.attn_kind == "retention"
+        if kv_dtype is not None and (retention or kv_dtype != jnp.bfloat16):
+            # MODEL_KV_DTYPE=bf16 has always meant "a K/V cache in the
+            # model's type"; only a retention state (float32 when
+            # unset) takes it as a type of its own
             overrides["kv_dtype"] = kv_dtype
         if attn_impl:
             overrides["attn_impl"] = attn_impl
@@ -3402,6 +3453,9 @@ class _TransformerRunner:
 
             self.cfg = dataclasses.replace(self.cfg, **overrides)
         self.decode_chunk_size = decode_chunk
+        self._state_prefill_gate = (
+            threading.BoundedSemaphore(2) if retention else None
+        )
         # mesh-fit validation BEFORE the params exist: a tp axis that
         # cannot divide the head count (or a dp/fsdp product the padded
         # batch cannot shard over) must fail in milliseconds with the
@@ -4121,7 +4175,7 @@ class _TransformerRunner:
         # decode unmetered past its budget just because it fell out of
         # the pool — same stage=decode contract as the pooled rows
         deadline = current_deadline()
-        max_len = int(cache["k"].shape[2])
+        max_len = _cache_max_len(cache, self.cfg)
         temp, tk, tp = sampler.temperature, sampler.top_k, sampler.top_p
         mp = sampler.min_p
         pen = sampler.repetition_penalty
@@ -4288,11 +4342,25 @@ class _TransformerRunner:
         prompts never pay top-bucket FLOPs). ``scheduler`` interleaves
         each chunk with pooled decode turns (tpu/scheduler.py) and the
         chunk count/defer land on the request's FlightRecord."""
+        if self._state_prefill_gate is not None:
+            # a state row is the same size whatever the prompt (Brumby: 0.27
+            # GB, and a slice holds the state it came from beside the one it
+            # makes): the device runs slices one after another anyway, so
+            # letting only two prompts hold rows at once costs no time and
+            # bounds what a burst of long prompts can take
+            with self._state_prefill_gate:
+                return self._chunked_prefill_slices(ids, params, bucket, scheduler)
+        return self._chunked_prefill_slices(ids, params, bucket, scheduler)
+
+    def _chunked_prefill_slices(
+        self, ids: np.ndarray, params: Any, bucket: Optional[int],
+        scheduler: Any,
+    ) -> dict:
         bucket = bucket or self.buckets[-1]
         # the shared zero cache: prefill never mutates its input, so every
         # chunked request can start from the same [1]-row allocation
         cache = self._zero_cache(1)
-        logits = next_ids = None
+        logits = next_ids = issued_before = None
         total = 0
         prm = self.params if params is None else params
         record = telemetry_record()
@@ -4325,12 +4393,21 @@ class _TransformerRunner:
                         tokens=size,
                     )
                     drec.chunks_ahead = _chunks_ahead(self)
+                    drec.carried = total > 0
                     if record is not None:
                         record.note_dispatch_id(drec.dispatch_id)
                 with phase(PREFILL_ISSUE, drec, end="t_issued"):
                     logits, next_ids, cache = self._prefill(
                         prm, tokens, cache, lengths
                     )
+                if self._state_prefill_gate is not None:
+                    # a slice's output is allocated when it is enqueued, so
+                    # a 12-slice prompt issued at once holds 12 states (3.3
+                    # GB of Brumby's). Wait for the slice BEFORE the one
+                    # just issued: one runs, one is queued, three states live
+                    if issued_before is not None:
+                        issued_before.block_until_ready()
+                    issued_before = cache["lengths"]
                 if record is not None:
                     record.note_prefill_chunk(bucket=bucket)
                 total += size
@@ -4781,7 +4858,7 @@ class _TransformerRunner:
         cache = state["cache"]
         cache_len = state["length"]
         state = None
-        max_len = int(cache["k"].shape[2])
+        max_len = _cache_max_len(cache, self.cfg)
         dcache = self._spec_prefill_draft(ids)
         stats = self.spec_stats
         emit = self._spec_emit_fn(out, on_token, stop, stop_tokens,
@@ -4859,7 +4936,7 @@ class _TransformerRunner:
         cache = state["cache"]
         cache_len = state["length"]
         state = None
-        max_len = int(cache["k"].shape[2])
+        max_len = _cache_max_len(cache, self.cfg)
         dcache = self._spec_prefill_draft(ids)
         stats = self.spec_stats
         temp, tk, tp_ = sampler.temperature, sampler.top_k, sampler.top_p
@@ -5146,9 +5223,7 @@ def _prompt_chunks(ids: np.ndarray, bucket: int):
 # write head back to ``n`` (speculative decoding rejects by length — the
 # garbage KV past n is masked by attention and overwritten by later steps)
 _cache_with_len = jax.jit(
-    lambda c, n: {
-        "k": c["k"], "v": c["v"], "lengths": jnp.zeros_like(c["lengths"]) + n,
-    },
+    lambda c, n: {**c, "lengths": jnp.zeros_like(c["lengths"]) + n},
     donate_argnums=(0,),
 )
 
@@ -5503,11 +5578,18 @@ class _PrefillState(dict):
 
 
 def _slice_cache(cache: dict, i: int) -> dict:
+    """Row ``i`` of every leaf: the row axis is the second of a stack
+    (K and V, or a retention model's S and z) and the first of ``lengths``."""
     return {
-        "k": cache["k"][:, i : i + 1],
-        "v": cache["v"][:, i : i + 1],
-        "lengths": cache["lengths"][i : i + 1],
+        name: leaf[i : i + 1] if leaf.ndim == 1 else leaf[:, i : i + 1]
+        for name, leaf in cache.items()
     }
+
+
+def _cache_max_len(cache: dict, cfg: Any) -> int:
+    """Positions a row can hold: the K/V cache's length axis, or for a
+    state, which has none, the model's ``max_seq`` (the rotary table)."""
+    return int(cache["k"].shape[2]) if "k" in cache else int(cfg.max_seq)
 
 
 def _load_or_init(model_path: Optional[str], init_fn: Any) -> Any:

@@ -102,6 +102,7 @@ class DispatchRecord:
         "t_done", "mfu", "mbu", "predicted_ms", "residual_ratio",
         "cost_source", "anomaly",
         "t_issued", "t_fetch", "t_fetched", "cadence_s", "chunks_ahead",
+        "state_bytes", "carried",
     )
 
     def __init__(
@@ -157,6 +158,13 @@ class DispatchRecord:
         # issued: what a prefill queued behind on the device, or the
         # pipeline depth a decode chunk actually reached
         self.chunks_ahead: Optional[int] = None
+        # a decode chunk of a model whose cache is a state per row: the
+        # bytes of state its live rows had to read and write (rows x
+        # layers x 2 x one row's bytes x steps); None for a K/V cache
+        self.state_bytes: Optional[int] = None
+        # a prefill chunk: whether it began from what an earlier chunk of
+        # the same prompt left in the cache
+        self.carried: Optional[bool] = None
 
     def mark_running(self) -> None:
         """Device execution begins (after any scheduler-interleave wait)."""
@@ -194,6 +202,8 @@ class DispatchRecord:
             "deliver_s": between(self.t_fetched, self.t_done),
             "cadence_s": self.cadence_s,
             "chunks_ahead": self.chunks_ahead,
+            "state_bytes": self.state_bytes,
+            "carried": self.carried,
             "mfu": self.mfu,
             "mbu": self.mbu,
             "predicted_ms": self.predicted_ms,

@@ -31,7 +31,7 @@ ALL_NAMES = {
     profiling.BATCHER_COLLECT, profiling.PREFILL_ISSUE, profiling.PREFILL_FETCH_WAIT,
     profiling.POOL_ISSUE, profiling.POOL_FETCH_WAIT, profiling.POOL_DELIVER,
     profiling.POOL_WAIT_WORK, profiling.SOLO_ISSUE, profiling.SOLO_FETCH_WAIT,
-    profiling.SSE_FIRST_FRAME,
+    profiling.SSE_FIRST_FRAME, profiling.POOL_STATE_INSERT,
 }
 
 

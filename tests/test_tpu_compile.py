@@ -114,3 +114,65 @@ def test_prefill_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu):
     movers = _cache_movers(hlo, rows)
     assert set(movers) <= {"ENTRY"}, movers
     assert len(movers.get("ENTRY", [])) <= 4, movers
+
+
+# -- a cache that is a state (attention kind "retention") ------------------------------------
+# Brumby-14B-Base's widths (benchmark/configs) with two layers and a small
+# vocabulary; 4 slots
+
+RCFG = T.TransformerConfig(
+    vocab_size=4096, dim=5120, n_layers=2, n_heads=40, n_kv_heads=8,
+    hidden_dim=17408, max_seq=4096, rope_theta=1e6, norm_eps=1e-6, attn_kind="retention",
+)
+
+
+def _state_writers(hlo: str, batch: int) -> dict[str, list[str]]:
+    """computation name -> instructions whose result has the whole state's
+    or one layer's shape, other than the kernels that own it."""
+    row = f"{batch},{RCFG.n_kv_heads},{RCFG.head_dim},8320"
+    shapes = (f"f32[{RCFG.n_layers},{row}]", f"f32[1,{row}]", f"f32[{row}]")
+    found: dict[str, list[str]] = {}
+    computation = ""
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            computation = "ENTRY" if head.group(1) else head.group(2)
+            continue
+        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
+        if match and match.group(2) in shapes and match.group(3) in _MOVERS:
+            found.setdefault(computation, []).append(match.group(1))
+    return found
+
+
+def _retention_params():
+    return lambda: T.init_transformer(jax.random.key(0), RCFG)
+
+
+def test_pooled_chunk_of_a_retention_model_leaves_its_state_to_the_kernel(one_chip, as_on_tpu):
+    hlo = _compiled(
+        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
+            p, t, c, RCFG, 8, key, temp, tk, tp, mp),
+        (2, 3), one_chip,
+        _retention_params(), jnp.zeros((SLOTS, 1), jnp.int32),
+        lambda: T.init_cache(RCFG, SLOTS), lambda: jax.random.key(0),
+        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
+        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.float32),
+    )
+    assert "retention_step" in hlo and "tpu_custom_call" in hlo
+    # the state is donated through the chunk: the kernel reads and writes
+    # its layer of it in place, and nothing copies or slices it anywhere
+    assert _state_writers(hlo, SLOTS) == {}
+
+
+def test_prefill_of_a_retention_model_copies_its_state_once_at_entry(one_chip, as_on_tpu):
+    rows = 2
+    hlo = _compiled(
+        lambda p, t, c, l: T.prefill(p, t, c, RCFG, l), (), one_chip,
+        _retention_params(), jnp.zeros((rows, 512), jnp.int32),
+        lambda: T.init_cache(RCFG, rows), jnp.zeros((rows,), jnp.int32),
+    )
+    assert "retention_chunk" in hlo and "tpu_custom_call" in hlo
+    # the caller keeps the cache it passed (a shared zero cache, or the
+    # state a slice carries on from), so the program copies it once
+    writers = _state_writers(hlo, rows)
+    assert set(writers) <= {"ENTRY"} and len(writers.get("ENTRY", [])) <= 1, writers
