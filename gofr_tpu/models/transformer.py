@@ -382,23 +382,22 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
 
         s, z = init_state(batch, cfg.n_kv_heads, cfg.head_dim, cfg.cache_dtype,
                           layers=cfg.n_layers)
-        # ``live``: the rows that hold a request. The decode pool keeps it
-        # to its active slots; every row of a prefill's cache is live.
-        return {"s": s, "z": z, "lengths": jnp.zeros((batch,), jnp.int32),
-                "live": jnp.ones((batch,), jnp.int32)}
-    shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, cfg.cache_dtype),
-        "v": jnp.zeros(shape, cfg.cache_dtype),
-        "lengths": jnp.zeros((batch,), jnp.int32),
-    }
+        stacks = {"s": s, "z": z}
+    else:
+        shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+        stacks = {"k": jnp.zeros(shape, cfg.cache_dtype),
+                  "v": jnp.zeros(shape, cfg.cache_dtype)}
+    # ``live``: the rows that hold a request. The decode pool keeps it to
+    # its active slots; every row of a prefill's or a solo cache is live,
+    # and so is every row of a cache that lacks the leaf.
+    return {**stacks, "lengths": jnp.zeros((batch,), jnp.int32),
+            "live": jnp.ones((batch,), jnp.int32)}
 
 
 def cache_leaves(cache: dict) -> tuple[str, ...]:
     """The names of a cache's device state: ``k`` and ``v``, or a retention
     model's ``s`` and ``z``; every one has the row (slot) axis second. The
-    per-row vectors ride beside them: ``lengths`` [B], and for a state
-    ``live`` [B]."""
+    per-row vectors ride beside them: ``lengths`` [B] and ``live`` [B]."""
     return tuple(sorted(name for name, leaf in cache.items() if leaf.ndim > 1))
 
 
@@ -414,7 +413,8 @@ def _run_cached(
 
     Keys valid for query j of request b: cache positions <= starts_b + j
     (causal handles the per-query bound; kv_lens bounds the written region
-    so never-written cache slots are excluded). A retention state has no
+    so never-written cache slots are excluded, and is 0 for a row that
+    ``cache['live']`` says holds no request). A retention state has no
     such region: there ``lengths`` (this call's real tokens per row) keeps
     bucket padding out of the state."""
     b, s = tokens.shape
@@ -423,7 +423,12 @@ def _run_cached(
     positions = starts[:, None] + jnp.arange(s)[None, :]  # [B, S]
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
+    live = cache.get("live")
     written = starts + s  # [B]
+    if live is not None:
+        # a row that holds no request has no keys: attention issues no
+        # read for it and returns zeros (ops/flash.py, the decode form)
+        written = jnp.where(live > 0, written, 0)
     valid = None
     if cfg.attn_kind == "retention" and lengths is not None:
         valid = jnp.arange(s)[None, :] < lengths[:, None]
@@ -438,7 +443,7 @@ def _run_cached(
         y, stacks, _ = _block(
             cfg, layer_params, x, freqs, positions,
             kv_cache=stacks, layer=layer, starts=starts,
-            kv_lens=written, valid=valid, live=cache.get("live"),
+            kv_lens=written, valid=valid, live=live,
         )
         return (y, stacks), None
 

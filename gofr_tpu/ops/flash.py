@@ -1,26 +1,41 @@
-"""Pallas TPU flash attention (forward kernel + recompute backward).
+"""Pallas TPU flash attention (forward kernels + fused backward).
 
 TPU-first design (pallas_guide: grid/block specs, scalar prefetch, online
-softmax in VMEM):
+softmax in VMEM). The forward has two forms, and which one a call takes is
+read from its shapes alone:
 
-- grid = (batch, q_heads, q_blocks); the KV loop runs *inside* the kernel
-  as a ``lax.fori_loop`` with a **dynamic trip count** — causal blocks past
-  the diagonal and blocks past the written KV length are never visited, so
-  prefill does half the work and ragged decode touches only the live cache
-  prefix.
-- K/V for one (batch, kv-head) live whole in VMEM (max_seq 8192 × 128 in
-  bf16 = 2 MiB each, well under the ~16 MiB budget); Q is tiled ``block_q``
-  rows at a time. GQA maps query head → kv head in the BlockSpec index map,
-  so repeated KV heads are never materialized. The forward kernel reads
-  K/V out of a stack [L, B, Skv, Hkv, D] at a layer index (a third
-  scalar-prefetch value): the serving path hands it the whole KV cache.
+- **the decode form**, for a call whose ``sq`` x ``groups`` query rows fit
+  one q block (the pooled and the solo decode chunk, ``sq`` = 1; the
+  speculative verify's few rows; a short training sequence): grid =
+  (batch, kv_heads), the ``groups`` q heads that share a kv head side by
+  side in one q block, so each K/V block is multiplied once for its
+  group. K and V stay in HBM; the kernel copies them in itself, a block
+  of ``block_kv`` positions at a time (``_FETCH_BUFFERS`` copies in
+  flight while the current block is in the arithmetic), only up to the
+  row's last live block. A row of length 0 (a decode slot that holds no
+  request) issues no copy, runs no iteration and returns zeros. Work and
+  bytes are in proportion to the tokens that are live, whatever
+  ``max_seq``.
+- **the prefill form**, for everything longer (prefill buckets, chunked
+  prefill, training): grid = (batch, q_heads, q_blocks); K/V for one
+  (batch, kv-head) live whole in VMEM (max_seq 8192 × 128 in bf16 = 2 MiB
+  each, well under the ~16 MiB budget), resident across the q blocks and
+  the heads of a group, which is the right trade when every q block reads
+  them; Q is tiled ``block_q`` rows at a time. GQA maps query head → kv
+  head in the BlockSpec index map, so repeated KV heads are never
+  materialized.
+- both run the KV loop *inside* the kernel as a ``lax.fori_loop`` with a
+  **dynamic trip count** — causal blocks past the diagonal and blocks past
+  the written KV length are never visited — and share one online-softmax
+  step (``_softmax_step``: running (m, l, acc) in f32; probabilities cast
+  back to the value dtype so the p·V matmul hits the MXU in bf16 with f32
+  accumulation). Both read K/V out of a stack [L, B, Skv, Hkv, D] at a
+  layer index (a third scalar-prefetch value): the serving path hands
+  them the whole KV cache.
 - per-batch scalars (``q_offset`` for ragged decode positions, ``kv_lens``
   bounding the valid cache prefix) ride scalar prefetch
   (``PrefetchScalarGridSpec``) — available before the body for the
-  dynamic loop bound.
-- online softmax: running (m, l, acc) in f32; probabilities cast back to
-  the value dtype so the p·V matmul hits the MXU in bf16 with f32
-  accumulation.
+  dynamic loop bound and the copies' addresses.
 - backward: **fused Pallas kernels** (FlashAttention-2 style). The forward
   additionally emits per-row logsumexp; ``_dq_kernel`` recomputes P from it
   and accumulates dQ over the same bounded KV loop as the forward, and
@@ -52,6 +67,57 @@ _NEG_INF = float(-1e30)
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 128
+
+
+def _softmax_init(rows: int, d: int) -> tuple:
+    """The running (max, sum, accumulator) of the online softmax, float32."""
+    return (
+        jnp.full((rows, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32),
+        jnp.zeros((rows, d), jnp.float32),
+    )
+
+
+def _softmax_step(carry, qb, kb, vb, k_pos, q_pos, kv_len, causal, scale):
+    """One KV block of the online softmax, shared by both forward bodies:
+    ``qb`` [rows, D] against ``kb``, ``vb`` [block_kv, D] whose keys stand
+    at ``k_pos`` [1, block_kv]; ``q_pos`` [rows, 1] (or a scalar) are the
+    queries' absolute positions."""
+    m_prev, l_prev, acc_prev = carry
+    s = jax.lax.dot_general(
+        qb,
+        kb,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [rows, block_kv]
+
+    mask = k_pos < kv_len
+    if causal:
+        mask = jnp.logical_and(mask, k_pos <= q_pos)
+    s = jnp.where(mask, s, _NEG_INF)
+
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)  # [rows, 1]
+    p = jnp.exp(s - m_new)  # [rows, block_kv] f32
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(vb.dtype),
+        vb,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l_new, acc_prev * alpha + pv
+
+
+def _softmax_store(carry, out_ref, lse_ref):
+    m, l, acc = carry
+    # fully-masked rows (padding, a row with no live key) have l == 0 →
+    # emit zeros, not NaN
+    out = acc / jnp.where(l == 0.0, 1.0, l)
+    out_ref[0, 0, :, :] = out.astype(out_ref.dtype)
+    # logsumexp residual for the fused backward; +inf on fully-masked rows
+    # makes their recomputed probabilities exp(-1e30 - inf) = 0 there
+    lse_ref[0, 0, :, :] = jnp.where(l > 0.0, m + jnp.log(l), jnp.inf)
 
 
 def _kernel(
@@ -102,48 +168,98 @@ def _kernel(
     hi = jnp.minimum(hi, num_kv_blocks)
 
     def body(j, carry):
-        m_prev, l_prev, acc_prev = carry
         kb = k_ref[0, 0, 0, pl.ds(j * block_kv, block_kv), :]  # [block_kv, D]
         vb = v_ref[0, 0, 0, pl.ds(j * block_kv, block_kv), :]
-
-        s = jax.lax.dot_general(
-            qb,
-            kb,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [block_q, block_kv]
-
-        k_pos = j * block_kv + k_ids  # [1, block_kv]
-        mask = k_pos < kv_len
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)  # [block_q, 1]
-        p = jnp.exp(s - m_new)  # [block_q, block_kv] f32
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(vb.dtype),
-            vb,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        return _softmax_step(
+            carry, qb, kb, vb, j * block_kv + k_ids, q_pos, kv_len, causal, scale
         )
-        acc_new = acc_prev * alpha + pv
-        return m_new, l_new, acc_new
 
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
+    carry = jax.lax.fori_loop(0, hi, body, _softmax_init(block_q, d))
+    _softmax_store(carry, out_ref, lse_ref)
 
-    # fully-masked rows (padding) have l == 0 → emit zeros, not NaN
-    out = acc / jnp.where(l == 0.0, 1.0, l)
-    out_ref[0, 0, :, :] = out.astype(out_ref.dtype)
-    # logsumexp residual for the fused backward; +inf on fully-masked rows
-    # makes their recomputed probabilities exp(-1e30 - inf) = 0 there
-    lse = jnp.where(l > 0.0, m + jnp.log(l), jnp.inf)
-    lse_ref[0, 0, :, :] = lse
+
+# The decode form copies K and V in itself, a block of ``block_kv``
+# positions at a time, this many copies of each in flight: the next ones
+# run while the current block is in the arithmetic.
+_FETCH_BUFFERS = 4
+
+
+def _decode_kernel(
+    offs_ref,  # [B] int32 scalar-prefetch: absolute position of q row 0
+    lens_ref,  # [B] int32 scalar-prefetch: valid KV prefix length (0: a
+    # row that holds no request)
+    layer_ref,  # [1] int32 scalar-prefetch
+    q_ref,  # [1, 1, rows, D]: the ``groups`` q heads of this kv head, each
+    # with its ``sq`` queries (row = g * sq + s), padded to the tile
+    k_hbm,  # [L, B, Hkv, Skv_pad, D], left where it is (HBM)
+    v_hbm,
+    out_ref,  # [1, 1, rows, D]
+    lse_ref,  # [1, 1, rows, 1] f32
+    k_buf,  # [_FETCH_BUFFERS, block_kv, D] VMEM
+    v_buf,
+    sem,  # DMA semaphores [2, _FETCH_BUFFERS]
+    *,
+    causal: bool,
+    scale: float,
+    sq: int,
+    block_kv: int,
+    num_kv_blocks: int,
+):
+    """One (row, kv head): K and V come in block by block, only up to the
+    row's last live block, each block multiplied once for the whole group.
+    A row of length 0 issues no copy and runs no iteration."""
+    b = pl.program_id(0)
+    h = pl.program_id(1)
+    offset = offs_ref[b]
+    kv_len = lens_ref[b]
+    lay = layer_ref[0]
+
+    qb = q_ref[0, 0, :, :]  # [rows, D]
+    rows, d = qb.shape
+    if sq == 1:
+        q_pos = offset
+    else:
+        q_pos = offset + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), sq
+        )  # [rows, 1]
+    k_ids = jax.lax.broadcasted_iota(jnp.int32, (1, block_kv), 1)
+
+    hi = pl.cdiv(kv_len, block_kv)
+    if causal:
+        hi = jnp.minimum(hi, pl.cdiv(offset + sq, block_kv))
+    hi = jnp.minimum(hi, num_kv_blocks)
+
+    def copies(j, slot):
+        at = pl.ds(pl.multiple_of(j * block_kv, block_kv), block_kv)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[lay, b, h, at, :], k_buf.at[slot], sem.at[0, slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[lay, b, h, at, :], v_buf.at[slot], sem.at[1, slot]),
+        )
+
+    def start(j):
+        @pl.when(j < hi)
+        def _():
+            for copy in copies(j, j % _FETCH_BUFFERS):
+                copy.start()
+
+    for j in range(_FETCH_BUFFERS - 1):
+        start(j)
+
+    def body(j, carry):
+        slot = j % _FETCH_BUFFERS
+        # the buffer this refills was last read an iteration ago
+        start(j + _FETCH_BUFFERS - 1)
+        for copy in copies(j, slot):
+            copy.wait()
+        return _softmax_step(
+            carry, qb, k_buf[slot], v_buf[slot], j * block_kv + k_ids,
+            q_pos, kv_len, causal, scale,
+        )
+
+    carry = jax.lax.fori_loop(0, hi, body, _softmax_init(rows, d))
+    _softmax_store(carry, out_ref, lse_ref)
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, to: int) -> jnp.ndarray:
@@ -172,42 +288,58 @@ def _flash_fwd_impl(
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``k``, ``v`` [L, B, Skv, Hkv, D] are the stacked KV cache, read at
-    ``layer`` [1] int32 by the kernel's own BlockSpecs (an unstacked k/v
-    comes in as a stack of one).
+    ``layer`` [1] int32 by the kernel itself (an unstacked k/v comes in as
+    a stack of one).
 
-    The kernel takes the stack as its transpose [L, B, Hkv, Skv, D] and
-    picks the (Skv, D) block of (layer, row, kv head) out of it. Written
-    as a transpose of the whole stack, it costs none inside a program's
-    loops: the compiler gives the loop-carried cache that physical layout
-    ({4,2,3,1,0}) and the transpose becomes a bitcast, so no slice of the
-    layer and no copy of K or V stands in front of the kernel. The program
-    pays one relayout of the cache where it enters and one where it leaves
-    (a chunk, not a step or a layer). The row-major "free" view [L, B,
-    Skv, Hkv·D] is not free under (8, 128) tiling: compiled for the v5e it
-    is a reshape of the whole stack in every layer."""
+    A call whose ``sq`` x ``groups`` query rows fit one q block
+    (``block_q``) takes the decode form (``_decode_form``: grid (batch,
+    kv_heads), K/V copied in block by block up to each row's length);
+    every longer one the prefill form below (grid (batch, q_heads,
+    q_blocks), the (Skv, D) block of (layer, row, kv head) whole in VMEM).
+    Nothing else chooses. The prefill form's ``cost_estimate`` counts one
+    layer of K and V once; the decode form's the same, as the bound of
+    what its rows' lengths let it skip.
+
+    Either kernel takes the stack as its transpose [L, B, Hkv, Skv, D].
+    Written as a transpose of the whole stack, it costs none inside a
+    program's loops: the compiler gives the loop-carried cache that
+    physical layout ({4,2,3,1,0}) and the transpose becomes a bitcast, so
+    no slice of the layer and no copy of K or V stands in front of the
+    kernel. The program pays one relayout of the cache where it enters and
+    one where it leaves (a chunk, not a step or a layer). The row-major
+    "free" view [L, B, Skv, Hkv·D] is not free under (8, 128) tiling:
+    compiled for the v5e it is a reshape of the whole stack in every layer."""
     b, sq, hq, d = q.shape
     n_layers, _, skv, hkv, _ = k.shape
     groups = hq // hkv
 
-    # q and the output in [B, H, S, D]: the kernel tiles (sublane=seq,
-    # lane=head_dim)
-    qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 2, 3)  # [L, B, Hkv, Skv, D]
     vt = jnp.swapaxes(v, 2, 3)
-
-    # sublane floor 16 covers the bf16 min tile (f32 needs only 8); sq=1
-    # decode pads its q block rather than falling back to XLA
-    block_q = min(block_q, max(sq, 16))
     block_kv = min(block_kv, skv)
-    sq_pad = pl.cdiv(sq, block_q) * block_q
     skv_pad = pl.cdiv(skv, block_kv) * block_kv
-    qt = _pad_axis(qt, 2, sq_pad)
     # a no-op for a cache (its length is a multiple of the block): padding
     # a stack would copy it
     kt = _pad_axis(kt, 3, skv_pad)
     vt = _pad_axis(vt, 3, skv_pad)
-    num_q_blocks = sq_pad // block_q
     num_kv_blocks = skv_pad // block_kv
+    cost = dict(
+        flops=4 * b * hq * sq * skv * d,
+        transcendentals=b * hq * sq * skv,
+    )
+
+    if sq * groups <= block_q:
+        return _decode_form(
+            q, kt, vt, offsets, kv_lens, layer, causal, scale, block_kv,
+            num_kv_blocks, interpret, cost,
+        )
+
+    # q and the output in [B, H, S, D]: the kernel tiles (sublane=seq,
+    # lane=head_dim)
+    qt = jnp.swapaxes(q, 1, 2)
+    block_q = min(block_q, max(sq, 16))
+    sq_pad = pl.cdiv(sq, block_q) * block_q
+    qt = _pad_axis(qt, 2, sq_pad)
+    num_q_blocks = sq_pad // block_q
 
     def kv_block(bi, h, qi, offs, lens, lay):
         return (lay[0], bi, h // groups, 0, 0)
@@ -249,14 +381,77 @@ def _flash_fwd_impl(
         ],
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * hq * sq * skv * d,
             bytes_accessed=(
                 q.size + (k.size + v.size) // n_layers
             ) * q.dtype.itemsize,
-            transcendentals=b * hq * sq * skv,
+            **cost,
         ),
     )(offsets, kv_lens, layer, qt, kt, vt)
     return jnp.swapaxes(out[:, :, :sq, :], 1, 2), lse[:, :, :sq, 0]
+
+
+def _decode_form(
+    q, kt, vt, offsets, kv_lens, layer, causal, scale, block_kv,
+    num_kv_blocks, interpret, cost,
+):
+    """The forward for a call whose ``sq`` x ``groups`` query rows fit one
+    q block: grid (row, kv head), the group's heads side by side in the
+    block, K and V ``kt``, ``vt`` [L, B, Hkv, Skv_pad, D] left in HBM and
+    copied in by ``_decode_kernel`` up to each row's length."""
+    b, sq, hq, d = q.shape
+    hkv = kt.shape[2]
+    groups = hq // hkv
+    rows = sq * groups
+    # sublane floor 16 covers the bf16 min tile (f32 needs only 8)
+    rows_pad = pl.cdiv(rows, 16) * 16
+    # [B, Sq, Hkv, G, D] -> [B, Hkv, G x Sq, D]: for sq = 1 a reshape
+    qg = q.reshape(b, sq, hkv, groups, d).transpose(0, 2, 3, 1, 4)
+    qg = _pad_axis(qg.reshape(b, hkv, rows, d), 2, rows_pad)
+
+    def q_block(bi, h, *_):
+        return (bi, h, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, hkv),
+        in_specs=[
+            pl.BlockSpec((1, 1, rows_pad, d), q_block),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, rows_pad, d), q_block),
+            pl.BlockSpec((1, 1, rows_pad, 1), q_block),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((_FETCH_BUFFERS, block_kv, d), kt.dtype),
+            pltpu.VMEM((_FETCH_BUFFERS, block_kv, d), vt.dtype),
+            pltpu.SemaphoreType.DMA((2, _FETCH_BUFFERS)),
+        ],
+    )
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _decode_kernel, causal=causal, scale=scale, sq=sq,
+            block_kv=block_kv, num_kv_blocks=num_kv_blocks,
+        ),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hkv, rows_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, rows_pad, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        # what a call moves hangs on its rows' lengths, which no shape
+        # tells: the bound, every row full
+        cost_estimate=pl.CostEstimate(
+            bytes_accessed=(
+                2 * q.size + (kt.size + vt.size) // kt.shape[0]
+            ) * q.dtype.itemsize,
+            **cost,
+        ),
+    )(offsets, kv_lens, layer, qg, kt, vt)
+    out = out[:, :, :rows].reshape(b, hkv, groups, sq, d)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
+    return out, lse[:, :, :rows, 0].reshape(b, hq, sq)
 
 
 def _dq_kernel(

@@ -111,11 +111,13 @@ def batch_spec(sp: bool = False) -> P:
 
 
 def cache_specs(cache: Any) -> Any:
-    """KV cache [L, B, S, Hkv, hd]: batch over dp(+fsdp), kv heads over tp."""
+    """KV cache [L, B, S, Hkv, hd]: batch over dp(+fsdp), kv heads over tp;
+    the per-row vectors ``lengths`` and ``live`` [B] go with the batch."""
     return {
         "k": P(None, ("dp", "fsdp"), None, "tp", None),
         "v": P(None, ("dp", "fsdp"), None, "tp", None),
         "lengths": P(("dp", "fsdp")),
+        "live": P(("dp", "fsdp")),
     }
 
 

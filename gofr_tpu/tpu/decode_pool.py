@@ -257,6 +257,11 @@ class DecodePool:
         self._state_row_bytes = sum(
             leaf.nbytes for name, leaf in self.cache.items() if name in ("s", "z")
         ) // (cfg.n_layers * n_slots)
+        # the positions of K/V the attention kernel fetches at a time
+        # (ops/flash.py, the decode form); 0 for a state
+        from gofr_tpu.ops.flash import DEFAULT_BLOCK_KV
+
+        self._kv_block = DEFAULT_BLOCK_KV if "k" in self.cache else 0
         self._live_mask: Optional[tuple] = None  # what cache["live"] holds
         self._n_params = n_params
         self._peak = peak_flops
@@ -323,6 +328,8 @@ class DecodePool:
         def read_slot(pool: dict, i) -> dict:
             # COPY, not a view: the pool cache is donated into every later
             # chunk dispatch; a handed-back row must own its buffers
+            # (``live`` comes back 1: the slot held the request in every
+            # mask since its row was written)
             return {
                 name: jnp.copy(jax.lax.dynamic_slice_in_dim(
                     leaf, i, 1, axis=0 if leaf.ndim == 1 else 1))
@@ -1045,14 +1052,7 @@ class DecodePool:
             self._top_ps_dev = jnp.asarray(self._top_ps)
             self._min_ps_dev = jnp.asarray(self._min_ps)
             self._sampling_dirty = False
-        if "live" in self.cache:
-            # a cache that is a state per slot: the step moves no state for a
-            # slot that holds no request (ops/retention.py), so say which do
-            live = tuple(int(i in self._active) for i in range(self.n_slots))
-            if live != self._live_mask:
-                self._live_mask = live
-                self.cache = {**self.cache, "live": self._replicate(
-                    jnp.asarray(live, jnp.int32))}
+        self._sync_live()
         drec = None
         if self._timeline is not None:
             # dispatch timeline: one record per chunk; every active
@@ -1107,6 +1107,16 @@ class DecodePool:
             # decode keeps its cadence; prefill chunks take the gaps
             # between these notes
             self._sched.note_decode_chunk(len(records))
+
+    def _sync_live(self) -> None:
+        """Keep ``cache["live"]`` to the active slots (pool lock held): a
+        step reads no K/V and moves no state for a slot that holds no
+        request (ops/flash.py, ops/retention.py)."""
+        live = tuple(int(i in self._active) for i in range(self.n_slots))
+        if live != self._live_mask:
+            self._live_mask = live
+            self.cache = {**self.cache, **self._place(
+                {"live": jnp.asarray(live, jnp.int32)})}
 
     # -- pooled speculative decoding (spec cycles) ----------------------------
     def _build_spec_exec(self, cfg: Any, cache_shardings: Any,
@@ -1251,6 +1261,7 @@ class DecodePool:
                 if req.record is not None:
                     req.record.note_dispatch_id(drec.dispatch_id)
             self._pending_chunk_drec = drec
+        self._sync_live()
         dispatch_start = _perf_counter()
         next_dev, self.cache = self._verify_pool(
             self.params, jnp.asarray(tokens), self.cache
@@ -1531,6 +1542,11 @@ class DecodePool:
                 elapsed if self._chunk_ema_s <= 0
                 else 0.8 * self._chunk_ema_s + 0.2 * elapsed
             )
+        if drec is not None and self._kv_block:
+            drec.kv_blocks_read = self._kv_blocks_read(records)
+            drec.kv_blocks_held = (
+                self.chunk * self.n_slots * (self.max_len // self._kv_block)
+            )
         delivered = 0
         for index, req in records:
             if req is None or req.finished:
@@ -1543,6 +1559,17 @@ class DecodePool:
         if drec is not None:
             drec.tokens = delivered
         self._account_chunk(delivered, elapsed, drec)
+
+    def _kv_blocks_read(self, records: list) -> int:
+        """Blocks of K/V the fetched chunk's attention had to read a layer
+        (pool lock held, before the rows' lengths move on): each step of
+        each row that rode it reads up to the row's length, the token
+        of that step included."""
+        return sum(
+            -(-min(req.cache_len + step + 1, self.max_len) // self._kv_block)
+            for _, req in records if req is not None
+            for step in range(self.chunk)
+        )
 
     def _deliver_one(self, index: int, req: "_Request", toks: np.ndarray,
                      lps: np.ndarray, tvals: Any, tids: Any) -> int:

@@ -102,7 +102,7 @@ class DispatchRecord:
         "t_done", "mfu", "mbu", "predicted_ms", "residual_ratio",
         "cost_source", "anomaly",
         "t_issued", "t_fetch", "t_fetched", "cadence_s", "chunks_ahead",
-        "state_bytes", "carried",
+        "state_bytes", "kv_blocks_read", "kv_blocks_held", "carried",
     )
 
     def __init__(
@@ -162,6 +162,12 @@ class DispatchRecord:
         # bytes of state its live rows had to read and write (rows x
         # layers x 2 x one row's bytes x steps); None for a K/V cache
         self.state_bytes: Optional[int] = None
+        # a decode chunk over a K/V cache: the blocks of 128 positions its
+        # attention had to read (steps x sum over live rows of the blocks
+        # up to the row's length) and the blocks the pool's cache holds
+        # (steps x slots x max_seq / 128); None for a state
+        self.kv_blocks_read: Optional[int] = None
+        self.kv_blocks_held: Optional[int] = None
         # a prefill chunk: whether it began from what an earlier chunk of
         # the same prompt left in the cache
         self.carried: Optional[bool] = None
@@ -203,6 +209,8 @@ class DispatchRecord:
             "cadence_s": self.cadence_s,
             "chunks_ahead": self.chunks_ahead,
             "state_bytes": self.state_bytes,
+            "kv_blocks_read": self.kv_blocks_read,
+            "kv_blocks_held": self.kv_blocks_held,
             "carried": self.carried,
             "mfu": self.mfu,
             "mbu": self.mbu,

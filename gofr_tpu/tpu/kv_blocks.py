@@ -1137,9 +1137,12 @@ class JaxKVArena:
             gv = jnp.take(av, ids, axis=1).reshape(
                 n_layers, nps * bt, -1, cfg.head_dim
             )[:, None]
+            # a row as ``init_cache`` makes one (its one row live), so the
+            # programs that take it were compiled for its leaves
             return {
                 "k": gk, "v": gv,
                 "lengths": jnp.reshape(length, (1,)).astype(jnp.int32),
+                "live": jnp.ones((1,), jnp.int32),
             }
 
         # the arena is donated through scatter (updated in place — it is

@@ -31,6 +31,9 @@ from gofr_tpu.models.transformer import (
 CFG = dataclasses.replace(TINY, max_seq=64)  # f32, XLA attention
 SLOTS, STEPS, WIDTH = 4, 4, 16
 LENS = (5, 0, 9, 0)  # ragged rows, idle slots between them
+# what a pool's cache says of such slots: not live, and a length left over
+# from the request before (the pool never resets one)
+LIVE, STALE = (1, 0, 1, 0), (0, 40, 0, 57)
 
 
 @pytest.fixture(scope="module")
@@ -159,14 +162,25 @@ def _prefilled(params, cfg, prompts, lens):
     return jnp.argmax(logits, -1).astype(jnp.int32), cache
 
 
+def _as_in_a_pool(cache):
+    """``live`` for the active slots only, stale lengths on the idle ones."""
+    live = jnp.asarray(LIVE, jnp.int32)
+    lengths = jnp.where(live > 0, cache["lengths"], jnp.asarray(STALE, jnp.int32))
+    return {**cache, "live": live, "lengths": lengths}
+
+
 def _pool_case(params, cfg=CFG, atol=2e-4, exact=True):
     prompts = _prompts(cfg, LENS)
     first, cache = _prefilled(params, cfg, prompts, LENS)
-    toks, lps, *_ = jax.jit(
+    toks, lps, *_, cache = jax.jit(
         lambda p, t, c, *a: decode_chunk_pool(p, t, c, cfg, STEPS, *a)
-    )(params, first[:, None], cache, *_pool_args())
+    )(params, first[:, None], _as_in_a_pool(cache), *_pool_args())
     want = _teacher(params, cfg, prompts, LENS, first, toks)
     _check_rows(want, toks, lps, [0, 2], atol, exact)
+    # the chunk hands the mask on as it came, and every row's length moves
+    assert np.asarray(cache["live"]).tolist() == list(LIVE)
+    assert np.asarray(cache["lengths"]).tolist() == [
+        (n if on else old) + STEPS for n, on, old in zip(LENS, LIVE, STALE)]
 
 
 def _penalized_case(params):
@@ -268,3 +282,33 @@ CASES = {
 @pytest.mark.parametrize("case", list(CASES))
 def test_cached_paths_match_the_plain_forward(params, case):
     CASES[case](params)
+
+
+def test_every_row_of_a_prefill_and_of_a_solo_cache_is_live(params):
+    """Only a pool marks rows off: the cache ``init_cache`` makes, what a
+    prefill returns and what a solo chunk returns say every row is live."""
+    lens = (11, 7)
+    assert np.asarray(init_cache(CFG, len(lens))["live"]).tolist() == [1, 1]
+    first, cache = _prefilled(params, CFG, _prompts(CFG, lens), lens)
+    assert np.asarray(cache["live"]).tolist() == [1, 1]
+    _, cache = jax.jit(lambda p, t, c, k: decode_chunk(p, t, c, CFG, STEPS, k))(
+        params, first[:, None], cache, jax.random.key(3))
+    assert np.asarray(cache["live"]).tolist() == [1, 1]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_row_that_is_not_live_attends_nothing(params, impl):
+    """Poison the idle slots' K and V: a step still gives the live rows the
+    tokens it gives them over a clean cache, on either attention path."""
+    cfg = dataclasses.replace(CFG, attn_impl=impl)
+    first, cache = _prefilled(params, cfg, _prompts(cfg, LENS), LENS)
+    cache = _as_in_a_pool(cache)
+    idle = (jnp.asarray(LIVE) == 0)[None, :, None, None, None]
+    poisoned = {**cache, "k": jnp.where(idle, jnp.nan, cache["k"]),
+                "v": jnp.where(idle, jnp.nan, cache["v"])}
+    run = jax.jit(lambda p, t, c, *a: decode_chunk_pool(p, t, c, cfg, 1, *a))
+    clean = run(params, first[:, None], cache, *_pool_args())
+    dirty = run(params, first[:, None], poisoned, *_pool_args())
+    for row in (0, 2):
+        assert np.asarray(dirty[0])[row].tolist() == np.asarray(clean[0])[row].tolist()
+        np.testing.assert_allclose(np.asarray(dirty[1])[row], np.asarray(clean[1])[row], atol=1e-6)

@@ -15,6 +15,7 @@ import time
 import urllib.request
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 from gofr_tpu import profiling
@@ -304,6 +305,29 @@ def test_pool_chunks_carry_marks_cadence_and_depth(held_pool):
     first = sorted(chunks, key=lambda r: r["dispatch_id"])[: held_pool.depth]
     assert [r["chunks_ahead"] for r in first] == list(range(held_pool.depth))
     assert held_pool.dev.decode_pool.chunks_in_flight == 0
+
+
+def test_pool_chunks_count_the_kv_blocks_their_attention_had_to_read(held_pool):
+    """Two slots of one 128-position block each (the tiny model's cache):
+    a step reads one block for each row that rides it."""
+    pool = held_pool.dev.decode_pool
+    for record in _records(held_pool.dev, "decode_chunk").values():
+        assert record["kv_blocks_held"] == pool.chunk * pool.n_slots
+        assert record["kv_blocks_read"] == pool.chunk * record["batch_size"]
+        assert record["state_bytes"] is None
+    # the mask is what the last chunk was issued with: whoever rode it
+    assert np.asarray(pool.cache["live"]).tolist() == list(pool._live_mask)
+    assert 1 in pool._live_mask
+
+
+def test_kv_blocks_read_follows_each_rows_length_over_the_steps():
+    from gofr_tpu.tpu.decode_pool import DecodePool
+
+    pool = SimpleNamespace(chunk=8, max_len=2048, _kv_block=128)
+    rows = [(0, SimpleNamespace(cache_len=125)), (1, None), (2, SimpleNamespace(cache_len=2044))]
+    # row 0 attends 126..133 keys: three steps of one block, five of two;
+    # row 2 is full after four steps and stays at the cache's 16 blocks
+    assert DecodePool._kv_blocks_read(pool, rows) == (3 * 1 + 5 * 2) + 8 * 16
 
 
 def test_prefill_issued_behind_k_pool_chunks_records_k(held_pool):

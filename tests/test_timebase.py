@@ -498,7 +498,10 @@ def test_stall_leaves_black_box_bundle_and_history(echo_app):
     # rebuild path — including its bundle-before-quarantine order —
     # is covered by tests/test_recovery.py)
     tpu.recovery.enabled = False
-    tpu.runner.stall_hook = lambda: time.sleep(0.7)
+    # long enough that the bundle is captured while the dispatch still stalls
+    # when every xdist worker is busy (0.7 s lost that race on a loaded
+    # machine: the watchdog fires at 0.15 s, the capture came late)
+    tpu.runner.stall_hook = lambda: time.sleep(2.0)
     try:
         worker = threading.Thread(
             target=lambda: _post(

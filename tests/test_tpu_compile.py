@@ -30,13 +30,17 @@ _MOVERS = ("copy", "reshape", "transpose", "dynamic-slice", "fusion")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as exc:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -101,6 +105,43 @@ def test_pooled_chunk_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu
     # chunk begins and once where it ends: a chunk, not a step or a layer
     assert set(movers) <= {"ENTRY"}, movers
     assert len(movers.get("ENTRY", [])) <= 4, movers
+
+
+def test_pooled_chunk_compiles_for_four_chips_under_tp(topo, as_on_tpu):
+    """``TPU_MESH=tp=4``: the kernel runs per shard under ``shard_map``,
+    2 of the 8 kv heads and their q heads to a chip; K and V left in HBM
+    and the copies out of them must compile there too."""
+    from jax.sharding import NamedSharding
+
+    from gofr_tpu.parallel.mesh import make_mesh, mesh_shape_for
+    from gofr_tpu.parallel.sharding import cache_specs, param_specs
+
+    mesh = make_mesh(mesh_shape_for(4, tp=4), devices=topo.devices)
+    cfg = dataclasses.replace(CFG, mesh=mesh)
+
+    def placed(tree, specs):
+        return jax.tree.map(
+            lambda x, spec: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+            tree, specs)
+
+    params = jax.eval_shape(_abstract_params())
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, SLOTS))
+    rest = jax.eval_shape(lambda: (
+        jnp.zeros((SLOTS, 1), jnp.int32), jax.random.key(0),
+        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
+        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.float32)))
+    whole = jax.sharding.PartitionSpec()
+    tok, *sampling = placed(rest, jax.tree.map(lambda _: whole, rest))
+    hlo = jax.jit(
+        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
+            p, t, c, cfg, 8, key, temp, tk, tp, mp),
+        donate_argnums=(2, 3),
+    ).lower(
+        placed(params, param_specs(params)), tok,
+        placed(cache, cache_specs(cache)), *sampling,
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
 
 
 def test_prefill_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu):
