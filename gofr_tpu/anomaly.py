@@ -1,16 +1,10 @@
-"""Shared anomaly vocabulary + bounded evidence ring (host-side).
+"""Anomaly vocabulary + bounded evidence ring (host-side).
 
-The anomaly ring was born in the dispatch cost model
-(``gofr_tpu/tpu/costmodel.py``) as the evidence store behind
-``GET /admin/anomalies``. The SLO engine (``gofr_tpu/slo.py``) lands its
-burn-rate verdicts in the SAME ring — one anomaly surface, whether the
-evidence is a dispatch blowing its prediction or an error budget
-burning — but it must be constructible on processes that never wire a
-device (fleet routers, bare containers), and ``gofr_tpu.tpu``'s package
-init pays the jax import. So the ring and the cause vocabulary live
-here, import-free of jax; ``costmodel.py`` re-exports both, and every
-existing ``from gofr_tpu.tpu.costmodel import AnomalyRing`` keeps
-working.
+The SLO engine (``gofr_tpu/slo.py``) lands its burn-rate verdicts in an
+:class:`AnomalyRing`, served by ``GET /admin/anomalies`` and carried in
+every postmortem bundle. The ring lives here, import-free of jax, so it
+is constructible on processes that never wire a device (fleet routers,
+bare containers).
 """
 
 from __future__ import annotations
@@ -21,11 +15,8 @@ import time
 from collections import deque
 from typing import Any, Optional
 
-# anomaly causes (the `cause` label of gofr_tpu_dispatch_anomalies_total
-# and the `?cause=` filter of GET /admin/anomalies)
+# anomaly causes (the `?cause=` filter of GET /admin/anomalies)
 ANOMALY_CAUSES = (
-    "slow_dispatch",  # one dispatch exceeded COSTMODEL_ANOMALY_FACTOR x prediction
-    "ema_drift",      # a family's residual EMA drifted past COSTMODEL_EMA_BAND
     "slo_fast_burn",  # an SLO objective burned past SLO_BURN_FAST_RATE on both fast windows
     "slo_slow_burn",  # an SLO objective burned past SLO_BURN_SLOW_RATE on both slow windows
 )

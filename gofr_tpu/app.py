@@ -29,7 +29,6 @@ from gofr_tpu.handler import (
     adapter_unload_handler,
     adapters_list_handler,
     anomalies_admin_handler,
-    costmodel_admin_handler,
     dispatches_admin_handler,
     engine_admin_handler,
     favicon_handler,
@@ -185,10 +184,7 @@ class App:
                         make_endpoint(engine_admin_handler, self.container))
         self.router.add("GET", "/admin/dispatches",
                         make_endpoint(dispatches_admin_handler, self.container))
-        # dispatch cost model (tpu/costmodel.py): cost sheets +
-        # calibration + residuals, and the anomaly surface it feeds
-        self.router.add("GET", "/admin/costmodel",
-                        make_endpoint(costmodel_admin_handler, self.container))
+        # the SLO engine's burn-alert evidence ring (anomaly.py)
         self.router.add("GET", "/admin/anomalies",
                         make_endpoint(anomalies_admin_handler, self.container))
         # telemetry timebase (timebase.py): retained metric history +

@@ -179,25 +179,6 @@ remaining deadline budget), ``SPEC_FAKE_ACCEPT`` (echo runner only: a
 cyclic schedule of per-cycle accept counts, e.g. "3,1,0", making
 every accept/reject/rollback branch deterministic in tier-1).
 
-Dispatch-cost-model keys (tpu/costmodel.py, see
-docs/advanced-guide/observability.md "Cost model & anomalies"):
-``COSTMODEL`` (on — per-dispatch roofline prediction + residual
-accounting + the anomaly surface; off removes the whole layer),
-``COSTMODEL_PROFILE`` (path to a cost-profile JSON; default the
-committed ``gofr_tpu/tpu/cost_profile.json`` — ``tools/costcal.py``
-owns the fit), ``COSTMODEL_HLO`` (``auto`` — harvest
-``cost_analysis()`` sheets by recompiling prefill buckets at warmup on
-TPU only; ``on`` forces it, ``off`` skips it — tier-1/CPU never pays
-the recompiles), ``COSTMODEL_ANOMALY_FACTOR`` (4 — observed past this
-multiple of predicted flags ``slow_dispatch``),
-``COSTMODEL_MIN_ANOMALY_MS`` (50 — absolute excess floor both anomaly
-causes must ALSO clear; the no-false-positive guarantee for
-microsecond dispatches), ``COSTMODEL_EMA_ALPHA`` (0.2) /
-``COSTMODEL_EMA_BAND`` (2.5) govern the per-family residual EMA and
-its ``ema_drift`` verdict (latched per excursion), and
-``ANOMALY_RING_SIZE`` (256) bounds the typed-event ring behind
-``GET /admin/anomalies``.
-
 SLO & tenant-metering keys (slo.py + telemetry.py TenantLedger, see
 docs/advanced-guide/observability.md "SLOs, budgets & tenants"):
 ``SLO_TARGETS`` (default
@@ -215,7 +196,8 @@ alerting is multi-window: the fast page fires past
 ``SLO_EVAL_INTERVAL_S`` (15) paces the evaluator thread and ``SLO``
 (on) removes the layer entirely. Windows clip silently to what the
 flight-record ring and ``TIMEBASE_WINDOW_S`` retain. Verdicts land in
-the anomaly ring (``slo_fast_burn``/``slo_slow_burn``), on
+the anomaly ring (``slo_fast_burn``/``slo_slow_burn``; bounded by
+``ANOMALY_RING_SIZE``, 256, served by ``GET /admin/anomalies``), on
 ``gofr_tpu_slo_burn_rate{objective,window}`` /
 ``gofr_tpu_slo_budget_remaining{objective}`` /
 ``gofr_tpu_slo_burn_alerts_total``, and on ``GET /admin/slo/budget``.
@@ -246,9 +228,9 @@ from typing import Optional, Protocol
 
 # The config-surface provenance registry (gofrlint GFL008): every env
 # key package code reads must have a row here, and every row must be
-# read somewhere in the tree (package, tools, bench or tests) — an
+# read somewhere in the tree (package, tools or tests) — an
 # unreadable row is an inert knob and fails lint. Harness-only knobs
-# (BENCH_*, FLEETSIM_GATE_*, WATCH_*) belong to their scripts, not to
+# (FLEETSIM_GATE_*, WATCH_*) belong to their scripts, not to
 # the package surface, and are deliberately NOT declared. The prose
 # sections of the module docstring above stay the operator-facing
 # documentation; this dict is the machine-checked index of it.
@@ -347,16 +329,8 @@ DECLARED_KEYS: dict[str, str] = {
     "TRACER_HOST": "zipkin exporter host",
     "TRACER_PORT": "zipkin exporter port",
     "FLEET_TRACE_SCRAPE_TIMEOUT_S": "per-replica trace-evidence budget",
-    # dispatch cost model
-    "COSTMODEL": "roofline prediction + anomaly surface",
-    "COSTMODEL_PROFILE": "cost-profile JSON path",
-    "COSTMODEL_HLO": "HLO cost-sheet harvest mode",
-    "COSTMODEL_ANOMALY_FACTOR": "slow-dispatch multiple",
-    "COSTMODEL_MIN_ANOMALY_MS": "absolute anomaly excess floor",
-    "COSTMODEL_EMA_ALPHA": "residual EMA smoothing",
-    "COSTMODEL_EMA_BAND": "residual EMA drift band",
-    "ANOMALY_RING_SIZE": "typed anomaly-event ring capacity",
     # SLO engine + tenant metering
+    "ANOMALY_RING_SIZE": "typed anomaly-event ring capacity",
     "SLO": "SLO evaluation layer toggle",
     "SLO_TARGETS": "objective spec (scope:metric=target;...)",
     "SLO_BURN_FAST_S": "fast-burn short window",

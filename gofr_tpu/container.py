@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
+from gofr_tpu.anomaly import AnomalyRing
 from gofr_tpu.config import Config
 from gofr_tpu.datasource.health import DOWN, UP, Health
 from gofr_tpu.logging import new_logger
@@ -107,23 +108,23 @@ class Container:
             self._wire_sql()
             self._wire_tpu()
         # SLO engine: error budgets + multi-window burn-rate alerting over
-        # the flight recorder and the timebase's shed counters. Wired
-        # AFTER the device so its verdicts land in the SAME anomaly ring
-        # as the dispatch cost model (one /admin/anomalies surface);
-        # router/bare processes get the engine's own host-side ring. A
+        # the flight recorder and the timebase's shed counters; its
+        # verdicts land in the ring behind /admin/anomalies. A
         # malformed SLO_TARGETS fails the boot with the clause named — an
         # objective silently not alerting is the one failure mode this
         # layer must not have.
         self.slo: Optional[SloEngine] = None
         if config.get_or_default("SLO", "on") != "off":
-            costmodel = getattr(self.tpu, "costmodel", None)
+            ring_size = int(config.get_or_default("ANOMALY_RING_SIZE", "256"))
+            if ring_size < 1:
+                raise ValueError("ANOMALY_RING_SIZE must be >= 1")
             self.slo = SloEngine(
                 self.telemetry,
                 timebase=self.timebase,
                 metrics=self.metrics,
                 logger=self.logger,
                 targets=config.get_or_default("SLO_TARGETS", DEFAULT_TARGETS),
-                ring=getattr(costmodel, "ring", None),
+                ring=AnomalyRing(ring_size),
                 fast_s=float(config.get_or_default("SLO_BURN_FAST_S", "300")),
                 fast_long_s=float(
                     config.get_or_default("SLO_BURN_FAST_LONG_S", "3600")

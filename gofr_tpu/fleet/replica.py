@@ -503,20 +503,9 @@ class ReplicaSet:
         engine["brownout_level"] = (
             brownout.get("level") if isinstance(brownout, dict) else None
         )
-        # cost-model watchtower: anomaly totals + worst residual EMA
-        # piggybacked off engine_snapshot()'s `costmodel` block, so
-        # /admin/fleet/overview can name the replica blowing its
-        # predictions without a second fan-out scrape
-        cm = data.get("costmodel")
-        engine["anomalies"] = (
-            cm.get("anomalies_total") if isinstance(cm, dict) else None
-        )
-        engine["worst_residual_ema"] = (
-            cm.get("worst_residual_ema") if isinstance(cm, dict) else None
-        )
         # SLO + tenant headlines ride the same scrape: the fleet
         # overview aggregates burn/budget/top-talkers router-side with
-        # zero extra endpoints (same piggyback discipline as costmodel)
+        # zero extra endpoints
         engine["slo"] = (
             data.get("slo") if isinstance(data.get("slo"), dict) else None
         )

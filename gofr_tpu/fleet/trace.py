@@ -17,7 +17,7 @@ This module joins the evidence the hop-correlation layer leaves behind:
   stamped with the same id.
 
 :func:`assemble` is PURE — dicts in, dict out, no I/O, no clock — so
-bench.py can measure it and tests can drive it with fuzzed garbage.
+tests can drive it with fuzzed garbage.
 :func:`gather_evidence` does the scraping (each attempt replica's
 ``/admin/requests?request_id=`` and the involved replicas'
 ``/admin/engine`` ledgers, over the same unauthenticated replica

@@ -395,52 +395,21 @@ def dispatches_admin_handler(ctx: Context) -> Any:
     return {"dispatches": records, "count": len(records)}
 
 
-def costmodel_admin_handler(ctx: Context) -> Any:
-    """GET /admin/costmodel: the dispatch cost model on one page — the
-    calibration in force (profile row + provenance), every cost sheet
-    (HLO-harvested or synthetic, source labeled), per-family residual
-    EMAs, anomaly thresholds, ring stats, and the anomaly-rate trend
-    from the timebase ring. Host-side reads only."""
-    from gofr_tpu.errors import HTTPError
-
-    _check_admin(ctx)
-    if ctx.tpu is None:
-        raise HTTPError(503, "tpu not configured (set MODEL_NAME)")
-    costmodel = getattr(ctx.tpu, "costmodel", None)
-    if costmodel is None:
-        raise HTTPError(503, "cost model disabled (set COSTMODEL=on)")
-    out = costmodel.snapshot()
-    out["anomalies_per_sec"] = _trend(
-        ctx.container.timebase.rate_total("gofr_tpu_dispatch_anomalies_total")
-    )
-    return out
-
-
 def anomalies_admin_handler(ctx: Context) -> Any:
     """GET /admin/anomalies: the anomaly surface — typed events, newest
-    first: the cost model's (``slow_dispatch`` when a dispatch blew past
-    its prediction, ``ema_drift`` when a family's residual EMA left the
-    band) and the SLO engine's burn verdicts (``slo_fast_burn`` /
-    ``slo_slow_burn``) in the SAME ring. ``?kind=`` / ``?cause=``
+    first: the SLO engine's burn verdicts (``slo_fast_burn`` /
+    ``slo_slow_burn``) from its evidence ring. ``?kind=`` / ``?cause=``
     filter; ``?limit=`` bounds the page (default 100). A healthy
     process serves an EMPTY list — every entry here is a regression
-    with evidence attached. On a device-wired replica the ring is the
-    cost model's (the SLO engine shares it); a router or bare process
-    serves the SLO engine's own host-side ring."""
+    with evidence attached."""
     from gofr_tpu.anomaly import ANOMALY_CAUSES
     from gofr_tpu.errors import HTTPError, InvalidParamError
 
     _check_admin(ctx)
-    costmodel = getattr(ctx.tpu, "costmodel", None)
     slo = getattr(ctx.container, "slo", None)
-    ring = costmodel.ring if costmodel is not None else (
-        slo.ring if slo is not None else None
-    )
-    if ring is None:
-        raise HTTPError(
-            503,
-            "no anomaly ring on this process (set COSTMODEL=on or SLO=on)",
-        )
+    if slo is None:
+        raise HTTPError(503, "no anomaly ring on this process (set SLO=on)")
+    ring = slo.ring
     try:
         limit = int(ctx.param("limit") or "100")
     except ValueError:
@@ -552,17 +521,6 @@ def overview_admin_handler(ctx: Context) -> Any:
     out["platform"] = tpu.platform
     out["watchdog"] = tpu.watchdog.snapshot()
     out["dispatches"] = tpu.timeline.stats()
-    costmodel = getattr(tpu, "costmodel", None)
-    if costmodel is not None:
-        # cost-model headline: sheet count, worst residual EMA, anomaly
-        # totals + rate trend (zero on a healthy engine — any other
-        # number is the page's loudest line)
-        out["costmodel"] = costmodel.overview()
-        out["anomalies_per_sec"] = _trend(
-            timebase.rate_total("gofr_tpu_dispatch_anomalies_total")
-        )
-    else:
-        out["costmodel"] = None
     batcher = getattr(tpu, "batcher", None)
     out["queue_depth"] = batcher._depth() if batcher is not None else None
     pool = getattr(tpu, "decode_pool", None)
@@ -681,8 +639,6 @@ def fleet_overview_handler(ctx: Context) -> Any:
     kv_seen = False
     transfers: dict[str, int] = {}
     brownout_max = 0
-    anomalies_total = 0
-    anomalies_seen = False
     slo_alerting: list[dict[str, Any]] = []
     slo_worst_burn = None
     slo_worst_replica = None
@@ -713,10 +669,6 @@ def fleet_overview_handler(ctx: Context) -> Any:
         level = engine.get("brownout_level")
         if isinstance(level, int):
             brownout_max = max(brownout_max, level)
-        anomalies = engine.get("anomalies")
-        if isinstance(anomalies, int) and not isinstance(anomalies, bool):
-            anomalies_seen = True
-            anomalies_total += anomalies
         # SLO + tenant rollup off the same piggybacked engine scrape —
         # router-side aggregation only, never a fan-out on request
         slo = engine.get("slo") or {}
@@ -759,10 +711,6 @@ def fleet_overview_handler(ctx: Context) -> Any:
             "kv_free": engine.get("kv_free"),
             "kv_total": engine.get("kv_total"),
             "brownout_level": level,
-            # cost-model residual watchtower, per replica: which box is
-            # blowing its predictions (scraped off /admin/engine)
-            "anomalies": anomalies,
-            "worst_residual_ema": engine.get("worst_residual_ema"),
             # SLO headline per replica: which box is burning its budget
             "slo_worst_burn": slo.get("worst_burn"),
             "slo_alerting": slo.get("alerting"),
@@ -783,7 +731,6 @@ def fleet_overview_handler(ctx: Context) -> Any:
         "kv_total": kv_total if kv_seen else None,
         "kv_transfers": transfers,
         "brownout_level_max": brownout_max,
-        "anomalies_total": anomalies_total if anomalies_seen else None,
         "slo": {
             "worst_burn": slo_worst_burn,
             "worst_replica": slo_worst_replica,

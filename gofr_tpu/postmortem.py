@@ -7,8 +7,7 @@ with it. This module is the flight recorder's crash-survivable twin: when the en
 wedges, the process crashes, or an operator asks, the ENTIRE
 observability state is serialized into one atomic
 ``postmortem-<ts>.json`` bundle under ``POSTMORTEM_DIR`` — readable
-after SIGKILL, harvestable by ``bench.py`` (``BENCH_POSTMORTEM_OUT``),
-pretty-printed by ``tools/postmortem_view.py``.
+after SIGKILL, pretty-printed by ``tools/postmortem_view.py``.
 
 Bundle contents (schema ``gofr-postmortem/1``):
 
@@ -67,8 +66,7 @@ SCHEMA = "gofr-postmortem/1"
 # config keys worth carrying in the fingerprint: every framework prefix
 # (the bundle must reproduce the serving shape, not the whole shell env)
 CONFIG_PREFIXES = (
-    "ADMIN_", "ANOMALY_", "APP_", "BATCH_", "BENCH_", "COMPILE_",
-    "COSTMODEL_", "DECODE_",
+    "ADMIN_", "ANOMALY_", "APP_", "BATCH_", "COMPILE_", "DECODE_",
     "DISPATCH_", "ECHO_", "FLIGHT_", "GEN_", "GRPC_", "HANDLER_", "HTTP_",
     "LOG_", "METRICS_", "MODEL_", "POSTMORTEM_", "PREFILL_", "PREFIX_",
     "SCHED_", "SLO", "SPEC_", "TENANT_", "TIMEBASE_", "TOKENIZER", "TPU_",
@@ -271,6 +269,7 @@ class PostmortemStore:
                 out["slo_budget"] = slo.budget()
             except Exception as exc:
                 out["slo_budget"] = {"error": repr(exc)}
+            out["anomalies"] = slo.ring.events(limit=slo.ring.capacity)
         tenants = getattr(c, "tenants", None)
         if tenants is not None:
             # who was on the box: top-K tenants by token volume (hashed
@@ -292,19 +291,6 @@ class PostmortemStore:
             timeline = getattr(tpu, "timeline", None)
             if timeline is not None:
                 out["dispatches"] = timeline.records(limit=1_000_000)
-            costmodel = getattr(tpu, "costmodel", None)
-            if costmodel is not None:
-                # the residual watchtower's state at death: calibration,
-                # sheets, per-family residual EMAs, and the full anomaly
-                # ring — "was the engine already blowing its predictions
-                # before it wedged" is the first postmortem question
-                try:
-                    out["costmodel"] = costmodel.snapshot()
-                    out["anomalies"] = costmodel.ring.events(
-                        limit=costmodel.ring.capacity
-                    )
-                except Exception as exc:
-                    out["costmodel"] = {"error": repr(exc)}
         return out
 
     def _write_atomic(self, bundle: dict[str, Any]) -> str:

@@ -30,12 +30,9 @@ the Google-SRE way:
   ``SLO_BURN_FAST_RATE`` (14.4) on BOTH the 5m and 1h windows; the
   **slow** ticket fires past ``SLO_BURN_SLOW_RATE`` (6) on both 6h and
   3d. Verdicts are latched per (objective, pair) — one anomaly event
-  per excursion, re-armed when the burn clears — and land in the SAME
-  anomaly ring as the dispatch cost model (``gofr_tpu/anomaly.py``;
-  on replicas the container points the engine at
-  ``tpu.costmodel.ring``, so ``GET /admin/anomalies`` shows
-  ``slo_fast_burn`` next to ``slow_dispatch``), on
-  ``gofr_tpu_slo_burn_alerts_total{objective,window}``, and in every
+  per excursion, re-armed when the burn clears — and land in the
+  anomaly ring (``gofr_tpu/anomaly.py``) behind ``GET /admin/anomalies``,
+  on ``gofr_tpu_slo_burn_alerts_total{objective,window}``, and in every
   postmortem bundle.
 
 - **Surfaces**: ``gofr_tpu_slo_burn_rate{objective,window}`` and
@@ -45,14 +42,12 @@ the Google-SRE way:
   and ``/admin/fleet/overview``.
 
 A healthy echo run evaluates to zero alerts (the tier-1 e2e asserts
-exactly that, same discipline as the cost model's zero-anomaly
-invariant); the default targets are deliberately loose enough that only
+exactly that); the default targets are deliberately loose enough that only
 real fault bursts burn.
 
 Host-side only: evaluation is a single ring scan plus float arithmetic
-per objective (bench.py's slo_microbench keeps it honest) on a named
-daemon thread every ``SLO_EVAL_INTERVAL_S``, and lazily on every
-``/admin/slo/budget`` read.
+per objective on a named daemon thread every ``SLO_EVAL_INTERVAL_S``,
+and lazily on every ``/admin/slo/budget`` read.
 """
 
 from __future__ import annotations
@@ -278,10 +273,9 @@ class SloEngine:
     """Windowed error-budget ledger + burn-rate alerting over the
     FlightRecorder ring and the timebase's shed counters.
 
-    ``ring`` is the anomaly evidence store the burn verdicts land in.
-    The container points it at ``tpu.costmodel.ring`` when a device is
-    wired (one `/admin/anomalies` surface); router/bare processes keep
-    the engine's own host-side ring."""
+    ``ring`` is the anomaly evidence store the burn verdicts land in
+    (the container sizes it from ``ANOMALY_RING_SIZE``); handed none,
+    the engine builds its own."""
 
     WINDOW_PAIRS = ("fast", "slow")
 
@@ -327,7 +321,7 @@ class SloEngine:
         self.ring = ring if ring is not None else AnomalyRing()
         # one latch per (objective, pair): an excursion records ONE
         # anomaly event, re-armed when the burn drops back under the
-        # threshold (mirrors the cost model's ema_drift latch)
+        # threshold
         self._latched: dict[tuple[str, str], bool] = {}
         self._alerts_total = 0
         self._evaluations = 0

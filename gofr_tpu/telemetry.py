@@ -256,7 +256,7 @@ class FlightRecord:
         "model", "endpoint", "status", "error", "stream",
         "tokens_in", "tokens_out", "batch_size", "pool_cohort",
         "prefill_chunks", "prefill_bucket", "sched_defer_s",
-        "pool_reject_reason", "dispatch_ids", "anomalous_dispatches",
+        "pool_reject_reason", "dispatch_ids",
         "spec_drafted", "spec_accepted", "spec_dispatches", "spec_emitted",
         "kv_blocks", "kv_aliased_blocks", "mesh_axes",
         "tenant", "deadline_s", "priority", "shed_stage",
@@ -310,10 +310,6 @@ class FlightRecord:
         self.sched_defer_s = 0.0  # total interference-scheduler defer
         self.pool_reject_reason = ""  # why the decode pool refused (solo'd)
         self.dispatch_ids: list[int] = []  # device dispatches this rode
-        # of those, the ones the cost model flagged anomalous
-        # (tpu/costmodel.py): a slow request's wide event names the
-        # exact dispatch that blew its prediction
-        self.anomalous_dispatches: list[int] = []
         # pooled speculative decoding (tpu/spec_pool.py): draft tokens
         # proposed/accepted and the verify dispatches + tokens they
         # emitted — tokens_per_dispatch is THE number speculation exists
@@ -423,18 +419,6 @@ class FlightRecord:
         with self._lock:
             if len(self.dispatch_ids) < self.MAX_DISPATCH_IDS:
                 self.dispatch_ids.append(dispatch_id)
-
-    def note_anomaly(self, dispatch_id: int) -> None:
-        """The cost model flagged a dispatch this request rode as
-        anomalous (observed blew past predicted, tpu/costmodel.py) —
-        the wide event then pins the slow request to the exact
-        `/admin/anomalies` entry. Same bound as the id list."""
-        with self._lock:
-            if (
-                dispatch_id not in self.anomalous_dispatches
-                and len(self.anomalous_dispatches) < self.MAX_DISPATCH_IDS
-            ):
-                self.anomalous_dispatches.append(dispatch_id)
 
     def note_pool_reject(self, reason: str) -> None:
         """The decode pool refused this request (it decoded solo); the
@@ -576,7 +560,6 @@ class FlightRecord:
             "sched_defer_s": self.sched_defer_s or None,
             "pool_reject_reason": self.pool_reject_reason or None,
             "dispatch_ids": list(self.dispatch_ids),
-            "anomalous_dispatches": list(self.anomalous_dispatches) or None,
             "spec_drafted": self.spec_drafted or None,
             "spec_accepted": self.spec_accepted or None,
             "tokens_per_dispatch": self.tokens_per_dispatch,
@@ -1008,7 +991,7 @@ class TenantLedger:
 
     Lock-guarded dict arithmetic only — the feed point is
     ``FlightRecorder.finish`` plus the shed paths, i.e. the request hot
-    path (bench.py's slo_microbench keeps the cost honest)."""
+    path."""
 
     OTHER = "~other"
     FIELDS = (

@@ -220,9 +220,8 @@ class TimebaseSampler:
     ) -> list[list[float]]:
         """Counter rate summed across every label-set — the "req/s"
         shape of a labeled counter. ``labels`` restricts the sum to
-        matching subsets (same semantics as ``series()``: the cost-model
-        rollup sums one anomaly ``cause`` across kinds). Empty list when
-        unknown."""
+        matching subsets (same semantics as ``series()``). Empty list
+        when unknown."""
         snaps = self.snapshots(window=window)
         points: list[tuple[float, float, float]] = []
         for snap in snaps:

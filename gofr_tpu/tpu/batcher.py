@@ -353,8 +353,8 @@ class DynamicBatcher:
         if drec is not None:
             # running starts AFTER the scheduler gate (the interleave
             # defer shows as the record's queue_wait tail) and activates
-            # on this thread so device code (run_batch) can stamp
-            # per-dispatch MFU/token values only it knows
+            # on this thread so device code (run_batch) can stamp the
+            # token count only it knows
             drec.mark_running()
             activate_dispatch(drec)
         try:
@@ -364,13 +364,6 @@ class DynamicBatcher:
                         [item.payload for item in batch]
                     )
                 self._finish_record(drec)  # before the error-sweep below
-                if drec is not None and drec.anomaly:
-                    # the cost model flagged this dispatch on finish():
-                    # pin it onto every rider's wide event so the slow
-                    # request resolves to the /admin/anomalies entry
-                    for item in batch:
-                        if item.record is not None:
-                            item.record.note_anomaly(drec.dispatch_id)
             except Exception as exc:
                 self._finish_record(drec, status="error")
                 span.set_tag("error", exc)

@@ -168,9 +168,6 @@ class DecodePool:
         chunk: int,
         metrics: Any = None,
         cache_shardings: Any = None,
-        n_params: Any = None,
-        peak_flops: Any = None,
-        peak_hbm_bw: Any = None,
         model: str = "",
         pipeline_depth: int = PIPELINE_DEPTH,
         penalties: str = "lazy",
@@ -263,8 +260,6 @@ class DecodePool:
 
         self._kv_block = DEFAULT_BLOCK_KV if "k" in self.cache else 0
         self._live_mask: Optional[tuple] = None  # what cache["live"] holds
-        self._n_params = n_params
-        self._peak = peak_flops
         self._model = model
         # under a mesh, pin EVERY executable's feedback outputs (tokens,
         # key) to replicated and the cache to its mesh placement: GSPMD
@@ -364,8 +359,7 @@ class DecodePool:
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._closed = False
-        self._peak_bw = peak_hbm_bw
-        self._init_metrics(metrics, params)
+        self._init_metrics(metrics)
         # warm the [n_slots]-shaped executable NOW: the first pooled request
         # must not compile under the pool lock on the serving path
         toks, _, _, _, _, self._key, self.cache = self._decode(
@@ -400,7 +394,7 @@ class DecodePool:
         )
         self._thread.start()
 
-    def _init_metrics(self, metrics: Any, params: Any) -> None:
+    def _init_metrics(self, metrics: Any) -> None:
         """Register the pool's metric instruments (None registry = all
         instruments None; callers already guard on that)."""
         self._depth_gauge = (
@@ -433,34 +427,13 @@ class DecodePool:
         # chunk): the admission gate's unit of "can this request still
         # get even one chunk of decode before its deadline"
         self._chunk_ema_s = 0.0
-        self._mfu_gauge = self._tokens_counter = self._mbu_gauge = None
-        self._bytes_per_step = 0
-        if metrics is not None:
-            from gofr_tpu.tpu.flops import tree_bytes
-
-            # lookups — the registration home (help text) for both
-            # families is tpu/device.py _init_metrics (GFL007)
-            self._mfu_gauge = metrics.gauge(
-                "gofr_tpu_mfu", labels=("model", "op")
-            )
-            self._tokens_counter = metrics.counter(
-                "gofr_tpu_tokens_total", labels=("model", "op")
-            )
-            # decode is bandwidth-bound: each step streams the full weight
-            # set plus the pool's KV window (static shapes — XLA reads the
-            # whole masked window), so MBU, not MFU, says how close the
-            # pooled decode runs to the hardware roofline. Both gauges are
-            # SET only where a peak exists (a TPU kind in the flops.py
-            # table): no other platform exports a utilization.
-            self._bytes_per_step = tree_bytes(params) + tree_bytes(
-                {k: v for k, v in self.cache.items() if v.ndim > 1}
-            )
-            self._mbu_gauge = metrics.gauge(
-                "gofr_tpu_mbu",
-                "HBM bandwidth utilization of the decode loop "
-                "(weights+KV bytes per step / time / peak bandwidth)",
-                labels=("model", "op"),
-            )
+        # lookup — the registration home (help text) is
+        # tpu/device.py _init_metrics (GFL007)
+        self._tokens_counter = (
+            metrics.counter("gofr_tpu_tokens_total", labels=("model", "op"))
+            if metrics is not None
+            else None
+        )
 
     # -- per-slot penalties ---------------------------------------------------
     def _enable_penalties(self) -> None:
@@ -1352,7 +1325,7 @@ class DecodePool:
             self._depth_gauge.set(len(self._active))
         if drec is not None:
             drec.tokens = delivered_total
-        self._account_chunk(delivered_total, elapsed, drec, steps=1)
+        self._account_chunk(delivered_total)
         # per-ROW semantics on the shared gauge: one verify serves
         # len(records) rows, and the echo mirror publishes per-request
         # values — dividing keeps "1.0 = plain decode" true for both
@@ -1495,13 +1468,12 @@ class DecodePool:
                 )
             self.chunks_in_flight -= 1
             fetch_done = _perf_counter()
-            # throughput denominator: the interval between consecutive
-            # deliveries at steady state (dispatch->fetch spans ~2 chunk
-            # computes when the pipeline is full and would halve the MFU
-            # gauge); after an idle gap, fall back to this chunk's own
-            # span. Floor at span/depth: a host stall can make both
-            # in-flight chunks finish before the next fetch, shrinking the
-            # inter-delivery gap to ~0 and spiking the gauge past reality.
+            # the cadence: the interval between consecutive deliveries
+            # at steady state (dispatch->fetch spans ~2 chunk computes
+            # when the pipeline is full); after an idle gap, fall back
+            # to this chunk's own span. Floor at span/depth: a host stall
+            # can make both in-flight chunks finish before the next
+            # fetch, shrinking the inter-delivery gap to ~0.
             span = fetch_done - dispatch_start
             dispatch_elapsed = max(
                 fetch_done - max(dispatch_start, last_fetch_done),
@@ -1523,12 +1495,6 @@ class DecodePool:
             raise
         if self._timeline is not None and drec is not None:
             self._timeline.finish(drec)
-            if drec.anomaly:
-                # cost-model flag landed on finish(): pin the anomalous
-                # chunk onto every rider's wide event
-                for _, req in records:
-                    if req is not None and req.record is not None:
-                        req.record.note_anomaly(drec.dispatch_id)
         return fetch_done
 
     def _deliver(self, records: list, toks: np.ndarray, lps: np.ndarray,
@@ -1558,7 +1524,7 @@ class DecodePool:
             self._depth_gauge.set(len(self._active))
         if drec is not None:
             drec.tokens = delivered
-        self._account_chunk(delivered, elapsed, drec)
+        self._account_chunk(delivered)
 
     def _kv_blocks_read(self, records: list) -> int:
         """Blocks of K/V the fetched chunk's attention had to read a layer
@@ -1629,50 +1595,12 @@ class DecodePool:
             self._finish_request(index, req, cancelled, expired=expired)
         return delivered
 
-    def _account_chunk(self, delivered: int, elapsed: float,
-                       drec: Any, steps: Optional[int] = None) -> None:
-        """Roofline accounting for one delivered chunk (pool lock
-        held): MFU/MBU gauges, token counter, dispatch-record stamps.
-        ``steps`` overrides the weight-stream count: a plain chunk
-        streams the weights once per scan step (``self.chunk``); a spec
-        verify is ONE forward over all positions — weights stream once,
-        which is the entire point of speculation."""
+    def _account_chunk(self, delivered: int) -> None:
+        """Count one delivered chunk's useful tokens (pool lock held):
+        only tokens put on request queues, not garbage rows, cancelled
+        requests or discarded chunk tails."""
         if delivered and self._tokens_counter is not None:
             self._tokens_counter.inc(delivered, model=self._model, op="decode")
-        if delivered and self._n_params and self._peak:
-            from gofr_tpu.tpu.flops import mfu
-
-            # useful tokens only: tokens put on request queues (garbage
-            # rows, cancelled requests, and discarded chunk tails are real
-            # compute but not useful throughput). With a full pipeline the
-            # per-chunk elapsed overlaps the next chunk's compute, so this
-            # gauge reflects steady-state throughput, not isolated latency.
-            value = mfu(self._n_params, delivered, elapsed, self._peak)
-            if self._mfu_gauge is not None:
-                self._mfu_gauge.set(value, model=self._model, op="decode")
-            if drec is not None:
-                drec.mfu = value
-        if self._peak_bw:
-            from gofr_tpu.tpu.flops import mbu
-
-            # bandwidth view of the same interval: a full chunk of steps
-            # streamed weights+KV once per step, whatever fraction of the
-            # emitted tokens was useful. Where a harvested cost sheet
-            # exists for the chunk family, its HLO bytes-accessed replaces
-            # the weights+KV approximation (source labeled on the record).
-            chunk_bytes = self._bytes_per_step * (steps or self.chunk)
-            costmodel = getattr(self._timeline, "costmodel", None)
-            if costmodel is not None and drec is not None:
-                hlo = costmodel.hlo_bytes(
-                    "decode_chunk", bucket=drec.bucket, batch=drec.batch_size
-                )
-                if hlo:
-                    chunk_bytes = hlo
-            value = mbu(chunk_bytes, elapsed, self._peak_bw)
-            if self._mbu_gauge is not None:
-                self._mbu_gauge.set(value, model=self._model, op="decode")
-            if drec is not None:
-                drec.mbu = value
 
     def _build_burst(
         self, req: "_Request", index: int, emitted: Any, emitted_lps: Any,

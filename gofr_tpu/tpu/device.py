@@ -290,8 +290,6 @@ class TPUDevice:
         self.device_kind = "pending"
         self.mesh = None
         self.mesh_axes: Optional[dict[str, int]] = None
-        self.peak_flops = 0.0
-        self.peak_hbm_bw = 0.0
         self.compile_cache_dir = ""  # placed by _boot, before the probe
 
         self._init_metrics(metrics)
@@ -302,30 +300,11 @@ class TPUDevice:
         # the stall watchdog — constructed BEFORE any boot work so the
         # probe itself is already observable
         self.engine = EngineState(metrics=metrics, logger=logger)
-        # dispatch cost model (tpu/costmodel.py): built BEFORE the
-        # timeline so every record — the probe's included — flows
-        # through its predict/observe hooks; calibration coefficients
-        # resolve at probe time (the device kind is known then)
-        self.costmodel = None
-        if self._costmodel_enabled:
-            from gofr_tpu.tpu.costmodel import CostModel
-
-            self.costmodel = CostModel(
-                metrics=metrics,
-                logger=logger,
-                profile_path=self._costmodel_profile,
-                anomaly_factor=self._costmodel_factor,
-                min_anomaly_ms=self._costmodel_floor_ms,
-                ema_alpha=self._costmodel_ema_alpha,
-                ema_band=self._costmodel_ema_band,
-                ring_size=self._anomaly_ring_size,
-            )
         self.timeline = DispatchTimeline(
             capacity=int(
                 config.get_or_default("DISPATCH_TIMELINE_SIZE", "512")
             ),
             metrics=metrics,
-            costmodel=self.costmodel,
         )
         self.watchdog = StallWatchdog(
             self.engine, metrics=metrics, logger=logger,
@@ -406,10 +385,6 @@ class TPUDevice:
         # the LAST COMPILE TO FINISH — not the last call — would win,
         # silently installing a stale bank
         self._adapter_lock = threading.Lock()
-        # prefill MFU steady-state window (see _run_batch): completions
-        # arrive from the batcher's dispatch-pool threads
-        self._last_batch_done = 0.0
-        self._mfu_window_lock = threading.Lock()
         # boot status: surfaced by /.well-known/ready and health details so
         # a slow cold boot (8B-class warmup compiles) is observable, never
         # indistinguishable from a hang
@@ -461,11 +436,6 @@ class TPUDevice:
         )
         self._mem_gauge = metrics.gauge(
             "gofr_tpu_device_memory_bytes", "device memory", labels=("kind",)
-        )
-        self._mfu_gauge = metrics.gauge(
-            "gofr_tpu_mfu",
-            "model FLOPs utilization of the last dispatch (2*N*tokens/time/peak)",
-            labels=("model", "op"),
         )
         self._tokens_counter = metrics.counter(
             "gofr_tpu_tokens_total", "tokens processed", labels=("model", "op")
@@ -564,45 +534,6 @@ class TPUDevice:
         self._echo_step_ms = float(config.get_or_default("ECHO_STEP_MS", "0"))
         if self._echo_step_ms < 0:
             raise ValueError("ECHO_STEP_MS must be >= 0")
-        # dispatch cost model (tpu/costmodel.py): COSTMODEL=off disables
-        # prediction/residual/anomaly accounting entirely; the rest are
-        # the anomaly thresholds and the calibrated-profile override
-        self._costmodel_enabled = (
-            config.get_or_default("COSTMODEL", "on").strip().lower() != "off"
-        )
-        self._costmodel_profile = (
-            config.get_or_default("COSTMODEL_PROFILE", "").strip() or None
-        )
-        self._costmodel_factor = float(
-            config.get_or_default("COSTMODEL_ANOMALY_FACTOR", "4.0")
-        )
-        if self._costmodel_factor <= 1.0:
-            raise ValueError("COSTMODEL_ANOMALY_FACTOR must be > 1")
-        self._costmodel_floor_ms = float(
-            config.get_or_default("COSTMODEL_MIN_ANOMALY_MS", "50")
-        )
-        if self._costmodel_floor_ms < 0:
-            raise ValueError("COSTMODEL_MIN_ANOMALY_MS must be >= 0")
-        self._costmodel_ema_alpha = float(
-            config.get_or_default("COSTMODEL_EMA_ALPHA", "0.2")
-        )
-        self._costmodel_ema_band = float(
-            config.get_or_default("COSTMODEL_EMA_BAND", "2.5")
-        )
-        self._anomaly_ring_size = int(
-            config.get_or_default("ANOMALY_RING_SIZE", "256")
-        )
-        if self._anomaly_ring_size < 1:
-            raise ValueError("ANOMALY_RING_SIZE must be >= 1")
-        hlo_raw = (
-            config.get_or_default("COSTMODEL_HLO", "auto").strip().lower()
-        )
-        if hlo_raw not in ("auto", "on", "off"):
-            raise ValueError(
-                f"COSTMODEL_HLO '{hlo_raw}' not supported — use auto "
-                "(harvest on TPU only), on, or off"
-            )
-        self._costmodel_hlo = hlo_raw
         raw_max_seq = config.get("MODEL_MAX_SEQ")
         self._max_seq_cfg = int(raw_max_seq) if raw_max_seq else None
         # MODEL_KV_DTYPE=f8 stores the KV cache in float8_e4m3fn — half the
@@ -1009,21 +940,6 @@ class TPUDevice:
             for axis, size in self.mesh.shape.items():
                 if size > 1 or axis in ("dp", "fsdp", "tp"):
                     self._mesh_axis_gauge.set(size, axis=axis)
-        from gofr_tpu.tpu.flops import device_peak_flops, device_peak_hbm_bw
-
-        # MFU/MBU denominators = aggregate peak of the chips actually
-        # serving (mesh size under TPU_MESH, else one chip); quant-aware
-        # (w8a8 runs the MXU int8 path — flops.py owns the factor)
-        n_chips = self.mesh.size if self.mesh is not None else 1
-        self.peak_flops = device_peak_flops(
-            str(self.device_kind), self.platform, quant=self.quant
-        ) * n_chips
-        self.peak_hbm_bw = device_peak_hbm_bw(str(self.device_kind), self.platform) * n_chips
-        if self.costmodel is not None:
-            # roofline coefficients resolve against the PROBED kind:
-            # the committed profile row (fit provenance) or the labeled
-            # nominal fallback — /admin/costmodel shows which
-            self.costmodel.calibrate(str(self.device_kind), self.platform)
 
     def _boot(self) -> None:
         del self.boot_timeline[:]
@@ -1194,18 +1110,6 @@ class TPUDevice:
                 self._prefill_chunk_cfg,
             )
         self.runner.warmup(progress=self._boot_progress)
-        if self.costmodel is not None:
-            if self.model_name == "echo":
-                # compile-free synthetic cost table: one echo run_batch
-                # costs one ECHO_STEP_MS sleep whatever the bucket or
-                # batch — the tier-1 predict→observe→alert loop runs
-                # entirely off these sheets (no XLA, no cost_analysis)
-                self.costmodel.install_synthetic("prefill", self._echo_step_ms)
-                self.costmodel.install_synthetic(
-                    "decode_chunk", self._echo_step_ms
-                )
-            elif self._hlo_harvest_enabled():
-                self._harvest_cost_sheets()
         # continuous batching: concurrent decodes share one fixed-shape
         # dispatch per chunk; seeded requests bypass it (device.generate
         # routes them solo — the per-request key sequence must reproduce).
@@ -1239,9 +1143,6 @@ class TPUDevice:
                 chunk=self.runner.decode_chunk_size,
                 metrics=self.metrics,
                 cache_shardings=getattr(self.runner, "_cache_shardings", None),
-                n_params=getattr(self.runner, "n_params", None),
-                peak_flops=self.peak_flops,
-                peak_hbm_bw=self.peak_hbm_bw,
                 model=self.model_name,
                 pipeline_depth=self._pool_depth,
                 penalties=self._pool_penalties,
@@ -1282,59 +1183,6 @@ class TPUDevice:
             timeline=self.timeline,
             watchdog=self.watchdog,
         )
-
-    def _hlo_harvest_enabled(self) -> bool:
-        """COSTMODEL_HLO gate: the AOT lower+compile the harvest needs is
-        NOT linked to the jit cache, so it costs one extra compile per
-        family — paid by default only on TPU (where the persistent
-        compilation cache usually absorbs it), never on the CPU tier-1
-        tiny-model path unless forced with COSTMODEL_HLO=on."""
-        if self._costmodel_hlo == "on":
-            return True
-        return self._costmodel_hlo == "auto" and self.platform == "tpu"
-
-    def _harvest_cost_sheets(self) -> None:
-        """Harvest ``cost_analysis()`` / ``memory_analysis()`` off each
-        warmed prefill executable family into CostSheets (the compiled
-        bucket x padded-batch shape IS the cost, whatever slice of it a
-        given dispatch fills). Prefill only: the decode pool compiles
-        its own pooled shapes — pricing them off the solo runner's b=1
-        decode executable would predict garbage and page people."""
-        runner = self.runner
-        fn = getattr(runner, "_prefill", None)
-        params = getattr(runner, "params", None)
-        zero_cache = getattr(runner, "_zero_cache", None)
-        if fn is None or params is None or zero_cache is None:
-            return
-        b = next_pow2(runner.max_batch)
-        harvested = 0
-        for bucket in getattr(runner, "buckets", ()) or ():
-            self._boot_progress(
-                f"harvesting cost sheet for prefill bucket {bucket}",
-                kind="cost_sheet", bucket=bucket,
-            )
-            try:
-                tokens = jnp.zeros((b, bucket), jnp.int32)
-                lengths = jnp.ones((b,), jnp.int32)
-                compiled = fn.lower(
-                    params, tokens, zero_cache(b), lengths
-                ).compile()
-                sheet = self.costmodel.harvest("prefill", bucket, b, compiled)
-                if sheet is not None:
-                    harvested += 1
-            except Exception as exc:
-                # a backend that can't lower/compile AOT loses the sheet
-                # for this family only — prediction falls back to "no
-                # prediction" there, never a boot failure
-                self.logger.warnf(
-                    "costmodel: HLO harvest failed for prefill bucket "
-                    "%s: %r", bucket, exc,
-                )
-        if harvested:
-            self.logger.infof(
-                "costmodel: harvested %d HLO cost sheet%s",
-                harvested, "" if harvested == 1 else "s",
-            )
 
     def _build_spec_cfg(self, include_fake: bool) -> Any:
         """One PoolSpecConfig per stack build (SPEC_POOLED=on): draft
@@ -2249,64 +2097,8 @@ class TPUDevice:
         drec = current_dispatch()  # the batcher activated this dispatch
         if drec is not None:
             drec.tokens = tokens
-        n_params = getattr(self.runner, "n_params", None)
-        if n_params:
-            from gofr_tpu.tpu.flops import mfu
-
-            if tokens:
-                # steady-state denominator, same shape as the decode
-                # pool's: the batcher pipelines dispatches, so under load
-                # this batch's host round trip overlapped the previous
-                # batch's — the interval between COMPLETIONS is the true
-                # per-batch cost, floored at elapsed/depth (the batcher's
-                # REAL pipeline depth) so an idle-then-burst pair cannot
-                # spike the gauge past reality. Single isolated batches
-                # keep their full (RTT-inclusive) elapsed.
-                depth = getattr(
-                    getattr(self, "batcher", None), "pipeline_depth", 2
-                )
-                with self._mfu_window_lock:
-                    # sampled INSIDE the lock: two dispatch threads
-                    # completing together must not move the window
-                    # backwards (a stale-earlier timestamp inflates the
-                    # next interval back to the isolated reading)
-                    done = time.perf_counter()
-                    steady = max(
-                        done - max(done - elapsed, self._last_batch_done),
-                        elapsed / depth,
-                    )
-                    self._last_batch_done = done
-                self._tokens_counter.inc(tokens, model=self.model_name, op="prefill")
-                if self.peak_flops:
-                    # a peak exists only for a TPU kind in the flops.py
-                    # table: no other platform exports a utilization
-                    self._mfu_gauge.set(
-                        mfu(n_params, tokens, steady, self.peak_flops),
-                        model=self.model_name, op="prefill",
-                    )
-                if drec is not None and self.peak_flops:
-                    # per-dispatch utilization: THIS dispatch's elapsed
-                    # (the steady-state window smooths the gauge; the
-                    # record describes one dispatch). Where an HLO cost
-                    # sheet exists its flops replace the 2·N·tokens
-                    # floor — compiled truth over approximation, source
-                    # labeled on the record (cost_source)
-                    hlo_flops = (
-                        self.costmodel.hlo_flops(
-                            "prefill", drec.bucket, drec.batch_size
-                        )
-                        if self.costmodel is not None else None
-                    )
-                    if hlo_flops:
-                        from gofr_tpu.tpu.flops import mfu_from_flops
-
-                        drec.mfu = mfu_from_flops(
-                            hlo_flops, elapsed, self.peak_flops
-                        )
-                    else:
-                        drec.mfu = mfu(
-                            n_params, tokens, elapsed, self.peak_flops
-                        )
+        if tokens:
+            self._tokens_counter.inc(tokens, model=self.model_name, op="prefill")
         return results
 
     def _note_cache_event(self, cache: str, event: str) -> None:
@@ -2368,14 +2160,6 @@ class TPUDevice:
             # interrupted (resumable), resume outcomes
             "journal": self.journal.stats() if self.journal is not None else None,
             "dispatches": self.timeline.stats(),
-            # cost-model headline (tpu/costmodel.py): calibration
-            # source, sheet count, worst family residual EMA, anomaly
-            # total — the fleet prober piggybacks this onto
-            # /admin/fleet/overview; /admin/costmodel has the full sheet
-            "costmodel": (
-                self.costmodel.overview()
-                if self.costmodel is not None else None
-            ),
             # overload-brownout state: live level, the signals behind
             # it, thresholds, shed count (deadline-aware serving)
             "brownout": self.brownout.snapshot(),
@@ -2845,9 +2629,6 @@ class _EchoRunner:
     # padded tokens on the compile-free path — the scheduler/cohort
     # machinery is then fully exercisable without XLA (tier-1 tests)
     buckets = (64, 128, 256, 512, 1024, 2048, 4096, 8192)
-    # bench gate: echo HAS a real generate loop (bench.py probes this
-    # attribute to decide whether a decode phase makes sense)
-    decode_chunk_size = 1
     # journal-resume contract: echo continues a generation natively at
     # ``resume_from`` (its decode is position-indexed), the compile-free
     # analogue of the transformer's teacher-forced prefill
@@ -3316,9 +3097,6 @@ class _BertRunner:
         else:
             self.cfg = BertConfig()
         self.bucket = 128 if self.cfg.max_seq >= 128 else self.cfg.max_seq
-        from gofr_tpu.tpu.flops import bert_param_count
-
-        self.n_params = bert_param_count(self.cfg)  # MFU gauge (config 2)
         params = _load_or_init(model_path, lambda: init_bert(jax.random.key(0), self.cfg))
         self.params = quantize_params(params, quant)
         cfg = self.cfg
@@ -3464,10 +3242,7 @@ class _TransformerRunner:
         self._load_params(model_path, quant, mesh)
         self._init_mesh(mesh, max_batch)
         self._build_entry_points(init_cache, prefill, decode_step)
-        from gofr_tpu.tpu.flops import transformer_param_count
-
         cfg = self.cfg
-        self.n_params = transformer_param_count(cfg)
         bucket_source = buckets if buckets else self.SEQ_BUCKETS
         self.buckets = [b for b in bucket_source if b <= cfg.max_seq] or [cfg.max_seq]
         # PREFILL_CHUNK_TOKENS: prompts whose bucket would exceed the

@@ -1,16 +1,14 @@
-"""FLOPs accounting and MFU (model-FLOPs-utilization).
+"""FLOPs accounting for training MFU (model-FLOPs-utilization).
 
-The judge's perf axis for serving is single-chip MFU; the reference has no
-equivalent (it publishes no numbers at all, BASELINE.md). Inference MFU uses
-the standard 2·N·tokens approximation (one multiply-accumulate per weight
-per token; attention FLOPs and norms are ignored, which slightly
+``train_mfu`` uses the standard 6·N·tokens approximation (2·N forward,
+4·N backward; attention FLOPs and norms are ignored, which slightly
 *under*-counts — the reported MFU is a floor, never inflated).
 
 ``device_peak_flops`` maps PJRT device kinds to published per-chip bf16
 peaks (unknown TPU kind: error; non-TPU platform: no peak, so every
-utilization reads 0.0). Matmuls run in bf16 even for int8 weight-only
-checkpoints (models/quant.py dequantizes into the bf16 MXU path), so the
-bf16 peak is the correct denominator either way.
+utilization reads 0.0). A serving utilization is not computed in the
+program: the benchmark reads it from device time in a trace against its
+own work sheets and peaks (``benchmark/kernels/``, ``benchmark/peaks.json``).
 """
 
 from __future__ import annotations
@@ -32,90 +30,23 @@ _PEAKS: tuple[tuple[str, float], ...] = (
     ("v2", 46e12),
 )
 
-# Published per-chip HBM bandwidth (bytes/s) by device_kind substring.
-# (v5e: 819 GB/s; v4: 1228; v5p: 2765; v6e/Trillium: 1640.) Decode is
-# bandwidth-bound (every step streams the whole model), so MBU — fraction
-# of peak HBM bandwidth — is the utilization number that says how close
-# decode is to the hardware roofline; decode MFU is inherently tiny.
-_HBM_BW: tuple[tuple[str, float], ...] = (
-    ("v6 lite", 1640e9),
-    ("v6e", 1640e9),
-    ("v5 lite", 819e9),
-    ("v5litepod", 819e9),
-    ("v5e", 819e9),
-    ("v5p", 2765e9),
-    ("v5", 2765e9),
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-)
 
-
-def _lookup(
-    table: tuple[tuple[str, float], ...], device_kind: str, platform: str
-) -> float:
-    """Shared device-kind table scan for the peak FLOP/s and HBM-bandwidth
-    lookups: ordered substring match. A TPU kind missing from the table
-    is an error, never a default — a utilization against the wrong peak
-    is a wrong number under a trusted name. Off-TPU there is no peak
-    (0.0), so ``mfu``/``mbu`` return 0.0 and no CPU run exports a
-    utilization."""
+def device_peak_flops(device_kind: str, platform: str) -> float:
+    """Per-chip bf16 peak for the device kind: ordered substring match.
+    A TPU kind missing from the table is an error, never a default — a
+    utilization against the wrong peak is a wrong number under a trusted
+    name. Off-TPU there is no peak (0.0), so ``mfu`` returns 0.0 and no
+    CPU run reports a utilization."""
     kind = (device_kind or "").lower()
     if platform != "tpu" and "tpu" not in kind:
         return 0.0
-    for needle, value in table:
+    for needle, value in _PEAKS:
         if needle in kind:
             return value
     raise ValueError(
         f"unknown TPU device_kind {device_kind!r}: add its published "
-        "per-chip peaks to gofr_tpu/tpu/flops.py"
+        "per-chip peak to gofr_tpu/tpu/flops.py"
     )
-
-
-def device_peak_flops(device_kind: str, platform: str, quant: str = "") -> float:
-    """Per-chip bf16 peak for the device kind; 0.0 off-TPU; raises
-    ``ValueError`` on a TPU kind the table does not hold.
-
-    ``quant="w8a8"`` returns the int8 peak: every shipped TPU generation's
-    MXU runs int8 at 2x its bf16 rate, and an MFU gauge fed the bf16 peak
-    would read 2x too high under w8a8. THE single home of that factor —
-    the serving gauge and the profiler must agree."""
-    peak = _lookup(_PEAKS, device_kind, platform)
-    return peak * 2.0 if quant == "w8a8" else peak
-
-
-def device_peak_hbm_bw(device_kind: str, platform: str) -> float:
-    """Per-chip HBM bandwidth for the device kind; 0.0 off-TPU; raises
-    ``ValueError`` on a TPU kind the table does not hold."""
-    return _lookup(_HBM_BW, device_kind, platform)
-
-
-def tree_bytes(tree: Any) -> int:
-    """Total device bytes of a param/cache pytree — the decode working set
-    a step streams from HBM (quantized leaves count their packed size,
-    which is the point of weight-only quantization). int4 leaves count a
-    half byte per element (TPU HBM packs two nibbles per byte; CPU's
-    byte-per-element .nbytes would overstate the stream)."""
-    import jax
-    import jax.numpy as jnp
-
-    total = 0
-    for leaf in jax.tree.leaves(tree):
-        if not hasattr(leaf, "nbytes"):
-            continue
-        if getattr(leaf, "dtype", None) in (jnp.int4, jnp.uint4):
-            total += -(-leaf.size // 2)
-        else:
-            total += leaf.nbytes
-    return total
-
-
-def mbu(bytes_streamed: float, seconds: float, peak_bw: float) -> float:
-    """Fraction of peak HBM bandwidth achieved streaming ``bytes_streamed``
-    in ``seconds``."""
-    if seconds <= 0 or peak_bw <= 0:
-        return 0.0
-    return bytes_streamed / seconds / peak_bw
 
 
 def transformer_param_count(cfg: Any) -> int:
@@ -157,23 +88,6 @@ def mfu(n_params: int, tokens: float, seconds: float, peak: float) -> float:
     if seconds <= 0 or peak <= 0:
         return 0.0
     return (2.0 * n_params * tokens) / seconds / peak
-
-
-def mfu_from_flops(flops: float, seconds: float, peak: float) -> float:
-    """MFU from an exact FLOP count — the HLO-derived path: where the
-    cost model harvested a sheet (``compiled.cost_analysis()``), its
-    flops replace the 2·N·tokens floor above (the approximation stays
-    the fallback; DispatchRecord.cost_source labels which one a record
-    used)."""
-    if seconds <= 0 or peak <= 0:
-        return 0.0
-    return flops / seconds / peak
-
-
-def mbu_from_bytes(bytes_accessed: float, seconds: float, peak_bw: float) -> float:
-    """MBU from an exact bytes-accessed count (HLO cost sheet) — same
-    contract as :func:`mfu_from_flops`, for the bandwidth axis."""
-    return mbu(bytes_accessed, seconds, peak_bw)
 
 
 def train_mfu(n_params: int, tokens: float, seconds: float, peak: float) -> float:

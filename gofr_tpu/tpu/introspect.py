@@ -12,8 +12,8 @@ This module gives the serving engine that layer:
   prefill slice, pooled decode chunk, warmup compile, device probe) gets
   a monotonic ``dispatch_id`` and a ``DispatchRecord`` — kind, bucket,
   batch size, padded tokens, queued/running/done marks and the split of
-  running -> done into issue / in flight / fetch wait / deliver,
-  per-dispatch MFU/MBU — in a bounded ring exposed at ``GET /admin/dispatches``.
+  running -> done into issue / in flight / fetch wait / deliver — in a
+  bounded ring exposed at ``GET /admin/dispatches``.
   FlightRecords carry the dispatch ids they rode
   (``FlightRecord.note_dispatch_id``), so a slow request in
   ``/admin/requests`` links directly to the dispatches that made it slow.
@@ -75,7 +75,7 @@ ENGINE_STATES = (
 
 # the contextvar lets device code deep below a dispatcher (e.g. the
 # device's run_batch under the batcher's dispatch thread) decorate the
-# CURRENT dispatch record with values only it knows (per-dispatch MFU)
+# CURRENT dispatch record with values only it knows (the tokens it ran)
 _current_dispatch: contextvars.ContextVar[Optional["DispatchRecord"]] = (
     contextvars.ContextVar("gofr_dispatch_record", default=None)
 )
@@ -99,10 +99,9 @@ class DispatchRecord:
     __slots__ = (
         "dispatch_id", "kind", "bucket", "batch_size", "padded_tokens",
         "tokens", "detail", "status", "wall_start", "t_queued", "t_running",
-        "t_done", "mfu", "mbu", "predicted_ms", "residual_ratio",
-        "cost_source", "anomaly",
-        "t_issued", "t_fetch", "t_fetched", "cadence_s", "chunks_ahead",
-        "state_bytes", "kv_blocks_read", "kv_blocks_held", "carried",
+        "t_done", "t_issued", "t_fetch", "t_fetched", "cadence_s",
+        "chunks_ahead", "state_bytes", "kv_blocks_read", "kv_blocks_held",
+        "carried",
     )
 
     def __init__(
@@ -132,16 +131,6 @@ class DispatchRecord:
         # with a real queue phase pass queued_at and mark_running later)
         self.t_running: Optional[float] = None if queued_at is not None else now
         self.t_done: Optional[float] = None
-        self.mfu: Optional[float] = None
-        self.mbu: Optional[float] = None
-        # cost-model fields (tpu/costmodel.py): the roofline prediction
-        # stamped at begin, the observed/predicted residual stamped at
-        # finish, the sheet source behind them (hlo | synthetic), and
-        # the anomaly cause when this dispatch was flagged
-        self.predicted_ms: Optional[float] = None
-        self.residual_ratio: Optional[float] = None
-        self.cost_source: Optional[str] = None
-        self.anomaly: Optional[str] = None
         # where the running -> done time went (set-once marks stamped by
         # profiling.phase at the lines that do the work): the jitted
         # call returned, the host began to fetch the result, the fetch
@@ -212,12 +201,6 @@ class DispatchRecord:
             "kv_blocks_read": self.kv_blocks_read,
             "kv_blocks_held": self.kv_blocks_held,
             "carried": self.carried,
-            "mfu": self.mfu,
-            "mbu": self.mbu,
-            "predicted_ms": self.predicted_ms,
-            "residual_ratio": self.residual_ratio,
-            "cost_source": self.cost_source,
-            "anomaly": self.anomaly,
         }
 
 
@@ -230,15 +213,7 @@ class DispatchTimeline:
     mark in place and is idempotent (error paths and success paths may
     both reach it)."""
 
-    def __init__(
-        self, capacity: int = 512, metrics: Any = None, costmodel: Any = None
-    ):
-        # the dispatch cost model (tpu/costmodel.py), when wired: begin
-        # stamps each record's roofline prediction, finish runs residual
-        # and anomaly accounting — this timeline is the SINGLE
-        # predict→observe chokepoint every dispatcher already flows
-        # through (batcher, chunked prefill, decode pool, spec verify)
-        self.costmodel = costmodel
+    def __init__(self, capacity: int = 512, metrics: Any = None):
         self._ids = itertools.count(1)
         self._ring: "deque[DispatchRecord]" = deque(maxlen=max(1, capacity))
         self._lock = threading.Lock()
@@ -274,8 +249,6 @@ class DispatchTimeline:
             padded_tokens=padded_tokens, tokens=tokens, detail=detail,
             queued_at=queued_at,
         )
-        if self.costmodel is not None:
-            self.costmodel.annotate(record)
         with self._lock:
             self._ring.append(record)
             self._by_kind[kind] = self._by_kind.get(kind, 0) + 1
@@ -294,8 +267,6 @@ class DispatchTimeline:
             self._in_flight.pop(record.dispatch_id, None)
         if self._dur is not None:
             self._dur.observe(record.duration or 0.0, kind=record.kind)
-        if self.costmodel is not None:
-            self.costmodel.observe(record)
 
     # -- read side (admin API) ------------------------------------------------
     def records(

@@ -14,7 +14,7 @@ resource-guard shape the analysis deliberately exempts — the finding
 must land on the cross-object reach-through in ``Journal.record``.)
 
 This file is a lint fixture, not production code: it lives outside the
-tree gate's paths (gofr_tpu/, tools/, bench.py) and is linted only by
+tree gate's paths (gofr_tpu/, tools/) and is linted only by
 its own test.
 """
 

@@ -338,26 +338,30 @@ def test_stop_tokens_in_stream(pooled):
     assert got == full[: full.index(stop_tok)]
 
 
-def test_pooled_decode_sets_mbu_gauge_only_where_a_peak_exists(
-    pooled, monkeypatch
-):
-    # decode is bandwidth-bound; the pool maintains an MBU gauge (bytes
-    # streamed per step / time / peak bw) next to the MFU one — but only
-    # against a real peak: the CPU has none, so it exports no utilization
-    def mbu_line():
-        return next(
+def test_pooled_decode_counts_delivered_tokens_only(pooled):
+    """gofr_tpu_tokens_total{op="decode"} moves by the tokens the pool put
+    on a request's queue: not the first token (the prefill's), not the
+    tail of a chunk past max_new_tokens, not a stop token or what the
+    chunk computed after it."""
+    def decoded():
+        line = next(
             (ln for ln in pooled.metrics.expose().splitlines()
-             if ln.startswith('gofr_tpu_mbu{model="tiny",op="decode"}')),
+             if ln.startswith('gofr_tpu_tokens_total{model="tiny",op="decode"}')),
             None,
         )
+        return float(line.rsplit(" ", 1)[1]) if line else 0.0
 
-    pooled.generate([1, 2, 3], max_new_tokens=6)
-    assert mbu_line() is None
-    assert pooled.decode_pool._bytes_per_step > 0
-    # the same accounting against v5e's published bandwidth
-    monkeypatch.setattr(pooled.decode_pool, "_peak_bw", 819e9)
-    pooled.generate([1, 2, 3], max_new_tokens=6)
-    assert float(mbu_line().rsplit(" ", 1)[1]) > 0.0
+    chunk = pooled.decode_pool.chunk
+    before = decoded()
+    full = pooled.generate([1, 2, 3], max_new_tokens=chunk + 3)
+    assert len(full) == chunk + 3
+    assert decoded() - before == chunk + 2  # two chunks ran, 2 x chunk steps
+    before = decoded()
+    stop_tok = full[3]
+    got = pooled.generate([1, 2, 3], max_new_tokens=chunk + 3,
+                          stop_tokens=[stop_tok])
+    assert got == full[: full.index(stop_tok)]
+    assert decoded() - before == max(len(got) - 1, 0)
 
 
 def test_slot_sampling_knobs_reset_on_free(pooled):

@@ -46,7 +46,7 @@ def test_gfl001_allows_writes_scripts_and_config():
     assert lint('import os\nos.environ.update({"K": "1"})\n') == []
     # entry-point scripts configure the process env before boot
     assert lint('import os\nx = os.environ.get("K")\n', rel="tools/x.py") == []
-    assert lint('import os\nx = os.getenv("K")\n', rel="bench.py") == []
+    assert lint('import os\nx = os.getenv("K")\n', rel="chip_smoke.py") == []
     # config.py IS the sanctioned reader
     assert lint(
         'import os\nx = os.environ.get("K")\n', rel="gofr_tpu/config.py"
@@ -172,7 +172,6 @@ def test_gfl005_convention_enforced_statically():
     assert rules_of(lint('m.counter("tpu_x_total", "x")\n')) == ["GFL005"]
     assert lint('m.counter("gofr_tpu_requests_total", "r")\n') == []
     assert lint('m.histogram("gofr_tpu_latency_seconds", "l")\n') == []
-    assert lint('m.gauge("gofr_tpu_mfu", "roofline")\n') == []  # allowlist
     # dynamically composed names are the runtime test's job, not ours
     assert lint("m.counter(name, 'x')\n") == []
 
@@ -244,19 +243,22 @@ def test_gfl005_trace_family_covered():
     ) == ["GFL005"]
 
 
-def test_gfl005_costmodel_family_covered():
-    """The dispatch cost-model family (tpu/costmodel.py): the residual
-    EMA gauge (``_ratio``) and the anomaly counter (``_total``) pass;
-    suffix drift within the family still fails."""
-    assert lint('m.gauge("gofr_tpu_dispatch_residual_ratio", "r")\n') == []
-    assert lint(
-        'm.counter("gofr_tpu_dispatch_anomalies_total", "a")\n'
-    ) == []
+def test_gfl005_engine_family_covered():
+    """The engine-introspection family (tpu/introspect.py): the state
+    gauge (``_state``), the dispatch counter (``_total``) and the
+    dispatch histogram (``_seconds``) pass; suffix drift within the
+    family still fails."""
+    assert lint('m.gauge("gofr_tpu_engine_state", "s")\n') == []
+    assert lint('m.counter("gofr_tpu_dispatches_total", "d")\n') == []
+    assert lint('m.histogram("gofr_tpu_dispatch_seconds", "d")\n') == []
     assert rules_of(
-        lint('m.gauge("gofr_tpu_dispatch_residual", "r")\n')
+        lint('m.gauge("gofr_tpu_engine", "s")\n')
     ) == ["GFL005"]
     assert rules_of(
-        lint('m.counter("gofr_tpu_dispatch_anomalies", "a")\n')
+        lint('m.counter("gofr_tpu_dispatches", "d")\n')
+    ) == ["GFL005"]
+    assert rules_of(
+        lint('m.histogram("gofr_tpu_dispatch", "d")\n')
     ) == ["GFL005"]
 
 
@@ -378,12 +380,12 @@ def test_syntax_error_is_reported_not_crashed(tmp_path):
 # -- the tree gate ------------------------------------------------------------
 
 def test_the_real_tree_is_clean():
-    """The acceptance contract, runnable as a test: the package, tools,
-    bench.py and chip_smoke.py carry zero unsuppressed violations. Same
+    """The acceptance contract, runnable as a test: the package, tools
+    and chip_smoke.py carry zero unsuppressed violations. Same
     "only shrinks" policy as the ruff debt ledger — fix new violations
     or suppress them IN-FILE with a reason."""
     violations, scanned = gofrlint.lint_paths([
-        str(REPO / "gofr_tpu"), str(REPO / "tools"), str(REPO / "bench.py"),
+        str(REPO / "gofr_tpu"), str(REPO / "tools"),
         str(REPO / "chip_smoke.py"),
     ])
     assert scanned > 50
@@ -647,7 +649,7 @@ def test_gfl008_inert_declared_knob(tmp_path):
 
 def test_gfl008_wrapper_and_harness_reads_count(tmp_path):
     """A one-hop wrapper read (the fleet ``_f`` idiom) traces to the
-    key; a harness-only read (bench/tools) proves a declared key live
+    key; a harness-only read (tools, scripts) proves a declared key live
     but is NOT itself held to the package registry."""
     out = run_tree(tmp_path, {
         "gofr_tpu/__init__.py": "",
@@ -658,9 +660,9 @@ def test_gfl008_wrapper_and_harness_reads_count(tmp_path):
             "    return get_env(key) or default\n"
             'x = _f("WRAPPED_KEY", "1")\n'
         ),
-        "bench.py": (
+        "chip_smoke.py": (
             "import os\n"
-            'y = os.getenv("BENCH_ONLY_KEY")\n'
+            'y = os.getenv("SMOKE_ONLY_KEY")\n'
         ),
     })
     assert out == []
@@ -720,7 +722,7 @@ def test_committed_ledger_matches_the_tree():
     ledger — the ratchet starts tight (a stale-but-loose baseline would
     let new suppressions ride in under old headroom)."""
     run = gofrlint.LintRun([
-        str(REPO / "gofr_tpu"), str(REPO / "tools"), str(REPO / "bench.py")
+        str(REPO / "gofr_tpu"), str(REPO / "tools")
     ])
     committed = json.loads(
         (REPO / "tools" / "gofrlint_ledger.json").read_text()

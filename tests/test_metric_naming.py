@@ -27,8 +27,6 @@ _GAUGE_SUFFIXES = (
     "_active", "_acceptance", "_state", "_blocks", "_size", "_level",
     "_per_dispatch", "_rate", "_remaining",
 )
-# roofline utilization gauges: the suffix IS the (well-known) metric name
-_GAUGE_ALLOWLIST = {"gofr_tpu_mfu", "gofr_tpu_mbu"}
 
 
 def _registrations():
@@ -95,10 +93,6 @@ def test_scanner_sees_the_known_registrations():
     # (tracing.py attach_metrics)
     assert {"gofr_tpu_router_hop_seconds",
             "gofr_tpu_trace_export_failures_total"} <= names
-    # dispatch cost model (tpu/costmodel.py): the per-family residual
-    # EMA gauge and the anomaly counter stay scan-visible
-    assert {"gofr_tpu_dispatch_residual_ratio",
-            "gofr_tpu_dispatch_anomalies_total"} <= names
     # SLO engine (slo.py) + bounded tenant metering (telemetry.py
     # TenantLedger): burn/budget surfaces and the sketch's OWN
     # cardinality ledger — per-tenant series are forbidden by design
@@ -146,7 +140,6 @@ def test_suffix_tables_match_gofrlint():
     assert gofrlint._COUNTER_SUFFIXES == _COUNTER_SUFFIXES
     assert gofrlint._HISTOGRAM_SUFFIXES == _HISTOGRAM_SUFFIXES
     assert gofrlint._GAUGE_SUFFIXES == _GAUGE_SUFFIXES
-    assert gofrlint._GAUGE_ALLOWLIST == _GAUGE_ALLOWLIST
 
 
 def test_every_metric_follows_the_naming_convention():
@@ -165,11 +158,10 @@ def test_every_metric_follows_the_naming_convention():
                 f"{where}: histogram {name} needs a unit suffix "
                 f"{_HISTOGRAM_SUFFIXES}"
             )
-        elif kind == "gauge" and name not in _GAUGE_ALLOWLIST and \
-                not name.endswith(_GAUGE_SUFFIXES):
+        elif kind == "gauge" and not name.endswith(_GAUGE_SUFFIXES):
             problems.append(
                 f"{where}: gauge {name} needs a unit/dimension suffix "
-                f"{_GAUGE_SUFFIXES} (or an explicit allowlist entry)"
+                f"{_GAUGE_SUFFIXES}"
             )
     assert not problems, "\n".join(problems)
 

@@ -349,12 +349,31 @@ def test_refused_request_leaves_decode_solo_records_on_its_flight(held_pool):
         assert_marks_in_order(record)
         assert record["batch_size"] == 1 and record["tokens"] == 4
         assert record["chunks_ahead"] == held_pool.depth
-        assert record["predicted_ms"] is None  # the cost model is not taught the kind
     # no solo record left running or abandoned behind the finished request
     assert all(r["status"] == "ok" for r in solo.values())
     for rider in held_pool.riders:  # the pooled riders admitted, and have no solo records
         assert rider["pool_reject_reason"] is None and rider["pool_admit_s"] >= 0
         assert not set(rider["dispatch_ids"]) & set(solo)
+
+
+def test_metrics_count_tokens_and_export_no_utilisation(held_pool):
+    """gofr_tpu_tokens_total moves by prompt tokens prefilled and by tokens
+    the pool delivered (the two riders' 20 each: not a chunk's tail, not
+    the refused request's solo tokens); no utilisation gauge and no
+    cost-model family is registered at all."""
+    text = held_pool.dev.metrics.expose()
+    count = {
+        op: float(next(
+            ln for ln in text.splitlines()
+            if ln.startswith(f'gofr_tpu_tokens_total{{model="tiny",op="{op}"}}')
+        ).rsplit(" ", 1)[1])
+        for op in ("prefill", "decode")
+    }
+    assert count == {"prefill": 2 * 5 + 3 + 4, "decode": 2 * 20}
+    for family in ("gofr_tpu_mfu", "gofr_tpu_mbu",
+                   "gofr_tpu_dispatch_residual_ratio",
+                   "gofr_tpu_dispatch_anomalies_total"):
+        assert family not in text
 
 
 def test_pool_and_solo_annotations_are_leaves(held_pool):

@@ -18,6 +18,7 @@ import urllib.request
 
 import pytest
 
+from gofr_tpu.anomaly import ANOMALY_CAUSES, AnomalyRing
 from gofr_tpu.metrics import Registry
 from gofr_tpu.slo import (
     DEFAULT_TARGETS,
@@ -247,6 +248,28 @@ def test_engine_healthy_run_raises_zero_alerts():
     assert row["alerting"] == {"fast": False, "slow": False}
     assert report["alerts_total"] == 0
     assert engine.ring.events(kind="slo") == []
+
+
+def test_anomaly_ring_bounds_filters_and_stats():
+    """The engine's evidence store: bounded, newest first, filtered by
+    kind and cause, counted per (kind, cause)."""
+    ring = _engine(FlightRecorder(capacity=4), ring=AnomalyRing(capacity=4)).ring
+    for i in range(10):
+        ring.record(kind="slo" if i % 2 else "other",
+                    cause="slo_fast_burn", objective=f"o{i}")
+    assert ring.total() == 10
+    events = ring.events(limit=100)
+    assert len(events) == 4  # bounded retention
+    assert [e["objective"] for e in events] == ["o9", "o8", "o7", "o6"]
+    assert [e["seq"] for e in events] == [10, 9, 8, 7]
+    assert all(e["kind"] == "slo" for e in ring.events(kind="slo"))
+    assert len(ring.events(limit=1)) == 1
+    assert ring.events(cause="slo_slow_burn") == []
+    stats = ring.stats()
+    assert stats["total"] == 10 and stats["retained"] == 4
+    assert stats["capacity"] == 4 and ring.capacity == 4
+    assert stats["by"]["slo/slo_fast_burn"] == 5
+    assert stats["last_ts"] == events[0]["ts"]
 
 
 def test_engine_burst_latches_one_alert_per_excursion():
@@ -487,6 +510,34 @@ def test_e2e_healthy_run_zero_alerts(slo_app):
     assert engine["tenants"]["tracked"] >= 1
 
 
+def test_e2e_healthy_loop_leaves_anomalies_empty(slo_app):
+    """A healthy echo loop with SLO=on serves an EMPTY /admin/anomalies
+    from the container's SLO engine, its ring sized by ANOMALY_RING_SIZE."""
+    app, base = slo_app
+    for _ in range(4):
+        status, _ = _post(
+            base, {"prompt": [4, 5, 6], "max_tokens": 3, "temperature": 0})
+        assert status == 200
+    _get(base, "/admin/slo/budget")  # a fresh evaluation over the loop
+    out = _get(base, "/admin/anomalies")
+    assert out["anomalies"] == [] and out["count"] == 0
+    assert out["stats"]["total"] == 0 and out["stats"]["capacity"] == 256
+    assert app.container.slo.ring.total() == 0
+    assert set(ANOMALY_CAUSES) == {"slo_fast_burn", "slo_slow_burn"}
+
+
+def test_anomalies_endpoint_validates_params(slo_app):
+    _, base = slo_app
+    for path in ("/admin/anomalies?limit=0",
+                 "/admin/anomalies?limit=x",
+                 "/admin/anomalies?cause=nope",
+                 "/admin/anomalies?cause=slow_dispatch"):
+        with pytest.raises(urllib.error.HTTPError) as err:
+            _get(base, path)
+        assert err.value.code == 400, path
+    assert _get(base, "/admin/anomalies?cause=slo_slow_burn&limit=1")["count"] == 0
+
+
 def test_e2e_tenant_metering_and_request_filter(slo_app):
     app, base = slo_app
     auth = "Bearer metered-key"
@@ -539,7 +590,6 @@ def test_e2e_fault_burst_pages_on_every_surface(slo_app):
     assert budget["alerts_total"] >= 2  # fast page + slow ticket
     causes = {e["cause"] for e in budget["recent_alerts"]}
     assert "slo_fast_burn" in causes
-    # same ring the dispatch watchtower uses
     anomalies = _get(base, "/admin/anomalies")
     assert "slo_fast_burn" in {a["cause"] for a in anomalies["anomalies"]}
     # exposition: the latched excursion counter

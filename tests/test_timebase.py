@@ -132,6 +132,22 @@ def test_rate_total_sums_across_label_sets():
     assert rate[0][1] == pytest.approx(3.0 / dt)
 
 
+def test_rate_total_labels_filter():
+    registry = Registry()
+    counter = registry.counter("gofr_x_total", "x", labels=("cause",))
+    sampler = _sampler(registry)
+    counter.inc(10, cause="a")
+    counter.inc(100, cause="b")
+    sampler.sample_now()
+    counter.inc(10, cause="a")
+    sampler.sample_now()
+    all_rates = sampler.rate_total("gofr_x_total")
+    only_a = sampler.rate_total("gofr_x_total", labels={"cause": "a"})
+    only_b = sampler.rate_total("gofr_x_total", labels={"cause": "b"})
+    assert all_rates[0][1] == only_a[0][1] > 0.0  # only `a` moved
+    assert only_b[0][1] == 0.0
+
+
 def test_sampler_validates_intervals():
     with pytest.raises(ValueError):
         TimebaseSampler(Registry(), interval_s=0, start=False)

@@ -374,12 +374,10 @@ def test_model_buckets_limits_warmup_compiles():
             os.environ.pop(k, None) if v is None else os.environ.__setitem__(k, v)
 
 
-def test_token_counter_and_no_cpu_utilization(tiny_device):
+def test_token_counter_and_param_count(tiny_device):
     tiny_device.infer({"tokens": [1, 2, 3, 4, 5]})
     text = tiny_device.metrics.expose()
     assert 'gofr_tpu_tokens_total{model="tiny",op="prefill"}' in text
-    # the CPU has no peak in the flops table: no utilization is exported
-    assert 'gofr_tpu_mfu{model="tiny",op="prefill"}' not in text
     from gofr_tpu.tpu.flops import transformer_param_count
 
     # analytic count matches the materialized tree
@@ -731,13 +729,6 @@ def test_flops_helpers():
     assert device_peak_flops("unknown", "cpu") == 0.0  # no peak off-TPU
     assert mfu(100, 10, 0.0, 1e3) == 0.0  # degenerate inputs never divide by 0
     assert train_mfu(100, 10, 1.0, 1e12) == pytest.approx(3 * mfu(100, 10, 1.0, 1e12))
-    # int4 leaves count half a byte per element in the decode stream
-    from gofr_tpu.tpu.flops import tree_bytes
-
-    import jax.numpy as jnp
-
-    tree = {"a": jnp.zeros((4, 4), jnp.int4), "s": jnp.zeros((4,), jnp.float32)}
-    assert tree_bytes(tree) == 16 // 2 + 16
 
 
 def test_seq_bucket_ladder_covers_full_context():
@@ -809,8 +800,7 @@ def test_bert_serving_counts_tokens(monkeypatch):
         out = device.infer({"tokens": [1, 2, 3]})
         assert np.isfinite(np.asarray(out)).all()
         text = device.metrics.expose()
-        assert 'gofr_tpu_tokens_total{model="bert-tiny",op="prefill"}' in text
-        assert 'gofr_tpu_mfu{model="bert-tiny",op="prefill"}' not in text
+        assert 'gofr_tpu_tokens_total{model="bert-tiny",op="prefill"} 3' in text
     finally:
         device.close()
 

@@ -2,8 +2,8 @@
 artifact (``tools/fleetsim.py``) against the committed
 ``fleetsim_baseline.json``.
 
-Two classes of check, in the bench-gate tradition (gate the artifact,
-always upload it, loose-first tolerances):
+Two classes of check (gate the artifact, always upload it, loose-first
+tolerances):
 
 ABSOLUTE invariants — correctness under chaos, no tolerance:
 

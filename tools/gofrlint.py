@@ -61,7 +61,6 @@ main = _impl.main
 _COUNTER_SUFFIXES = _impl._COUNTER_SUFFIXES
 _HISTOGRAM_SUFFIXES = _impl._HISTOGRAM_SUFFIXES
 _GAUGE_SUFFIXES = _impl._GAUGE_SUFFIXES
-_GAUGE_ALLOWLIST = _impl._GAUGE_ALLOWLIST
 
 if __name__ == "__main__":
     sys.exit(main())

@@ -39,7 +39,6 @@ union. See docs/advanced-guide/static-analysis.md."""
 
 from .base import (  # noqa: F401
     _COUNTER_SUFFIXES,
-    _GAUGE_ALLOWLIST,
     _GAUGE_SUFFIXES,
     _HISTOGRAM_SUFFIXES,
     RULES,

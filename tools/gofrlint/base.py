@@ -36,7 +36,6 @@ _GAUGE_SUFFIXES = (  # keep in lockstep with tests/test_metric_naming.py
     "_active", "_acceptance", "_state", "_blocks", "_size", "_level",
     "_per_dispatch", "_rate", "_remaining",
 )
-_GAUGE_ALLOWLIST = {"gofr_tpu_mfu", "gofr_tpu_mbu"}
 
 # GFL004 heuristics (shared with the interprocedural summaries)
 _LOCKISH_RE = re.compile(r"(lock|mutex|_mu)\b", re.IGNORECASE)

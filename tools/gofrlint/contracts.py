@@ -312,9 +312,9 @@ def check_config_keys(project: Project, root: Path) -> list[Violation]:
         )]
     reads = _key_reads(project)
     # provenance is a PACKAGE contract: a read inside the gofr_tpu
-    # package must trace to DECLARED_KEYS; harness knobs (bench.py,
-    # tools/) are out of the package's config surface, though their
-    # reads still prove a declared key live below
+    # package must trace to DECLARED_KEYS; harness knobs (tools/) are out
+    # of the package's config surface, though their reads still prove
+    # a declared key live below
     pkg_prefix = str(Path(config_mod.rel).parent).replace("\\", "/") + "/"
     for key in sorted(reads):
         if key in declared or key in _EXTERNAL_KEYS:

@@ -12,7 +12,6 @@ from typing import Optional
 
 from .base import (
     _COUNTER_SUFFIXES,
-    _GAUGE_ALLOWLIST,
     _GAUGE_SUFFIXES,
     _HISTOGRAM_SUFFIXES,
     Directives,
@@ -279,12 +278,8 @@ class FileLinter:
             problem = "counter must end in _total"
         elif kind == "histogram" and not name.endswith(_HISTOGRAM_SUFFIXES):
             problem = f"histogram needs a unit suffix {_HISTOGRAM_SUFFIXES}"
-        elif kind == "gauge" and name not in _GAUGE_ALLOWLIST and \
-                not name.endswith(_GAUGE_SUFFIXES):
-            problem = (
-                f"gauge needs a unit/dimension suffix {_GAUGE_SUFFIXES} "
-                "(or an allowlist entry)"
-            )
+        elif kind == "gauge" and not name.endswith(_GAUGE_SUFFIXES):
+            problem = f"gauge needs a unit/dimension suffix {_GAUGE_SUFFIXES}"
         if problem:
             self.report("GFL005", node, f"metric {name!r}: {problem}")
 

@@ -41,7 +41,7 @@ import sys
 
 # path components that anchor a repo-relative spelling inside an
 # absolute one — everything before the LAST occurrence is machine-local
-_ROOTS = ("gofr_tpu", "tests", "tools", "bench.py")
+_ROOTS = ("gofr_tpu", "tests", "tools")
 
 
 def normalize(node: str) -> str:

@@ -7,7 +7,7 @@ dispatch visible, thread stacks, timebase snapshots, and flight data.
 
 Compile-free (MODEL_NAME=echo, no XLA): safe for CPU-only CI runners.
 Unlike the unit/e2e tests this exercises the FULL out-of-process
-contract — the same bundle file a wedged bench round leaves in hw/rNN/,
+contract — the same bundle file a wedged process leaves on disk,
 validated through tools/postmortem_view.py, the same way a human (or
 the driver) would read it after the process is gone.
 """
