@@ -282,7 +282,6 @@ DECLARED_KEYS: dict[str, str] = {
     "DECODE_CHUNK": "decode loop chunk size",
     "DECODE_SLOTS": "decode pool slot count",
     "DECODE_POOL": "enable the continuous-batching pool",
-    "DECODE_PIPELINE": "overlap host/device decode stages",
     "DECODE_POOL_PENALTIES": "penalized-pool admission weights",
     "PREFIX_CACHE": "shared prefix cache toggle",
     "PREFIX_LCP_MIN": "min longest-common-prefix to reuse",

@@ -12,14 +12,16 @@ ON DEVICE (``_last_tokens``, fed forward chunk-to-chunk exactly like the
 in-chunk scan feeds itself), so chunk N+1 dispatches immediately after
 chunk N — its inputs are N's output futures — and the host fetch of chunk
 N's tokens overlaps chunk N+1's execution. Without this, the device idles
-one host fetch per chunk (how long that fetch is against a chunk's compute
-is unmeasured on this machine — ROADMAP S3).
+one host round trip per chunk. Two chunks are kept in flight, one running
+and one queued: the host's part of a chunk is 1-2 ms against chunks of
+21-290 ms (PERF.md section 6, PR 31), and every further chunk in flight
+is a chunk a prefill, and a newly seated row's first decode, queue behind.
 
 Mechanics:
 - a finished prefill row is copied into a free slot (one jitted
   dynamic_update_slice per cache field) and its first token is written
   into the device-resident token row;
-- the worker keeps up to ``PIPELINE_DEPTH`` chunks in flight; each
+- the worker keeps ``pipeline_depth`` chunks in flight (2); each
   dispatch snapshots (slot index -> request) so a slot freed and reused
   mid-pipeline never leaks garbage tokens to the new request;
 - inactive slots decode garbage in lockstep (fixed shapes = one compiled
@@ -69,13 +71,6 @@ DONE = object()  # end-of-stream marker on a slot's token queue
 # expired mid-decode: the consumer re-raises DeadlineExceeded instead
 # of treating the truncated stream as a clean finish
 DEADLINE = object()
-
-# Chunks in flight (DECODE_PIPELINE config): the host fetch of chunk N's
-# tokens overlaps execution of the younger in-flight chunks. Depth d
-# covers a host fetch up to (d-1) x chunk-compute long; the cost of extra
-# depth is wasted lockstep steps for slots freed mid-pipeline. The
-# default of 3 is unmeasured on this machine (ROADMAP S3).
-PIPELINE_DEPTH = 3
 
 
 class PoolFailure:
@@ -169,7 +164,7 @@ class DecodePool:
         metrics: Any = None,
         cache_shardings: Any = None,
         model: str = "",
-        pipeline_depth: int = PIPELINE_DEPTH,
+        pipeline_depth: int = 2,
         penalties: str = "lazy",
         scheduler: Any = None,
         timeline: Any = None,
@@ -185,6 +180,12 @@ class DecodePool:
             raise ValueError(
                 f"penalties must be lazy|eager|off, got {penalties!r}"
             )
+        # chunks kept in flight: one running, one queued. No
+        # configuration key reaches this; only tests and a by-hand
+        # comparison pass another number. A third bought no busy time on
+        # the chip at chunks of 21-290 ms and stood in front of every
+        # prefill (PERF.md section 6, PR 31); at 1 the device idles one
+        # host round trip a chunk by construction
         self.pipeline_depth = pipeline_depth
         # interference scheduler (tpu/scheduler.py): the pool NOTES each
         # chunk dispatch (never throttled) so prefill chunks can
@@ -1469,11 +1470,11 @@ class DecodePool:
             self.chunks_in_flight -= 1
             fetch_done = _perf_counter()
             # the cadence: the interval between consecutive deliveries
-            # at steady state (dispatch->fetch spans ~2 chunk computes
-            # when the pipeline is full); after an idle gap, fall back
-            # to this chunk's own span. Floor at span/depth: a host stall
-            # can make both in-flight chunks finish before the next
-            # fetch, shrinking the inter-delivery gap to ~0.
+            # at steady state (dispatch->fetch spans depth x chunk
+            # computes when the pipeline is full); after an idle gap,
+            # fall back to this chunk's own span. Floor at span/depth: a
+            # host stall can make every in-flight chunk finish before
+            # the next fetch, shrinking the inter-delivery gap to ~0.
             span = fetch_done - dispatch_start
             dispatch_elapsed = max(
                 fetch_done - max(dispatch_start, last_fetch_done),

@@ -742,15 +742,6 @@ class TPUDevice:
         self._refuse_what_a_state_cannot_do(config)
         self._pool_enabled = config.get_or_default("DECODE_POOL", "on") != "off"
         self._pool_slots = int(config.get_or_default("DECODE_SLOTS", str(self.max_batch)))
-        from gofr_tpu.tpu.decode_pool import PIPELINE_DEPTH
-
-        # chunks kept in flight by the pool worker — the knob that hides
-        # the host<->device round trip (see decode_pool.PIPELINE_DEPTH)
-        self._pool_depth = int(
-            config.get_or_default("DECODE_PIPELINE", str(PIPELINE_DEPTH))
-        )
-        if self._pool_depth < 1:
-            raise ValueError("DECODE_PIPELINE must be >= 1")
         # lazy (default): the penalized-pool executable builds in the
         # background on first penalized request (which solos meanwhile);
         # eager: build at boot; off: penalized requests always decode solo
@@ -1144,7 +1135,6 @@ class TPUDevice:
                 metrics=self.metrics,
                 cache_shardings=getattr(self.runner, "_cache_shardings", None),
                 model=self.model_name,
-                pipeline_depth=self._pool_depth,
                 penalties=self._pool_penalties,
                 scheduler=self.scheduler,
                 timeline=self.timeline,

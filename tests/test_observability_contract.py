@@ -172,7 +172,7 @@ def test_removed_key_is_an_undeclared_name(key, monkeypatch):
     from gofr_tpu.testutil import MockLogger
     from gofr_tpu.tpu.device import new_device
 
-    assert key not in DECLARED_KEYS and len(DECLARED_KEYS) == 152
+    assert key not in DECLARED_KEYS and len(DECLARED_KEYS) == 151
     monkeypatch.setenv(key, "not-a-value")
     monkeypatch.setenv("MODEL_NAME", "echo")
     monkeypatch.setenv("TIMEBASE_ENABLED", "off")

@@ -26,7 +26,17 @@ from gofr_tpu.slo import (
     SloEngine,
     parse_targets,
 )
-from gofr_tpu.telemetry import FlightRecorder, TenantLedger, activate_tenant
+from gofr_tpu.telemetry import (
+    FlightRecorder, TenantLedger, activate_record, activate_tenant,
+)
+
+
+@pytest.fixture(autouse=True)
+def _no_record_left_active():
+    """``recorder.start()`` binds the contextvar: a record left active
+    would bleed into whichever file this worker runs next."""
+    yield
+    activate_record(None)
 
 
 # -- unit: SLO_TARGETS parsing ------------------------------------------------
