@@ -35,19 +35,6 @@ def step_work(run) -> tuple[float, float, float]:
     return flops, mw.weight_bytes(sz) + gate_bytes(sz), state
 
 
-def pooled_program(run):
-    """The pool's jitted lambda is named by nothing: it is the
-    ``jit__lambda(<id>)`` with the most device time in the trace.
-    -> (its name, {"seconds", "runs"}) or None."""
-    if run.trace is None:
-        return None
-    lambdas = {n: v for n, v in run.trace["programs"].items() if n.startswith("jit__lambda(")}
-    if not lambdas:
-        return None
-    name = max(lambdas, key=lambda n: lambdas[n]["seconds"])
-    return name, lambdas[name]
-
-
 def work(run, runs: int) -> tuple[float, float]:
     """(flops, bytes) the traced ``runs`` of the program had to do."""
     steps = runs * int(run.server_env.get("DECODE_CHUNK", "8"))

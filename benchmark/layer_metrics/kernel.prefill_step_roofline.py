@@ -2,8 +2,8 @@
 window's prefill dispatches (2 x weights x real tokens + causal attention)
 over the bf16 peak, against the device time of the traced runs of
 ``jit__prefill_fn``."""
-from benchmark.readers import roofline_share
+from benchmark.readers import is_prefill, roofline_share
 
 
 def read(run):
-    return roofline_share(run, "prefill_step", lambda name: name.startswith("jit__prefill_fn"))
+    return roofline_share(run, "prefill_step", is_prefill)

@@ -4,14 +4,14 @@ run of ``jit__prefill_fn``) over the device time of the operations named
 ``retention_chunk*`` in the trace. A program whose chunked form is not one
 named kernel gives nothing to read."""
 from benchmark import spec
-from benchmark.readers import dispatches, program_seconds
+from benchmark.readers import dispatches, is_prefill, program_seconds
 
 
 def read(run):
     if run.trace is None or run.peaks is None:
         return None
     seconds = sum(s for name, s in run.trace["device_ops"] if name.startswith("retention_chunk"))
-    _, runs = program_seconds(run, lambda name: name.startswith("jit__prefill_fn"))
+    _, runs = program_seconds(run, is_prefill)
     prefills = dispatches(run, ("prefill", "prefill_chunk"))
     if seconds <= 0 or not runs or not prefills:
         return None

@@ -1,0 +1,9 @@
+"""The prefill program of a retention model, its share of the chip's bf16
+peak: useful FLOPs of the window's prefill dispatches (weights and the
+chunked form's products over real tokens) over what the peak does in the
+device time of the traced runs of ``jit__prefill_fn``."""
+from benchmark.readers import is_prefill, mfu_share
+
+
+def read(run):
+    return mfu_share(run, "retention_prefill_step", is_prefill)

@@ -4,17 +4,16 @@ the traced chunks' live rows had to read and write (DispatchRecord
 decode program) over the device time of the operations named
 ``retention_step*`` in the trace. It reads only while that kernel is among
 the trace's largest operations (``breakdown.device_ops``)."""
-from benchmark import spec
-from benchmark.readers import dispatches
+from benchmark.readers import dispatches, pooled_program
 
 
 def read(run):
     if run.trace is None or run.peaks is None:
         return None
     seconds = sum(s for name, s in run.trace["device_ops"] if name.startswith("retention_step"))
-    found = spec.load_module("kernels", "retention_decode_step").pooled_program(run)
+    pooled = pooled_program(run)
     moved = [d["state_bytes"] for d in dispatches(run, ("decode_chunk",)) if d.get("state_bytes")]
-    if seconds <= 0 or found is None or not moved:
+    if seconds <= 0 or pooled is None or not moved:
         return None
-    least = found[1]["runs"] * sum(moved) / len(moved) / run.peaks["hbm_bytes_per_s"]
+    least = run.trace["programs"][pooled]["runs"] * sum(moved) / len(moved) / run.peaks["hbm_bytes_per_s"]
     return 100.0 * least / seconds

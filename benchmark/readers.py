@@ -32,16 +32,44 @@ def program_seconds(run: Any, match: Callable[[str], bool]) -> tuple[float, int]
     return sum(v["seconds"] for v in hits), int(sum(v["runs"] for v in hits))
 
 
-def roofline_share(run: Any, kernel: str, match: Callable[[str], bool]) -> Optional[float]:
-    """100 x (least time the chip could take for the traced runs of a
-    program) / (device time the trace shows for them). The work comes from
+def is_prefill(name: str) -> bool:
+    """The trace's name for a prefill program (``jit__prefill_fn(<id>)``)."""
+    return name.startswith("jit__prefill_fn")
+
+
+def traced_work(run: Any, kernel: str, match: Callable[[str], bool]) -> Optional[tuple]:
+    """(flops, bytes, device seconds) of the traced runs of the programs
+    whose name matches; the work comes from
     ``kernels/<kernel>.py::work(run, runs) -> (flops, bytes)``."""
     seconds, runs = program_seconds(run, match)
     if not runs or seconds <= 0 or run.peaks is None:
         return None
     flops, nbytes = spec.load_module("kernels", kernel).work(run, runs)
+    return flops, nbytes, seconds
+
+
+def roofline_share(run: Any, kernel: str, match: Callable[[str], bool]) -> Optional[float]:
+    """100 x (least time the chip could take for the traced runs of a
+    program) / (device time the trace shows for them)."""
+    work = traced_work(run, kernel, match)
+    if work is None:
+        return None
+    flops, nbytes, seconds = work
     least = max(flops / run.peaks["bf16_flops_per_s"], nbytes / run.peaks["hbm_bytes_per_s"])
     return 100.0 * least / seconds
+
+
+def mfu_share(run: Any, kernel: str, match: Callable[[str], bool]) -> Optional[float]:
+    """100 x (the sheet's FLOPs for the traced runs of a program) / (what
+    the chip's bf16 peak does in the device time the trace shows for them):
+    the whole step's share of the chip's peak, whatever bounds it. It stands
+    beside the step's roofline share and reads on when a kernel inside the
+    step is replaced; a step whose sheet counts no FLOPs reads nothing."""
+    work = traced_work(run, kernel, match)
+    if work is None or work[0] <= 0:
+        return None
+    flops, _, seconds = work
+    return 100.0 * flops / (run.peaks["bf16_flops_per_s"] * seconds)
 
 
 # -- readers shared by the .steady / .saturated twins --------------------------
@@ -80,14 +108,40 @@ def hbm_peak_gb(run: Any) -> Optional[float]:
     return peak / 1e9 if peak else None
 
 
-def decode_step_roofline(run: Any) -> Optional[float]:
-    """The pool's jitted lambda is named by nothing, so it is found as the
-    ``jit__lambda(<id>)`` module with the most device time."""
+POOL_WAIT = "gofr.pool.fetch_wait"  # the span in which the pool's worker fetches a chunk's tokens
+
+
+def pooled_program(run: Any) -> Optional[str]:
+    """The trace's name for the pool's jitted lambda, which the program
+    names by nothing: the program whose runs end the pool's own waits
+    (``trace_reduce.released_by``: the span ``gofr.pool.fetch_wait`` returns
+    as its chunk's run ends on the device). Not the ``jit__lambda`` with
+    most device time: past the knee the solo fallback's lambda has more of
+    the trace than the pool's (PR 31), and its runs end
+    ``gofr.solo.fetch_wait``. (Run counts against ``decode_chunk`` records
+    cannot tell them apart: the reduced trace starts at its first device
+    event, which the wall clock does not place.) Where fewer than three waits
+    were placed, or under nine in ten of them on one program, nothing is
+    decided and None comes back rather than a guess."""
     if run.trace is None:
         return None
-    lambdas = {n: v["seconds"] for n, v in run.trace["programs"].items()
-               if n.startswith("jit__lambda(")}
-    if not lambdas:
-        return None
-    biggest = max(lambdas, key=lambdas.get)
-    return roofline_share(run, "decode_step", lambda name: name == biggest)
+    ended = run.trace.get("released", {}).get(POOL_WAIT, {})
+    placed = sum(ended.values())
+    best = max(ended, key=ended.get, default=None)
+    return best if placed >= 3 and ended[best] >= 0.9 * placed else None
+
+
+def of_pooled(run: Any, share: Callable, kernel: str) -> Optional[float]:
+    """``share`` (``roofline_share`` or ``mfu_share``) of the sheet
+    ``kernels/<kernel>.py`` over the device time of the pool's own program,
+    found by ``pooled_program``."""
+    pooled = pooled_program(run)
+    return None if pooled is None else share(run, kernel, lambda name: name == pooled)
+
+
+def decode_step_roofline(run: Any) -> Optional[float]:
+    return of_pooled(run, roofline_share, "decode_step")
+
+
+def decode_step_mfu(run: Any) -> Optional[float]:
+    return of_pooled(run, mfu_share, "decode_step")

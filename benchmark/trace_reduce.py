@@ -1,6 +1,7 @@
 """From a profiler trace (``.xplane.pb``) to numbers: device busy and idle,
-device time per compiled program, the operations that took most time, and
-the longest idle gaps with what the host was doing in them.
+device time per compiled program, the operations that took most time, the
+longest idle gaps with what the host was doing in them, and which program's
+runs end the host's waits for a result.
 
 Reads the trace with ``jax.profiler.ProfileData`` alone. Importing this
 module loads no TPU library and describes no topology. Planes named
@@ -11,6 +12,7 @@ program. Host threads are the lines of ``/host:CPU``.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -86,11 +88,43 @@ def _host_activity(host_lines: list[tuple[str, list]], start: float, end: float)
     return best
 
 
+WAIT_SPAN = ".fetch_wait"  # the program's spans in which the host waits for a result
+BLOCKED_S = 0.5e-3  # a shorter wait found its result there: it waited for no run
+HELPER_S = 1e-3  # a shorter run is a slice, a convert or a row write, not what a wait is for
+SKEW_S = 0.2e-3  # host and device clocks of one trace agree to within this
+RETURN_S = 10e-3  # a result is back within this of its run's end; later, something else held the wait
+
+
+def released_by(host_lines: list[tuple[str, list]], modules: list) -> dict[str, dict[str, int]]:
+    """Wait span name -> program -> how many of those waits a run of that
+    program ended. The device runs its stream in order and a result is
+    fetched as its program's run ends, so a blocked wait is released by the
+    last run (helpers left out) to end before the wait does, provided it
+    ended inside the wait and no more than ``RETURN_S`` before its end (a
+    wait held by a stalled transfer ends long after its run, and after
+    other programs' runs); a wait that no run ended is counted nowhere.
+    This is what tells the pool's jitted lambda from the solo fallback's:
+    neither has a name, each ends the waits of its own span."""
+    runs = sorted((end, name) for start, end, name in modules if end - start >= HELPER_S)
+    ends = [end for end, _ in runs]
+    out: dict[str, dict[str, int]] = {}
+    for _, events in host_lines:
+        for start, end, name in events:
+            if not name.endswith(WAIT_SPAN) or end - start < BLOCKED_S:
+                continue
+            at = bisect.bisect_right(ends, end + SKEW_S) - 1
+            if at >= 0 and ends[at] >= max(start, end - RETURN_S):
+                by = out.setdefault(name, {})
+                by[runs[at][1]] = by.get(runs[at][1], 0) + 1
+    return out
+
+
 def reduce_trace(data: Any, top: int = 10) -> dict[str, Any]:
     """-> ``window_s`` (first device event to last, over all devices),
     ``busy_s`` and ``idle_share`` (averaged over devices), ``programs``
-    (module name with its id -> {seconds, runs}, device 0), ``device_ops`` and ``idle_gaps``
-    (at most ``top`` ``[name, seconds]`` pairs each), ``n_devices``."""
+    (module name with its id -> {seconds, runs}, device 0), ``released``
+    (``released_by`` on device 0), ``device_ops`` and ``idle_gaps`` (at most
+    ``top`` ``[name, seconds]`` pairs each), ``n_devices``."""
     devices = [p for p in data.planes if DEVICE_PLANE.match(p.name)]
     if not devices:
         raise ValueError("the trace holds no /device:TPU:<n> plane")
@@ -133,7 +167,7 @@ def reduce_trace(data: Any, top: int = 10) -> dict[str, Any]:
     return {
         "window_s": window, "busy_s": busy_s, "n_devices": len(per_device),
         "idle_share": 1.0 - busy_s / window if window > 0 else None,
-        "programs": programs,
+        "programs": programs, "released": released_by(host_lines, modules0),
         "device_ops": sorted(([n, s] for n, s in by_op.items()), key=lambda x: -x[1])[:top],
         "idle_gaps": sorted(([n, s] for n, s in by_gap.items()), key=lambda x: -x[1])[:top],
     }

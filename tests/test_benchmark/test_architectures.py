@@ -212,39 +212,7 @@ def test_with_another_architectures_reference_the_same_cell_is_not_correct(with_
     assert any(n["value"] > n["limit"] for n in result["check"])
 
 
-# -- (d) which cell reports which per-layer metric ------------------------------------------
-
-STEADY = """client.ttft_p50_ms client.ttft_p90_ms client.tpot_p50_ms client.tpot_p90_ms
-client.late_p99_ms client.frame_gap_p99_ms client.stall_max_ms.steady batcher.queue_wait_p50_ms
-batcher.pad_share sched.defer_p90_ms pool.chunk_rows_mean.steady pool.reject_share.steady
-step.prefill_p50_ms step.decode_chunk_p50_ms.steady kernel.steady.decode_step_roofline
-kernel.prefill_step_roofline device.idle_share.steady device.hbm_peak_gb.steady
-step.decode_chunk_cadence_p50_ms.steady step.prefill_chunks_ahead_mean step.prefill_issue_p50_ms
-step.solo_chunk_p50_ms.steady pool.host_share.steady pool.admit_p50_ms request.parse_p50_ms
-request.first_frame_p50_ms request.server_ttft_mean_ms""".split()
-SATURATED = """pool.chunk_rows_mean.saturated pool.reject_share.saturated client.stall_max_ms.saturated
-step.decode_chunk_p50_ms.saturated kernel.saturated.decode_step_roofline device.idle_share.saturated
-device.hbm_peak_gb.saturated step.decode_chunk_cadence_p50_ms.saturated
-step.solo_chunk_p50_ms.saturated pool.host_share.saturated""".split()
-# since PR 26 the InternLM2 pool refuses nobody, so no request decodes solo there
-PER_LAYER = {
-    "mistral-7b-int8.chat-steady": STEADY,
-    "internlm2-1.8b-bf16.chat-steady": [m for m in STEADY if m != "step.solo_chunk_p50_ms.steady"],
-    "mistral-7b-int8.chat-saturated": SATURATED,
-}
-END_TO_END = {
-    "mistral-7b-int8.chat-steady": ["ttft_mean_ms", "tpot_mean_ms", "setup_s"],
-    "internlm2-1.8b-bf16.chat-steady": ["ttft_mean_ms", "tpot_mean_ms", "setup_s"],
-    "mistral-7b-int8.chat-saturated": ["out_tok_s", "setup_s"],
-}
-
-
-@pytest.mark.parametrize("cell", sorted(PER_LAYER))
-def test_metrics_of_cell_against_the_lists_written_out(cell):
-    names = lambda section: [m["name"] for m in spec.metrics_of_cell(MANIFEST, cell, section)]  # noqa: E731
-    assert names("end_to_end") == END_TO_END[cell]
-    assert names("per_layer") == PER_LAYER[cell]
-
+# -- (d) every per-layer metric says where it is read (the cells' lists: test_manifest_floors.py) --
 
 def test_every_per_layer_metric_lists_its_cells():
     """A cell a later PR adds inherits no reader written for another model:

@@ -1,8 +1,8 @@
 """The ``power_retention`` architecture in the harness: a tiny configuration
 added to a copy of the rehearsal data is served and checked by its own plain
-reference (and comes out not correct against ``dense_gqa``'s), the five
-cells' metric lists written out, and the retention work sheets against hand
-counts at the tiny shape. No chip."""
+reference (and comes out not correct against ``dense_gqa``'s), and the
+retention work sheets against hand counts at the tiny shape (its cell's
+metric lists: ``test_manifest_floors.py``). No chip."""
 
 import json
 import os
@@ -89,50 +89,6 @@ def test_the_parents_program_refuses_the_architecture_cleanly(monkeypatch):
     run = types.SimpleNamespace(cfg=cfg, sizes=arch.sizes_of(cfg), seed=1, log=print)
     with pytest.raises(spec.SpecError, match="no attention kind"):
         arch.register(run)
-
-
-# -- the cells' metric lists, written out --------------------------------------------------
-
-STEADY = [
-    "client.ttft_p50_ms", "client.ttft_p90_ms", "client.tpot_p50_ms", "client.tpot_p90_ms",
-    "client.late_p99_ms", "client.frame_gap_p99_ms", "client.stall_max_ms.steady",
-    "batcher.queue_wait_p50_ms", "batcher.pad_share", "sched.defer_p90_ms",
-    "pool.chunk_rows_mean.steady", "pool.reject_share.steady", "step.prefill_p50_ms",
-    "step.decode_chunk_p50_ms.steady", "device.idle_share.steady", "device.hbm_peak_gb.steady",
-    "step.decode_chunk_cadence_p50_ms.steady", "step.prefill_chunks_ahead_mean",
-    "step.prefill_issue_p50_ms", "pool.host_share.steady", "pool.admit_p50_ms",
-    "request.parse_p50_ms", "request.first_frame_p50_ms", "request.server_ttft_mean_ms",
-]
-DENSE = ["kernel.steady.decode_step_roofline", "kernel.prefill_step_roofline"]
-RETENTION = [
-    "kernel.retention.decode_step_roofline", "kernel.retention.prefill_step_roofline",
-    "kernel.retention.step_roofline", "kernel.retention.chunk_roofline", "state.move_share",
-    "state.insert_p50_ms",
-]
-SATURATED = [
-    "pool.chunk_rows_mean.saturated", "pool.reject_share.saturated",
-    "client.stall_max_ms.saturated", "step.decode_chunk_p50_ms.saturated",
-    "kernel.saturated.decode_step_roofline", "device.idle_share.saturated",
-    "device.hbm_peak_gb.saturated", "step.decode_chunk_cadence_p50_ms.saturated",
-    "step.solo_chunk_p50_ms.saturated", "pool.host_share.saturated",
-]
-CELLS = {
-    "mistral-7b-int8.chat-steady": (["ttft_mean_ms", "tpot_mean_ms", "setup_s"],
-                                    STEADY + DENSE + ["step.solo_chunk_p50_ms.steady"]),
-    "internlm2-1.8b-bf16.chat-steady": (["ttft_mean_ms", "tpot_mean_ms", "setup_s"], STEADY + DENSE),
-    "mistral-7b-int8.chat-saturated": (["out_tok_s", "setup_s"], SATURATED),
-    "mistral-7b-int8.docqa-steady": (["ttft_mean_ms", "tpot_mean_ms", "setup_s"], STEADY + DENSE),
-    "brumby-14b-bf16.longdoc-steady": (["ttft_mean_ms", "tpot_mean_ms", "setup_s"],
-                                       STEADY + RETENTION),
-}
-
-
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_metrics_of_cell_for_the_five_cells(cell):
-    e2e, layers = CELLS[cell]
-    assert [m["name"] for m in spec.metrics_of_cell(MANIFEST, cell, "end_to_end")] == e2e
-    assert sorted(m["name"] for m in spec.metrics_of_cell(MANIFEST, cell, "per_layer")) == sorted(layers)
-    assert sorted(c["name"] for c in MANIFEST["workloads"]) == sorted(CELLS)
 
 
 def test_the_brumby_configuration_keeps_every_published_number():

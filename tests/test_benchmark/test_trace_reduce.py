@@ -36,6 +36,35 @@ def test_device_time_per_program(reduced):
     assert out["programs"]["jit__lambda_(22)"] == {"seconds": pytest.approx(3e-3), "runs": 2}
 
 
+def test_a_blocked_wait_is_released_by_the_run_that_ended_it():
+    """On the hand-built trace of a pool beside a prefill and a solo chunk
+    (fixtures/trace_spans.textproto): each kind's waits end on its own
+    program; the wait that did not block and the tiny lambda after the solo
+    chunk are placed nowhere."""
+    from jax.profiler import ProfileData
+
+    path = os.path.join(spec.HERE, "fixtures", "trace_spans.textproto")
+    with open(path, encoding="utf-8") as fh:
+        out = trace_reduce.reduce_trace(ProfileData.from_text_proto(fh.read()))
+    assert out["released"] == {
+        "gofr.pool.fetch_wait": {"jit__lambda_(22)": 2},
+        "gofr.prefill.fetch_wait": {"jit__prefill_fn(11)": 1},
+        "gofr.solo.fetch_wait": {"jit__lambda_(33)": 1},
+    }
+
+
+def test_a_wait_no_run_ended_and_a_helper_run_release_nothing():
+    runs = [(0.0, 0.010, "pool"), (0.010, 0.0104, "slice"), (0.020, 0.030, "solo")]
+    waits = [("worker", [(0.004, 0.0106, "gofr.pool.fetch_wait"),  # ends on pool's run, not the helper's
+                         (0.012, 0.015, "gofr.pool.fetch_wait"),  # no run ended inside it
+                         (0.0299, 0.0301, "gofr.solo.fetch_wait"),  # did not block
+                         (0.021, 0.0301, "gofr.solo.fetch_wait"),
+                         (0.005, 0.0450, "gofr.pool.fetch_wait"),  # a stalled transfer: 15 ms after solo's run
+                         (0.004, 0.0106, "gofr.pool.deliver")])]  # not a wait span
+    assert trace_reduce.released_by(waits, runs) == {
+        "gofr.pool.fetch_wait": {"pool": 1}, "gofr.solo.fetch_wait": {"solo": 1}}
+
+
 def test_operation_labels_are_short_and_containers_left_out():
     long = "%copy.2 = bf16[8,128]{1,0:T(8,128)(2,1)} copy(bf16[8,128]{0,1} %p), metadata={}"
     assert trace_reduce.short_op(long) == ("copy.2 bf16[8,128] copy", "copy")
