@@ -60,6 +60,51 @@ TINY_RETENTION = TransformerConfig(
     attn_kind="retention",
 )
 
+# ZAYA1-8B's block at test size: attention in a latent narrower than the
+# hidden size ("cca": 4 q / 2 kv heads of 16 in a hidden of 64, two causal
+# convolutions, a value shift, half-rotary) and a top-1 routed expert layer
+# behind an MLP router; tied head. CPU tests.
+TINY_ZAYA = TransformerConfig(
+    vocab_size=256,
+    dim=64,
+    n_layers=3,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    hidden_dim=32,
+    max_seq=128,
+    rope_theta=10000.0,
+    rope_fraction=0.5,
+    dtype=jnp.float32,
+    attn_impl="xla",
+    attn_kind="cca",
+    ffn_kind="moe",
+    n_experts=4,
+    router_dim=16,
+    tie_embeddings=True,
+)
+
+# ZAYA1-8B (huggingface.co/Zyphra/ZAYA1-8B config.json): 40 layers of CCA
+# attention (8 q / 2 kv heads of 128 in a hidden of 2048) and 16 experts of
+# 2048, top-1, router MLP of 256, tied 262,272-row table; bf16
+ZAYA1_8B = TransformerConfig(
+    vocab_size=262272,
+    dim=2048,
+    n_layers=40,
+    n_heads=8,
+    n_kv_heads=2,
+    head_dim=128,
+    hidden_dim=2048,
+    max_seq=131072,
+    rope_theta=5000000.0,
+    rope_fraction=0.5,
+    attn_kind="cca",
+    ffn_kind="moe",
+    n_experts=16,
+    router_dim=256,
+    tie_embeddings=True,
+)
+
 # Small-but-realistic single-chip bench model (fits v5e-1 in bf16 and
 # exercises the same kernels/shapes class as 8B)
 SMALL = TransformerConfig(
@@ -76,6 +121,8 @@ SMALL = TransformerConfig(
 CONFIGS: dict[str, TransformerConfig] = {
     "tiny": TINY,
     "tiny-retention": TINY_RETENTION,
+    "tiny-zaya": TINY_ZAYA,
+    "zaya1-8b": ZAYA1_8B,
     "small": SMALL,
     "llama3-8b": LLAMA3_8B,
     "llama3-70b": LLAMA3_70B,

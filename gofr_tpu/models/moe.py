@@ -148,6 +148,54 @@ def _moe_mlp_dense(p: dict, x: jnp.ndarray, cfg: MoEConfig) -> tuple[jnp.ndarray
     return out.reshape(b, s, d).astype(x.dtype), aux
 
 
+def routed_mlp(
+    cfg: TransformerConfig, p: dict, h: jnp.ndarray, r_before: jnp.ndarray,
+    experts: dict, layer: jnp.ndarray, token_mask: Any = None,
+) -> tuple[jnp.ndarray, dict]:
+    """The serving path's expert layer (``cfg.ffn_kind == "moe"``): an MLP
+    router whose state passes down the stack, top-1, and the routed product
+    (ops/experts.py), which reads only the experts that got a token.
+
+    ``h`` [B, S, D] is the normed input, ``r_before`` [B, S, R] float32 the
+    router state of the layer above (zeros at the first), ``experts`` the
+    whole stacks [L, E, ...] with ``layer`` this layer's index, ``p`` the
+    layer's router leaves. ``token_mask`` [B, S] (None: all) says which
+    tokens are real: a pad token and a dead row go to no expert, add
+    nothing and are counted nowhere. Returns (y [B, S, D], {"router_state":
+    r [B, S, R], "expert_counts": [E] int32}).
+
+        r = h Wd + bd + gamma * r_before
+        z = W3 gelu(W2 gelu(W1 rms(r) + b1) + b2);  p = softmax(z);  e = argmax p
+        y = p[e] (silu(h Wgate[e]) * h Wup[e]) Wdown[e]
+    """
+    from gofr_tpu.ops.experts import routed_experts
+
+    b, s, d = h.shape
+    f32 = jnp.float32
+    with jax.named_scope("moe.router"):
+        # float32 throughout: a choice between two near experts should not
+        # turn on the rounding of a 256-wide MLP
+        dot = lambda x, w: jnp.einsum(  # noqa: E731
+            "...i,io->...o", x, p[w].astype(f32), precision=lax.Precision.HIGHEST)
+        r = (dot(h.astype(f32), "router_down") + p["router_down_b"].astype(f32)
+             + p["router_gamma"].astype(f32) * r_before)
+        a = rms_norm(r, p["router_norm"], cfg.norm_eps)
+        a = jax.nn.gelu(dot(a, "router_w1") + p["router_b1"].astype(f32), approximate=False)
+        a = jax.nn.gelu(dot(a, "router_w2") + p["router_b2"].astype(f32), approximate=False)
+        probs = jax.nn.softmax(dot(a, "router_w3"), axis=-1)  # [B, S, E]
+        choice = jnp.argmax(probs, axis=-1).astype(jnp.int32)
+        weight = jnp.max(probs, axis=-1)
+        if token_mask is not None:
+            choice = jnp.where(token_mask, choice, cfg.n_experts)
+    y, counts = routed_experts(
+        h.reshape(b * s, d), choice.reshape(b * s), experts["w_gate"],
+        experts["w_up"], experts["w_down"], layer,
+    )
+    with jax.named_scope("moe.combine"):
+        y = (y.reshape(b, s, d).astype(f32) * weight[..., None]).astype(h.dtype)
+    return y, {"router_state": r, "expert_counts": counts}
+
+
 def moe_block(
     cfg: MoEConfig,
     p: dict,

@@ -58,11 +58,45 @@ class TransformerConfig:
     # (ops/retention.py): RMSNorm over each head of q and k, a gate per kv
     # head (leaves q_norm, k_norm, w_g), and a cache that is a fixed-size
     # state per row, float32 unless kv_dtype says otherwise.
+    # "cca": softmax attention in a latent narrower than ``dim``
+    # (``n_heads * head_dim != dim``): q and k pass two causal convolutions
+    # over the sequence, a q-k mean and an L2 norm, v is shifted by a token
+    # (``_cca_qkv``); beside its K/V rows a cached row keeps a fixed
+    # ``tail`` (the last token's convolution inputs and shifted value).
     attn_kind: str = "softmax"
+    # the size of a head; 0 means ``dim // n_heads``
+    head_dim: int = 0
+    # the leading share of a head's dims that rotary turns (the rest pass)
+    rope_fraction: float = 1.0
+    # the output head is ``embed`` transposed: the tree has no ``lm_head``
+    tie_embeddings: bool = False
+    # what follows attention. "dense": SwiGLU of width ``hidden_dim``.
+    # "moe": ``n_experts`` SwiGLU experts of width ``hidden_dim``, one a
+    # token, chosen by an MLP router of width ``router_dim`` whose state
+    # passes from layer to layer (models/moe.py::routed_mlp).
+    ffn_kind: str = "dense"
+    n_experts: int = 0
+    router_dim: int = 0
+
+    def __post_init__(self) -> None:
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.dim // self.n_heads)
 
     @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+    def q_dim(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        return int(self.head_dim * self.rope_fraction)
+
+    @property
+    def tail_dim(self) -> int:
+        """What a row of a "cca" cache keeps a layer beside K and V: the
+        last token's q~|k~ and first convolution's output, and the half of
+        its value projection that the next token takes."""
+        kv_dim = self.n_kv_heads * self.head_dim
+        return 2 * (self.q_dim + kv_dim) + kv_dim // 2
 
     @property
     def cache_dtype(self) -> Any:
@@ -136,6 +170,8 @@ def init_transformer(
         return jnp.zeros(shape, x.dtype, device=NamedSharding(mesh, spec))
 
     quantizer_for(quantize)  # validate the mode eagerly
+    if quantize and cfg.ffn_kind == "moe":
+        raise ValueError("the quantiser does not take expert-stacked leaves")
     n_keys = cfg.n_layers * 7 + 3
     keys = iter(jax.random.split(key, n_keys))
 
@@ -158,7 +194,45 @@ def init_transformer(
             next(keys), (cfg.dim, cfg.vocab_size), cfg.dim, name="lm_head"
         ),
     })
+    if cfg.tie_embeddings:
+        del params["lm_head"]  # the head is ``embed`` transposed
     kv_dim = cfg.n_kv_heads * cfg.head_dim
+    q_dim = cfg.q_dim
+
+    def extra(i: int, n: int, shape: tuple[int, ...], fan_in: int) -> jnp.ndarray:
+        # keys of their own, so the dense leaves' values stay what they were
+        k = jax.random.fold_in(jax.random.fold_in(jax.random.fold_in(key, n_keys), i), n)
+        return (jax.random.truncated_normal(k, -3, 3, shape) * (fan_in ** -0.5)).astype(cfg.dtype)
+
+    def cca_leaves(i: int) -> dict:
+        heads, d = cfg.n_heads + cfg.n_kv_heads, cfg.head_dim
+        return {
+            # two causal convolutions of kernel 2 over q~|k~: depthwise
+            # [tap, channel], then grouped by head [tap, head, in, out];
+            # tap 0 multiplies the token before, tap 1 this one
+            "cca_w0": extra(i, 1, (2, heads * d), 2),
+            "cca_b0": jnp.zeros((heads * d,), cfg.dtype),
+            "cca_w1": extra(i, 2, (2, heads, d, d), 2 * d),
+            "cca_b1": jnp.zeros((heads, d), cfg.dtype),
+            "cca_temp": jnp.ones((cfg.n_kv_heads,), cfg.dtype),
+        }
+
+    def moe_leaves(i: int) -> dict:
+        r, e, f = cfg.router_dim, cfg.n_experts, cfg.hidden_dim
+        return {
+            "router_down": extra(i, 3, (cfg.dim, r), cfg.dim),
+            "router_down_b": jnp.zeros((r,), cfg.dtype),
+            "router_gamma": jnp.full((r,), 0.5, cfg.dtype),
+            "router_norm": jnp.ones((r,), cfg.dtype),
+            "router_w1": extra(i, 4, (r, r), r),
+            "router_b1": jnp.zeros((r,), cfg.dtype),
+            "router_w2": extra(i, 5, (r, r), r),
+            "router_b2": jnp.zeros((r,), cfg.dtype),
+            "router_w3": extra(i, 6, (r, e), r),
+            "w_gate": extra(i, 7, (e, cfg.dim, f), cfg.dim),
+            "w_up": extra(i, 8, (e, cfg.dim, f), cfg.dim),
+            "w_down": extra(i, 9, (e, f, cfg.dim), f),
+        }
 
     def retention_leaves(i: int) -> dict:
         # keys of their own, so the dense leaves' values stay what they were
@@ -172,17 +246,21 @@ def init_transformer(
         }
 
     def make_layer() -> dict:
-        return {
+        layer = {
             "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
-            "wq": dense(next(keys), (cfg.dim, cfg.dim), cfg.dim),
+            "wq": dense(next(keys), (cfg.dim, q_dim), cfg.dim),
             "wk": dense(next(keys), (cfg.dim, kv_dim), cfg.dim),
             "wv": dense(next(keys), (cfg.dim, kv_dim), cfg.dim),
-            "wo": dense(next(keys), (cfg.dim, cfg.dim), cfg.dim),
+            "wo": dense(next(keys), (q_dim, cfg.dim), q_dim),
             "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
-            "w_gate": dense(next(keys), (cfg.dim, cfg.hidden_dim), cfg.dim),
-            "w_up": dense(next(keys), (cfg.dim, cfg.hidden_dim), cfg.dim),
-            "w_down": dense(next(keys), (cfg.hidden_dim, cfg.dim), cfg.hidden_dim),
         }
+        if cfg.ffn_kind == "dense":
+            layer.update({
+                "w_gate": dense(next(keys), (cfg.dim, cfg.hidden_dim), cfg.dim),
+                "w_up": dense(next(keys), (cfg.dim, cfg.hidden_dim), cfg.dim),
+                "w_down": dense(next(keys), (cfg.hidden_dim, cfg.dim), cfg.hidden_dim),
+            })
+        return layer
 
     # layers live as ONE pytree level of [n_layers, ...] arrays, scanned in
     # the forward — one compiled layer body instead of n_layers copies.
@@ -196,6 +274,10 @@ def init_transformer(
         layer = make_layer()
         if cfg.attn_kind == "retention":
             layer.update(retention_leaves(i))
+        if cfg.attn_kind == "cca":
+            layer.update(cca_leaves(i))
+        if cfg.ffn_kind == "moe":
+            layer.update(moe_leaves(i))
         layer = put(layer)
         if stacked is None:
             stacked = jax.tree.map(stack_like, layer)
@@ -234,13 +316,85 @@ def _write_kv(
     return stack
 
 
+# what a layer holds per expert: [n_experts, ...] a layer, so
+# [n_layers, n_experts, ...] in the tree. The layer loops do not slice them
+# (a slice of one layer's experts would be copied whole for the kernel that
+# reads a few): ``_split_experts`` keeps the stacks out of the scan's xs and
+# ops/experts.py indexes [layer, expert] itself.
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+_L2_EPS = 1e-6  # under the root of the "cca" L2 norm: a dead row's q is 0
+
+
+def _split_experts(cfg: TransformerConfig, layers: dict) -> tuple[dict, Optional[dict]]:
+    """(what the layer loop scans, the expert stacks it closes over)."""
+    if cfg.ffn_kind != "moe":
+        return layers, None
+    return ({k: v for k, v in layers.items() if k not in EXPERT_LEAVES},
+            {k: layers[k] for k in EXPERT_LEAVES})
+
+
+def _shift(x: jnp.ndarray, before: jnp.ndarray) -> jnp.ndarray:
+    """``x`` [B, S, C] a token later: position t holds x[t-1], position 0
+    holds ``before`` [B, C] (zeros at a sequence's first token)."""
+    return jnp.concatenate([before[:, None].astype(x.dtype), x[:, :-1]], axis=1)
+
+
+def _cca_qkv(
+    cfg: TransformerConfig, p: dict, h: jnp.ndarray, tail: jnp.ndarray,
+    last: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """q, k, v of a "cca" layer before rotary, from the normed input ``h``
+    [B, S, D] and the row's ``tail`` [B, tail_dim] (what the token before
+    this call left: zeros at a sequence's start), and the tail this call
+    leaves, taken at token ``last`` [B] of each row. Plain jax.numpy: the
+    convolutions are 1280 channels wide at the published sizes."""
+    b, s, _ = h.shape
+    n_q, n_kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    rep, width, half = n_q // n_kv, (n_q + n_kv) * d, n_kv * d // 2
+    f32 = jnp.float32
+    c_before, c1_before, u_before = jnp.split(tail, [width, 2 * width], axis=-1)
+    with jax.named_scope("attn.qkv"):
+        qt, kt, u = _mm(h, p["wq"]), _mm(h, p["wk"]), _mm(h, p["wv"])
+    with jax.named_scope("attn.cca.conv"):
+        c = jnp.concatenate([qt, kt], axis=-1)  # [B, S, width]
+        w0, w1 = p["cca_w0"].astype(f32), p["cca_w1"]
+        c1 = (w0[0] * _shift(c, c_before).astype(f32) + w0[1] * c.astype(f32)
+              + p["cca_b0"].astype(f32)).astype(h.dtype)
+        by_head = lambda x: x.reshape(b, s, n_q + n_kv, d)  # noqa: E731
+        taps = lambda x, w: jnp.einsum(  # noqa: E731
+            "bsgi,gio->bsgo", by_head(x), w, preferred_element_type=f32)
+        c2 = (taps(_shift(c1, c1_before), w1[0]) + taps(c1, w1[1])
+              + p["cca_b1"].astype(f32))  # [B, S, heads, d] float32
+    with jax.named_scope("attn.cca.mix_norm"):
+        qh = qt.astype(f32).reshape(b, s, n_q, d)
+        kh = kt.astype(f32).reshape(b, s, n_kv, d)
+        mq = (qh + jnp.repeat(kh, rep, axis=2)) / 2.0
+        mk = jnp.mean(mq.reshape(b, s, n_kv, rep, d), axis=3)
+        q, k = c2[:, :, :n_q] + mq, c2[:, :, n_q:] + mk
+        unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
+        q = ((d ** 0.5) * unit(q)).astype(h.dtype)
+        k = ((d ** 0.5) * p["cca_temp"].astype(f32)[:, None] * unit(k)).astype(h.dtype)
+    with jax.named_scope("attn.cca.value_shift"):
+        # the first half of a token's value is its own, the second half
+        # the projection of the token before: viewed as kv heads
+        u_late = u[..., half:]
+        v = jnp.concatenate([u[..., :half], _shift(u_late, u_before)], axis=-1)
+        v = v.reshape(b, s, n_kv, d)
+        at_last = lambda x: jnp.take_along_axis(  # noqa: E731
+            x, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        new_tail = jnp.concatenate(
+            [at_last(c), at_last(c1), at_last(u_late)], axis=-1).astype(tail.dtype)
+    return q, k, v, new_tail
+
+
 def _block(
     cfg: TransformerConfig,
     p: dict,
     x: jnp.ndarray,
     freqs: jnp.ndarray,
     positions: jnp.ndarray,
-    kv_cache: Optional[tuple[jnp.ndarray, jnp.ndarray]] = None,
+    kv_cache: Optional[tuple[jnp.ndarray, ...]] = None,
     layer: Optional[jnp.ndarray] = None,
     starts: Optional[jnp.ndarray] = None,
     kv_lens: Optional[jnp.ndarray] = None,
@@ -248,11 +402,11 @@ def _block(
     mlp_fn: Optional[Any] = None,
     valid: Optional[jnp.ndarray] = None,
     live: Optional[jnp.ndarray] = None,
-) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray], dict]:
+) -> tuple[jnp.ndarray, tuple[jnp.ndarray, ...], dict]:
     """One decoder block — the single implementation shared by the
     no-cache forward, the cached prefill/decode path, the sequence-parallel
-    ring path (which passes ``attn_fn``), and the MoE model (which passes
-    ``mlp_fn`` returning (out, aux_losses)).
+    ring path (which passes ``attn_fn``), and the MoE models (which pass
+    ``mlp_fn`` returning (out, aux)).
 
     Without cache: attention over this call's keys (via ``attn_fn`` when
     given), returns (out, (k, v), aux). With cache: ``kv_cache`` is the
@@ -269,16 +423,39 @@ def _block(
     bucket padding to be dead in, so a pad token must not enter it.
     ``live`` [B] says which rows hold a request (the decode pool's slots):
     the one-token step moves no state for the others.
+
+    ``cfg.attn_kind == "cca"``: ``kv_cache`` is (k, tail, v), the tail
+    [L, B, tail_dim] beside the K/V stacks. The call reads its layer's tail
+    at entry and leaves the one of each row's last ``valid`` token (bucket
+    padding does not enter it); a row that is not ``live`` keeps its own.
     """
     # the named scopes are names only (HLO op metadata: a device
     # operation in a profiler trace then says which of these lines it
     # came from); they change no program, shape or module name
     b, s, _ = x.shape
-    with jax.named_scope("attn.qkv"):
+    tail_stack = None
+    if cfg.attn_kind == "cca":
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q = _mm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-        k = _mm(h, p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        v = _mm(h, p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        if kv_cache is None:
+            tail = jnp.zeros((b, cfg.tail_dim), x.dtype)
+        else:
+            k_stack, tail_stack, v_stack = kv_cache
+            kv_cache = (k_stack, v_stack)
+            tail = jax.lax.dynamic_index_in_dim(tail_stack, layer, 0, keepdims=False)
+        last = (jnp.full((b,), s - 1, jnp.int32) if valid is None
+                else jnp.maximum(jnp.sum(valid, axis=1) - 1, 0))
+        q, k, v, new_tail = _cca_qkv(cfg, p, h, tail, last)
+        if tail_stack is not None:
+            if live is not None:
+                new_tail = jnp.where(live[:, None] > 0, new_tail, tail)
+            tail_stack = jax.lax.dynamic_update_slice(
+                tail_stack, new_tail[None], (layer, 0, 0))
+    else:
+        with jax.named_scope("attn.qkv"):
+            h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+            q = _mm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = _mm(h, p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = _mm(h, p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     if cfg.attn_kind == "retention":
         with jax.named_scope("attn.qk_norm"):
             q = rms_norm(q, p["q_norm"], cfg.norm_eps)
@@ -327,14 +504,62 @@ def _block(
                 layer=layer,
             )
         merged = (k_stack, v_stack)
+        if tail_stack is not None:
+            merged = (k_stack, tail_stack, v_stack)  # by name, as cache_leaves
 
     with jax.named_scope("attn.out"):
-        x = x + _mm(attn.reshape(b, s, cfg.dim), p["wo"])
+        x = x + _mm(attn.reshape(b, s, cfg.q_dim), p["wo"])
     with jax.named_scope("mlp"):
         h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
         y, aux = (mlp_fn or _default_mlp)(p, h)
         x = x + y
     return x, merged, aux
+
+
+def _logits(params: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """The output head over ``x`` [..., D], float32. A tied model has no
+    ``lm_head``: the head is the embedding table read along its rows."""
+    if "lm_head" in params:
+        return _mm(x, params["lm_head"]).astype(jnp.float32)
+    return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(jnp.float32)
+
+
+def _scan_layers(
+    cfg: TransformerConfig, params: dict, x: jnp.ndarray, stacks: Optional[tuple],
+    block: Any, token_mask: Optional[jnp.ndarray] = None,
+) -> tuple[jnp.ndarray, Optional[tuple], dict]:
+    """The layer loop of every forward. ``block(layer_params, x, stacks,
+    layer, mlp_fn)`` runs one ``_block``; the cache stacks ride the loop's
+    CARRY (a scan's ys is a fresh buffer, so stacks passed as xs/ys are
+    copied slab by slab every call, and whole at the carry of any loop
+    around this one). An expert model also carries the router's state
+    beside ``x`` (it lives within one forward) and gives back what routing
+    did: ``aux["expert_counts"]`` [L, E], the tokens each expert got."""
+    scanned, experts = _split_experts(cfg, params["layers"])
+    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    if experts is None:
+        def body(carry, inputs):
+            x, stacks = carry
+            y, stacks, _ = block(inputs[0], x, stacks, inputs[1], None)
+            return (y, stacks), None
+
+        (x, stacks), _ = jax.lax.scan(body, (x, stacks), (scanned, layer_ids))
+        return x, stacks, {}
+
+    from gofr_tpu.models.moe import routed_mlp
+
+    def body(carry, inputs):
+        x, stacks, r = carry
+        layer_params, layer = inputs
+        mlp_fn = lambda p, h: routed_mlp(  # noqa: E731
+            cfg, p, h, r, experts, layer, token_mask)
+        y, stacks, aux = block(layer_params, x, stacks, layer, mlp_fn)
+        return (y, stacks, aux["router_state"]), aux["expert_counts"]
+
+    r0 = jnp.zeros(x.shape[:-1] + (cfg.router_dim,), jnp.float32)
+    (x, stacks, _), counts = jax.lax.scan(
+        body, (x, stacks, r0), (scanned, layer_ids))
+    return x, stacks, {"expert_counts": counts}
 
 
 def transformer_forward(
@@ -343,19 +568,19 @@ def transformer_forward(
     """Full-sequence forward -> logits [B, S, V] (training / no-cache
     scoring). Layers run under lax.scan over stacked weights."""
     b, s = tokens.shape
-    freqs = jnp.asarray(_cached_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta))
+    freqs = jnp.asarray(_cached_freqs(cfg.rope_dim, cfg.max_seq, cfg.rope_theta))
     positions = jnp.arange(s)
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
 
-    def body(carry, layer_params):
-        y, _, _ = _block(cfg, layer_params, carry, freqs, positions)
-        return y, None
+    def block(layer_params, x, stacks, layer, mlp_fn):
+        y, _, aux = _block(cfg, layer_params, x, freqs, positions, mlp_fn=mlp_fn)
+        return y, None, aux
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _, _ = _scan_layers(cfg, params, x, None, block)
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["norm_f"], cfg.norm_eps)
-        return _mm(x, params["lm_head"]).astype(jnp.float32)
+        return _logits(params, x)
 
 
 # -- KV-cached ragged-batch serving path -------------------------------------
@@ -387,6 +612,11 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
         shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
         stacks = {"k": jnp.zeros(shape, cfg.cache_dtype),
                   "v": jnp.zeros(shape, cfg.cache_dtype)}
+        if cfg.attn_kind == "cca":
+            # beside the rows that grow by the token, a fixed tail per row
+            # (``TransformerConfig.tail_dim``), in the model's own type
+            # whatever K and V are held in: zeros at a sequence's start
+            stacks["tail"] = jnp.zeros((cfg.n_layers, batch, cfg.tail_dim), cfg.dtype)
     # ``live``: the rows that hold a request. The decode pool keeps it to
     # its active slots; every row of a prefill's or a solo cache is live,
     # and so is every row of a cache that lacks the leaf.
@@ -395,31 +625,34 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
 
 
 def cache_leaves(cache: dict) -> tuple[str, ...]:
-    """The names of a cache's device state: ``k`` and ``v``, or a retention
-    model's ``s`` and ``z``; every one has the row (slot) axis second. The
-    per-row vectors ride beside them: ``lengths`` [B] and ``live`` [B]."""
+    """The names of a cache's device state: ``k`` and ``v`` (with ``tail``
+    for a "cca" model), or a retention model's ``s`` and ``z``; every one
+    has the row (slot) axis second. The per-row vectors ride beside them:
+    ``lengths`` [B] and ``live`` [B]."""
     return tuple(sorted(name for name, leaf in cache.items() if leaf.ndim > 1))
 
 
 def _run_cached(
     params: dict, tokens: jnp.ndarray, cache: dict, cfg: TransformerConfig,
     lengths: Optional[jnp.ndarray] = None,
-) -> tuple[jnp.ndarray, dict, jnp.ndarray]:
+) -> tuple[jnp.ndarray, dict, jnp.ndarray, dict]:
     """Shared cached-forward body (prefill, decode, and the speculative
     verify all run THIS): ``tokens`` [B, S] starting at per-request
     ``cache['lengths']``. Returns the final-norm hidden states [B, S, D],
     the cache's stacks by name — the buffers that came in, with this
-    call's tokens written into them — and ``starts`` [B].
+    call's tokens written into them — ``starts`` [B], and what the layers
+    report of themselves (``_scan_layers``; empty for a dense model).
 
     Keys valid for query j of request b: cache positions <= starts_b + j
     (causal handles the per-query bound; kv_lens bounds the written region
     so never-written cache slots are excluded, and is 0 for a row that
     ``cache['live']`` says holds no request). A retention state has no
     such region: there ``lengths`` (this call's real tokens per row) keeps
-    bucket padding out of the state."""
+    bucket padding out of the state, as it does out of a "cca" cache's
+    tail and out of every expert's tokens."""
     b, s = tokens.shape
     starts = cache["lengths"]  # [B]
-    freqs = jnp.asarray(_cached_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta))
+    freqs = jnp.asarray(_cached_freqs(cfg.rope_dim, cfg.max_seq, cfg.rope_theta))
     positions = starts[:, None] + jnp.arange(s)[None, :]  # [B, S]
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
@@ -430,30 +663,29 @@ def _run_cached(
         # read for it and returns zeros (ops/flash.py, the decode form)
         written = jnp.where(live > 0, written, 0)
     valid = None
-    if cfg.attn_kind == "retention" and lengths is not None:
+    masks_pads = cfg.attn_kind in ("retention", "cca") or cfg.ffn_kind == "moe"
+    if masks_pads and lengths is not None:
         valid = jnp.arange(s)[None, :] < lengths[:, None]
+    token_mask = None
+    if cfg.ffn_kind == "moe":
+        # a pad token and the row of a slot without a request go to no expert
+        token_mask = jnp.ones((b, s), bool) if valid is None else valid
+        if live is not None:
+            token_mask = token_mask & (live[:, None] > 0)
     names = cache_leaves(cache)
 
-    # the stacks ride the layer loop's CARRY: a scan's ys is a fresh
-    # buffer, so stacks passed as xs/ys are copied slab by slab every
-    # call, and whole at the carry of any loop around this one
-    def body(carry, inputs):
-        x, stacks = carry
-        layer_params, layer = inputs
-        y, stacks, _ = _block(
+    def block(layer_params, x, stacks, layer, mlp_fn):
+        return _block(
             cfg, layer_params, x, freqs, positions,
             kv_cache=stacks, layer=layer, starts=starts,
-            kv_lens=written, valid=valid, live=live,
+            kv_lens=written, valid=valid, live=live, mlp_fn=mlp_fn,
         )
-        return (y, stacks), None
 
-    (x, stacks), _ = jax.lax.scan(
-        body, (x, tuple(cache[name] for name in names)),
-        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-    )
+    x, stacks, aux = _scan_layers(
+        cfg, params, x, tuple(cache[name] for name in names), block, token_mask)
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["norm_f"], cfg.norm_eps)
-    return x, dict(zip(names, stacks)), starts
+    return x, dict(zip(names, stacks)), starts, aux
 
 
 def _forward_with_cache(
@@ -462,15 +694,17 @@ def _forward_with_cache(
     cache: dict,
     cfg: TransformerConfig,
     lengths: Optional[jnp.ndarray],
-) -> tuple[jnp.ndarray, dict]:
+    with_aux: bool = False,
+) -> tuple:
     """Run ``tokens`` [B, S] starting at per-request ``cache['lengths']``.
     ``lengths`` [B] gives the true (un-padded) token count of this call per
     request (defaults to S). Returns logits at each request's final real
-    position and the updated cache."""
+    position and the updated cache; ``with_aux`` adds ``_run_cached``'s
+    report of the layers as a third."""
     b, s = tokens.shape
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
-    x, stacks, starts = _run_cached(
+    x, stacks, starts, aux = _run_cached(
         params, tokens, cache, cfg, lengths if s > 1 else None
     )
     with jax.named_scope("lm_head"):
@@ -480,9 +714,9 @@ def _forward_with_cache(
         x_last = jnp.take_along_axis(
             x, last_idx[:, None, None].astype(jnp.int32), axis=1
         )[:, 0]
-        logits = _mm(x_last, params["lm_head"]).astype(jnp.float32)
+        logits = _logits(params, x_last)
     new_cache = {**cache, **stacks, "lengths": starts + lengths}
-    return logits, new_cache
+    return (logits, new_cache, aux) if with_aux else (logits, new_cache)
 
 
 def prefill(
@@ -491,9 +725,11 @@ def prefill(
     cache: dict,
     cfg: TransformerConfig,
     lengths: Optional[jnp.ndarray] = None,
-) -> tuple[jnp.ndarray, dict]:
+    with_aux: bool = False,
+) -> tuple:
     """Process a (possibly padded) prompt bucket [B, S]; ``lengths`` [B] are
-    true prompt lengths. Returns next-token logits [B, V] + cache.
+    true prompt lengths. Returns next-token logits [B, V] + cache (and with
+    ``with_aux`` what the layers report: ``_run_cached``).
 
     Chunk-resume contract (chunked prefill, PREFILL_CHUNK_TOKENS): this
     call starts at ``cache['lengths']`` and attends the full written
@@ -503,14 +739,15 @@ def prefill(
     its queries see every earlier slice's KV. That is what lets the
     serving layer bound per-dispatch prefill compute without changing
     outputs (asserted bit-exact in tests/test_tpu.py)."""
-    return _forward_with_cache(params, tokens, cache, cfg, lengths)
+    return _forward_with_cache(params, tokens, cache, cfg, lengths, with_aux)
 
 
 def decode_step(
-    params: dict, token: jnp.ndarray, cache: dict, cfg: TransformerConfig
-) -> tuple[jnp.ndarray, dict]:
+    params: dict, token: jnp.ndarray, cache: dict, cfg: TransformerConfig,
+    with_aux: bool = False,
+) -> tuple:
     """One autoregressive step: ``token`` [B, 1] -> logits [B, V] + cache."""
-    return _forward_with_cache(params, token, cache, cfg, None)
+    return _forward_with_cache(params, token, cache, cfg, None, with_aux)
 
 
 def verify_chunk(
@@ -531,8 +768,8 @@ def verify_chunk(
     shapes, so near-tie logits can in principle break exact greedy
     equality on low-precision checkpoints."""
     s = tokens.shape[1]
-    x, stacks, starts = _run_cached(params, tokens, cache, cfg)
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, S, V]
+    x, stacks, starts, _ = _run_cached(params, tokens, cache, cfg)
+    logits = _logits(params, x)  # [B, S, V]
     next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     new_cache = {**cache, **stacks, "lengths": starts + s}
     return next_ids, new_cache
@@ -571,8 +808,8 @@ def verify_chunk_sampled(
 
     b, s = tokens.shape
     k_drafts = s - 1
-    x, stacks, starts = _run_cached(params, tokens, cache, cfg)
-    logits = _mm(x, params["lm_head"]).astype(jnp.float32)  # [B, S, V]
+    x, stacks, starts, _ = _run_cached(params, tokens, cache, cfg)
+    logits = _logits(params, x)  # [B, S, V]
     v = logits.shape[-1]
     p = warped_probs(
         logits.reshape(b * s, v), temperature, top_k, top_p, min_p
@@ -768,6 +1005,23 @@ def score_tokens(
 TOP_LOGPROBS = 5  # OpenAI's completions cap; compiled into every chunk
 
 
+def pack_expert_counts(ids: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
+    """What routing did, behind a step's token ids ``ids`` [B] int32 in the
+    one array the host already fetches: ``counts`` [L, E] (tokens each
+    expert of each layer got) flattened after them -> [B + L * E]."""
+    return jnp.concatenate([ids, counts.reshape(-1).astype(ids.dtype)])
+
+
+def unpack_expert_counts(ids: Any, rows: int, n_experts: int) -> tuple[Any, Optional[Any]]:
+    """A fetched (numpy) ``pack_expert_counts`` array, rows first ([rows + L
+    * E] or, a chunk's, [rows + L * E, steps]) -> (ids, counts [..., L, E]
+    with a chunk's steps first); counts None where nothing rode along."""
+    if ids.shape[0] == rows:
+        return ids, None
+    packed = ids[rows:].T if ids.ndim > 1 else ids[rows:]
+    return ids[:rows], packed.reshape(packed.shape[:-1] + (-1, n_experts))
+
+
 def _chosen_logprobs(logits: jnp.ndarray, nxt: jnp.ndarray) -> jnp.ndarray:
     """[B] f32 RAW log-probabilities of the chosen tokens — log-softmax of
     the UNPENALIZED logits, the one logprob convention every decode path
@@ -818,10 +1072,14 @@ def decode_chunk_pool(
     from gofr_tpu.ops.sampling import sample_logits_rows
 
     key, sub = jax.random.split(key)
+    routed = cfg.ffn_kind == "moe"
 
     def body(carry, _):
         tok, c, k = carry
-        logits, c = decode_step(params, tok, c, cfg)
+        if routed:
+            logits, c, aux = decode_step(params, tok, c, cfg, with_aux=True)
+        else:
+            logits, c = decode_step(params, tok, c, cfg)
         with jax.named_scope("sample"):
             k, s = jax.random.split(k)
             nxt = sample_logits_rows(
@@ -830,7 +1088,8 @@ def decode_chunk_pool(
             lp, tv, ti = _lp_outputs(logits, nxt)
         # the cache is this loop's carry and the layer loop's too
         # (_run_cached): the step hands on the buffer it was given
-        return (nxt[:, None], c, k), (nxt, lp, tv, ti)
+        out = pack_expert_counts(nxt, aux["expert_counts"]) if routed else nxt
+        return (nxt[:, None], c, k), (out, lp, tv, ti)
 
     (tok, cache, _), (toks, lps, tvals, tids) = jax.lax.scan(
         body, (token, cache, sub), None, length=n_steps

@@ -1,0 +1,172 @@
+"""The routed expert product: every token through the ONE SwiGLU expert it
+was sent to, reading only the experts that got a token.
+
+``routed_experts(x, expert, w_gate, w_up, w_down, layer)``: ``x`` [T, D]
+tokens, ``expert`` [T] int32 the expert of each (``n_experts`` for a token
+that goes to none: bucket padding, the row of a slot without a request),
+the weights as the model holds them, stacked over layers
+([L, E, D, F], [L, E, D, F], [L, E, F, D]) with ``layer`` an int32 scalar,
+or one layer's ([E, ...], ``layer`` None). Returns ``y`` [T, D] (zeros for
+a token of no expert) and ``counts`` [E], the tokens each expert got.
+
+Shapes are static in T; the group sizes are data:
+
+- dispatch: tokens sorted by expert (a stable argsort; tokens of no expert
+  sort last and belong to no group), and the schedule of (row tile, expert)
+  visits made from the group sizes;
+- experts: two Pallas calls on the TPU, ``moe_experts_gated``
+  (silu(x Wg) * (x Wu)) and ``moe_experts_down``. The grid is (column
+  tiles, visits); a visit multiplies one row tile by one expert's column
+  tile and keeps the rows that are that expert's. An expert with no token
+  is never visited, so its weights are never read from HBM; the visits
+  past the last real one repeat its block indices, which moves nothing.
+  The kernels index [layer, expert] of the stacks themselves: a layer's
+  slice handed in by a scan would be copied whole first. Off the TPU (and
+  under ``impl="xla"``) ``jax.lax.ragged_dot`` over the same sorted tokens;
+- combine: the sorted result back in token order, zeros where no expert.
+
+The all-expert form this is tested against is ``models/moe.py::
+_moe_mlp_dense``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+ROW_TILE = 128  # rows of sorted tokens a visit multiplies (fewer when T is)
+COL_TILE = 512  # columns of an expert's weight a visit reads: [K, 512] bf16 = 2 MB
+
+
+def _row_tile(tokens: int) -> int:
+    return ROW_TILE if tokens >= ROW_TILE else -(-tokens // 16) * 16
+
+
+def visit_schedule(counts: jnp.ndarray, tiles: int, tile: int) -> tuple:
+    """The (row tile, expert) pairs a grouped product has to visit, experts
+    in order, for sorted tokens with ``counts`` [E] a group over ``tiles``
+    row tiles of ``tile`` rows -> (offsets [E + 1], expert [V], row tile [V],
+    visits [1]) with V = tiles + E - 1, the most there can be. Entries past
+    ``visits`` repeat the last real one."""
+    n = counts.shape[0]
+    ends = jnp.cumsum(counts)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends]).astype(jnp.int32)
+    first = offsets[:-1] // tile
+    last = jnp.maximum(ends - 1, 0) // tile
+    per_expert = jnp.where(counts > 0, last - first + 1, 0)
+    visit_ends = jnp.cumsum(per_expert)
+    total = visit_ends[-1]
+    at = jnp.minimum(jnp.arange(tiles + n - 1), jnp.maximum(total - 1, 0))
+    expert = jnp.minimum(jnp.searchsorted(visit_ends, at, side="right"), n - 1)
+    row_tile = first[expert] + at - (visit_ends - per_expert)[expert]
+    return (offsets, expert.astype(jnp.int32),
+            jnp.clip(row_tile, 0, tiles - 1).astype(jnp.int32),
+            jnp.reshape(total, (1,)).astype(jnp.int32))
+
+
+def _visit_kernel(layer_ref, offsets_ref, expert_ref, tile_ref, visits_ref,
+                  x_ref, *refs, tile: int, gated: bool):
+    from jax.experimental import pallas as pl
+
+    del layer_ref
+    out_ref = refs[-1]
+    i = pl.program_id(1)
+
+    @pl.when(i < visits_ref[0])
+    def _():
+        e, t = expert_ref[i], tile_ref[i]
+        x = x_ref[...]
+        y = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
+        if gated:
+            y = jax.nn.silu(y) * jnp.dot(x, refs[1][...], preferred_element_type=jnp.float32)
+        row = t * tile + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        mine = (row >= offsets_ref[e]) & (row < offsets_ref[e + 1])
+        # a row tile's visits follow one another: the first of them starts
+        # the tile's output from zeros, the others add their expert's rows
+        fresh = jnp.logical_or(i == 0, tile_ref[jnp.maximum(i - 1, 0)] != t)
+        kept = jnp.where(fresh, 0.0, out_ref[...].astype(jnp.float32))
+        out_ref[...] = jnp.where(mine, y, kept).astype(out_ref.dtype)
+
+
+def _grouped(x: jnp.ndarray, weights: tuple, layer: jnp.ndarray, schedule: tuple,
+             tile: int, interpret: bool) -> jnp.ndarray:
+    """One Pallas call over the visit schedule: ``x`` [M, K] sorted tokens
+    (M a multiple of ``tile``), ``weights`` one stack [L, E, K, N] (the
+    product) or two (the gated pair) -> [M, N]. Rows of no expert are not
+    written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = x.shape
+    n = weights[0].shape[-1]
+    cols = COL_TILE if n % COL_TILE == 0 else n
+    offsets, expert, row_tile, visits = schedule
+    rows_of = lambda j, i, lyr, off, ex, rt, nv: (rt[i], 0)  # noqa: E731
+    weight_of = lambda j, i, lyr, off, ex, rt, nv: (lyr[0], ex[i], 0, j)  # noqa: E731
+    out_of = lambda j, i, lyr, off, ex, rt, nv: (rt[i], j)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n // cols, expert.shape[0]),
+        in_specs=[pl.BlockSpec((tile, k), rows_of)]
+        + [pl.BlockSpec((None, None, k, cols), weight_of) for _ in weights],
+        out_specs=pl.BlockSpec((tile, cols), out_of),
+    )
+    block_bytes = k * cols * weights[0].dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_visit_kernel, tile=tile, gated=len(weights) == 2),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # in order: a tile's later visits build on its earlier ones
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # each weight block double-buffered, and room for the rest
+            vmem_limit_bytes=int(2 * len(weights) * block_bytes + (24 << 20))),
+        interpret=interpret,
+        name="moe_experts_gated" if len(weights) == 2 else "moe_experts_down",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), offsets, expert, row_tile, visits,
+      x, *weights)
+
+
+def _use_pallas(impl: str, x: jnp.ndarray, w: jnp.ndarray) -> bool:
+    if impl == "auto":
+        return (jax.default_backend() == "tpu" and x.shape[-1] % 128 == 0
+                and w.shape[-1] % 128 == 0)
+    return impl == "pallas"
+
+
+def routed_experts(
+    x: jnp.ndarray, expert: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
+    w_down: jnp.ndarray, layer: Optional[jnp.ndarray] = None, impl: str = "auto",
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    if layer is None:
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+        layer = jnp.int32(0)
+    t, n = x.shape[0], w_gate.shape[1]
+    with jax.named_scope("moe.dispatch"):
+        counts = jnp.sum(expert[:, None] == jnp.arange(n)[None, :], axis=0).astype(jnp.int32)
+        order = jnp.argsort(expert, stable=True)
+        xs = x[order]
+    with jax.named_scope("moe.experts"):
+        if _use_pallas(impl, x, w_gate):
+            tile = _row_tile(t)
+            padded = -(-t // tile) * tile
+            xs = jnp.pad(xs, ((0, padded - t), (0, 0)))
+            schedule = visit_schedule(counts, padded // tile, tile)
+            interpret = jax.default_backend() != "tpu"
+            hidden = _grouped(xs, (w_gate, w_up), layer, schedule, tile, interpret)
+            ys = _grouped(hidden, (w_down,), layer, schedule, tile, interpret)[:t]
+        else:
+            wg, wu, wd = (jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+                          for w in (w_gate, w_up, w_down))
+            dot = functools.partial(jax.lax.ragged_dot, group_sizes=counts,
+                                    preferred_element_type=jnp.float32)
+            hidden = (jax.nn.silu(dot(xs, wg)) * dot(xs, wu)).astype(x.dtype)
+            ys = dot(hidden, wd).astype(x.dtype)
+    with jax.named_scope("moe.combine"):
+        # rows of no expert hold whatever the kernel's buffer held
+        back = jnp.zeros((t,), jnp.int32).at[order].set(jnp.arange(t, dtype=jnp.int32))
+        y = jnp.where((expert < n)[:, None], ys[back], jnp.zeros((), ys.dtype))
+    return y, counts

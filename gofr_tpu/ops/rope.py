@@ -23,8 +23,14 @@ def apply_rope(x: jnp.ndarray, freqs: jnp.ndarray, positions: jnp.ndarray) -> jn
 
     ``positions``: [seq] or [batch, seq] absolute positions (decode passes
     the cache offset). Split-half convention: (x1, x2) -> (x1*cos - x2*sin,
-    x2*cos + x1*sin).
+    x2*cos + x1*sin). A table narrower than the head (partial rotary:
+    ``freqs`` built for the first ``r`` dims) turns dims 0..r-1, split-half
+    within them, and passes the rest.
     """
+    rot = 2 * freqs.shape[-2]
+    if rot < x.shape[-1]:
+        turned = apply_rope(x[..., :rot], freqs, positions)
+        return jnp.concatenate([turned, x[..., rot:]], axis=-1)
     dtype = x.dtype
     cos_sin = freqs[positions]  # [..., seq, head_dim//2, 2]
     cos = cos_sin[..., 0][..., None, :]  # broadcast over heads: [..., seq, 1, hd/2]

@@ -1442,6 +1442,8 @@ class DecodePool:
         submissions can take the lock to join the next dispatch), then
         deliver its tokens. Returns the fetch-completion mark the next
         call uses as its throughput-denominator anchor."""
+        from gofr_tpu.models.transformer import unpack_expert_counts
+
         (records, toks_dev, lps_dev, tvals_dev, tids_dev,
          dispatch_start, drec) = in_flight.popleft()
         # the blocking host fetch is WHERE a wedged device manifests:
@@ -1459,7 +1461,12 @@ class DecodePool:
             with watch, phase(
                 POOL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"
             ):
-                toks = np.asarray(toks_dev)
+                # an expert model's routing counts ride behind the rows
+                toks, routing = unpack_expert_counts(
+                    np.asarray(toks_dev), self.n_slots,
+                    getattr(self.cfg, "n_experts", 0))
+                if drec is not None and routing is not None:
+                    drec.note_routing(routing)
                 lps = np.asarray(lps_dev)
                 tvals = (
                     np.asarray(tvals_dev) if tvals_dev is not None else None

@@ -3,12 +3,16 @@ batching, health, query logging, metrics.
 
 Config keys (SURVEY.md §2 #22 TPU-native additions):
 - ``MODEL_NAME``: mlp | bert-tiny | bert-base | tiny | small | llama3-8b |
-  llama3-70b (transformer names from gofr_tpu.models.llama.CONFIGS)
+  llama3-70b | zaya1-8b (transformer names from
+  gofr_tpu.models.llama.CONFIGS; the entry states the attention kind and
+  the feed-forward kind, no setting does)
 - ``MODEL_PATH``: optional checkpoint — an HF safetensors file/dir (routed
   through models/ingest.py) or an orbax dir (absent -> seeded init)
 - ``MODEL_QUANT``: "int8" (per-channel) or "int4" (group-wise scales) for
   weight-only quantized serving — decode streams the whole weight set per
   step, so packed weights raise its throughput ceiling 2x / ~4x over bf16
+  (refused for a model of routed experts: the quantiser does not take
+  expert-stacked leaves)
 - ``MODEL_KV_DTYPE``: "f8" stores the KV cache in float8_e4m3fn (2x
   context length or decode slots per HBM byte, small accuracy cost); a
   model whose cache is a retention state holds it in float32 when unset
@@ -1003,41 +1007,62 @@ class TPUDevice:
             raise RuntimeError("TPU boot failed") from self._boot_error
 
     def _refuse_what_a_state_cannot_do(self, config: Any) -> None:
-        """A model whose cache is a fixed-size state per row (attention
-        kind "retention") has no K/V rows to share by prefix, roll back by
-        length, hold in float8, send over the wire or shard by the head
-        axis of ``cache_specs``. Each such setting is refused here, by
-        name, at boot: none of them may give a wrong answer instead."""
+        """A model whose cache has a leaf that is not K/V rows cannot be
+        served by what aliases, rolls back, ships or shards K/V rows: a
+        fixed-size state per row (attention kind "retention") has no rows
+        at all, and a "cca" cache keeps a fixed tail per row beside its K
+        and V that none of those settings would carry along. Each such
+        setting is refused here, by name, at boot: none of them may give a
+        wrong answer instead. So is ``MODEL_QUANT`` for a model whose
+        experts are stacked leaves the quantiser does not take."""
         from gofr_tpu.models.llama import CONFIGS
 
         cfg = CONFIGS.get(self.model_name)
-        if getattr(cfg, "attn_kind", "softmax") != "retention":
+        kind = getattr(cfg, "attn_kind", "softmax")
+        if getattr(cfg, "ffn_kind", "dense") == "moe" and self.quant:
+            raise ValueError(
+                f"MODEL_QUANT is not supported for MODEL_NAME '{self.model_name}': "
+                "the quantiser does not take expert-stacked leaves"
+            )
+        if kind not in ("retention", "cca"):
             return
+        state = kind == "retention"
         stated = (config.get("KV_TRANSFER") or "").strip().lower()
-        blocks = "the paged arena holds K/V blocks"
-        rollback = "speculation rolls a cache back by length; a state has no length"
-        wire = "the wire format carries K/V blocks"
-        refused = {  # setting -> (is it on, why a state cannot serve it)
-            "PREFIX_CACHE": (self._prefix_cache_size > 0,
-                             "prefix sharing aliases K/V rows; a state would need snapshots"),
+        blocks = ("the paged arena holds K/V blocks",
+                  "the paged arena holds K/V blocks and would drop the tail")
+        rollback = ("speculation rolls a cache back by length; a state has no length",
+                    "speculation rolls a cache back by length; the tail of the token "
+                    "rolled back to is gone")
+        wire = ("the wire format carries K/V blocks",
+                "the wire format carries K/V blocks, not the tail")
+        moves = ("prefill/decode disaggregation moves K/V over the wire",) * 2
+        refused = {  # setting -> (is it on, (why a state, why a cache with a tail, cannot serve it))
+            "PREFIX_CACHE": (self._prefix_cache_size > 0, (
+                "prefix sharing aliases K/V rows; a state would need snapshots",
+                "prefix sharing aliases K/V rows; a shared prefix would need the tail "
+                "at its last token")),
             "KV_BLOCKS": (self._kv_paged and self._kv_blocks_cfg > 0, blocks),
             "KV_HBM_BUDGET_MB": (self._kv_paged and self._kv_budget_mb > 0, blocks),
             "DRAFT_MODEL_NAME": (bool(self._draft_name), rollback),
             "SPEC_POOLED": (self._spec_pooled, rollback),
-            "MODEL_KV_DTYPE": (self._kv_dtype == jnp.float8_e4m3fn,
-                               "f8 is a K/V type; a state takes float32 (unset) or bf16"),
+            # float8 is a K/V type: a "cca" cache's K and V take it (the
+            # tail stays in the model's type)
+            "MODEL_KV_DTYPE": (state and self._kv_dtype == jnp.float8_e4m3fn, (
+                "f8 is a K/V type; a state takes float32 (unset) or bf16", "")),
             "KV_TRANSFER": (stated not in ("", "off"), wire),
             "KV_TRANSFER_TRUST_HINT": (self.kv_hint_trusted, wire),
-            "FLEET_ROLE": (self.role != "mixed",
-                           "prefill/decode disaggregation moves K/V over the wire"),
-            "TPU_MESH": (_parse_mesh_request(self._mesh_request) is not None,
-                         "the state is not yet sharded over a mesh (by kv head under tp)"),
+            "FLEET_ROLE": (self.role != "mixed", moves),
+            "TPU_MESH": (_parse_mesh_request(self._mesh_request) is not None, (
+                "the state is not yet sharded over a mesh (by kv head under tp)",
+                "neither the tail nor the expert stacks are sharded over a mesh yet")),
         }
+        what = ("whose cache is a retention state" if state else
+                "whose cache keeps a tail per row beside its K/V rows")
         for name, (on, why) in refused.items():
             if on:
                 raise ValueError(
                     f"{name} is not supported for MODEL_NAME '{self.model_name}', "
-                    f"whose cache is a retention state: {why}"
+                    f"{what}: {why[0 if state else 1]}"
                 )
         # unset, KV transfer is armed by default; here there is nothing to send
         self.kv_transfer_enabled = False
@@ -3502,14 +3527,20 @@ class _TransformerRunner:
         # prefill also argmaxes on device: the hot /infer path fetches [B]
         # int32 next-token ids, never the [B, V] logits
         def _prefill_fn(p, t, c, l):
-            logits, new_cache = prefill(p, t, c, cfg, l)
+            if cfg.ffn_kind == "moe":
+                logits, new_cache, aux = prefill(p, t, c, cfg, l, with_aux=True)
+            else:
+                logits, new_cache = prefill(p, t, c, cfg, l)
             with jax.named_scope("sample"):
                 next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if cfg.ffn_kind == "moe":
+                # what routing did rides the ids: one fetch (_note_routing)
+                next_ids = pack_expert_counts(next_ids, aux["expert_counts"])
             return logits, next_ids, new_cache
 
         self._prefill = jax.jit(_prefill_fn)
         self._decode = jax.jit(lambda p, t, c: decode_step(p, t, c, cfg))
-        from gofr_tpu.models.transformer import decode_chunk
+        from gofr_tpu.models.transformer import decode_chunk, pack_expert_counts
 
         # ONE parameterized family of decode-chunk executables keyed by
         # (penalized, logprobs). Penalized chunks thread a [1, V] presence
@@ -3659,6 +3690,10 @@ class _TransformerRunner:
             tokens, lengths = pack_token_rows(payloads, bsz, bucket)
             full_lengths = np.maximum(lengths, 1)  # padded rows need length>=1
             cache = self._zero_cache(bsz)
+            if self.cfg.ffn_kind == "moe":
+                # a row the batch was padded with holds no request: its
+                # tokens go to no expert (models/transformer.py::_run_cached)
+                cache = {**cache, "live": jnp.asarray(lengths > 0, jnp.int32)}
             tokens_dev, lengths_dev = jnp.asarray(tokens), jnp.asarray(full_lengths)
             if self._token_sharding is not None:
                 tokens_dev = jax.device_put(tokens_dev, self._token_sharding)
@@ -3675,6 +3710,7 @@ class _TransformerRunner:
         # the D2H copy.
         with phase(PREFILL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"):
             next_ids = np.asarray(next_ids)
+        next_ids = _note_routing(drec, next_ids, bsz, self.cfg)
         return [
             _PrefillState(
                 cache, logits, i,
@@ -4126,6 +4162,7 @@ class _TransformerRunner:
         # chunked request can start from the same [1]-row allocation
         cache = self._zero_cache(1)
         logits = next_ids = issued_before = None
+        routed: list = []  # (record, ids) of the slices before the last
         total = 0
         prm = self.params if params is None else params
         record = telemetry_record()
@@ -4153,6 +4190,7 @@ class _TransformerRunner:
                     # /admin/dispatches.
                     if drec is not None:
                         self.timeline.finish(drec)
+                        routed.append((drec, next_ids))
                     drec = self.timeline.begin(
                         "prefill_chunk", bucket=bucket, batch_size=1,
                         tokens=size,
@@ -4189,7 +4227,12 @@ class _TransformerRunner:
             with watch, phase(
                 PREFILL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"
             ):
-                next_token = int(np.asarray(next_ids)[0])
+                next_ids = np.asarray(next_ids)
+            next_token = int(_note_routing(drec, next_ids, 1, self.cfg)[0])
+            if self.cfg.ffn_kind == "moe":
+                # the earlier slices are done: their ids are there to read
+                for earlier, ids in routed:
+                    _note_routing(earlier, np.asarray(ids), 1, self.cfg)
         except BaseException:
             # a raising slice dispatch (or fetch) must not leak the open
             # record as a phantom "running" dispatch
@@ -5340,6 +5383,18 @@ class _PrefillState(dict):
             return self[key]
         except KeyError:
             return default
+
+
+def _note_routing(drec: Any, ids: np.ndarray, rows: int, cfg: Any) -> np.ndarray:
+    """A fetched prefill's ids with an expert model's routing counts behind
+    them (``pack_expert_counts``) -> the ids alone; the counts go onto the
+    dispatch's record."""
+    from gofr_tpu.models.transformer import unpack_expert_counts
+
+    ids, counts = unpack_expert_counts(ids, rows, getattr(cfg, "n_experts", 0))
+    if drec is not None and counts is not None:
+        drec.note_routing(counts)
+    return ids
 
 
 def _slice_cache(cache: dict, i: int) -> dict:

@@ -101,7 +101,7 @@ class DispatchRecord:
         "tokens", "detail", "status", "wall_start", "t_queued", "t_running",
         "t_done", "t_issued", "t_fetch", "t_fetched", "cadence_s",
         "chunks_ahead", "state_bytes", "kv_blocks_read", "kv_blocks_held",
-        "carried",
+        "carried", "expert_tokens", "experts_read", "expert_tokens_max",
     )
 
     def __init__(
@@ -160,6 +160,20 @@ class DispatchRecord:
         # a prefill chunk: whether it began from what an earlier chunk of
         # the same prompt left in the cache
         self.carried: Optional[bool] = None
+        # an expert model's dispatch (decode_chunk, prefill, prefill_chunk),
+        # summed over its steps and layers: tokens routed to an expert,
+        # experts that got at least one (what a step had to read), and the
+        # fullest expert's tokens (the straggler); None for a dense model
+        self.expert_tokens: Optional[int] = None
+        self.experts_read: Optional[int] = None
+        self.expert_tokens_max: Optional[int] = None
+
+    def note_routing(self, counts: Any) -> None:
+        """``counts`` [..., layers, experts]: the tokens each expert of each
+        layer got in each step of this dispatch (a numpy array)."""
+        self.expert_tokens = int(counts.sum())
+        self.experts_read = int((counts > 0).sum())
+        self.expert_tokens_max = int(counts.max(axis=-1).sum())
 
     def mark_running(self) -> None:
         """Device execution begins (after any scheduler-interleave wait)."""
@@ -201,6 +215,9 @@ class DispatchRecord:
             "kv_blocks_read": self.kv_blocks_read,
             "kv_blocks_held": self.kv_blocks_held,
             "carried": self.carried,
+            "expert_tokens": self.expert_tokens,
+            "experts_read": self.experts_read,
+            "expert_tokens_max": self.expert_tokens_max,
         }
 
 

@@ -217,3 +217,63 @@ def test_prefill_of_a_retention_model_copies_its_state_once_at_entry(one_chip, a
     # state a slice carries on from), so the program copies it once
     writers = _state_writers(hlo, rows)
     assert set(writers) <= {"ENTRY"} and len(writers.get("ENTRY", [])) <= 1, writers
+
+
+# -- routed experts and a cache with a tail (attention kind "cca", feed-forward "moe") ---------
+# ZAYA1-8B's widths (benchmark/configs) with two layers and a small
+# vocabulary; 32 slots
+
+ZCFG = T.TransformerConfig(
+    vocab_size=4096, dim=2048, n_layers=2, n_heads=8, n_kv_heads=2, head_dim=128,
+    hidden_dim=2048, max_seq=2048, rope_theta=5e6, rope_fraction=0.5, attn_kind="cca",
+    ffn_kind="moe", n_experts=16, router_dim=256, tie_embeddings=True,
+)
+ZSLOTS = 32
+
+
+def _expert_movers(hlo: str) -> list[str]:
+    """Instructions whose result has the shape of the expert stacks, of one
+    layer's experts or of one expert: none may exist, the kernels read
+    [layer, expert] of the stacks where they lie."""
+    shape = f"{ZCFG.dim},{ZCFG.hidden_dim}"
+    shapes = {f"bf16[{ZCFG.n_layers},{ZCFG.n_experts},{shape}]",
+              f"bf16[{ZCFG.n_experts},{shape}]", f"bf16[1,{ZCFG.n_experts},{shape}]",
+              f"bf16[{shape}]"}
+    found = []
+    for line in hlo.splitlines():
+        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
+        if match and match.group(2) in shapes and match.group(3) in _MOVERS:
+            found.append(match.group(1))
+    return found
+
+
+def _zaya_params():
+    return T.init_transformer(jax.random.key(0), ZCFG)
+
+
+def test_pooled_chunk_of_an_expert_model_leaves_its_experts_where_they_lie(one_chip, as_on_tpu):
+    hlo = _compiled(
+        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
+            p, t, c, ZCFG, 8, key, temp, tk, tp, mp),
+        (2, 3), one_chip,
+        _zaya_params, jnp.zeros((ZSLOTS, 1), jnp.int32),
+        lambda: T.init_cache(ZCFG, ZSLOTS), lambda: jax.random.key(0),
+        jnp.zeros((ZSLOTS,), jnp.float32), jnp.zeros((ZSLOTS,), jnp.int32),
+        jnp.zeros((ZSLOTS,), jnp.float32), jnp.zeros((ZSLOTS,), jnp.float32),
+    )
+    # the decode form of flash attention and the two expert products
+    assert hlo.count("tpu_custom_call") >= 3
+    assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
+    assert _expert_movers(hlo) == []
+
+
+@pytest.mark.parametrize("rows,bucket", [(2, 256), (1, 256), (2, 128)])
+def test_prefill_of_an_expert_model_compiles_at_the_cells_buckets(one_chip, as_on_tpu, rows, bucket):
+    """512, 256 and 256 tokens: four, two and two row tiles of 128."""
+    hlo = _compiled(
+        lambda p, t, c, l: T.prefill(p, t, c, ZCFG, l, with_aux=True), (), one_chip,
+        _zaya_params, jnp.zeros((rows, bucket), jnp.int32),
+        lambda: T.init_cache(ZCFG, rows), jnp.zeros((rows,), jnp.int32),
+    )
+    assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
+    assert _expert_movers(hlo) == []

@@ -88,10 +88,13 @@ def program_runs(data: Any) -> dict[str, list[tuple[float, float]]]:
 
 
 def programs_of(runs: dict[str, list]) -> dict[str, list[str]]:
-    """Which programs a dispatch kind runs. The pooled chunk is the
-    ``jit__lambda`` program with most device time (the rule of
-    ``benchmark/readers.decode_step_roofline``), the solo chunk the one
-    with the next most (the pool's token write is a lambda too, a tiny one)."""
+    """Which programs a dispatch kind runs. The pooled chunk is taken as
+    the ``jit__lambda`` program with most device time, the solo chunk as the
+    one with the next most (the pool's token write is a lambda too, a tiny
+    one). That is this tool's own rule, by hand and below the knee; the
+    benchmark's readers left it in PR 32 (``benchmark/readers.pooled_program``
+    takes the program whose runs end the pool's own waits), because past the
+    knee the solo fallback's lambda has more of the trace than the pool's."""
     lambdas = sorted((n for n in runs if n.startswith(LAMBDA_PROGRAM)),
                      key=lambda n: -sum(e - s for s, e in runs[n]))
     return {
