@@ -301,17 +301,19 @@ def _write_kv(
     starts: jnp.ndarray,
 ) -> jnp.ndarray:
     """Write ``new`` [B, s, kv_heads, head_dim] into the stacked cache
-    [L, B, max_seq, kv_heads, head_dim] at [layer, row, starts[row]:+s].
+    [L, B, kv_heads, max_seq, head_dim] at [layer, row, :, starts[row]:+s].
 
     One ``dynamic_update_slice`` per row, each of this call's s tokens
     alone: the stack is updated in place and only B·s·kv_heads·head_dim
-    elements move. (One batched-index update would be a scatter, which a
-    backend may widen to the whole operand.) Starts clamp as every
-    dynamic_update_slice does, so a full row overwrites its own tail."""
-    new = new.astype(stack.dtype)
+    elements move; what is transposed into the stored order is the call's
+    own tokens, never the stack. (One batched-index update would be a
+    scatter, which a backend may widen to the whole operand.) Starts clamp
+    as every dynamic_update_slice does, so a full row overwrites its own
+    tail."""
+    new = jnp.swapaxes(new, 1, 2).astype(stack.dtype)  # [B, kv_heads, s, head_dim]
     for row in range(new.shape[0]):
         stack = jax.lax.dynamic_update_slice(
-            stack, new[row][None, None], (layer, row, starts[row], 0, 0)
+            stack, new[row][None, None], (layer, row, 0, starts[row], 0)
         )
     return stack
 
@@ -410,9 +412,9 @@ def _block(
 
     Without cache: attention over this call's keys (via ``attn_fn`` when
     given), returns (out, (k, v), aux). With cache: ``kv_cache`` is the
-    WHOLE stacked cache (k, v), each [L, B, max_seq, kv_heads, head_dim],
+    WHOLE stacked cache (k, v), each [L, B, kv_heads, max_seq, head_dim],
     and ``layer`` this block's index into it. This call's k/v are written
-    at [layer, row, starts[row] : starts[row] + s] and nothing else of
+    at [layer, row, :, starts[row] : starts[row] + s] and nothing else of
     the stacks is touched; attention reads its layer out of the stack over
     the full cache window. Returns (out, (k_stack, v_stack), aux): the
     buffers that came in, so the caller's loops carry them in place.
@@ -586,13 +588,16 @@ def transformer_forward(
 # -- KV-cached ragged-batch serving path -------------------------------------
 
 def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -> dict:
-    """Cache layout [n_layers, B, max_seq, n_kv_heads, head_dim] with
-    per-request ``lengths`` [B]. ``max_seq`` must not exceed cfg.max_seq
-    (the RoPE table bounds valid positions).
+    """Cache layout [n_layers, B, n_kv_heads, max_seq, head_dim] with
+    per-request ``lengths`` [B]: the order the attention kernels read
+    (ops/flash.py), stored so everywhere K and V are held (the pool, the
+    prefill caches, the block arena, the wire), so no program relays the
+    cache where it takes it or gives it back. ``max_seq`` must not exceed
+    cfg.max_seq (the RoPE table bounds valid positions).
 
     The two stacks are ONE buffer each for as long as a program runs:
     every cached forward carries them through its layer loop (and a chunk
-    through its step loop), writes a token's k/v at [layer, row, position]
+    through its step loop), writes a token's k/v at [layer, row, :, position]
     and reads a layer at a time out of the stack (``_run_cached``). A
     caller that donates the cache gets the same buffer back."""
     max_seq = max_seq or cfg.max_seq
@@ -609,7 +614,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
                           layers=cfg.n_layers)
         stacks = {"s": s, "z": z}
     else:
-        shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq, cfg.head_dim)
         stacks = {"k": jnp.zeros(shape, cfg.cache_dtype),
                   "v": jnp.zeros(shape, cfg.cache_dtype)}
         if cfg.attn_kind == "cca":

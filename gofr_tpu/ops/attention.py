@@ -7,9 +7,10 @@
 - ``impl="auto"``: pallas on TPU when shapes are tile-friendly, else XLA.
 
 Layouts: q [B, Sq, Hq, D]; k, v [B, Skv, Hkv, D]; Hq % Hkv == 0. With
-``layer`` (an int32 scalar) k and v are the whole stacked KV cache
-[L, B, Skv, Hkv, D] and attention reads that layer of it: the Pallas
-kernel indexes the stack itself, the XLA path slices it.
+``layer`` (an int32 scalar) k and v are the whole stacked KV cache in the
+order it is stored, [L, B, Hkv, Skv, D], and attention reads that layer of
+it: the Pallas kernel indexes the stack itself, the XLA path slices it and
+contracts over the stored order. Nothing transposes the cache.
 ``q_offset`` positions the query block absolutely (decode: cache length).
 ``kv_lens`` [B] bounds the valid key prefix (padded/unwritten cache tail)
 — the structured form of a padding mask, supported by both paths.
@@ -43,16 +44,19 @@ def attention(
     mesh: Optional[Any] = None,
     layer: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
+    stacked = layer is not None
+    skv = k.shape[3] if stacked else k.shape[1]
     if impl == "auto":
         # arbitrary masks stay on the XLA path (kv_lens is fine: the flash
         # kernel bounds its KV loop with it)
-        impl = "pallas" if (mask is None and _pallas_ok(q, k)) else "xla"
-    if layer is not None and (impl != "pallas" or k.dtype != q.dtype):
+        impl = "pallas" if (mask is None and _pallas_ok(q, skv)) else "xla"
+    if stacked and (impl != "pallas" or k.dtype != q.dtype):
         # the XLA path, and a low-precision cache on either path, read
-        # their layer by a slice the compiler is free to fuse
-        k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
-        v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
-        layer = None
+        # their layer by a slice the compiler is free to fuse: a stack of
+        # one, still in the stored order
+        k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=True)
+        v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=True)
+        layer = jnp.zeros((), jnp.int32)
     if k.dtype != q.dtype:
         # low-precision KV cache (float8_e4m3fn via cfg.kv_dtype): upcast
         # at the attention boundary, one layer at a time — capacity is the
@@ -75,15 +79,17 @@ def attention(
             q, k, v, causal=causal, q_offset=q_offset, kv_lens=kv_lens,
             scale=scale, layer=layer,
         )
+    if stacked:
+        k, v = k[0], v[0]  # [B, Hkv, Skv, D]
     if kv_lens is not None:
-        len_mask = jnp.arange(k.shape[1])[None, :] < kv_lens[:, None]  # [B, Skv]
+        len_mask = jnp.arange(skv)[None, :] < kv_lens[:, None]  # [B, Skv]
         if mask is None:
             mask = len_mask
         elif mask.ndim == 2:
             mask = jnp.logical_and(mask, len_mask)
         else:
             mask = jnp.logical_and(mask, len_mask[:, None, :])
-    return _xla_attention(q, k, v, causal, q_offset, mask, scale)
+    return _xla_attention(q, k, v, causal, q_offset, mask, scale, stacked)
 
 
 def _sharded_flash(
@@ -102,16 +108,20 @@ def _sharded_flash(
     partitioned"), so the kernel runs once per shard: batch rows over
     (dp, fsdp), heads over tp. Query and KV heads split alike, so every
     shard keeps whole GQA groups, and attention mixes neither axis — no
-    collective is needed. Stacked k/v (``layer`` given) carry the layer
-    axis in front, never sharded; the index itself is replicated."""
+    collective is needed. Stacked k/v (``layer`` given) are the cache as
+    ``parallel/sharding.py::cache_specs`` places it: the layer axis in
+    front, never sharded, heads before positions; the index itself is
+    replicated."""
     from jax.sharding import PartitionSpec as P
 
     from gofr_tpu.ops.flash import _normalize_scalars, flash_attention
 
-    offsets, lens = _normalize_scalars(q, k, q_offset, kv_lens)
+    skv = k.shape[1] if layer is None else k.shape[3]
+    offsets, lens = _normalize_scalars(q, skv, q_offset, kv_lens)
     rows = P(("dp", "fsdp"))
     heads = P(("dp", "fsdp"), None, "tp", None)
-    kv_heads, index = (heads, None) if layer is None else (P(None, *heads), P())
+    kv_heads, index = (heads, None) if layer is None else (
+        P(None, ("dp", "fsdp"), "tp", None, None), P())
 
     def per_shard(q_, k_, v_, offsets_, lens_, layer_):
         return flash_attention(
@@ -126,13 +136,12 @@ def _sharded_flash(
     )(q, k, v, offsets, lens, layer)
 
 
-def _pallas_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
-    """``k`` [B, Skv, Hkv, D], or the stacked cache with a layer axis in
-    front: only Skv is read."""
+def _pallas_ok(q: jnp.ndarray, skv: int) -> bool:
+    """Whether ``q`` [B, Sq, Hq, D] against ``skv`` key positions takes
+    the Pallas kernel under ``impl="auto"``."""
     if jax.default_backend() not in ("tpu",):
         return False
     b, sq, hq, d = q.shape
-    skv = k.shape[-3]
     if sq == 1 and skv < 2048:
         # short-cache decode: per-layer kernel launch overhead outweighs
         # the bounded-KV-loop win (measured on llama3-8b int8, 512-slot
@@ -151,9 +160,15 @@ def _xla_attention(
     q_offset: int | jnp.ndarray,
     mask: Optional[jnp.ndarray],
     scale: Optional[float],
+    heads_first: bool = False,
 ) -> jnp.ndarray:
+    """``k``, ``v`` [B, Skv, Hkv, D], or with ``heads_first`` a layer of
+    the cache as it is stored, [B, Hkv, Skv, D]."""
     b, sq, hq, d = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    if heads_first:
+        kv, (hkv, skv) = "bhkd", k.shape[1:3]
+    else:
+        kv, (skv, hkv) = "bkhd", k.shape[1:3]
     groups = hq // hkv
     if scale is None:
         scale = d ** -0.5
@@ -161,7 +176,7 @@ def _xla_attention(
     qg = q.reshape(b, sq, hkv, groups, d)
     # [b, hkv, groups, sq, skv]; accumulate in f32 for softmax stability
     logits = jnp.einsum(
-        "bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32
+        f"bqhgd,{kv}->bhgqk", qg, k, preferred_element_type=jnp.float32
     ) * scale
 
     if causal:
@@ -190,5 +205,5 @@ def _xla_attention(
         # the uniform-softmax mean(v) that finite -inf masking would give
         all_masked = jnp.all(logits <= _NEG_INF / 2, axis=-1, keepdims=True)
         probs = jnp.where(all_masked, 0.0, probs.astype(jnp.float32)).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    out = jnp.einsum(f"bhgqk,{kv}->bqhgd", probs, v)
     return out.reshape(b, sq, hq, d)

@@ -29,9 +29,9 @@ read from its shapes alone:
   the written KV length are never visited — and share one online-softmax
   step (``_softmax_step``: running (m, l, acc) in f32; probabilities cast
   back to the value dtype so the p·V matmul hits the MXU in bf16 with f32
-  accumulation). Both read K/V out of a stack [L, B, Skv, Hkv, D] at a
+  accumulation). Both read K/V out of a stack [L, B, Hkv, Skv, D] at a
   layer index (a third scalar-prefetch value): the serving path hands
-  them the whole KV cache.
+  them the whole KV cache, which is stored in that order.
 - per-batch scalars (``q_offset`` for ragged decode positions, ``kv_lens``
   bounding the valid cache prefix) ride scalar prefetch
   (``PrefetchScalarGridSpec``) — available before the body for the
@@ -47,7 +47,8 @@ read from its shapes alone:
   ``FUSED_BWD = False`` escape hatch.
 
 Layouts match gofr_tpu.ops.attention: q [B, Sq, Hq, D]; k, v [B, Skv,
-Hkv, D]; Hq % Hkv == 0. On non-TPU backends the kernel runs in pallas
+Hkv, D], or with ``layer`` the stacked cache [L, B, Hkv, Skv, D];
+Hq % Hkv == 0. On non-TPU backends the kernel runs in pallas
 interpret mode (tests exercise the real kernel logic on the CPU mesh, the
 way the reference tests run against in-process fakes, SURVEY.md §4).
 """
@@ -287,9 +288,13 @@ def _flash_fwd_impl(
     block_kv: int,
     interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``k``, ``v`` [L, B, Skv, Hkv, D] are the stacked KV cache, read at
-    ``layer`` [1] int32 by the kernel itself (an unstacked k/v comes in as
-    a stack of one).
+    """``k``, ``v`` [L, B, Hkv, Skv, D] are the stacked KV cache, read at
+    ``layer`` [1] int32 by the kernel itself, in the order it is stored
+    (``models/transformer.py::init_cache``): no slice of the layer, no
+    transpose and no copy of K or V stands in front of the kernel, inside
+    a program's loops or where the cache enters and leaves it. (An
+    unstacked k/v comes in as a stack of one, its own tokens transposed:
+    ``_flash_fwd``.)
 
     A call whose ``sq`` x ``groups`` query rows fit one q block
     (``block_q``) takes the decode form (``_decode_form``: grid (batch,
@@ -300,27 +305,19 @@ def _flash_fwd_impl(
     layer of K and V once; the decode form's the same, as the bound of
     what its rows' lengths let it skip.
 
-    Either kernel takes the stack as its transpose [L, B, Hkv, Skv, D].
-    Written as a transpose of the whole stack, it costs none inside a
-    program's loops: the compiler gives the loop-carried cache that
-    physical layout ({4,2,3,1,0}) and the transpose becomes a bitcast, so
-    no slice of the layer and no copy of K or V stands in front of the
-    kernel. The program pays one relayout of the cache where it enters and
-    one where it leaves (a chunk, not a step or a layer). The row-major
-    "free" view [L, B, Skv, Hkv·D] is not free under (8, 128) tiling:
-    compiled for the v5e it is a reshape of the whole stack in every layer."""
+    The row-major "free" view [L, B, Skv, Hkv·D] is not free under
+    (8, 128) tiling: compiled for the v5e it is a reshape of the whole
+    stack in every layer."""
     b, sq, hq, d = q.shape
-    n_layers, _, skv, hkv, _ = k.shape
+    n_layers, _, hkv, skv, _ = k.shape
     groups = hq // hkv
 
-    kt = jnp.swapaxes(k, 2, 3)  # [L, B, Hkv, Skv, D]
-    vt = jnp.swapaxes(v, 2, 3)
     block_kv = min(block_kv, skv)
     skv_pad = pl.cdiv(skv, block_kv) * block_kv
     # a no-op for a cache (its length is a multiple of the block): padding
     # a stack would copy it
-    kt = _pad_axis(kt, 3, skv_pad)
-    vt = _pad_axis(vt, 3, skv_pad)
+    kt = _pad_axis(k, 3, skv_pad)
+    vt = _pad_axis(v, 3, skv_pad)
     num_kv_blocks = skv_pad // block_kv
     cost = dict(
         flops=4 * b * hq * sq * skv * d,
@@ -760,11 +757,13 @@ def _flash_bwd_impl(
 
 def _normalize_scalars(
     q: jnp.ndarray,
-    k: jnp.ndarray,
+    skv: int,
     q_offset: int | jnp.ndarray,
     kv_lens: Optional[jnp.ndarray],
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    b, skv = q.shape[0], k.shape[-3]  # k may carry a layer axis in front
+    """Per-row ``q_offset`` and ``kv_lens`` [B] int32, the lengths capped
+    at the ``skv`` positions k and v hold."""
+    b = q.shape[0]
     offsets = jnp.asarray(q_offset, jnp.int32)
     if offsets.ndim == 0:
         offsets = jnp.full((b,), offsets, jnp.int32)
@@ -845,7 +844,8 @@ def _flash(q, k, v, offsets, kv_lens, causal, scale, block_q, block_kv, interpre
 
 def _flash_fwd(q, k, v, offsets, kv_lens, causal, scale, block_q, block_kv, interpret):
     out, lse = _flash_fwd_impl(
-        q, k[None], v[None], offsets, kv_lens, _LAYER_0,
+        q, jnp.swapaxes(k, 1, 2)[None], jnp.swapaxes(v, 1, 2)[None],
+        offsets, kv_lens, _LAYER_0,
         causal, scale, block_q, block_kv, interpret,
     )
     return out, (q, k, v, offsets, kv_lens, out, lse)
@@ -901,15 +901,16 @@ def flash_attention(
     backward kernels (gradients flow to q, k, v; not to the position
     scalars).
 
-    ``layer`` (int32 scalar): k, v are the stacked KV cache [L, B, Skv,
-    Hkv, D] and the kernel reads that layer of it (the serving path: no
+    ``layer`` (int32 scalar): k, v are the stacked KV cache [L, B, Hkv,
+    Skv, D] and the kernel reads that layer of it (the serving path: no
     gradient is defined through the stack).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    offsets, lens = _normalize_scalars(q, k, q_offset, kv_lens)
+    skv = k.shape[1] if layer is None else k.shape[3]
+    offsets, lens = _normalize_scalars(q, skv, q_offset, kv_lens)
     if layer is None:
         return _flash(
             q, k, v, offsets, lens, causal, float(scale), block_q, block_kv,

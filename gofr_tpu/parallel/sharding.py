@@ -111,23 +111,23 @@ def batch_spec(sp: bool = False) -> P:
 
 
 def cache_specs(cache: Any) -> Any:
-    """KV cache [L, B, S, Hkv, hd]: batch over dp(+fsdp), kv heads over tp;
+    """KV cache [L, B, Hkv, S, hd]: batch over dp(+fsdp), kv heads over tp;
     the per-row vectors ``lengths`` and ``live`` [B] go with the batch."""
     return {
-        "k": P(None, ("dp", "fsdp"), None, "tp", None),
-        "v": P(None, ("dp", "fsdp"), None, "tp", None),
+        "k": P(None, ("dp", "fsdp"), "tp", None, None),
+        "v": P(None, ("dp", "fsdp"), "tp", None, None),
         "lengths": P(("dp", "fsdp")),
         "live": P(("dp", "fsdp")),
     }
 
 
 def kv_arena_spec() -> P:
-    """Paged-KV block arena [L, n_blocks, bt, Hkv, hd]: kv heads over tp
+    """Paged-KV block arena [L, n_blocks, Hkv, bt, hd]: kv heads over tp
     (the same head split :func:`cache_specs` gives the compute caches,
     so scatter/gather between blocks and rows moves no bytes across the
     tp axis); block and token axes stay unsharded — block ids are
     mesh-agnostic bookkeeping."""
-    return P(None, None, None, "tp", None)
+    return P(None, None, "tp", None, None)
 
 
 def shard_params(params: Any, mesh: Mesh, specs: Optional[Any] = None) -> Any:

@@ -5409,7 +5409,7 @@ def _slice_cache(cache: dict, i: int) -> dict:
 def _cache_max_len(cache: dict, cfg: Any) -> int:
     """Positions a row can hold: the K/V cache's length axis, or for a
     state, which has none, the model's ``max_seq`` (the rotary table)."""
-    return int(cache["k"].shape[2]) if "k" in cache else int(cfg.max_seq)
+    return int(cache["k"].shape[3]) if "k" in cache else int(cfg.max_seq)
 
 
 def _load_or_init(model_path: Optional[str], init_fn: Any) -> Any:

@@ -33,7 +33,7 @@ Two arenas implement the storage side:
   compile-free in tier-1 (and :class:`HostPagedKV` is the engine the
   echo runner drives it through);
 - :class:`JaxKVArena` — device-side block storage
-  ``[layers, n_blocks, block_tokens, kv_heads, head_dim]`` with jitted
+  ``[layers, n_blocks, kv_heads, block_tokens, head_dim]`` with jitted
   scatter/gather between block tables and the contiguous rows the
   compiled prefill/decode executables consume. Compute still runs on
   gathered contiguous rows (bit-identity with the slot model is a hard
@@ -1015,14 +1015,17 @@ class HostPagedKV:
 class JaxKVArena:
     """Device-side block storage + the jitted block<->row bridge.
 
-    Layout ``[n_layers, n_blocks, block_tokens, n_kv_heads, head_dim]``
-    for k and v. Block id 0 is the SCRATCH block (pair with
-    ``BlockPool(scratch=True)``): the fixed-shape scatter/scan and
-    gather/take ops pad every table to ``blocks_per_seq`` entries, and
-    the padding must land somewhere harmless.
+    Layout ``[n_layers, n_blocks, n_kv_heads, block_tokens, head_dim]``
+    for k and v: a block is ``block_tokens`` positions of a row in the
+    order the compute caches hold them (``models/transformer.py::
+    init_cache``), so a block leaves a row as a slice and a row is its
+    blocks side by side along the position axis. Block id 0 is the
+    SCRATCH block (pair with ``BlockPool(scratch=True)``): the fixed-shape
+    scatter/scan and gather/take ops pad every table to ``blocks_per_seq``
+    entries, and the padding must land somewhere harmless.
 
     - ``scatter_row(row, table, skip_blocks)``: write a contiguous
-      ``[L, 1, max_seq, H, D]`` row's first ``table.length`` tokens into
+      ``[L, 1, H, max_seq, D]`` row's first ``table.length`` tokens into
       the table's blocks, skipping the first ``skip_blocks`` (aliased
       blocks keep their donor's content — writing "equal" KV from a
       different executable's row would break bit-lineage);
@@ -1060,7 +1063,7 @@ class JaxKVArena:
         self.blocks_per_seq = max_seq // block_tokens
         self.mesh = mesh
         shape = (
-            cfg.n_layers, n_blocks, block_tokens, cfg.n_kv_heads,
+            cfg.n_layers, n_blocks, cfg.n_kv_heads, block_tokens,
             cfg.head_dim,
         )
         arena_sharding = row_shardings = None
@@ -1107,16 +1110,16 @@ class JaxKVArena:
         n_layers = cfg.n_layers
 
         def scatter(ak, av, rk, rv, ids):
-            # one scan over the table: block j <- row[j*bt:(j+1)*bt]
+            # one scan over the table: block j <- row[:, j*bt:(j+1)*bt]
             # (padded/skipped entries carry id 0 = scratch)
             def body(carry, x):
                 ak, av = carry
                 bid, start = x
                 blk_k = jax.lax.dynamic_slice_in_dim(
-                    rk[:, 0], start, bt, axis=1
+                    rk[:, 0], start, bt, axis=2
                 )
                 blk_v = jax.lax.dynamic_slice_in_dim(
-                    rv[:, 0], start, bt, axis=1
+                    rv[:, 0], start, bt, axis=2
                 )
                 ak = jax.lax.dynamic_update_slice(
                     ak, blk_k[:, None], (0, bid, 0, 0, 0)
@@ -1131,12 +1134,14 @@ class JaxKVArena:
             return ak, av
 
         def gather(ak, av, ids, length):
-            gk = jnp.take(ak, ids, axis=1).reshape(
-                n_layers, nps * bt, -1, cfg.head_dim
-            )[:, None]
-            gv = jnp.take(av, ids, axis=1).reshape(
-                n_layers, nps * bt, -1, cfg.head_dim
-            )[:, None]
+            def row_of(arena):
+                # [L, nps, H, bt, D] -> [L, 1, H, nps * bt, D]: the blocks
+                # side by side along a row's positions
+                blocks = jnp.moveaxis(jnp.take(arena, ids, axis=1), 1, 2)
+                return blocks.reshape(
+                    n_layers, -1, nps * bt, cfg.head_dim)[:, None]
+
+            gk, gv = row_of(ak), row_of(av)
             # a row as ``init_cache`` makes one (its one row live), so the
             # programs that take it were compiled for its leaves
             return {
@@ -1165,7 +1170,7 @@ class JaxKVArena:
         )
         # warm both NOW: serving-path calls must reuse, never compile
         zero_row_k = jnp.zeros(
-            (n_layers, 1, max_seq, cfg.n_kv_heads, cfg.head_dim),
+            (n_layers, 1, cfg.n_kv_heads, max_seq, cfg.head_dim),
             cfg.cache_dtype,
         )
         if row_shardings is not None:
@@ -1208,7 +1213,7 @@ class JaxKVArena:
     # -- cross-replica transfer codec (fleet/kvwire.py) ----------------------
     @property
     def _block_shape(self) -> tuple:
-        # one block's k (or v) slice: [layers, block_tokens, heads, dim]
+        # one block's k (or v) slice: [layers, heads, block_tokens, dim]
         s = self.k.shape
         return (s[0], s[2], s[3], s[4])
 

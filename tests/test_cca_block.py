@@ -188,7 +188,7 @@ def test_the_cache_names_its_leaves_and_the_tail_has_the_row_axis_second():
     cache = T.init_cache(cfg, 3)
     assert T.cache_leaves(cache) == ("k", "tail", "v")
     assert cfg.tail_dim == 2 * (4 + 2) * 16 + 16
-    assert cache["tail"].shape == (3, 3, cfg.tail_dim) and cache["k"].shape == (3, 3, 128, 2, 16)
+    assert cache["tail"].shape == (3, 3, cfg.tail_dim) and cache["k"].shape == (3, 3, 2, 128, 16)
     big = CONFIGS["zaya1-8b"]
     assert (big.q_dim, big.tail_dim, big.rope_dim) == (1024, 2688, 64)
     f8 = dataclasses.replace(cfg, kv_dtype=jnp.float8_e4m3fn)

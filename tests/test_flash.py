@@ -336,13 +336,14 @@ def test_fused_backward_zero_kv_lens_row():
 @pytest.mark.parametrize("heads", [(32, 8), (16, 8)])
 @pytest.mark.parametrize("layer", [0, 1, 2])
 def test_stacked_read_matches_xla_on_the_sliced_layer(layer, heads, sq):
-    """The kernel reads layer ``layer`` out of the stacked cache
-    [L, B, Skv, Hkv, D] by its own BlockSpecs (ragged kv_lens, GQA)."""
+    """The kernel reads layer ``layer`` out of the stacked cache, in the
+    order it is stored, [L, B, Hkv, Skv, D], by its own BlockSpecs (ragged
+    kv_lens, GQA)."""
     hq, hkv = heads
     n_layers, b, skv, d = 3, 2, 64, 32
     q = _rand(60, (b, sq, hq, d))
-    k = _rand(61, (n_layers, b, skv, hkv, d))
-    v = _rand(62, (n_layers, b, skv, hkv, d))
+    k = _rand(61, (n_layers, b, hkv, skv, d))
+    v = _rand(62, (n_layers, b, hkv, skv, d))
     offsets = jnp.array([9, 40], jnp.int32)
     kv_lens = offsets + sq
     got = flash_attention(
@@ -350,7 +351,9 @@ def test_stacked_read_matches_xla_on_the_sliced_layer(layer, heads, sq):
         block_q=16, block_kv=16, layer=jnp.int32(layer),
     )
     len_mask = jnp.arange(skv)[None, :] < kv_lens[:, None]
-    want = _xla_attention(q, k[layer], v[layer], True, offsets, len_mask, None)
+    want = _xla_attention(
+        q, jnp.swapaxes(k[layer], 1, 2), jnp.swapaxes(v[layer], 1, 2),
+        True, offsets, len_mask, None)
     _assert_close(got, want)
     # and through attention(), which is what the cached forward calls
     via = attention(

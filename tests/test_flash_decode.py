@@ -1,7 +1,9 @@
 """The decode form of the flash forward (ops/flash.py), in interpret mode:
 a call whose ``sq`` x ``groups`` query rows fit one q block runs on a grid
 of (row, kv head), copies K and V in by blocks up to each row's length and
-skips a row of length 0. Against ``_xla_attention`` on the sliced layer.
+skips a row of length 0. K and V are a stacked cache in the order it is
+stored, [L, B, Hkv, Skv, D]; the reference is ``_xla_attention`` on the
+sliced layer, transposed here into the order fresh projections have.
 
 Not slow-marked (tests/test_flash.py is): small head width, three layers."""
 
@@ -32,7 +34,7 @@ def stacks():
     made = {}
     for batch in LENGTHS:
         kk, kv = jax.random.split(jax.random.key(batch))
-        shape = (LAYERS, batch, SKV, HKV, D)
+        shape = (LAYERS, batch, HKV, SKV, D)
         made[batch] = (jax.random.normal(kk, shape), jax.random.normal(kv, shape))
     return made
 
@@ -43,7 +45,9 @@ def _query(batch, sq, groups):
 
 def _reference(q, k, v, layer, offsets, lens):
     mask = jnp.arange(SKV)[None, :] < lens[:, None]
-    return _xla_attention(q, k[layer], v[layer], True, offsets, mask, None)
+    return _xla_attention(
+        q, jnp.swapaxes(k[layer], 1, 2), jnp.swapaxes(v[layer], 1, 2),
+        True, offsets, mask, None)
 
 
 def _spans(lens, sq):
@@ -114,7 +118,7 @@ def test_a_row_that_is_not_live_returns_zeros_and_is_never_read(stacks, sq):
     block = flash.DEFAULT_BLOCK_KV
     first_dead = -(-np.asarray(lens) // block) * block  # [B]
     dead = np.arange(SKV)[None, :] >= first_dead[:, None]  # [B, Skv]
-    poison = jnp.asarray(dead)[None, :, :, None, None]
+    poison = jnp.asarray(dead)[None, :, None, :, None]
     out = flash.flash_attention(
         q, jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v),
         causal=True, q_offset=offsets, kv_lens=lens, layer=jnp.int32(layer))
@@ -122,6 +126,24 @@ def test_a_row_that_is_not_live_returns_zeros_and_is_never_read(stacks, sq):
     assert np.isfinite(out).all()
     assert not out[~live].any()
     np.testing.assert_allclose(out[live], np.asarray(want)[live], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("cache_type", [jnp.float32, jnp.float8_e4m3fn], ids=["same", "f8"])
+@pytest.mark.parametrize("sq", [1, 200], ids=["decode_form", "prefill_form"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_both_paths_read_their_layer_of_the_stack_as_it_is_stored(stacks, impl, sq, cache_type):
+    """``attention(layer=...)`` on the whole cache: the XLA path slices its
+    layer and contracts over the stored order, the kernel indexes the stack;
+    a cache of another type than q (``MODEL_KV_DTYPE=f8``) is sliced and
+    upcast a layer at a time on either path."""
+    batch, groups, layer = 6, 4, 2
+    k, v = (x.astype(cache_type) for x in stacks[batch])
+    q = _query(batch, sq, groups)
+    offsets, lens = _spans(LENGTHS[batch], sq)
+    out = attention(q, k, v, causal=True, q_offset=offsets, kv_lens=lens,
+                    impl=impl, layer=jnp.int32(layer))
+    want = _reference(q, k.astype(q.dtype), v.astype(q.dtype), layer, offsets, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
 def test_cache_shorter_than_the_copies_in_flight():

@@ -442,58 +442,106 @@ def test_freed_blocks_admit_waiting_request_mid_flight():
 
 # -- device arena: block <-> row bridge (small jit, CPU-fast) -----------------
 
-def test_jax_arena_scatter_gather_roundtrip_and_skip():
+def _arena_row(cfg, seed, length):
+    """A compute row as ``init_cache`` lays one out, [L, 1, Hkv, S, D]."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    shape = (cfg.n_layers, 1, cfg.n_kv_heads, cfg.max_seq, cfg.head_dim)
+    r = rng.standard_normal(shape, dtype=np.float32)
+    return {
+        "k": jnp.asarray(r, cfg.cache_dtype),
+        "v": jnp.asarray(-r, cfg.cache_dtype),
+        "lengths": jnp.asarray([length], jnp.int32),
+    }
+
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape
+    assert a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "f8"])
+def test_jax_arena_scatter_gather_roundtrip_and_skip(kv_dtype):
+    import dataclasses
+
     import jax.numpy as jnp
 
     from gofr_tpu.models.llama import CONFIGS
+    from gofr_tpu.models.transformer import init_cache
     from gofr_tpu.tpu.kv_blocks import JaxKVArena
 
     cfg = CONFIGS["tiny"]  # max_seq 128
+    if kv_dtype:
+        cfg = dataclasses.replace(cfg, kv_dtype=jnp.float8_e4m3fn)
     bt = 32
     arena = JaxKVArena(cfg, n_blocks=9, block_tokens=bt)
     pool = BlockPool(9, bt, block_bytes=arena.block_bytes, scratch=True)
-
-    def row_of(seed, length):
-        import numpy as _np
-
-        rng = _np.random.default_rng(seed)
-        shape = (cfg.n_layers, 1, cfg.max_seq, cfg.n_kv_heads, cfg.head_dim)
-        r = rng.standard_normal(shape, dtype=_np.float32)
-        return {
-            "k": jnp.asarray(r, cfg.cache_dtype),
-            "v": jnp.asarray(-r, cfg.cache_dtype),
-            "lengths": jnp.asarray([length], jnp.int32),
-        }
+    # blocks in the order the compute caches have: heads before positions
+    assert arena.k.shape == (cfg.n_layers, 9, cfg.n_kv_heads, bt, cfg.head_dim)
 
     length = 70  # 3 blocks, boundary mid-block
-    row = row_of(1, length)
+    row = _arena_row(cfg, 1, length)
     t = pool.reserve(length)
     t.length = length
     copied = arena.scatter_row(row, t)
     assert copied == 3 * arena.block_bytes
     back = arena.gather_row(t, length)
-    # bit-identical for every valid position; lengths mirrors the request
+    # a row as init_cache makes one; bit-identical for every valid
+    # position; lengths mirrors the request
+    assert back["k"].shape == init_cache(cfg, 1)["k"].shape
     assert int(back["lengths"][0]) == length
     for f in ("k", "v"):
-        np.testing.assert_array_equal(
-            np.asarray(back[f][:, :, :length]),
-            np.asarray(row[f][:, :, :length]),
-        )
+        _same_bits(back[f][:, :, :, :length], row[f][:, :, :, :length])
     # skip_blocks: an aliased prefix keeps its DONOR content even when a
     # different row is scattered over the same table
-    other = row_of(2, length)
+    other = _arena_row(cfg, 2, length)
     copied2 = arena.scatter_row(other, t, skip_blocks=2)
     assert copied2 == 1 * arena.block_bytes
     back2 = arena.gather_row(t, length)
     for f in ("k", "v"):
-        np.testing.assert_array_equal(  # first 2 blocks: original content
-            np.asarray(back2[f][:, :, : 2 * bt]),
-            np.asarray(row[f][:, :, : 2 * bt]),
-        )
-        np.testing.assert_array_equal(  # third block: the new row's
-            np.asarray(back2[f][:, :, 2 * bt : length]),
-            np.asarray(other[f][:, :, 2 * bt : length]),
-        )
+        # first 2 blocks: original content; third block: the new row's
+        _same_bits(back2[f][:, :, :, : 2 * bt], row[f][:, :, :, : 2 * bt])
+        _same_bits(back2[f][:, :, :, 2 * bt : length], other[f][:, :, :, 2 * bt : length])
+
+
+def test_jax_arena_block_payloads_survive_the_wire():
+    """A donor's blocks, framed by fleet/kvwire.py and installed in a
+    receiver's arena, gather into the donor's row bit for bit; a peer
+    whose blocks lie in another order is refused by the spec check."""
+    from gofr_tpu.fleet import kvwire
+    from gofr_tpu.models.llama import CONFIGS
+    from gofr_tpu.tpu.kv_blocks import JaxKVArena
+
+    cfg = CONFIGS["tiny"]
+    bt, length = 32, 70
+    donor = JaxKVArena(cfg, n_blocks=9, block_tokens=bt)
+    receiver = JaxKVArena(cfg, n_blocks=9, block_tokens=bt)
+    spec = donor.wire_spec()
+    assert spec["block_shape"] == [cfg.n_layers, cfg.n_kv_heads, bt, cfg.head_dim]
+    row = _arena_row(cfg, 5, length)
+    t = BlockPool(9, bt, block_bytes=donor.block_bytes, scratch=True).reserve(length)
+    t.length = length
+    donor.scatter_row(row, t)
+    frames = b"".join(kvwire.encode_entry(
+        dict(spec, length=length, n_blocks=3),
+        (donor.export_block_payload(t, j) for j in range(3))))
+    header, payloads = kvwire.decode_stream([frames[:100], frames[100:]], max_blocks=3)
+    kvwire.check_spec(header, receiver.wire_spec())
+    pool = BlockPool(9, bt, block_bytes=receiver.block_bytes, scratch=True)
+    pool.reserve(bt)  # the receiver's block ids differ from the donor's
+    t2 = pool.reserve(length)
+    t2.length = length
+    assert t2.blocks != t.blocks
+    for j, payload in enumerate(payloads):
+        assert receiver.ingest_block_payload(t2, j, payload) == len(payload)
+    back = receiver.gather_row(t2, length)
+    for f in ("k", "v"):
+        _same_bits(back[f][:, :, :, :length], row[f][:, :, :, :length])
+    old_order = dict(spec, block_shape=[cfg.n_layers, bt, cfg.n_kv_heads, cfg.head_dim])
+    with pytest.raises(kvwire.VersionSkew, match="block_shape"):
+        kvwire.check_spec(old_order, receiver.wire_spec())
 
 
 def test_jax_arena_rejects_non_tiling_block_size():
@@ -523,15 +571,10 @@ def test_jax_arena_sharded_over_tp_matches_unsharded():
     plain = JaxKVArena(cfg, n_blocks=9, block_tokens=bt)
     assert len(sharded.k.sharding.device_set) == 2
 
-    rng = np.random.default_rng(3)
+    # the head axis is the arena's third, as it is the compute caches'
+    assert sharded.k.sharding.spec[2] == "tp"
     length = 70
-    shape = (cfg.n_layers, 1, cfg.max_seq, cfg.n_kv_heads, cfg.head_dim)
-    r = rng.standard_normal(shape, dtype=np.float32)
-    row = {
-        "k": jnp.asarray(r, cfg.cache_dtype),
-        "v": jnp.asarray(-r, cfg.cache_dtype),
-        "lengths": jnp.asarray([length], jnp.int32),
-    }
+    row = _arena_row(cfg, 3, length)
     for arena in (sharded, plain):
         pool = BlockPool(9, bt, block_bytes=arena.block_bytes, scratch=True)
         t = pool.reserve(length)
@@ -539,10 +582,7 @@ def test_jax_arena_sharded_over_tp_matches_unsharded():
         assert arena.scatter_row(row, t) == 3 * arena.block_bytes
         back = arena.gather_row(t, length)
         for f in ("k", "v"):
-            np.testing.assert_array_equal(
-                np.asarray(back[f][:, :, :length]),
-                np.asarray(row[f][:, :, :length]),
-            )
+            _same_bits(back[f][:, :, :, :length], row[f][:, :, :, :length])
 
 
 def test_jax_arena_mesh_rejects_indivisible_heads():

@@ -1,6 +1,7 @@
 """The cached forward carries its KV cache: one buffer that the step loop
 and the layer loop hand on, written only where a token lands, and read a
-layer at a time out of the stack (ISSUE 26).
+layer at a time out of the stack (ISSUE 26), in the order the attention
+kernel reads, [L, B, Hkv, S, D] (ISSUE 35).
 
 Structure (jaxpr and compiled CPU HLO), the write, and every caller of
 ``_run_cached`` against ``transformer_forward`` on the same tokens."""
@@ -13,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.models.llama import TINY
+from gofr_tpu.models.llama import CONFIGS, TINY
 from gofr_tpu.models.lora import add_lora, build_lora_stack
 from gofr_tpu.models.transformer import (
     _write_kv,
@@ -25,6 +26,7 @@ from gofr_tpu.models.transformer import (
     init_transformer,
     prefill,
     transformer_forward,
+    unpack_expert_counts,
     verify_chunk,
 )
 
@@ -111,14 +113,16 @@ def test_pooled_chunk_compiles_without_a_copy_of_the_cache(params):
 @pytest.mark.parametrize("s", [1, 8])
 @pytest.mark.parametrize("layer", [0, 1, 2])
 def test_write_touches_only_where_the_tokens_land(layer, s):
-    n_layers, b, max_seq, h, d = 3, 3, 32, 2, 8
-    stack = jax.random.normal(jax.random.key(1), (n_layers, b, max_seq, h, d))
+    """[layer, row, :, start:start + s, :] and nothing else of the stack;
+    a start past ``max_seq - s`` (a full row) clamps to it."""
+    n_layers, b, max_seq, h, d = 3, 4, 32, 2, 8
+    stack = jax.random.normal(jax.random.key(1), (n_layers, b, h, max_seq, d))
     new = jax.random.normal(jax.random.key(2), (b, s, h, d))
-    starts = jnp.asarray([0, 7, max_seq - s], jnp.int32)
+    starts = jnp.asarray([0, 7, max_seq - s, max_seq], jnp.int32)
     got = np.asarray(jax.jit(_write_kv)(stack, new, jnp.int32(layer), starts))
     want = np.asarray(stack).copy()
-    for row, start in enumerate(np.asarray(starts)):
-        want[layer, row, start:start + s] = np.asarray(new)[row]
+    for row, start in enumerate(np.minimum(np.asarray(starts), max_seq - s)):
+        want[layer, row, :, start:start + s] = np.asarray(new)[row].transpose(1, 0, 2)
     np.testing.assert_array_equal(got, want)
 
 
@@ -282,6 +286,54 @@ CASES = {
 @pytest.mark.parametrize("case", list(CASES))
 def test_cached_paths_match_the_plain_forward(params, case):
     CASES[case](params)
+
+
+KINDS = {
+    "dense_gqa": CFG,
+    # ZAYA1's block at test size: K and V beside a tail per row, routed experts
+    "cca": dataclasses.replace(CONFIGS["tiny-zaya"], max_seq=64),
+}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_ragged_rows_a_dead_row_and_a_full_row_beside_each_other(kind, impl):
+    """A pool's cache as a chunk meets it: rows 0 and 2 hold prompts of 5
+    and 9 tokens, slot 1 holds no request (not live, a stale length, NaN
+    where its K and V were) and slot 3 is full (live, ``max_seq`` long,
+    another request's K and V). Rows 0 and 2 decode what the plain forward,
+    which has no cache, gives them; the full row's writes clamp to its last
+    position and touch nothing before it."""
+    cfg = dataclasses.replace(KINDS[kind], attn_impl=impl)
+    params = init_transformer(jax.random.key(0), cfg)
+    prompts = _prompts(cfg, LENS)
+    first, cache = _prefilled(params, cfg, prompts, LENS)
+    row = jnp.arange(SLOTS)[None, :, None, None, None]
+    for i, name in enumerate(("k", "v")):
+        junk = jax.random.normal(jax.random.key(20 + i), cache[name].shape, cache[name].dtype)
+        cache[name] = jnp.where(row == 1, jnp.nan, jnp.where(row == 3, junk, cache[name]))
+    cache["live"] = jnp.asarray([1, 0, 1, 1], jnp.int32)
+    cache["lengths"] = jnp.asarray([LENS[0], 40, LENS[2], cfg.max_seq], jnp.int32)
+    before = jax.tree.map(np.asarray, cache)
+    toks, lps, *_, after = jax.jit(
+        lambda p, t, c, *a: decode_chunk_pool(p, t, c, cfg, STEPS, *a)
+    )(params, first[:, None], cache, *_pool_args())
+    toks, _ = unpack_expert_counts(np.asarray(toks), SLOTS, cfg.n_experts)
+    want = _teacher(params, cfg, prompts, LENS, first, toks)
+    _check_rows(want, toks, lps, [0, 2], 2e-4)
+    last = cfg.max_seq - 1
+    for name in ("k", "v"):
+        got = np.asarray(after[name])
+        np.testing.assert_array_equal(got[:, 3, :, :last], before[name][:, 3, :, :last])
+        assert not np.array_equal(got[:, 3, :, last], before[name][:, 3, :, last])
+        for r, n in ((0, LENS[0]), (2, LENS[2])):  # a live row: its new tokens alone
+            np.testing.assert_array_equal(got[:, r, :, :n], before[name][:, r, :, :n])
+            np.testing.assert_array_equal(
+                got[:, r, :, n + STEPS:], before[name][:, r, :, n + STEPS:])
+    assert np.asarray(after["lengths"]).tolist() == [
+        LENS[0] + STEPS, 40 + STEPS, LENS[2] + STEPS, cfg.max_seq + STEPS]
+    if "tail" in before:  # the slot without a request keeps its tail
+        np.testing.assert_array_equal(np.asarray(after["tail"])[:, 1], before["tail"][:, 1])
 
 
 def test_every_row_of_a_prefill_and_of_a_solo_cache_is_live(params):

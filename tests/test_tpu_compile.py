@@ -1,7 +1,8 @@
 """Compile the cached forward for a described TPU v5e (no chip attached)
-and read what the chip's compiler made of the KV cache: inside the
-program's loops nothing copies, reshapes or slices it; the Pallas kernel
-reads the loop-carried buffer itself. What the CPU backend and interpret
+and read what the chip's compiler made of the KV cache: nothing copies,
+reshapes, transposes or slices it, inside the program's loops or where the
+cache enters and leaves; the Pallas kernel reads the buffer the program
+was handed, in the order it is stored. What the CPU backend and interpret
 mode cannot show. A compile is not a run: no time is read here.
 
 The topology is described inside a fixture, never at import: only one
@@ -18,15 +19,18 @@ from jax.sharding import SingleDeviceSharding
 
 from gofr_tpu.models import transformer as T
 
-# InternLM2-1.8B's widths (benchmark/configs) with two layers and a small
-# vocabulary (the sampler is most of the compile): what the
-# compiler does to the cache depends on neither
+# InternLM2-1.8B's widths and depth (benchmark/configs) with a small
+# vocabulary (the sampler is most of the compile; the layers are one
+# scanned body, so depth costs nothing here). The cache has the size it
+# has on the chip: a cache of two layers the compiler parks in another
+# memory space and fetches back, which no deployment's cache fits
 CFG = T.TransformerConfig(
-    vocab_size=4096, dim=2048, n_layers=2, n_heads=16, n_kv_heads=8,
+    vocab_size=4096, dim=2048, n_layers=24, n_heads=16, n_kv_heads=8,
     hidden_dim=8192, max_seq=2048, rope_theta=1e6,
 )
-SLOTS = 4
-_MOVERS = ("copy", "reshape", "transpose", "dynamic-slice", "fusion")
+SLOTS = 12
+_MOVERS = ("copy", "reshape", "transpose", "dynamic-slice", "fusion", "slice",
+           "copy-done", "slice-done")
 
 
 @pytest.fixture(scope="module")
@@ -67,11 +71,15 @@ def _compiled(fn, donate, one_chip, *trees):
     return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
 
 
-def _cache_movers(hlo: str, batch: int) -> dict[str, list[str]]:
+def _cache_movers(hlo: str, batch: int, cfg=CFG) -> dict[str, list[str]]:
     """computation name -> the instructions in it that write a result of
-    the whole cache's or one layer slab's shape by moving data."""
-    slab = f"{batch},{CFG.max_seq},{CFG.n_kv_heads},{CFG.head_dim}"
-    shapes = (f"bf16[{CFG.n_layers},{slab}]", f"bf16[1,{slab}]", f"bf16[{slab}]")
+    the whole cache's or one layer slab's shape by moving data: in the
+    order the cache is stored, [L, B, Hkv, S, D], or in the one it had
+    before, [L, B, S, Hkv, D] (a relayout would show as either)."""
+    shapes = set()
+    for slab in (f"{batch},{cfg.n_kv_heads},{cfg.max_seq},{cfg.head_dim}",
+                 f"{batch},{cfg.max_seq},{cfg.n_kv_heads},{cfg.head_dim}"):
+        shapes |= {f"bf16[{cfg.n_layers},{slab}]", f"bf16[1,{slab}]", f"bf16[{slab}]"}
     found: dict[str, list[str]] = {}
     computation = ""
     for line in hlo.splitlines():
@@ -89,7 +97,7 @@ def _abstract_params():
     return lambda: T.init_transformer(jax.random.key(0), CFG)
 
 
-def test_pooled_chunk_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu):
+def test_pooled_chunk_moves_its_cache_nowhere(one_chip, as_on_tpu):
     hlo = _compiled(
         lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
             p, t, c, CFG, 8, key, temp, tk, tp, mp),
@@ -100,17 +108,16 @@ def test_pooled_chunk_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu
         jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.float32),
     )
     assert "tpu_custom_call" in hlo  # the Mosaic kernel, not interpret mode
-    movers = _cache_movers(hlo, SLOTS)
-    # K and V are relaid into the layout the kernel reads once where the
-    # chunk begins and once where it ends: a chunk, not a step or a layer
-    assert set(movers) <= {"ENTRY"}, movers
-    assert len(movers.get("ENTRY", [])) <= 4, movers
+    # the cache is stored in the order the kernel reads and donated through
+    # the chunk: the token writes and the kernel work on the buffer itself
+    assert _cache_movers(hlo, SLOTS) == {}
 
 
 def test_pooled_chunk_compiles_for_four_chips_under_tp(topo, as_on_tpu):
     """``TPU_MESH=tp=4``: the kernel runs per shard under ``shard_map``,
     2 of the 8 kv heads and their q heads to a chip; K and V left in HBM
-    and the copies out of them must compile there too."""
+    and the copies out of them must compile there too. The cache's heads
+    are its third axis (``cache_specs``)."""
     from jax.sharding import NamedSharding
 
     from gofr_tpu.parallel.mesh import make_mesh, mesh_shape_for
@@ -142,19 +149,28 @@ def test_pooled_chunk_compiles_for_four_chips_under_tp(topo, as_on_tpu):
         placed(cache, cache_specs(cache)), *sampling,
     ).compile().as_text()
     assert "tpu_custom_call" in hlo
+    assert cache_specs(cache)["k"][2] == "tp"
+    per_chip = dataclasses.replace(CFG, n_kv_heads=CFG.n_kv_heads // 4)
+    assert _cache_movers(hlo, SLOTS, per_chip) == {}
 
 
-def test_prefill_moves_its_cache_only_at_entry_and_exit(one_chip, as_on_tpu):
+@pytest.mark.parametrize("donated", [True, False])
+def test_prefill_moves_its_cache_nowhere(one_chip, as_on_tpu, donated):
     rows = 2
     hlo = _compiled(
-        lambda p, t, c, l: T.prefill(p, t, c, CFG, l), (2,), one_chip,
+        lambda p, t, c, l: T.prefill(p, t, c, CFG, l), (2,) if donated else (), one_chip,
         _abstract_params(), jnp.zeros((rows, 512), jnp.int32),
         lambda: T.init_cache(CFG, rows), jnp.zeros((rows,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
     movers = _cache_movers(hlo, rows)
-    assert set(movers) <= {"ENTRY"}, movers
-    assert len(movers.get("ENTRY", [])) <= 4, movers
+    if donated:
+        assert movers == {}
+    else:
+        # as the server calls it (a shared zero cache, or the cache a slice
+        # carries on from, which the caller keeps): K and V are copied once
+        # where they enter, in the order they have, and never again
+        assert set(movers) == {"ENTRY"} and len(movers["ENTRY"]) == 2, movers
 
 
 # -- a cache that is a state (attention kind "retention") ------------------------------------
@@ -220,11 +236,11 @@ def test_prefill_of_a_retention_model_copies_its_state_once_at_entry(one_chip, a
 
 
 # -- routed experts and a cache with a tail (attention kind "cca", feed-forward "moe") ---------
-# ZAYA1-8B's widths (benchmark/configs) with two layers and a small
-# vocabulary; 32 slots
+# ZAYA1-8B's widths and the cell's 20 layers (benchmark/configs) with a
+# small vocabulary; 32 slots
 
 ZCFG = T.TransformerConfig(
-    vocab_size=4096, dim=2048, n_layers=2, n_heads=8, n_kv_heads=2, head_dim=128,
+    vocab_size=4096, dim=2048, n_layers=20, n_heads=8, n_kv_heads=2, head_dim=128,
     hidden_dim=2048, max_seq=2048, rope_theta=5e6, rope_fraction=0.5, attn_kind="cca",
     ffn_kind="moe", n_experts=16, router_dim=256, tie_embeddings=True,
 )
@@ -265,6 +281,7 @@ def test_pooled_chunk_of_an_expert_model_leaves_its_experts_where_they_lie(one_c
     assert hlo.count("tpu_custom_call") >= 3
     assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
     assert _expert_movers(hlo) == []
+    assert _cache_movers(hlo, ZSLOTS, ZCFG) == {}
 
 
 @pytest.mark.parametrize("rows,bucket", [(2, 256), (1, 256), (2, 128)])
@@ -277,3 +294,8 @@ def test_prefill_of_an_expert_model_compiles_at_the_cells_buckets(one_chip, as_o
     )
     assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
     assert _expert_movers(hlo) == []
+    # called as the server calls it, the caller keeping its cache: K and V
+    # are copied once where they enter (42 MB a stack: one of them by way
+    # of the fast memory, where the loop then keeps it) and in no loop
+    movers = _cache_movers(hlo, rows, ZCFG)
+    assert set(movers) <= {"ENTRY"} and len(movers.get("ENTRY", [])) <= 2, movers

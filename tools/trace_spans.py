@@ -15,7 +15,7 @@ from the end of the issue to the start of the run, and the pooled decode
 chunks the device finished in that wait. ``--ops`` also lists the device
 operations that took most time, each with the scope (``jax.named_scope``
 path) and the line of source the compiler recorded for it; ``--shape
-bf16[32,6,2048,8,128]`` lists the operations that write a result of that
+bf16[32,6,8,2048,128]`` lists the operations that write a result of that
 shape (the compiler's own copies carry no scope: their shape finds them).
 
 Reads the trace with ``benchmark.trace_reduce`` and ``jax.profiler.ProfileData``
@@ -223,7 +223,7 @@ def op_scopes(data: Any, top: int, metadata: dict[str, dict[str, str]],
     """The ``top`` device operations by time (the labels of
     ``trace_reduce``'s ``device_ops``), each with its scope, its source and
     the number of times it ran. With ``shapes``, only the operations one of
-    whose results has one of those shapes (``bf16[32,6,2048,8,128]``): an
+    whose results has one of those shapes (``bf16[32,6,8,2048,128]``): an
     operation no ``named_scope`` reaches is found by what it writes; ``top``
     0 then lists them all."""
     line = device_lines(data).get(TR.OPS_LINE)
@@ -295,7 +295,7 @@ def main() -> int:
     ap.add_argument("--ops", type=int, default=0, help="also list this many device operations")
     ap.add_argument("--shape", action="append", default=[], metavar="TYPE[DIMS]",
                     help="list only the operations with a result of this shape, e.g. "
-                         "bf16[32,6,2048,8,128] (repeatable; all of them unless --ops limits)")
+                         "bf16[32,6,8,2048,128] (repeatable; all of them unless --ops limits)")
     ap.add_argument("--json", action="store_true", help="print the whole join as JSON")
     args = ap.parse_args()
     path = args.trace if args.trace.endswith(".pb") else TR.find_xplane(args.trace)
