@@ -105,6 +105,57 @@ ZAYA1_8B = TransformerConfig(
     tie_embeddings=True,
 )
 
+
+def _jamba_kinds(n_layers: int, period: int, offset: int) -> tuple:
+    """Layer i attends where ``i % period == offset``; every other layer is
+    a state-space mixer (the family's attn_layer_period / attn_layer_offset)."""
+    return tuple("softmax" if i % period == offset else "ssm" for i in range(n_layers))
+
+
+# Jamba's stack at test size: state-space (Mamba-1) layers with a softmax
+# layer mid-stack (ssm, ssm, softmax, ssm, ssm: three runs), 4 q heads on
+# one kv head, no rotary, tied head. CPU tests.
+TINY_JAMBA = TransformerConfig(
+    vocab_size=256,
+    dim=64,
+    n_layers=5,
+    n_heads=4,
+    n_kv_heads=1,
+    hidden_dim=128,
+    max_seq=128,
+    rope_fraction=0.0,
+    norm_eps=1e-6,
+    dtype=jnp.float32,
+    attn_impl="xla",
+    layer_kinds=_jamba_kinds(5, 5, 2),
+    ssm_state=16,
+    ssm_conv=4,
+    ssm_dt_rank=4,
+    tie_embeddings=True,
+)
+
+# AI21-Jamba2-3B (huggingface.co/ai21labs/AI21-Jamba2-3B config.json): 28
+# layers, attention (20 q heads of 128 on 1 kv head, no positional
+# embedding) where i % 14 == 7 and a Mamba-1 mixer (5120 channels, state
+# 16, convolution 4, dt rank 160) everywhere else; dense SwiGLU of 8192
+# (num_experts 1), tied 65,536-row table; bf16
+JAMBA2_3B = TransformerConfig(
+    vocab_size=65536,
+    dim=2560,
+    n_layers=28,
+    n_heads=20,
+    n_kv_heads=1,
+    hidden_dim=8192,
+    max_seq=262144,
+    rope_fraction=0.0,
+    norm_eps=1e-6,
+    layer_kinds=_jamba_kinds(28, 14, 7),
+    ssm_state=16,
+    ssm_conv=4,
+    ssm_dt_rank=160,
+    tie_embeddings=True,
+)
+
 # Small-but-realistic single-chip bench model (fits v5e-1 in bf16 and
 # exercises the same kernels/shapes class as 8B)
 SMALL = TransformerConfig(
@@ -123,6 +174,8 @@ CONFIGS: dict[str, TransformerConfig] = {
     "tiny-retention": TINY_RETENTION,
     "tiny-zaya": TINY_ZAYA,
     "zaya1-8b": ZAYA1_8B,
+    "tiny-jamba": TINY_JAMBA,
+    "jamba2-3b": JAMBA2_3B,
     "small": SMALL,
     "llama3-8b": LLAMA3_8B,
     "llama3-70b": LLAMA3_70B,

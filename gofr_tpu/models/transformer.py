@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,10 +77,80 @@ class TransformerConfig:
     ffn_kind: str = "dense"
     n_experts: int = 0
     router_dim: int = 0
+    # the mixer of each layer, one name a layer, where the layers are not
+    # all alike; empty means every layer is ``attn_kind``. Beside the three
+    # attention kinds above a layer may be "ssm": a selective state-space
+    # (Mamba-1) mixer (``_ssm_mixer``, ops/ssm.py) with no q, k, v and no
+    # ``wo``, whose cache is a diagonal state and a convolution tail per
+    # row. Parameters and cache are stacked per kind (``MIXERS``) and the
+    # layer loop scans the pattern's period (``layer_period``).
+    layer_kinds: tuple = ()
+    # an "ssm" layer: ``ssm_expand * dim`` channels, ``ssm_state`` entries
+    # of state a channel, a causal depthwise convolution of ``ssm_conv``
+    # taps, a step size projected through ``ssm_dt_rank``. The state is
+    # held float32 between steps (MODEL_KV_DTYPE is what K and V take)
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    ssm_expand: int = 2
 
     def __post_init__(self) -> None:
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+        if self.layer_kinds:
+            object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
+            unknown = set(self.layer_kinds) - set(MIXER_KINDS)
+            if len(self.layer_kinds) != self.n_layers or unknown:
+                raise ValueError(
+                    f"layer_kinds names {len(self.layer_kinds)} layers of kinds "
+                    f"{sorted(set(self.layer_kinds))}; the model has {self.n_layers} and a "
+                    f"kind is one of {MIXER_KINDS}")
+            if self.ffn_kind != "dense":
+                raise ValueError("layers of more than one kind take the dense feed-forward")
+
+    @property
+    def kinds(self) -> tuple:
+        """The mixer of every layer, first to last."""
+        return self.layer_kinds or (self.attn_kind,) * self.n_layers
+
+    @property
+    def kinds_present(self) -> tuple:
+        """The kinds the model has, in the order they first appear."""
+        return tuple(dict.fromkeys(self.kinds))
+
+    @property
+    def mixed(self) -> bool:
+        """Whether parameters and cache are stacked per kind (a model of
+        one kind keeps one stack over all its layers, as it always had)."""
+        return len(self.kinds_present) > 1
+
+    def n_of(self, kind: str) -> int:
+        return self.kinds.count(kind)
+
+    @property
+    def layer_period(self) -> tuple:
+        """(P, runs): the shortest period P of the layers' kinds that
+        divides the depth, and the runs of equal layers within one period
+        as (kind, first index among the period's layers of that kind,
+        layers). What the layer loop of a model with layers of more than
+        one kind scans: the period ``n_layers / P`` times, each run inside."""
+        kinds = self.kinds
+        period = next(p for p in range(1, self.n_layers + 1)
+                      if self.n_layers % p == 0
+                      and all(kinds[i] == kinds[i % p] for i in range(self.n_layers)))
+        runs: list = []
+        seen: dict = {}
+        for kind in kinds[:period]:
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, seen.get(kind, 0), 1])
+            seen[kind] = seen.get(kind, 0) + 1
+        return period, tuple(tuple(r) for r in runs)
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_expand * self.dim
 
     @property
     def q_dim(self) -> int:
@@ -100,9 +170,10 @@ class TransformerConfig:
 
     @property
     def cache_dtype(self) -> Any:
-        if self.attn_kind == "retention":
+        if self.attn_kind == "retention" and not self.layer_kinds:
             return self.kv_dtype or jnp.float32
         return self.kv_dtype or self.dtype
+
 
 
 @functools.lru_cache(maxsize=16)
@@ -119,6 +190,15 @@ def _cached_freqs(head_dim: int, max_seq: int, theta: float):
     inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
     freqs = np.outer(np.arange(max_seq, dtype=np.float32), inv_freq)
     return np.stack([np.cos(freqs), np.sin(freqs)], axis=-1).astype(np.float32)
+
+
+def _rope_table(cfg: TransformerConfig) -> Optional[jnp.ndarray]:
+    """The model's rotary table, or None for a model without rotary
+    (``rope_fraction`` 0: a table of width 0 cannot be built, and no layer
+    turns anything)."""
+    if cfg.rope_dim == 0:
+        return None
+    return jnp.asarray(_cached_freqs(cfg.rope_dim, cfg.max_seq, cfg.rope_theta))
 
 
 # write one layer into a preallocated [n_layers, ...] stack IN PLACE: the
@@ -158,10 +238,10 @@ def init_transformer(
 
         return shard_params(tree, mesh)
 
-    def stack_like(x: jnp.ndarray) -> jnp.ndarray:
-        """Zeros for ``cfg.n_layers`` stacked copies of ``x``, allocated in
-        ``x``'s layout (the layer axis is never sharded)."""
-        shape = (cfg.n_layers,) + x.shape
+    def stack_like(x: jnp.ndarray, n: int) -> jnp.ndarray:
+        """Zeros for ``n`` stacked copies of ``x``, allocated in ``x``'s
+        layout (the layer axis is never sharded)."""
+        shape = (n,) + x.shape
         if mesh is None:
             return jnp.zeros(shape, x.dtype)
         from jax.sharding import NamedSharding, PartitionSpec
@@ -172,6 +252,8 @@ def init_transformer(
     quantizer_for(quantize)  # validate the mode eagerly
     if quantize and cfg.ffn_kind == "moe":
         raise ValueError("the quantiser does not take expert-stacked leaves")
+    if quantize and cfg.mixed:
+        raise ValueError("the quantiser does not take layers stacked per kind")
     n_keys = cfg.n_layers * 7 + 3
     keys = iter(jax.random.split(key, n_keys))
 
@@ -245,15 +327,54 @@ def init_transformer(
                     * (cfg.dim ** -0.5)).astype(cfg.dtype),
         }
 
-    def make_layer() -> dict:
-        layer = {
-            "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
-            "wq": dense(next(keys), (cfg.dim, q_dim), cfg.dim),
-            "wk": dense(next(keys), (cfg.dim, kv_dim), cfg.dim),
-            "wv": dense(next(keys), (cfg.dim, kv_dim), cfg.dim),
-            "wo": dense(next(keys), (q_dim, cfg.dim), q_dim),
-            "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
+    def ssm_leaves(i: int) -> dict:
+        di, n, r, taps = cfg.d_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+        f32 = jnp.float32
+        # the family's initialisation, not 1 / sqrt(fan-in) noise (which
+        # gives states that forget in a token or overflow): A = -(1..N) in
+        # every channel, and a step-size bias whose softplus is spread
+        # log-uniformly over [0.001, 0.1]
+        k = jax.random.fold_in(jax.random.fold_in(jax.random.fold_in(key, n_keys), i), 20)
+        step = jnp.exp(jax.random.uniform(k, (di,), f32, jnp.log(0.001), jnp.log(0.1)))
+        return {
+            "ssm_in": extra(i, 10, (cfg.dim, 2 * di), cfg.dim),
+            # [tap, channel]: tap ``taps - 1`` multiplies this token
+            "ssm_conv_w": extra(i, 11, (taps, di), taps),
+            "ssm_conv_b": jnp.zeros((di,), cfg.dtype),
+            "ssm_x": extra(i, 12, (di, r + 2 * n), di),
+            "ssm_dt_norm": jnp.ones((r,), cfg.dtype),
+            "ssm_b_norm": jnp.ones((n,), cfg.dtype),
+            "ssm_c_norm": jnp.ones((n,), cfg.dtype),
+            "ssm_dt": extra(i, 13, (r, di), r),
+            # float32, as the scan takes them: the inverse of softplus at
+            # ``step``; log(1..N) down the state's entries, [N, Di] as the
+            # state is kept (ops/ssm.py)
+            "ssm_dt_b": step + jnp.log(-jnp.expm1(-step)),
+            "ssm_a_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, n + 1, dtype=f32))[:, None], (n, di)),
+            "ssm_d": jnp.ones((di,), f32),
+            "ssm_out": extra(i, 14, (di, cfg.dim), di),
         }
+
+    def make_layer(kind: str, i: int) -> dict:
+        if kind == "ssm":
+            layer = {"attn_norm": jnp.ones((cfg.dim,), cfg.dtype), **ssm_leaves(i),
+                     "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype)}
+        else:
+            layer = {
+                "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
+                "wq": dense(next(keys), (cfg.dim, q_dim), cfg.dim),
+                "wk": dense(next(keys), (cfg.dim, kv_dim), cfg.dim),
+                "wv": dense(next(keys), (cfg.dim, kv_dim), cfg.dim),
+                "wo": dense(next(keys), (q_dim, cfg.dim), q_dim),
+                "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
+            }
+        if kind == "retention":
+            layer.update(retention_leaves(i))
+        if kind == "cca":
+            layer.update(cca_leaves(i))
+        if cfg.ffn_kind == "moe":
+            layer.update(moe_leaves(i))
         if cfg.ffn_kind == "dense":
             layer.update({
                 "w_gate": dense(next(keys), (cfg.dim, cfg.hidden_dim), cfg.dim),
@@ -269,23 +390,22 @@ def init_transformer(
     # such copies holds the whole model twice until the old tree is
     # dropped — at 8B int8 that is ~14 GB of a 16 GB chip during boot.
     # (Quantized {"q","scale"} dicts thread per-field through the tree maps.)
-    stacked = None
-    for i in range(cfg.n_layers):
-        layer = make_layer()
-        if cfg.attn_kind == "retention":
-            layer.update(retention_leaves(i))
-        if cfg.attn_kind == "cca":
-            layer.update(cca_leaves(i))
-        if cfg.ffn_kind == "moe":
-            layer.update(moe_leaves(i))
-        layer = put(layer)
-        if stacked is None:
-            stacked = jax.tree.map(stack_like, layer)
-        stacked = jax.tree.map(
-            lambda s, x, i=i: _place_layer(s, x, i), stacked, layer
+    # A model whose layers are not all alike has one such stack a kind,
+    # ``params["layers"][kind]``, a layer at its index among its kind.
+    stacks: dict[str, Any] = {}
+    placed: dict[str, int] = {}
+    for i, kind in enumerate(cfg.kinds):
+        layer = put(make_layer(kind, i))
+        at = placed.get(kind, 0)
+        if kind not in stacks:
+            stacks[kind] = jax.tree.map(
+                lambda x, n=cfg.n_of(kind): stack_like(x, n), layer)
+        stacks[kind] = jax.tree.map(
+            lambda s, x, at=at: _place_layer(s, x, at), stacks[kind], layer
         )
+        placed[kind] = at + 1
         del layer
-    params["layers"] = stacked
+    params["layers"] = stacks if cfg.mixed else stacks[cfg.kinds[0]]
     return params
 
 
@@ -404,6 +524,7 @@ def _block(
     mlp_fn: Optional[Any] = None,
     valid: Optional[jnp.ndarray] = None,
     live: Optional[jnp.ndarray] = None,
+    kind: Optional[str] = None,
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, ...], dict]:
     """One decoder block — the single implementation shared by the
     no-cache forward, the cached prefill/decode path, the sequence-parallel
@@ -430,13 +551,52 @@ def _block(
     [L, B, tail_dim] beside the K/V stacks. The call reads its layer's tail
     at entry and leaves the one of each row's last ``valid`` token (bucket
     padding does not enter it); a row that is not ``live`` keeps its own.
+
+    ``kind`` is this layer's mixer where the model's layers are not all
+    alike (``cfg.layer_kinds``; default ``cfg.attn_kind``), ``layer`` its
+    index among the layers of its kind, and ``kv_cache`` that kind's stacks
+    (``MIXERS[kind].cache``). A mixer is the part between the first norm
+    and the residual: the three attention kinds share the body below; "ssm"
+    is ``_ssm_mixer``. ``freqs`` None: no rotary (``cfg.rope_dim == 0``).
     """
+    kind = kind or cfg.attn_kind
+    x, merged = MIXERS[kind].mix(
+        cfg, kind, p, x, kv_cache, layer,
+        _Call(freqs, positions, starts, kv_lens, attn_fn, valid, live))
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+        y, aux = (mlp_fn or _default_mlp)(p, h)
+        x = x + y
+    return x, merged, aux
+
+
+class _Call(NamedTuple):
+    """What a forward hands every layer's mixer beside its own parameters
+    and cache (``_block``'s arguments of the same names)."""
+    freqs: Optional[jnp.ndarray]
+    positions: Optional[jnp.ndarray]
+    starts: Optional[jnp.ndarray]
+    kv_lens: Optional[jnp.ndarray]
+    attn_fn: Optional[Any]
+    valid: Optional[jnp.ndarray]
+    live: Optional[jnp.ndarray]
+
+
+def _attention_mixer(
+    cfg: TransformerConfig, kind: str, p: dict, x: jnp.ndarray,
+    kv_cache: Optional[tuple[jnp.ndarray, ...]], layer: Optional[jnp.ndarray], call: _Call,
+) -> tuple[jnp.ndarray, tuple[jnp.ndarray, ...]]:
+    """The mixer of the three attention kinds (``_block``): norm, q, k, v,
+    the kind's own steps, attention over the kind's cache, ``wo`` and the
+    residual -> (x, the kind's stacks as they came in, this call's tokens
+    written)."""
+    freqs, positions, starts, kv_lens, attn_fn, valid, live = call
     # the named scopes are names only (HLO op metadata: a device
     # operation in a profiler trace then says which of these lines it
     # came from); they change no program, shape or module name
     b, s, _ = x.shape
     tail_stack = None
-    if cfg.attn_kind == "cca":
+    if kind == "cca":
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
         if kv_cache is None:
             tail = jnp.zeros((b, cfg.tail_dim), x.dtype)
@@ -458,15 +618,16 @@ def _block(
             q = _mm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
             k = _mm(h, p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
             v = _mm(h, p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    if cfg.attn_kind == "retention":
+    if kind == "retention":
         with jax.named_scope("attn.qk_norm"):
             q = rms_norm(q, p["q_norm"], cfg.norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    with jax.named_scope("attn.rope"):
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
+    if freqs is not None:
+        with jax.named_scope("attn.rope"):
+            q = apply_rope(q, freqs, positions)
+            k = apply_rope(k, freqs, positions)
 
-    if cfg.attn_kind == "retention":
+    if kind == "retention":
         from gofr_tpu.ops import retention
 
         with jax.named_scope("attn.gate"):
@@ -511,11 +672,159 @@ def _block(
 
     with jax.named_scope("attn.out"):
         x = x + _mm(attn.reshape(b, s, cfg.q_dim), p["wo"])
-    with jax.named_scope("mlp"):
-        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        y, aux = (mlp_fn or _default_mlp)(p, h)
-        x = x + y
-    return x, merged, aux
+    return x, merged
+
+
+def _ssm_mixer(
+    cfg: TransformerConfig, kind: str, p: dict, x: jnp.ndarray,
+    cache: Optional[tuple[jnp.ndarray, jnp.ndarray]], layer: Optional[jnp.ndarray], call: _Call,
+) -> tuple[jnp.ndarray, Optional[tuple[jnp.ndarray, jnp.ndarray]]]:
+    """The selective state-space (Mamba-1) mixer with the family's three
+    inner norms, for this call's tokens ``x`` [B, T, D] -> (x with the
+    mixer's output added, the stacks (conv, ssm) that came in, layer
+    ``layer`` advanced). For token t::
+
+        [u, z] = rms(x) W_in
+        u_t = silu(b_c + sum_j w_c[j] u_{t-(K-1)+j})      (depthwise, causal)
+        [dt, B, C] = u W_x;  dt, B, C = rms(dt), rms(B), rms(C)
+        delta = softplus(dt W_dt + b_dt);  A = -exp(A_log)
+        s_t = exp(delta_t A) s_{t-1} + (delta_t u_t) B_t^T;  y_t = s_t C_t + D u_t
+        out = (y * silu(z)) W_out
+
+    ``cache``: ``conv`` [L, B, (K-1) * Di], the K-1 inputs of the
+    convolution before this call's first token, oldest first, in the
+    model's type (zeros at a sequence's start), and ``ssm`` [L, B, N, Di],
+    the state (ops/ssm.py). A token that is not ``valid`` (bucket padding;
+    a row's real tokens come first) enters neither: its ``delta`` is 0 and
+    the tail is taken at the row's last valid tokens. A row that is not
+    ``live`` keeps both. Without a cache the sequence starts from zeros.
+    The state and the scan are float32 whatever the model's type."""
+    from gofr_tpu.ops import ssm
+
+    valid, live = call.valid, call.live
+    b, t, _ = x.shape
+    di, n, r, taps = cfg.d_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+    f32 = jnp.float32
+    with jax.named_scope("ssm.in_proj"):
+        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        uz = _mm(h, p["ssm_in"])
+        u, z = uz[..., :di], uz[..., di:]
+    with jax.named_scope("ssm.conv"):
+        if cache is None:
+            conv_stack = ssm_stack = None
+            tail = jnp.zeros((b, (taps - 1) * di), x.dtype)
+        else:
+            conv_stack, ssm_stack = cache
+            tail = jax.lax.dynamic_index_in_dim(conv_stack, layer, 0, keepdims=False)
+        w = p["ssm_conv_w"].astype(f32)
+        if t == 1:
+            # lane slices of the flat tail: no [B, K-1, Di] view, which
+            # under (8, 128) tiling would be a relayout of it
+            taps_in = [tail[:, j * di:(j + 1) * di] for j in range(taps - 1)] + [u[:, 0]]
+            conv = sum(w[j] * taps_in[j].astype(f32) for j in range(taps))[:, None]
+            new_tail = jnp.concatenate([tail[:, di:], u[:, 0].astype(tail.dtype)], axis=-1)
+        else:
+            seen = jnp.concatenate([tail.reshape(b, taps - 1, di).astype(u.dtype), u], axis=1)
+            conv = sum(w[j] * seen[:, j:j + t].astype(f32) for j in range(taps))
+            # the K-1 inputs before the row's next token: those that end
+            # at its last valid one
+            count = (jnp.full((b,), t, jnp.int32) if valid is None
+                     else jnp.sum(valid, axis=1, dtype=jnp.int32))
+            new_tail = jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+                row, at, taps - 1, axis=0))(seen, count)
+            new_tail = new_tail.reshape(b, (taps - 1) * di).astype(tail.dtype)
+        # float32 into the scan and the skip; the model's type into the matmul
+        uf = jax.nn.silu(conv + p["ssm_conv_b"].astype(f32))
+        u = uf.astype(x.dtype)
+        if conv_stack is not None:
+            if live is not None:
+                new_tail = jnp.where(live[:, None] > 0, new_tail, tail)
+            conv_stack = jax.lax.dynamic_update_slice(conv_stack, new_tail[None], (layer, 0, 0))
+    with jax.named_scope("ssm.dt_bc"):
+        # the two small projections give float32 (their sums are float32
+        # anyway): what feeds the float32 scan is rounded to the model's
+        # type once, where it enters a matmul, and not again on the way out.
+        # A step size rounded to bfloat16 before its softplus is 3% off.
+        project = lambda y, w: jnp.einsum(  # noqa: E731
+            "btd,dk->btk", y, w, preferred_element_type=f32)
+        dbc = project(u, p["ssm_x"])
+        dt = rms_norm(dbc[..., :r], p["ssm_dt_norm"], cfg.norm_eps)
+        b_t = rms_norm(dbc[..., r:r + n], p["ssm_b_norm"], cfg.norm_eps)
+        c_t = rms_norm(dbc[..., r + n:], p["ssm_c_norm"], cfg.norm_eps)
+        delta = jax.nn.softplus(project(dt.astype(x.dtype), p["ssm_dt"])
+                                + p["ssm_dt_b"].astype(f32))
+        if valid is not None:
+            delta = jnp.where(valid[:, :, None], delta, 0.0)
+        a = -jnp.exp(p["ssm_a_log"].astype(f32))  # [N, Di]
+    if ssm_stack is None:
+        with jax.named_scope("ssm.scan"):
+            y, _ = ssm.scan_chunked(uf, delta, a, b_t, c_t, jnp.zeros((b, n, di), f32))
+    else:
+        with jax.named_scope("ssm.step" if t == 1 else "ssm.scan"):
+            y, ssm_stack = ssm.scan_cached(
+                uf, delta, a, b_t, c_t, ssm_stack, layer, impl=cfg.attn_impl, live=live)
+    with jax.named_scope("ssm.out_proj"):
+        y = (y + p["ssm_d"].astype(f32) * uf) * jax.nn.silu(z.astype(f32))
+        x = x + _mm(y.astype(x.dtype), p["ssm_out"])
+    return x, (None if cache is None else (conv_stack, ssm_stack))
+
+
+def _kv_rows(cfg: TransformerConfig, n: int, batch: int, max_seq: int) -> dict:
+    shape = (n, batch, cfg.n_kv_heads, max_seq, cfg.head_dim)
+    return {"k": jnp.zeros(shape, cfg.kv_dtype or cfg.dtype),
+            "v": jnp.zeros(shape, cfg.kv_dtype or cfg.dtype)}
+
+
+def _cca_cache(cfg: TransformerConfig, n: int, batch: int, max_seq: int) -> dict:
+    # beside the rows that grow by the token, a fixed tail per row
+    # (``TransformerConfig.tail_dim``), in the model's own type whatever K
+    # and V are held in: zeros at a sequence's start
+    return {**_kv_rows(cfg, n, batch, max_seq),
+            "tail": jnp.zeros((n, batch, cfg.tail_dim), cfg.dtype)}
+
+
+def _retention_state(cfg: TransformerConfig, n: int, batch: int, max_seq: int) -> dict:
+    # a state per row, whatever the context: no length axis
+    from gofr_tpu.ops.retention import init_state
+
+    # (beside another kind's K/V rows, ``kv_dtype`` is theirs)
+    dtype = (None if cfg.mixed else cfg.kv_dtype) or jnp.float32
+    s, z = init_state(batch, cfg.n_kv_heads, cfg.head_dim, dtype, layers=n)
+    return {"s": s, "z": z}
+
+
+def _ssm_state(cfg: TransformerConfig, n: int, batch: int, max_seq: int) -> dict:
+    # the diagonal state, channels along the lanes (ops/ssm.py), and the
+    # convolution's K-1 earlier inputs flat, oldest first, in the model's type
+    return {"ssm": jnp.zeros((n, batch, cfg.ssm_state, cfg.d_inner), jnp.float32),
+            "conv": jnp.zeros((n, batch, (cfg.ssm_conv - 1) * cfg.d_inner), cfg.dtype)}
+
+
+@dataclass(frozen=True)
+class Mixer:
+    """A kind of layer: what stands between a block's first norm and its
+    residual, and the cache it keeps. ``mix(cfg, kind, p, x, cache, layer,
+    call) -> (x, cache)`` takes the kind's stacks in the order of ``cache``
+    (None without a cache) and gives them back, layer ``layer`` of them
+    advanced; ``make_cache(cfg, n, batch, max_seq)`` makes them for ``n``
+    layers, the row axis second. ``state`` names the leaves that are a
+    fixed-size state per row, read and written whole at every token and
+    with no length axis (K and V rows grow by the token; a "cca" tail is a
+    row's appendix to them)."""
+    mix: Any
+    cache: tuple
+    make_cache: Any
+    state: tuple = ()
+
+
+MIXERS: dict[str, Mixer] = {
+    "softmax": Mixer(_attention_mixer, ("k", "v"), _kv_rows),
+    "cca": Mixer(_attention_mixer, ("k", "tail", "v"), _cca_cache),
+    "retention": Mixer(_attention_mixer, ("s", "z"), _retention_state, state=("s", "z")),
+    "ssm": Mixer(_ssm_mixer, ("conv", "ssm"), _ssm_state, state=("conv", "ssm")),
+}
+MIXER_KINDS = tuple(MIXERS)  # what ``TransformerConfig.layer_kinds`` may name
+STATE_LEAVES = tuple(name for m in MIXERS.values() for name in m.state)
 
 
 def _logits(params: dict, x: jnp.ndarray) -> jnp.ndarray:
@@ -527,22 +836,62 @@ def _logits(params: dict, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _scan_layers(
-    cfg: TransformerConfig, params: dict, x: jnp.ndarray, stacks: Optional[tuple],
+    cfg: TransformerConfig, params: dict, x: jnp.ndarray, stacks: Optional[dict],
     block: Any, token_mask: Optional[jnp.ndarray] = None,
-) -> tuple[jnp.ndarray, Optional[tuple], dict]:
+) -> tuple[jnp.ndarray, Optional[dict], dict]:
     """The layer loop of every forward. ``block(layer_params, x, stacks,
-    layer, mlp_fn)`` runs one ``_block``; the cache stacks ride the loop's
-    CARRY (a scan's ys is a fresh buffer, so stacks passed as xs/ys are
-    copied slab by slab every call, and whole at the carry of any loop
-    around this one). An expert model also carries the router's state
-    beside ``x`` (it lives within one forward) and gives back what routing
-    did: ``aux["expert_counts"]`` [L, E], the tokens each expert got."""
+    layer, mlp_fn, kind)`` runs one ``_block``; the cache stacks (by name)
+    ride the loop's CARRY (a scan's ys is a fresh buffer, so stacks passed
+    as xs/ys are copied slab by slab every call, and whole at the carry of
+    any loop around this one). An expert model also carries the router's
+    state beside ``x`` (it lives within one forward) and gives back what
+    routing did: ``aux["expert_counts"]`` [L, E], the tokens each expert got.
+
+    A model whose layers are not all alike (``cfg.mixed``) scans the
+    pattern's period (``cfg.layer_period``; Jamba's 14 layers twice) and
+    inside it each run of equal layers, a single layer inline; every kind's
+    stacks ride every carry. A layer reads its kind's parameter stack,
+    which the loops close over, at its place among its kind (what a scan
+    does with its xs). One compiled body a run of the PERIOD, not of the
+    stack: two state-space bodies and one attention body here, whatever
+    the depth."""
+    if cfg.mixed:
+        period, runs = cfg.layer_period
+        per_period = {kind: sum(n for k, _, n in runs if k == kind) for kind in cfg.kinds_present}
+
+        def one(carry, kind, layer):
+            x, stacks = carry
+            layer_params = jax.tree.map(
+                lambda leaf: jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False),
+                params["layers"][kind])
+            y, stacks, _ = block(layer_params, x, stacks, layer, None, kind)
+            return y, stacks
+
+        def a_period(carry, rep):
+            for kind, first, count in runs:
+                at = rep * per_period[kind] + first
+                if count == 1:
+                    carry = one(carry, kind, at)
+                else:
+                    carry, _ = jax.lax.scan(
+                        lambda c, layer, kind=kind: (one(c, kind, layer), None), carry,
+                        at + jnp.arange(count, dtype=jnp.int32))
+            return carry, None
+
+        reps = cfg.n_layers // period
+        if reps == 1:
+            (x, stacks), _ = a_period((x, stacks), jnp.int32(0))
+        else:
+            (x, stacks), _ = jax.lax.scan(
+                a_period, (x, stacks), jnp.arange(reps, dtype=jnp.int32))
+        return x, stacks, {}
+    kind = cfg.kinds[0]
     scanned, experts = _split_experts(cfg, params["layers"])
     layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
     if experts is None:
         def body(carry, inputs):
             x, stacks = carry
-            y, stacks, _ = block(inputs[0], x, stacks, inputs[1], None)
+            y, stacks, _ = block(inputs[0], x, stacks, inputs[1], None, kind)
             return (y, stacks), None
 
         (x, stacks), _ = jax.lax.scan(body, (x, stacks), (scanned, layer_ids))
@@ -555,7 +904,7 @@ def _scan_layers(
         layer_params, layer = inputs
         mlp_fn = lambda p, h: routed_mlp(  # noqa: E731
             cfg, p, h, r, experts, layer, token_mask)
-        y, stacks, aux = block(layer_params, x, stacks, layer, mlp_fn)
+        y, stacks, aux = block(layer_params, x, stacks, layer, mlp_fn, kind)
         return (y, stacks, aux["router_state"]), aux["expert_counts"]
 
     r0 = jnp.zeros(x.shape[:-1] + (cfg.router_dim,), jnp.float32)
@@ -570,13 +919,13 @@ def transformer_forward(
     """Full-sequence forward -> logits [B, S, V] (training / no-cache
     scoring). Layers run under lax.scan over stacked weights."""
     b, s = tokens.shape
-    freqs = jnp.asarray(_cached_freqs(cfg.rope_dim, cfg.max_seq, cfg.rope_theta))
+    freqs = _rope_table(cfg)
     positions = jnp.arange(s)
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
 
-    def block(layer_params, x, stacks, layer, mlp_fn):
-        y, _, aux = _block(cfg, layer_params, x, freqs, positions, mlp_fn=mlp_fn)
+    def block(layer_params, x, stacks, layer, mlp_fn, kind):
+        y, _, aux = _block(cfg, layer_params, x, freqs, positions, mlp_fn=mlp_fn, kind=kind)
         return y, None, aux
 
     x, _, _ = _scan_layers(cfg, params, x, None, block)
@@ -588,7 +937,7 @@ def transformer_forward(
 # -- KV-cached ragged-batch serving path -------------------------------------
 
 def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -> dict:
-    """Cache layout [n_layers, B, n_kv_heads, max_seq, head_dim] with
+    """K and V are laid out [n_layers, B, n_kv_heads, max_seq, head_dim] with
     per-request ``lengths`` [B]: the order the attention kernels read
     (ops/flash.py), stored so everywhere K and V are held (the pool, the
     prefill caches, the block arena, the wire), so no program relays the
@@ -606,22 +955,9 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
             f"cache max_seq {max_seq} exceeds config max_seq {cfg.max_seq} "
             "(RoPE table bound)"
         )
-    if cfg.attn_kind == "retention":
-        # a state per row, whatever the context: no length axis
-        from gofr_tpu.ops.retention import init_state
-
-        s, z = init_state(batch, cfg.n_kv_heads, cfg.head_dim, cfg.cache_dtype,
-                          layers=cfg.n_layers)
-        stacks = {"s": s, "z": z}
-    else:
-        shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq, cfg.head_dim)
-        stacks = {"k": jnp.zeros(shape, cfg.cache_dtype),
-                  "v": jnp.zeros(shape, cfg.cache_dtype)}
-        if cfg.attn_kind == "cca":
-            # beside the rows that grow by the token, a fixed tail per row
-            # (``TransformerConfig.tail_dim``), in the model's own type
-            # whatever K and V are held in: zeros at a sequence's start
-            stacks["tail"] = jnp.zeros((cfg.n_layers, batch, cfg.tail_dim), cfg.dtype)
+    stacks: dict = {}
+    for kind in cfg.kinds_present:
+        stacks.update(MIXERS[kind].make_cache(cfg, cfg.n_of(kind), batch, max_seq))
     # ``live``: the rows that hold a request. The decode pool keeps it to
     # its active slots; every row of a prefill's or a solo cache is live,
     # and so is every row of a cache that lacks the leaf.
@@ -631,10 +967,20 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
 
 def cache_leaves(cache: dict) -> tuple[str, ...]:
     """The names of a cache's device state: ``k`` and ``v`` (with ``tail``
-    for a "cca" model), or a retention model's ``s`` and ``z``; every one
-    has the row (slot) axis second. The per-row vectors ride beside them:
+    for a "cca" model), a retention model's ``s`` and ``z``, a state-space
+    layer's ``ssm`` and ``conv``; a model with layers of two kinds has both
+    kinds' leaves, each stacked over the layers of its kind. Every one has
+    the row (slot) axis second. The per-row vectors ride beside them:
     ``lengths`` [B] and ``live`` [B]."""
     return tuple(sorted(name for name, leaf in cache.items() if leaf.ndim > 1))
+
+
+def state_row_bytes(cache: dict) -> int:
+    """What one row of the cache holds as a fixed-size state over all its
+    layers, in bytes (``Mixer.state``: read and written whole at every
+    token); 0 for a cache of K/V rows alone."""
+    return sum(leaf.size // leaf.shape[1] * leaf.dtype.itemsize  # (a shape has no nbytes)
+               for name, leaf in cache.items() if name in STATE_LEAVES)
 
 
 def _run_cached(
@@ -657,7 +1003,7 @@ def _run_cached(
     tail and out of every expert's tokens."""
     b, s = tokens.shape
     starts = cache["lengths"]  # [B]
-    freqs = jnp.asarray(_cached_freqs(cfg.rope_dim, cfg.max_seq, cfg.rope_theta))
+    freqs = _rope_table(cfg)
     positions = starts[:, None] + jnp.arange(s)[None, :]  # [B, S]
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
@@ -668,7 +1014,9 @@ def _run_cached(
         # read for it and returns zeros (ops/flash.py, the decode form)
         written = jnp.where(live > 0, written, 0)
     valid = None
-    masks_pads = cfg.attn_kind in ("retention", "cca") or cfg.ffn_kind == "moe"
+    # K/V rows have a "past the length" for padding to be dead in; a state,
+    # a tail and an expert's tokens have not
+    masks_pads = cfg.ffn_kind == "moe" or any(k != "softmax" for k in cfg.kinds_present)
     if masks_pads and lengths is not None:
         valid = jnp.arange(s)[None, :] < lengths[:, None]
     token_mask = None
@@ -677,20 +1025,21 @@ def _run_cached(
         token_mask = jnp.ones((b, s), bool) if valid is None else valid
         if live is not None:
             token_mask = token_mask & (live[:, None] > 0)
-    names = cache_leaves(cache)
-
-    def block(layer_params, x, stacks, layer, mlp_fn):
-        return _block(
+    def block(layer_params, x, stacks, layer, mlp_fn, kind):
+        names = MIXERS[kind].cache
+        y, merged, aux = _block(
             cfg, layer_params, x, freqs, positions,
-            kv_cache=stacks, layer=layer, starts=starts,
-            kv_lens=written, valid=valid, live=live, mlp_fn=mlp_fn,
+            kv_cache=tuple(stacks[name] for name in names), layer=layer, starts=starts,
+            kv_lens=written, valid=valid, live=live, mlp_fn=mlp_fn, kind=kind,
         )
+        return y, {**stacks, **dict(zip(names, merged))}, aux
 
     x, stacks, aux = _scan_layers(
-        cfg, params, x, tuple(cache[name] for name in names), block, token_mask)
+        cfg, params, x, {name: cache[name] for name in cache_leaves(cache)}, block,
+        token_mask)
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["norm_f"], cfg.norm_eps)
-    return x, dict(zip(names, stacks)), starts, aux
+    return x, stacks, starts, aux
 
 
 def _forward_with_cache(

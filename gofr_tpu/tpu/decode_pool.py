@@ -250,11 +250,13 @@ class DecodePool:
         # written in from prefill already live on the same mesh
         self._cache_shardings = cache_shardings
         self.cache = self._place(init_cache(cfg, n_slots))
-        # one row of one layer of a retention state (S and z), in bytes:
-        # what a live row reads and writes a layer a step; 0 for K/V
-        self._state_row_bytes = sum(
-            leaf.nbytes for name, leaf in self.cache.items() if name in ("s", "z")
-        ) // (cfg.n_layers * n_slots)
+        # one row's fixed-size state over all its layers (a retention
+        # model's S and z; a state-space layer's state and convolution
+        # tail), in bytes: what a live row reads and writes a step; 0 for
+        # a cache of K/V rows alone
+        from gofr_tpu.models.transformer import state_row_bytes
+
+        self._state_row_bytes = state_row_bytes(self.cache)
         # the positions of K/V the attention kernel fetches at a time
         # (ops/flash.py, the decode form); 0 for a state
         from gofr_tpu.ops.flash import DEFAULT_BLOCK_KV
@@ -302,8 +304,9 @@ class DecodePool:
             ),
         )
 
-        # a cache is K and V, or a retention model's S and z: every stack
-        # has its slot axis second, ``lengths`` [slots] has it first
+        # a cache is K and V, a state, a tail, or K/V rows and a state
+        # side by side, each stacked over the layers of its kind: every
+        # stack has its slot axis second, ``lengths`` [slots] has it first
         def write_slot(pool: dict, row: dict, i) -> dict:
             return {
                 name: jax.lax.dynamic_update_slice_in_dim(
@@ -1039,8 +1042,7 @@ class DecodePool:
             drec.chunks_ahead = self.chunks_in_flight  # depth reached
             if self._state_row_bytes:
                 drec.state_bytes = (
-                    len(records) * self.cfg.n_layers * 2
-                    * self._state_row_bytes * self.chunk
+                    len(records) * 2 * self._state_row_bytes * self.chunk
                 )
             for _, req in records:
                 if req is not None and req.record is not None:

@@ -1009,60 +1009,85 @@ class TPUDevice:
     def _refuse_what_a_state_cannot_do(self, config: Any) -> None:
         """A model whose cache has a leaf that is not K/V rows cannot be
         served by what aliases, rolls back, ships or shards K/V rows: a
-        fixed-size state per row (attention kind "retention") has no rows
-        at all, and a "cca" cache keeps a fixed tail per row beside its K
-        and V that none of those settings would carry along. Each such
+        fixed-size state per row (every layer power retention) has no rows
+        at all, a "cca" cache keeps a fixed tail per row beside its K and
+        V, and a model with state-space layers among its attention layers
+        keeps a state and a convolution tail per row beside the K/V rows of
+        its attention layers; none of those settings would carry the state
+        or the tail along. What the cache holds is asked of the kinds of
+        layer the model has (``models/transformer.py::MIXERS``). Each such
         setting is refused here, by name, at boot: none of them may give a
         wrong answer instead. So is ``MODEL_QUANT`` for a model whose
-        experts are stacked leaves the quantiser does not take."""
+        experts are stacked leaves, or whose layers are stacked per kind,
+        neither of which the quantiser takes."""
         from gofr_tpu.models.llama import CONFIGS
+        from gofr_tpu.models.transformer import MIXERS
 
         cfg = CONFIGS.get(self.model_name)
-        kind = getattr(cfg, "attn_kind", "softmax")
-        if getattr(cfg, "ffn_kind", "dense") == "moe" and self.quant:
+        kinds = tuple(getattr(cfg, "kinds_present", ("softmax",)))
+        mixed = len(kinds) > 1
+        if self.quant and (getattr(cfg, "ffn_kind", "dense") == "moe" or mixed):
             raise ValueError(
                 f"MODEL_QUANT is not supported for MODEL_NAME '{self.model_name}': "
-                "the quantiser does not take expert-stacked leaves"
+                "the quantiser does not take "
+                + ("layers stacked per kind" if mixed else "expert-stacked leaves")
             )
-        if kind not in ("retention", "cca"):
+        if mixed and self._lora_adapters:
+            raise ValueError(
+                f"LORA_ADAPTERS is not supported for MODEL_NAME '{self.model_name}': "
+                "an adapter wraps one stack of layers, and this model's are stacked per kind"
+            )
+        leaves = {name for kind in kinds for name in MIXERS[kind].cache}
+        if leaves <= {"k", "v"}:
             return
-        state = kind == "retention"
+        # which of the three reasons applies: 0 a state and no K/V rows,
+        # 1 a tail beside K/V rows, 2 a state beside K/V rows
+        case = 0 if "k" not in leaves else 1 if kinds == ("cca",) else 2
         stated = (config.get("KV_TRANSFER") or "").strip().lower()
         blocks = ("the paged arena holds K/V blocks",
-                  "the paged arena holds K/V blocks and would drop the tail")
+                  "the paged arena holds K/V blocks and would drop the tail",
+                  "the paged arena holds K/V blocks and would drop the state")
         rollback = ("speculation rolls a cache back by length; a state has no length",
                     "speculation rolls a cache back by length; the tail of the token "
+                    "rolled back to is gone",
+                    "speculation rolls a cache back by length; the state of the token "
                     "rolled back to is gone")
         wire = ("the wire format carries K/V blocks",
-                "the wire format carries K/V blocks, not the tail")
-        moves = ("prefill/decode disaggregation moves K/V over the wire",) * 2
-        refused = {  # setting -> (is it on, (why a state, why a cache with a tail, cannot serve it))
+                "the wire format carries K/V blocks, not the tail",
+                "the wire format carries K/V blocks, not the state")
+        moves = ("prefill/decode disaggregation moves K/V over the wire",) * 3
+        refused = {  # setting -> (is it on, why each of the three cases cannot serve it)
             "PREFIX_CACHE": (self._prefix_cache_size > 0, (
                 "prefix sharing aliases K/V rows; a state would need snapshots",
                 "prefix sharing aliases K/V rows; a shared prefix would need the tail "
+                "at its last token",
+                "prefix sharing aliases K/V rows; a shared prefix would need the state "
                 "at its last token")),
             "KV_BLOCKS": (self._kv_paged and self._kv_blocks_cfg > 0, blocks),
             "KV_HBM_BUDGET_MB": (self._kv_paged and self._kv_budget_mb > 0, blocks),
             "DRAFT_MODEL_NAME": (bool(self._draft_name), rollback),
             "SPEC_POOLED": (self._spec_pooled, rollback),
-            # float8 is a K/V type: a "cca" cache's K and V take it (the
-            # tail stays in the model's type)
-            "MODEL_KV_DTYPE": (state and self._kv_dtype == jnp.float8_e4m3fn, (
-                "f8 is a K/V type; a state takes float32 (unset) or bf16", "")),
+            # float8 is a K/V type: K and V beside a tail or a state take
+            # it (the tail stays in the model's type, the state float32)
+            "MODEL_KV_DTYPE": (case == 0 and self._kv_dtype == jnp.float8_e4m3fn, (
+                "f8 is a K/V type; a state takes float32 (unset) or bf16", "", "")),
             "KV_TRANSFER": (stated not in ("", "off"), wire),
             "KV_TRANSFER_TRUST_HINT": (self.kv_hint_trusted, wire),
             "FLEET_ROLE": (self.role != "mixed", moves),
             "TPU_MESH": (_parse_mesh_request(self._mesh_request) is not None, (
                 "the state is not yet sharded over a mesh (by kv head under tp)",
-                "neither the tail nor the expert stacks are sharded over a mesh yet")),
+                "neither the tail nor the expert stacks are sharded over a mesh yet",
+                "neither the state nor the per-kind parameter stacks are sharded "
+                "over a mesh yet")),
         }
-        what = ("whose cache is a retention state" if state else
-                "whose cache keeps a tail per row beside its K/V rows")
+        what = ("whose cache is a retention state",
+                "whose cache keeps a tail per row beside its K/V rows",
+                "whose cache holds a state per row beside its K/V rows")[case]
         for name, (on, why) in refused.items():
             if on:
                 raise ValueError(
                     f"{name} is not supported for MODEL_NAME '{self.model_name}', "
-                    f"{what}: {why[0 if state else 1]}"
+                    f"{what}: {why[case]}"
                 )
         # unset, KV transfer is armed by default; here there is nothing to send
         self.kv_transfer_enabled = False
@@ -3231,7 +3256,10 @@ class _TransformerRunner:
             # int8 only with a smaller KV allocation than the model's full
             # context (MODEL_MAX_SEQ config key)
             overrides["max_seq"] = max_seq
-        retention = self.cfg.attn_kind == "retention"
+        # of a model whose every layer is power retention: a model with
+        # layers of two kinds keeps its state float32 and MODEL_KV_DTYPE is
+        # what its K and V take
+        retention = self.cfg.kinds_present == ("retention",)
         if kv_dtype is not None and (retention or kv_dtype != jnp.bfloat16):
             # MODEL_KV_DTYPE=bf16 has always meant "a K/V cache in the
             # model's type"; only a retention state (float32 when
@@ -3246,8 +3274,15 @@ class _TransformerRunner:
 
             self.cfg = dataclasses.replace(self.cfg, **overrides)
         self.decode_chunk_size = decode_chunk
+        # asked of the cache's leaves, whatever kinds of layer made them:
+        # a row that holds a large fixed-size state (Brumby's 0.27 GB; a
+        # state-space row's 9 MB is none) has its chunked prefills gated
+        # (``_chunked_prefill``)
+        from gofr_tpu.models.transformer import state_row_bytes
+
+        row_state = state_row_bytes(jax.eval_shape(lambda: init_cache(self.cfg, 1)))
         self._state_prefill_gate = (
-            threading.BoundedSemaphore(2) if retention else None
+            threading.BoundedSemaphore(2) if row_state >= _STATE_GATE_BYTES else None
         )
         # mesh-fit validation BEFORE the params exist: a tp axis that
         # cannot divide the head count (or a dp/fsdp product the padded
@@ -5397,9 +5432,22 @@ def _note_routing(drec: Any, ids: np.ndarray, rows: int, cfg: Any) -> np.ndarray
     return ids
 
 
+# a row whose fixed-size state is at least this large has its chunked
+# prefills gated: two prompts hold rows at once and a slice waits for the
+# one before the one just issued (``_chunked_prefill``). What the gate
+# bounds is the memory of slices issued at once, each holding a row: a
+# 16-slice prompt (MODEL_MAX_SEQ 2048 in buckets of 128) of rows under 64
+# MiB holds under 1 GiB ungated. The two readings behind it (PERF.md, PRs
+# 28 and 36): Brumby's 0.27 GB row took 3.3 GB for one 12-slice prompt
+# ungated; a state-space row of 9 MB, ungated, raised the peak by 0.18 GB
+# with 66 requests at once. No row between the two has been measured.
+_STATE_GATE_BYTES = 64 << 20
+
+
 def _slice_cache(cache: dict, i: int) -> dict:
-    """Row ``i`` of every leaf: the row axis is the second of a stack
-    (K and V, or a retention model's S and z) and the first of ``lengths``."""
+    """Row ``i`` of every leaf: the row axis is the second of a stack (K
+    and V, a state, a tail: whatever kinds of layer the model has, each
+    stacked over the layers of its kind) and the first of ``lengths``."""
     return {
         name: leaf[i : i + 1] if leaf.ndim == 1 else leaf[:, i : i + 1]
         for name, leaf in cache.items()
@@ -5407,8 +5455,10 @@ def _slice_cache(cache: dict, i: int) -> dict:
 
 
 def _cache_max_len(cache: dict, cfg: Any) -> int:
-    """Positions a row can hold: the K/V cache's length axis, or for a
-    state, which has none, the model's ``max_seq`` (the rotary table)."""
+    """Positions a row can hold: the length axis of its K/V rows (a cache
+    that holds a state beside them is bound by them too), or for a cache
+    that is a state alone, which has none, the model's ``max_seq`` (the
+    rotary table)."""
     return int(cache["k"].shape[3]) if "k" in cache else int(cfg.max_seq)
 
 

@@ -299,3 +299,107 @@ def test_prefill_of_an_expert_model_compiles_at_the_cells_buckets(one_chip, as_o
     # of the fast memory, where the loop then keeps it) and in no loop
     movers = _cache_movers(hlo, rows, ZCFG)
     assert set(movers) <= {"ENTRY"} and len(movers.get("ENTRY", [])) <= 2, movers
+
+
+# -- layers of two kinds in one stack: state-space mixers among attention layers ----------------
+# AI21-Jamba2-3B's widths and depth (benchmark/configs: 28 layers, attention
+# at 7 and 21, 20 query heads on ONE kv head, no rotary) with a small
+# vocabulary; the cell's 64 slots
+
+JCFG = T.TransformerConfig(
+    vocab_size=4096, dim=2560, n_layers=28, n_heads=20, n_kv_heads=1, hidden_dim=8192,
+    max_seq=2048, rope_fraction=0.0, norm_eps=1e-6, ssm_dt_rank=160, tie_embeddings=True,
+    layer_kinds=tuple("softmax" if i % 14 == 7 else "ssm" for i in range(28)),
+)
+JSLOTS = 64
+# a weight stack of either kind, and one layer of it (not the two attention
+# layers' q and o projections, 26 MB a stack: the compiler relays those once
+# where a program begins and parks a layer of them in the fast memory)
+_JWEIGHTS = {f"bf16[{n},{shape}]" for n in (26, 2, 1) for shape in (
+    "2560,10240", "5120,2560", "2560,8192", "8192,2560")}
+
+
+def _hybrid_movers(hlo: str, batch: int) -> dict[str, list[str]]:
+    """computation name -> the instructions in it that write, by moving
+    data, a result of the shape of a whole cache leaf (the state, the
+    convolution tail, K or V), of a layer of the state, or of a weight
+    stack. A stack's in-place update comes as a fusion of the stack's shape
+    named ``*dynamic-update-slice*`` (``bitcast_dynamic-update-slice_fusion``)
+    and is no move; the compiler's own prefetches of a small stack into the
+    fast memory (``copy-start`` / ``copy-done``) are counted apart."""
+    state = f"{batch},{JCFG.ssm_state},{JCFG.d_inner}"
+    leaves = {f"f32[26,{state}]", f"f32[1,{state}]", f"f32[{state}]",
+              f"bf16[26,{batch},{3 * JCFG.d_inner}]",
+              f"bf16[2,{batch},1,{JCFG.max_seq},128]"} | _JWEIGHTS
+    found: dict[str, list[str]] = {}
+    computation = ""
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            computation = "ENTRY" if head.group(1) else head.group(2)
+            continue
+        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
+        # inside a fused computation nothing is written out: a layer's weights
+        # sliced there feed the product they are fused with
+        if (not match or match.group(2) not in leaves
+                or computation.startswith("fused_computation")):
+            continue
+        name, opcode = match.group(1), match.group(3)
+        if opcode in ("copy", "transpose", "reshape", "slice", "dynamic-slice") or (
+                opcode == "fusion" and not _updates_in_place(hlo, name)):
+            found.setdefault(computation, []).append(name)
+    return found
+
+
+def _updates_in_place(hlo: str, fusion: str) -> bool:
+    """Whether the fusion ``fusion`` is rooted in a dynamic-update-slice of
+    its own operand: the in-place write of one layer's rows into a stack."""
+    called = re.search(rf"%{re.escape(fusion)} = .*calls=%([\w.\-]+)", hlo)
+    if not called:
+        return False
+    body = hlo.split(f"%{called.group(1)} (", 1)[1].split("\n}\n", 1)[0]
+    root = [line for line in body.splitlines() if "ROOT" in line]
+    return bool(root) and "dynamic-update-slice(" in root[0]
+
+
+def _jamba_params():
+    return T.init_transformer(jax.random.key(0), JCFG)
+
+
+def test_pooled_chunk_of_a_hybrid_model_leaves_state_tail_and_weights_where_they_lie(
+        one_chip, as_on_tpu):
+    hlo = _compiled(
+        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
+            p, t, c, JCFG, 8, key, temp, tk, tp, mp),
+        (2, 3), one_chip,
+        _jamba_params, jnp.zeros((JSLOTS, 1), jnp.int32),
+        lambda: T.init_cache(JCFG, JSLOTS), lambda: jax.random.key(0),
+        jnp.zeros((JSLOTS,), jnp.float32), jnp.zeros((JSLOTS,), jnp.int32),
+        jnp.zeros((JSLOTS,), jnp.float32), jnp.zeros((JSLOTS,), jnp.float32),
+    )
+    # one state-space body a run of the PERIOD (7 and 6 layers: two step
+    # kernels) and the decode form of flash attention at a group of 20
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 3
+    assert "ssm_step" in hlo and "ssm_scan" not in hlo
+    # the cache is donated through the chunk: the step kernel reads and
+    # writes its layer of the state in place, the tail's rows are written
+    # into their stack, and no weight stack is sliced into a copy
+    assert _hybrid_movers(hlo, JSLOTS) == {}
+
+
+@pytest.mark.parametrize("rows,bucket", [(2, 256), (1, 256), (2, 128)])
+def test_prefill_of_a_hybrid_model_compiles_at_the_cells_buckets(one_chip, as_on_tpu, rows, bucket):
+    hlo = _compiled(
+        lambda p, t, c, l: T.prefill(p, t, c, JCFG, l), (), one_chip,
+        _jamba_params, jnp.zeros((rows, bucket), jnp.int32),
+        lambda: T.init_cache(JCFG, rows), jnp.zeros((rows,), jnp.int32),
+    )
+    # two chunked-scan kernels (the period's runs) and the prefill form of
+    # flash attention, the one kv head's block revisited by 20 query heads
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 3
+    assert "ssm_scan" in hlo and "ssm_step" not in hlo
+    # called as the server calls it, the caller keeping its cache: every
+    # leaf is copied where it enters (a row or two: 11 MB a row; the one-row
+    # tail, 0.8 MB, twice) and in no loop
+    movers = _hybrid_movers(hlo, rows)
+    assert set(movers) <= {"ENTRY"} and len(movers.get("ENTRY", [])) <= 6, movers
