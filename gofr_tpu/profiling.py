@@ -39,6 +39,7 @@ POOL_FETCH_WAIT = "gofr.pool.fetch_wait"
 POOL_DELIVER = "gofr.pool.deliver"
 POOL_WAIT_WORK = "gofr.pool.wait_work"
 POOL_STATE_INSERT = "gofr.pool.state_insert"
+POOL_SEAT_WAIT = "gofr.pool.seat_wait"
 SOLO_ISSUE = "gofr.solo.issue"
 SOLO_FETCH_WAIT = "gofr.solo.fetch_wait"
 SSE_FIRST_FRAME = "gofr.sse.first_frame"

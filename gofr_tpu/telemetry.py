@@ -264,6 +264,7 @@ class FlightRecord:
         "t_first_token", "t_last_token", "t_done", "wall_done", "_lock",
         "t_pool_admit", "t_first_frame",
         "t_state_insert", "t_state_inserted",
+        "t_seat_wait", "t_seated",
         # the recorder's in-flight index holds records WEAKLY (an
         # abandoned record must vanish with its request, not leak)
         "__weakref__",
@@ -308,7 +309,9 @@ class FlightRecord:
         self.prefill_chunks = 0  # bounded-compute prefill dispatches
         self.prefill_bucket = 0  # widest compiled bucket the prefill rode
         self.sched_defer_s = 0.0  # total interference-scheduler defer
-        self.pool_reject_reason = ""  # why the decode pool refused (solo'd)
+        # why the decode pool refused (the request decoded solo); a full
+        # pool refuses nothing: the request waits for a seat (t_seat_wait)
+        self.pool_reject_reason = ""
         self.dispatch_ids: list[int] = []  # device dispatches this rode
         # pooled speculative decoding (tpu/spec_pool.py): draft tokens
         # proposed/accepted and the verify dispatches + tokens they
@@ -347,8 +350,9 @@ class FlightRecord:
         self.t_enqueue: Optional[float] = None
         self.t_dispatch: Optional[float] = None
         self.t_first_token: Optional[float] = None
-        # the decode pool gave this request a slot, or refused it (the
-        # request then decodes solo); unset on paths that never asked
+        # the decode pool gave this request a slot, after whatever wait
+        # for one, or refused it (the request then decodes solo); unset
+        # on paths that never asked
         self.t_pool_admit: Optional[float] = None
         # the first token's frame was handed to the socket (streams only:
         # the responder's write returned) — t_first_token is when the
@@ -360,6 +364,11 @@ class FlightRecord:
         # copy's device time is the trace's)
         self.t_state_insert: Optional[float] = None
         self.t_state_inserted: Optional[float] = None
+        # the pool was full and this prefilled request waited for a seat:
+        # profiling.phase POOL_SEAT_WAIT stamps both; unset on a request
+        # that found a seat at once
+        self.t_seat_wait: Optional[float] = None
+        self.t_seated: Optional[float] = None
         self.t_last_token: Optional[float] = None
         self.t_done: Optional[float] = None
         self.wall_done: Optional[float] = None
@@ -421,8 +430,11 @@ class FlightRecord:
                 self.dispatch_ids.append(dispatch_id)
 
     def note_pool_reject(self, reason: str) -> None:
-        """The decode pool refused this request (it decoded solo); the
-        FIRST rejection reason is kept — later fan-out candidates may
+        """The decode pool refused this request, for a reason no finishing
+        request cures (an executable it does not run beside the others, a
+        closed pool, a hopeless deadline): it decoded solo, or was shed. A
+        full pool is no refusal: the request waits (``pool_seat_wait_s``).
+        The FIRST rejection reason is kept — later fan-out candidates may
         see a different pool state."""
         if not self.pool_reject_reason:
             self.pool_reject_reason = reason
@@ -583,6 +595,7 @@ class FlightRecord:
             "prefill_s": self.prefill,
             "first_frame_s": between(self.t_first_token, self.t_first_frame),
             "pool_admit_s": between(self.t_first_token, self.t_pool_admit),
+            "pool_seat_wait_s": between(self.t_seat_wait, self.t_seated),
             "state_insert_s": between(self.t_state_insert, self.t_state_inserted),
             "server_ttft_s": between(self.t_start, self.t_first_frame),
             "ttft_s": self.ttft,

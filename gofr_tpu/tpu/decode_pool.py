@@ -27,6 +27,13 @@ Mechanics:
 - inactive slots decode garbage in lockstep (fixed shapes = one compiled
   executable) and are overwritten on reuse;
 - per-request host-tracked lengths stop a request at the cache bound;
+- a prefilled request that finds every slot taken (or the KV ledger
+  spent on pooled rows) WAITS for a seat, in arrival order, holding its
+  one-row cache: the worker seats it before its next dispatch once a
+  finishing request has freed a slot, so it rides the second chunk after
+  that delivery, as a request submitted to a free slot does. At most
+  ``standing_room`` prefilled requests wait so; ``gate()`` holds the
+  ones past that before their prefill, with nothing on the device;
 - requests with an explicit sampling seed bypass the pool (the
   per-request path reproduces exactly; pooled key order depends on
   co-tenants);
@@ -39,6 +46,7 @@ Mechanics:
 from __future__ import annotations
 
 import contextlib
+import functools
 import queue
 import threading
 from collections import deque
@@ -60,6 +68,7 @@ from gofr_tpu.profiling import (
     POOL_DELIVER,
     POOL_FETCH_WAIT,
     POOL_ISSUE,
+    POOL_SEAT_WAIT,
     POOL_STATE_INSERT,
     POOL_WAIT_WORK,
     phase,
@@ -71,6 +80,10 @@ DONE = object()  # end-of-stream marker on a slot's token queue
 # expired mid-decode: the consumer re-raises DeadlineExceeded instead
 # of treating the truncated stream as a clean finish
 DEADLINE = object()
+# how often a request waiting for a seat, or for a place before its
+# prefill, looks at its stop event and its deadline (a seat itself wakes it
+# at once)
+_WAIT_POLL_S = 0.05
 
 
 class PoolFailure:
@@ -145,6 +158,27 @@ class _Request:
         self.pending = int(pending)
 
 
+class _Waiter:
+    """A prefilled request that stands waiting for a seat: its request and
+    what else ``submit`` was given to seat it with. ``settled`` is set under
+    the pool lock by whoever ends the wait (the worker that seated or
+    refused it, a failing pool, the waiter itself on leaving); ``error`` is
+    what ``submit`` then raises, None once seated."""
+
+    __slots__ = ("request", "row_cache", "sampler", "penalty", "adapter",
+                 "settled", "error")
+
+    def __init__(self, request: _Request, row_cache: dict, sampler: Any,
+                 penalty: Optional[tuple], adapter: Optional[str]):
+        self.request = request
+        self.row_cache = row_cache
+        self.sampler = sampler
+        self.penalty = penalty
+        self.adapter = adapter
+        self.settled = threading.Event()
+        self.error: Optional[BaseException] = None
+
+
 class _Slot:
     __slots__ = ("index", "request")
 
@@ -171,6 +205,7 @@ class DecodePool:
         watchdog: Any = None,
         kv: Any = None,
         spec: Any = None,
+        standing_room: int = 2,
     ):
         from gofr_tpu.models.transformer import decode_chunk_pool
 
@@ -346,6 +381,17 @@ class DecodePool:
         self._slots = [_Slot(i) for i in range(n_slots)]
         self._free = list(reversed(self._slots))
         self._active: dict[int, _Slot] = {}
+        # prefilled requests waiting for a seat, in arrival order; each
+        # holds one row's cache on the device, so ``gate()`` lets no more
+        # than ``standing_room`` stand here: as many as one prefill dispatch
+        # makes rows (the device passes its BATCH_MAX_SIZE; no
+        # configuration key of its own)
+        self._waiters: deque = deque()
+        self.standing_room = standing_room
+        # the gate: places not taken, and the turns of the requests
+        # waiting for one before their prefill (each a threading.Event)
+        self._places_free = n_slots + standing_room
+        self._gate_line: deque = deque()
         self._temps = np.zeros(n_slots, np.float32)
         self._top_ks = np.zeros(n_slots, np.int32)
         self._top_ps = np.ones(n_slots, np.float32)
@@ -408,9 +454,21 @@ class DecodePool:
         )
         # submit rejections by reason: this counter (and the
         # FlightRecord's pool_reject_reason) says WHY a stream missed the
-        # pool and decoded solo
+        # pool and decoded solo. A full pool is not among them: the
+        # request waits, and the histogram below says for how long
         self._reject_counter = (
             pool_reject_counter(metrics)
+            if metrics is not None
+            else None
+        )
+        self._seat_wait_hist = (
+            metrics.histogram(
+                "gofr_tpu_pool_seat_wait_seconds",
+                "time a prefilled request waited for a decode slot while "
+                "the pool was full (requests seated at once are not "
+                "observed)",
+                labels=("model",),
+            )
             if metrics is not None
             else None
         )
@@ -676,9 +734,20 @@ class DecodePool:
         want_kv: bool = False,
         spec_ctx: Optional[Any] = None,
     ) -> "queue.Queue":
-        """Claim a slot for a prefilled request; returns the queue its
-        decoded token ids (then DONE) arrive on. Raises queue.Full when all
-        slots are busy — callers fall back to the solo decode path.
+        """Seat a prefilled request; returns the queue its decoded token
+        ids (then DONE) arrive on. With a slot and the request's KV budget
+        free the seat is taken at once. With the pool FULL the call waits
+        for one, in arrival order: the request keeps its one-row cache, the
+        worker seats it before its next dispatch once a finishing request
+        has freed a slot and budget, and the call returns then. The wait
+        ends without a seat when ``stop`` is set (the queue comes back
+        holding DONE alone), when the deadline can no longer cover a chunk
+        (``DeadlineExceeded``, accounted as at the gate below), and when
+        the pool closes or dies (``RuntimeError``: the caller decodes
+        solo, as after a submit to a closed pool).
+
+        Raises queue.Full for what no finishing request cures; the caller
+        decodes such a request solo:
 
         ``penalty`` pools a penalized request: (presence_row [1, V] bool,
         counts_row [1, V] f32, bias_row [1, V] f32, repetition_penalty,
@@ -692,7 +761,12 @@ class DecodePool:
         The name resolves against the CURRENT bank under the lock — never
         a stale pre-checked index. Raises queue.Full when the bank is
         off/rebuilding, the name is unknown to the bank, or a penalized
-        slot is active (the chunk runs ONE executable; the mix solos).
+        slot is active (the chunk runs ONE executable; the mix solos). A
+        request that waited is asked again as it is seated.
+
+        A KV ledger that is spent while NO row is pooled (something else
+        holds it: live paged sequences, an operator's claim) is refused
+        with ``kv_exhausted`` too: no finishing request would return it.
 
         ``spec_ctx`` (prompt token ids) arms pooled speculative decoding
         for this request when the pool has a spec config and the request
@@ -706,67 +780,199 @@ class DecodePool:
             spec_ctx, first_token, sampler, penalty, adapter,
             want_logprobs, want_top_logprobs,
         )
+        waiter = _Waiter(
+            _Request(out, max_new, start_len, stop,
+                     frozenset(stop_tokens or ()),
+                     want_lp=want_logprobs, want_top=want_top_logprobs,
+                     want_kv=want_kv, record=current_record(),
+                     journal=current_journal_entry(),
+                     deadline=deadline, spec=spec_state,
+                     pending=first_token),
+            row_cache, sampler, penalty, adapter,
+        )
         with self._work:
             if self._closed:
                 self._reject("closed", count_only=True)
                 raise RuntimeError("decode pool closed")
             self._admit_deadline(deadline)
             adapter_idx = self._admit(adapter, penalty)
-            if not self._free:
-                self._reject("no_free_slots", "no free decode slots")
-            kv_reserved = self._reserve_kv(start_len, max_new)
-            slot = self._free.pop()
-            record = current_record()
-            slot.request = _Request(out, max_new, start_len, stop,
-                                    frozenset(stop_tokens or ()),
-                                    want_lp=want_logprobs,
-                                    want_top=want_top_logprobs,
-                                    want_kv=want_kv, record=record,
-                                    kv_reserved=kv_reserved,
-                                    journal=current_journal_entry(),
-                                    deadline=deadline, spec=spec_state,
-                                    pending=first_token)
-            if record is not None and kv_reserved:
-                record.note_kv(kv_reserved)
-            self._apply_sampling(slot.index, sampler)
-            if spec_state is not None:
-                # a fresh request's context may draft where the current
-                # cohort's could not — re-open the spec window
-                self._spec_idle = 0
-            if adapter_idx:
-                self._lora_ids[slot.index] = adapter_idx
-                self._lora_dirty = True
-                self._lora_slots.add(slot.index)
-            if penalty is not None:
-                self._apply_penalty(slot.index, penalty)
-            # cache/token writes happen under the lock: jax sequences them
-            # after any in-flight chunk (their inputs are its outputs), so
-            # the new request's first real decode lands in the next
-            # dispatched chunk
-            with phase(POOL_STATE_INSERT, record, start="t_state_insert",
-                       end="t_state_inserted"):
-                self.cache = self._write_slot(self.cache, row_cache, slot.index)
-            self._last_tokens = self._write_token(
-                self._last_tokens, jnp.asarray([[first_token]], jnp.int32), slot.index
-            )
-            self._active[slot.index] = slot
-            if record is not None:
-                # flight record: this request decodes pooled, alongside
-                # len(_active)-1 co-tenants
-                record.mark_pooled(len(self._active))
-            if self._depth_gauge:
-                self._depth_gauge.set(len(self._active))
-            self._work.notify()
+            # behind whoever already waits, even past a slot a delivery
+            # has just freed: seats are taken in arrival order
+            if not self._waiters and self._seat(waiter, adapter_idx):
+                self._work.notify()
+                return out
+            self._waiters.append(waiter)
+        self._await_seat(waiter)
         return out
 
-    def _reserve_kv(self, start_len: int, max_new: int) -> int:
+    def _seat(self, waiter: _Waiter, adapter_idx: int) -> bool:
+        """Seat a prefilled request if a slot and its KV budget are free
+        (pool lock held): the reservation, the slot's knobs, the row and
+        token writes, the marks. False when it has to wait: no slot, or
+        the ledger spent on rows whose finish returns it."""
+        if not self._free:
+            return False
+        req = waiter.request
+        record = req.record
+        kv_reserved = self._reserve_kv(req.cache_len, req.remaining, record)
+        if kv_reserved is None:
+            return False
+        slot = self._free.pop()
+        slot.request = req
+        req.kv_reserved = kv_reserved
+        if record is not None and kv_reserved:
+            record.note_kv(kv_reserved)
+        self._apply_sampling(slot.index, waiter.sampler)
+        if req.spec is not None:
+            # a fresh request's context may draft where the current
+            # cohort's could not — re-open the spec window
+            self._spec_idle = 0
+        if adapter_idx:
+            self._lora_ids[slot.index] = adapter_idx
+            self._lora_dirty = True
+            self._lora_slots.add(slot.index)
+        if waiter.penalty is not None:
+            self._apply_penalty(slot.index, waiter.penalty)
+        # cache/token writes happen under the lock: jax sequences them
+        # after any in-flight chunk (their inputs are its outputs), so
+        # the new request's first real decode lands in the next
+        # dispatched chunk
+        with phase(POOL_STATE_INSERT, record, start="t_state_insert",
+                   end="t_state_inserted"):
+            self.cache = self._write_slot(
+                self.cache, waiter.row_cache, slot.index)
+        self._last_tokens = self._write_token(
+            self._last_tokens,
+            jnp.asarray([[req.pending]], jnp.int32), slot.index,
+        )
+        self._active[slot.index] = slot
+        if record is not None:
+            # flight record: this request decodes pooled, alongside
+            # len(_active)-1 co-tenants
+            record.mark_pooled(len(self._active))
+        if self._depth_gauge:
+            self._depth_gauge.set(len(self._active))
+        return True
+
+    def _seat_waiters(self) -> None:
+        """Seat who waits, in arrival order, while slots are free (pool
+        lock held; the worker, before it dispatches): a row freed when
+        chunk k is delivered is written here and rides chunk k+2, k+1
+        being queued already. The head of the line keeps its place while
+        the KV ledger cannot cover it. One the pool can no longer run
+        beside its rows (an executable mix that arose while it waited) is
+        refused as it would have been on arrival."""
+        while self._waiters and self._free:
+            waiter = self._waiters[0]
+            try:
+                if not self._seat(waiter, self._admit(
+                        waiter.adapter, waiter.penalty,
+                        waiter.request.record)):
+                    return
+            except queue.Full as exc:
+                waiter.error = exc
+            self._waiters.popleft()
+            waiter.settled.set()
+
+    def _await_seat(self, waiter: _Waiter) -> None:
+        """The caller's side of the wait (no lock held): block until the
+        worker settles the waiter, looking every ``_WAIT_POLL_S`` at what
+        ends the wait without a seat."""
+        req = waiter.request
+        started = _perf_counter()
+        try:
+            with phase(POOL_SEAT_WAIT, req.record, start="t_seat_wait",
+                       end="t_seated"):
+                while not waiter.settled.wait(_WAIT_POLL_S):
+                    self._leave_if_hopeless(waiter)
+        finally:
+            if self._seat_wait_hist is not None:
+                self._seat_wait_hist.observe(
+                    _perf_counter() - started, model=self._model)
+        if waiter.error is not None:
+            raise waiter.error
+
+    def _leave_if_hopeless(self, waiter: _Waiter) -> None:
+        """Take the waiter out of the line if its client has gone (its
+        queue gets DONE) or its deadline can no longer cover a chunk
+        (``_admit_deadline`` accounts and raises); the caller's thread."""
+        req = waiter.request
+        with self._work:
+            if waiter.settled.is_set():
+                return  # seated, or failed, while this thread took the lock
+            if req.stop is None or not req.stop.is_set():
+                try:
+                    self._admit_deadline(req.deadline)
+                except DeadlineExceeded:
+                    self._waiters.remove(waiter)
+                    raise
+                return
+            self._waiters.remove(waiter)
+            req.out_queue.put(DONE)
+            waiter.settled.set()
+
+    def _fail_waiters(self) -> None:
+        """The pool closed or died (pool lock held): whoever waits for a
+        seat leaves as a submit to a closed pool does."""
+        while self._waiters:
+            waiter = self._waiters.popleft()
+            self._reject("closed", count_only=True,
+                         record=waiter.request.record)
+            waiter.error = RuntimeError("decode pool closed")
+            waiter.settled.set()
+
+    @contextlib.contextmanager
+    def gate(self, stop: Optional[threading.Event] = None) -> Any:
+        """One of the pool's ``n_slots + standing_room`` places, held from
+        before a request's prefill to its end: the requests past them
+        wait HERE, in arrival order and with nothing on the device, so
+        that no more than ``standing_room`` prefilled rows ever stand
+        waiting for a seat. The wait gives up, and the request goes on
+        without a place, when its ``stop`` is set, its deadline has run
+        out or the pool is closed: the paths behind end such a request
+        (the stream's own stop, the batcher's deadline shed, the closed
+        pool's refusal)."""
+        deadline = current_deadline()
+        turn = threading.Event()  # set, under the pool lock, with the place
+        with self._work:
+            if self._places_free and not self._gate_line:
+                self._places_free -= 1
+                turn.set()
+            else:
+                self._gate_line.append(turn)
+        while not turn.wait(_WAIT_POLL_S):
+            if (
+                self._closed
+                or (stop is not None and stop.is_set())
+                or (deadline is not None and deadline.expired())
+            ):
+                with self._work:
+                    if not turn.is_set():  # else a place came meanwhile
+                        self._gate_line.remove(turn)
+                        break
+        try:
+            yield
+        finally:
+            if turn.is_set():
+                with self._work:
+                    if self._gate_line:  # to whoever has waited longest
+                        self._gate_line.popleft().set()
+                    else:
+                        self._places_free += 1
+
+    def _reserve_kv(self, start_len: int, max_new: int,
+                    record: Any) -> Optional[int]:
         """Reserve the request's whole KV block budget (pool lock held):
         prompt + first token + every decode step it may take, capped at
         the cache bound — a LEDGER claim on the shared BlockPool (the
         bytes themselves live in this pool's slot cache; cached prefix
-        blocks count as reclaimable against the same budget).
-        Exhaustion rejects with the ``kv_exhausted`` reason (distinct
-        from slot/executable-mix rejects), and the caller's solo
+        blocks count as reclaimable against the same budget). None when
+        the ledger cannot cover it while rows are pooled: their finish
+        returns budget, so the request waits (the BlockPool counts the
+        failed reservation, ``kv_exhausted_rejects``, which is what the
+        fleet's prober reads as saturation). With no row pooled nothing
+        here returns budget: that is the ``kv_exhausted`` refusal
+        (distinct from the executable-mix ones), and the caller's solo
         fallback serves the request."""
         if self._kv is None:
             return 0
@@ -777,7 +983,12 @@ class DecodePool:
                 min(start_len + 1 + max_new, self.max_len)
             )
         except KVExhausted as exc:
-            self._reject("kv_exhausted", f"KV block budget exhausted: {exc}")
+            if not self._active:
+                self._reject(
+                    "kv_exhausted", f"KV block budget exhausted: {exc}",
+                    record=record,
+                )
+            return None
 
     def _admit_deadline(self, deadline: Any) -> None:
         """Deadline admission gate (pool lock held): a request whose
@@ -814,42 +1025,46 @@ class DecodePool:
             f"{self._chunk_ema_s * 1000:.0f} ms)", stage="admission",
         )
 
-    def _admit(self, adapter: Optional[str], penalty: Optional[tuple]) -> int:
+    def _admit(self, adapter: Optional[str], penalty: Optional[tuple],
+               record: Any = None) -> int:
         """The submit reject gates (pool lock held): raises queue.Full
         via ``_reject`` on any executable-mix or readiness conflict.
-        Returns the adapter's bank index (0 = base weights)."""
+        Returns the adapter's bank index (0 = base weights). ``record``
+        is the request's when another thread asks for it (the worker, as
+        it seats a request that waited)."""
+        reject = functools.partial(self._reject, record=record)
         adapter_idx = 0
         if adapter is not None:
             if penalty is not None:
-                self._reject(
+                reject(
                     "penalized_adapter",
                     "penalized adapter requests decode solo",
                 )
             if not self._lora_ready:
-                self._reject(
+                reject(
                     "bank_rebuilding", "adapter bank off or rebuilding"
                 )
             if self._pen_slots:
-                self._reject(
+                reject(
                     "penalized_mix",
                     "penalized slots active (one executable per chunk)",
                 )
             idx = self._lora_index.get(adapter)
             if idx is None:
-                self._reject(
+                reject(
                     "unknown_adapter",
                     f"adapter '{adapter}' not in the pool bank",
                 )
             adapter_idx = idx
         if penalty is not None and self._lora_slots:
-            self._reject(
+            reject(
                 "adapter_mix",
                 "adapter slots active (one executable per chunk)",
             )
         if penalty is not None and not self._pen_ready:
             if self._pen_mode == "lazy":
                 self._pen_kick()
-            self._reject(
+            reject(
                 "penalties_off" if self._pen_mode == "off"
                 else "penalties_warming",
                 "penalized pool path "
@@ -887,13 +1102,17 @@ class DecodePool:
         self._pen_dirty = True
         self._pen_slots.add(index)
 
-    def _reject(self, reason: str, msg: str = "", count_only: bool = False):
-        """Account a submit rejection (counter + the caller's flight
-        record) and raise ``queue.Full`` unless ``count_only`` — the
-        device's fallback path then decodes the request solo."""
+    def _reject(self, reason: str, msg: str = "", count_only: bool = False,
+                record: Any = None):
+        """Account a submit rejection (counter + the request's flight
+        record: the calling thread's, or ``record`` where the worker
+        refuses a request that waited) and raise ``queue.Full`` unless
+        ``count_only`` — the device's fallback path then decodes the
+        request solo. A full pool is never a rejection: that request
+        waits for a seat (``submit``)."""
         if self._reject_counter is not None:
             self._reject_counter.inc(reason=reason)
-        record = current_record()
+        record = record or current_record()
         if record is not None:
             record.note_pool_reject(reason)
         if not count_only:
@@ -927,6 +1146,7 @@ class DecodePool:
                 self._timeline.finish(entry[6], status="error")
 
     def _fail_active(self, exc: BaseException) -> None:
+        self._fail_waiters()
         for slot in self._active.values():
             req = slot.request
             if req is not None and not req.finished and req.out_queue is not None:
@@ -965,6 +1185,7 @@ class DecodePool:
         last_fetch_done: float = 0.0
         while True:
             with self._work:
+                self._seat_waiters()
                 while not self._active and not in_flight and not self._closed:
                     with phase(POOL_WAIT_WORK):  # parked: no live slot
                         self._work.wait()
@@ -1161,15 +1382,6 @@ class DecodePool:
             or want_logprobs or want_top_logprobs
             or not getattr(sampler, "greedy", False)
         ):
-            return None
-        if not self._free:
-            # overload fast-out: with no free slot visible the submit
-            # is about to reject — don't pay the O(prompt) context
-            # copies for a request that will solo anyway. The read is
-            # lock-free on purpose; in the rare race where a slot frees
-            # concurrently, the request pools WITHOUT spec state (plain
-            # pooled decode — correctness-neutral) rather than
-            # serializing every overload rejection on the pool lock.
             return None
         return self.spec_cfg.new_state(
             [int(t) for t in spec_ctx], first_token
@@ -1738,6 +1950,8 @@ class DecodePool:
                 "slots": self.n_slots,
                 "active": len(self._active),
                 "free": len(self._free),
+                # prefilled requests waiting for a seat (a full pool)
+                "waiting": len(self._waiters),
                 "chunk": self.chunk,
                 "pipeline_depth": self.pipeline_depth,
                 "lora_slots": len(self._lora_slots),
@@ -1761,5 +1975,8 @@ class DecodePool:
     def close(self) -> None:
         with self._work:
             self._closed = True
+            # the worker fails them too as it leaves; a worker that has
+            # died already cannot
+            self._fail_waiters()
             self._work.notify_all()
         self._thread.join(timeout=5)

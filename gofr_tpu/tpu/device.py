@@ -1190,6 +1190,9 @@ class TPUDevice:
                 timeline=self.timeline,
                 watchdog=self.watchdog,
                 kv=self.kv_pool,
+                # as many prefilled requests may stand waiting for a
+                # seat as one prefill dispatch makes rows
+                standing_room=self.max_batch,
                 # the real pool speculates only with a real draft
                 # source: n-gram. A fake-schedule-only config (echo
                 # tier-1 scaffolding) must not clamp a transformer
@@ -3777,6 +3780,50 @@ class _TransformerRunner:
             from gofr_tpu.ops.sampling import Sampler
 
             sampler = Sampler()  # greedy
+        # a request that will ask the decode pool for a seat holds one of
+        # the pool's places from before its prefill to its end: past the
+        # slots and the standing room it waits here, with nothing on the
+        # device (DecodePool.gate)
+        asks_pool = (
+            decode_pool is not None and not sampler.seeded
+            and max_new_tokens > 1
+            and not self._spec_solo(sampler, logprobs, adapter, decode_pool)
+        )
+        with decode_pool.gate(stop) if asks_pool else contextlib.nullcontext():
+            return self._generate(
+                tokens, max_new_tokens, on_token, stop, sampler, stop_tokens,
+                decode_pool, prefill_batcher, ttft_cb, logprobs,
+                top_logprobs, adapter, adapter_params, scheduler,
+            )
+
+    def _spec_solo(self, sampler: Any, logprobs: bool,
+                   adapter: Optional[str], decode_pool: Any) -> bool:
+        """Whether a request takes the solo draft-and-verify path:
+        requests with a configured draft do (DRAFT_MODEL_NAME opts the
+        deployment into latency mode, so these requests bypass the
+        throughput pool). Greedy emits exactly the target's argmax;
+        sampled (unseeded, k >= 2) uses canonical speculative sampling —
+        the emitted sequence is distributed exactly as the target's
+        warped distribution, whatever the draft proposes. SPEC_POOLED
+        opts the deployment into pooled speculation instead: the solo
+        latency mode stands down and eligible requests speculate THROUGH
+        the pool (which builds their n-gram draft state from spec_ctx)."""
+        return (
+            self.spec is not None and not sampler.penalized
+            and not logprobs and adapter is None
+            and getattr(decode_pool, "spec_cfg", None) is None
+            and (sampler.greedy or (not sampler.seeded and self.spec.k >= 2))
+        )
+
+    def _generate(
+        self, tokens: list[int], max_new_tokens: int, on_token: Any,
+        stop: Any, sampler: Any, stop_tokens: Any, decode_pool: Any,
+        prefill_batcher: Any, ttft_cb: Any, logprobs: bool,
+        top_logprobs: bool, adapter: Optional[str],
+        adapter_params: Optional[Any], scheduler: Any,
+    ) -> "list[int] | tuple[list[int], list[float]] | tuple":
+        """``generate`` behind the pool's gate: prefill, the first token,
+        then the decode path the request's kind takes."""
         stop_tokens = frozenset(stop_tokens or ())
         ids = self.prepare(tokens)
         prm = self.params
@@ -3871,25 +3918,8 @@ class _TransformerRunner:
         if max_new_tokens <= 1:
             return _done()
 
-        # speculative decoding: requests with a configured draft take the
-        # draft-and-verify path (DRAFT_MODEL_NAME opts the deployment
-        # into latency mode, so these requests bypass the throughput
-        # pool). Greedy emits exactly the target's argmax; sampled
-        # (unseeded, k >= 2) uses canonical speculative sampling — the
-        # emitted sequence is distributed exactly as the target's warped
-        # distribution, whatever the draft proposes.
-        # SPEC_POOLED opts the deployment into pooled speculation
-        # instead: the solo draft-and-verify latency mode stands down
-        # and eligible requests speculate THROUGH the pool below (the
-        # pool builds their n-gram draft state from spec_ctx)
-        pool_spec = (
-            decode_pool is not None
-            and getattr(decode_pool, "spec_cfg", None) is not None
-        )
-        spec_ok = (
-            self.spec is not None and presence is None
-            and not logprobs and adapter is None and not pool_spec
-        )
+        pool_spec = getattr(decode_pool, "spec_cfg", None) is not None
+        spec_solo = self._spec_solo(sampler, logprobs, adapter, decode_pool)
         # seed the prefix cache with the finish-time conversation KV (base
         # requests on an unsharded-batch cache): a follow-up turn then
         # reuses the WHOLE conversation's KV. ONE predicate for the
@@ -3898,7 +3928,7 @@ class _TransformerRunner:
             self._prefix_cache is not None and adapter is None
             and self._can_chunk_prefill()
         )
-        if spec_ok and sampler.greedy:
+        if spec_solo and sampler.greedy:
             out, spec_cache = self._spec_generate(
                 state, ids, out, token, max_new_tokens, on_token, stop,
                 stop_tokens,
@@ -3906,7 +3936,7 @@ class _TransformerRunner:
             if seed_kv:
                 self._prefix_store_generation(ids, out, spec_cache, sampler)
             return out
-        if spec_ok and not sampler.seeded and self.spec.k >= 2:
+        if spec_solo:
             out, spec_cache = self._spec_generate_sampled(
                 state, ids, out, token, max_new_tokens, on_token, stop,
                 stop_tokens, sampler,
@@ -3925,7 +3955,10 @@ class _TransformerRunner:
         # the batch instead of decoding solo. ADAPTER requests join via
         # the pool's stacked bank (per-slot adapter selection); the pool
         # rejects them — and they solo — while the bank is off,
-        # rebuilding, mesh-disabled, or a penalized slot is active.
+        # rebuilding, mesh-disabled, or a penalized slot is active. A
+        # FULL pool rejects nobody: submit returns once a finishing
+        # request has left this one a seat (its first token is out
+        # already; the stream pauses until then).
         if decode_pool is not None and not sampler.seeded:
             import queue as queue_mod
 
@@ -3946,12 +3979,14 @@ class _TransformerRunner:
                     spec_ctx=ids if pool_spec else None,
                 )
             except (queue_mod.Full, RuntimeError):
-                # pool saturated/closed -> solo decode below (the reason
-                # is on the FlightRecord and gofr_tpu_pool_reject_total)
+                # an executable the pool does not run beside its rows,
+                # or a closed pool -> solo decode below (the reason is on
+                # the FlightRecord and gofr_tpu_pool_reject_total)
                 slot_q = None
             record = telemetry_record()
             if record is not None:
-                record.mark_pool_admit()  # a slot, or the refusal
+                # a slot, after whatever wait for one, or the refusal
+                record.mark_pool_admit()
             if slot_q is not None:
                 state = None
                 kv_row = self._consume_pool(
@@ -4007,7 +4042,7 @@ class _TransformerRunner:
         from gofr_tpu.deadline import current_deadline
 
         # the solo path honors the per-chunk decode expiry too: a
-        # pool-rejected (no_free_slots / adapter-mix) request must not
+        # pool-rejected (adapter-mix, penalties off) request must not
         # decode unmetered past its budget just because it fell out of
         # the pool — same stage=decode contract as the pooled rows
         deadline = current_deadline()

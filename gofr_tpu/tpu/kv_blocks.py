@@ -56,8 +56,11 @@ import numpy as np
 
 class KVExhausted(RuntimeError):
     """No free KV blocks (and nothing evictable): the caller's request
-    cannot be admitted — decode falls back to the solo path and the
-    rejection is accounted as ``pool_reject{reason="kv_exhausted"}``."""
+    cannot be admitted now. The decode pool has it wait for a pooled row
+    to finish (``exhausted_rejects`` counts the failed reservation all the
+    same); where none holds the budget, and on the echo runner, decode
+    falls back to the solo path and the rejection is accounted as
+    ``pool_reject{reason="kv_exhausted"}``."""
 
 
 class ForeignKVRejected(RuntimeError):

@@ -111,20 +111,75 @@ def test_slots_recycle_across_many_requests(pooled, solo):
 
 
 def test_pool_saturation_falls_back_to_solo(pooled, solo):
-    # 8 concurrent streams, 4 slots: the overflow must still complete
+    """The name is older than the behaviour: since PR 37 nothing falls back.
+    8 concurrent streams over 4 slots, the worker held at a fetch so that
+    the pool is full when the last four arrive, one after another: they
+    wait for a seat, are seated in arrival order as the first four end, and
+    every stream reads as the solo reference does. No solo program runs and
+    no ``no_free_slots`` is counted. (tests/test_pool_seat_wait.py holds the
+    ways a wait ends without a seat.)"""
+    import time
+
+    from gofr_tpu.telemetry import FlightRecorder, activate_record
+
+    pool = pooled.decode_pool
     prompts = [[i + 1, 9, 9] for i in range(8)]
     want = [solo.generate(p, max_new_tokens=7) for p in prompts]
     got = [None] * 8
+    flights = [None] * 8
+    recorder = FlightRecorder()
+    solo_before = len(pooled.timeline.records(limit=5000, kind="decode_solo"))
 
     def run(i):
-        got[i] = pooled.generate(prompts[i], max_new_tokens=7)
+        record = recorder.start(model="tiny", endpoint="/t")
+        try:
+            got[i] = pooled.generate(prompts[i], max_new_tokens=7)
+        finally:
+            recorder.finish(record)
+            activate_record(None)
+        flights[i] = record
 
+    def until(cond):
+        end = time.monotonic() + 30.0
+        while time.monotonic() < end and not cond():
+            time.sleep(0.002)
+        assert cond()
+
+    until(lambda: pool.chunks_in_flight == 0 and not pool._active)
+    gate = threading.Event()
+    real_fetch = pool._fetch_and_deliver
+
+    def held_fetch(in_flight, last_fetch_done):
+        gate.wait(60.0)
+        return real_fetch(in_flight, last_fetch_done)
+
+    pool._fetch_and_deliver = held_fetch
     threads = [threading.Thread(target=run, args=(i,)) for i in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
+    try:
+        for t in threads[:4]:
+            t.start()
+        until(lambda: len(pool._active) == 4 and pool.chunks_in_flight == pool.pipeline_depth)
+        for place, t in enumerate(threads[4:], start=1):
+            t.start()
+            until(lambda: pool.occupancy()["waiting"] == place)
+        time.sleep(0.02)
+    finally:
+        gate.set()
+        for t in threads:
+            t.join(60.0)
+        pool._fetch_and_deliver = real_fetch
+    assert not any(t.is_alive() for t in threads)
     assert got == want
+    for flight in flights:
+        assert flight.pool_reject_reason == ""
+    assert all(f.to_dict()["pool_seat_wait_s"] is None for f in flights[:4])
+    assert all(f.to_dict()["pool_seat_wait_s"] > 0.02 for f in flights[4:])
+    seated = [f.t_state_insert for f in flights[4:]]
+    assert seated == sorted(seated)  # in arrival order
+    assert len(pooled.timeline.records(limit=5000, kind="decode_solo")) == solo_before
+    counter = pooled.metrics.counter("gofr_tpu_pool_reject_total", labels=("reason",))
+    assert counter.value(reason="no_free_slots") == 0
+    assert pool.occupancy()["waiting"] == 0
 
 
 def test_seeded_requests_bypass_pool(pooled):

@@ -111,10 +111,12 @@ def test_scanner_sees_the_known_registrations():
             "gofr_tpu_prefix_hit_ratio",
             "gofr_tpu_prefix_partial_hit_ratio",
             "gofr_tpu_prefix_entries"} <= names
-    # continuous batching internals: queue-wait histogram (batcher.py)
-    # and the live decode-slot gauge (decode_pool.py)
+    # continuous batching internals: queue-wait histogram (batcher.py),
+    # the live decode-slot gauge and the wait for a seat in a full pool
+    # (decode_pool.py)
     assert {"gofr_tpu_queue_wait_seconds",
-            "gofr_tpu_decode_slots_active"} <= names
+            "gofr_tpu_decode_slots_active",
+            "gofr_tpu_pool_seat_wait_seconds"} <= names
     # crash-recovery surfaces: engine recovery outcomes (tpu/recovery.py),
     # journal resume modes (telemetry.py), and the fleet's replica
     # restart / stream-resume ledgers (fleet/router.py)

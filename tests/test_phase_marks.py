@@ -3,9 +3,9 @@
 flight / fetch wait / deliver, ``chunks_ahead``, the ``decode_solo`` kind,
 the FlightRecord's partition of the server-side TTFT, and the rule that the
 profiler annotations are leaves. The request path runs on the no-JAX echo
-model over HTTP; the decode pool and the solo fallback on the tiny
-transformer (ONE compiled bucket, two slots: a few seconds of CPU compiles,
-the price of keeping the pool's marks in tier-1)."""
+model over HTTP; the decode pool, the wait for a seat in it and the solo
+decode on the tiny transformer (ONE compiled bucket, two slots: a few
+seconds of CPU compiles, the price of keeping the pool's marks in tier-1)."""
 
 import json
 import os
@@ -22,6 +22,7 @@ from gofr_tpu import profiling
 from gofr_tpu.config import EnvConfig
 from gofr_tpu.logging import Level
 from gofr_tpu.metrics import Registry
+from gofr_tpu.ops.sampling import Sampler
 from gofr_tpu.telemetry import FlightRecorder, activate_record
 from gofr_tpu.testutil import MockLogger
 from gofr_tpu.tpu.device import new_device
@@ -33,6 +34,7 @@ ALL_NAMES = {
     profiling.POOL_ISSUE, profiling.POOL_FETCH_WAIT, profiling.POOL_DELIVER,
     profiling.POOL_WAIT_WORK, profiling.SOLO_ISSUE, profiling.SOLO_FETCH_WAIT,
     profiling.SSE_FIRST_FRAME, profiling.POOL_STATE_INSERT,
+    profiling.POOL_SEAT_WAIT,
 }
 
 
@@ -214,7 +216,7 @@ def test_request_path_annotations_are_leaves(echo_app, monkeypatch):
     assert stub.names <= ALL_NAMES
 
 
-# -- the decode pool and the solo fallback: tiny transformer ----------------------
+# -- the decode pool, the wait for a seat and the solo decode: tiny transformer ---
 
 _TINY = {
     "MODEL_NAME": "tiny", "BATCH_MAX_SIZE": "2", "BATCH_TIMEOUT_MS": "1",
@@ -225,9 +227,10 @@ _TINY = {
 @pytest.fixture(scope="module")
 def held_pool():
     """A two-slot pool whose worker is held at its first fetch with both
-    slots taken and the pipeline full, a prefill and a refused request
-    served meanwhile, then let go: (device, depth, prefill flight, refused
-    flight, the annotation names seen, nested pairs)."""
+    slots taken and the pipeline full; a prefill and a seeded request (it
+    decodes solo) served meanwhile, and a third pooled request that finds
+    no seat and stands waiting; then the worker is let go: (device, depth,
+    the flights, the annotation names seen, nested pairs)."""
     old = {k: os.environ.get(k) for k in _TINY}
     os.environ.update(_TINY)
     try:
@@ -250,10 +253,10 @@ def held_pool():
     pool._fetch_and_deliver = held_fetch
     recorder = FlightRecorder()
 
-    def serve(prompt, n, out):
+    def serve(prompt, n, out, sampler=None):
         record = recorder.start(model="tiny", endpoint="/t")
         try:
-            dev.generate(prompt, max_new_tokens=n)
+            dev.generate(prompt, max_new_tokens=n, sampler=sampler)
         finally:
             recorder.finish(record)
             activate_record(None)
@@ -273,20 +276,28 @@ def held_pool():
         assert pool.chunks_in_flight == depth and len(pool._active) == 2
         prefill_only: list[dict] = []
         serve([9, 8, 7], 1, prefill_only)  # one token: prefill, never the pool
-        refused: list[dict] = []
-        serve([2, 7, 1, 8], 9, refused)  # no free slot: decodes solo
+        seeded: list[dict] = []  # a seeded request never asks the pool: solo
+        serve([6, 2, 8], 9, seeded, Sampler(temperature=1.0, seed=5))
+        waited: list[dict] = []  # no free slot: waits for a rider's
+        threads.append(threading.Thread(target=serve, args=([2, 7, 1, 8], 9, waited)))
+        threads[-1].start()
+        while time.monotonic() < deadline and pool.occupancy()["waiting"] != 1:
+            time.sleep(0.005)
+        assert pool.occupancy()["waiting"] == 1 and not waited
+        time.sleep(0.02)  # a wait the clock can see
     finally:
         gate.set()
         for thread in threads:
             thread.join(60.0)
         pool._fetch_and_deliver = real_fetch
-    assert not any(thread.is_alive() for thread in threads) and len(riders) == 2
+    assert not any(thread.is_alive() for thread in threads)
+    assert len(riders) == 2 and len(waited) == 1
     deadline = time.monotonic() + 10.0
     while time.monotonic() < deadline and profiling.POOL_WAIT_WORK not in stub.names:
         time.sleep(0.005)  # the worker parks once its last slot is free
     profiling._annotation = real_annotation
     yield SimpleNamespace(dev=dev, depth=depth, prefill_only=prefill_only[0],
-                          refused=refused[0], riders=riders, stub=stub)
+                          seeded=seeded[0], waited=waited[0], riders=riders, stub=stub)
     dev.close()
 
 
@@ -339,9 +350,42 @@ def test_prefill_issued_behind_k_pool_chunks_records_k(held_pool):
 
 
 def test_refused_request_leaves_decode_solo_records_on_its_flight(held_pool):
-    flight = held_pool.refused
-    assert flight["pool_reject_reason"] == "no_free_slots"
-    assert flight["pool_admit_s"] is not None and flight["pool_admit_s"] >= 0
+    """The name is PR 25's; since PR 37 a full pool refuses nobody: the
+    held pool's third request stands waiting with its prefilled row and is
+    seated as a rider ends, and no solo program ever runs for it."""
+    flight = held_pool.waited
+    assert flight["status"] == "ok" and flight["tokens_out"] == 9
+    assert flight["pool_reject_reason"] is None
+    assert flight["pool_seat_wait_s"] > 0.02
+    # the pool's answer is the seat: pool_admit_s now holds the wait
+    assert flight["pool_admit_s"] >= flight["pool_seat_wait_s"]
+    assert flight["state_insert_s"] is not None and flight["pool_cohort"] >= 1
+    chunks = _records(held_pool.dev, "decode_chunk")
+    solo = _records(held_pool.dev, "decode_solo")
+    prefills = _records(held_pool.dev, "prefill")
+    decodes = [i for i in flight["dispatch_ids"] if i not in prefills]
+    # 8 tokens in chunks of 4, and the chunk already queued when the last came
+    assert len(decodes) == 2 + held_pool.depth - 1 and set(decodes) <= set(chunks)
+    assert not set(flight["dispatch_ids"]) & set(solo)
+    for i in decodes:
+        assert_marks_in_order(chunks[i])
+    # seated before the worker's next dispatch once the riders' last chunk
+    # was delivered: its first chunk is issued after theirs ended
+    riders_last = max(i for rider in held_pool.riders for i in rider["dispatch_ids"])
+    assert min(decodes) > riders_last
+    for rider in held_pool.riders:  # seated at once: no wait, no refusal
+        assert rider["pool_reject_reason"] is None and rider["pool_admit_s"] >= 0
+        assert rider["pool_seat_wait_s"] is None
+        assert not set(rider["dispatch_ids"]) & set(solo)
+
+
+def test_seeded_request_leaves_decode_solo_records_on_its_flight(held_pool):
+    """What still decodes solo (here a seeded request: the pool's key order
+    depends on co-tenants) leaves one ``decode_solo`` record a chunk, each
+    issued behind the pool's chunks in flight."""
+    flight = held_pool.seeded
+    assert flight["pool_reject_reason"] is None and flight["pool_admit_s"] is None
+    assert flight["pool_seat_wait_s"] is None
     solo = _records(held_pool.dev, "decode_solo")
     mine = [solo[i] for i in flight["dispatch_ids"] if i in solo]
     assert len(mine) == 2  # 8 tokens after the first, chunks of 4
@@ -351,16 +395,14 @@ def test_refused_request_leaves_decode_solo_records_on_its_flight(held_pool):
         assert record["chunks_ahead"] == held_pool.depth
     # no solo record left running or abandoned behind the finished request
     assert all(r["status"] == "ok" for r in solo.values())
-    for rider in held_pool.riders:  # the pooled riders admitted, and have no solo records
-        assert rider["pool_reject_reason"] is None and rider["pool_admit_s"] >= 0
-        assert not set(rider["dispatch_ids"]) & set(solo)
 
 
 def test_metrics_count_tokens_and_export_no_utilisation(held_pool):
     """gofr_tpu_tokens_total moves by prompt tokens prefilled and by tokens
-    the pool delivered (the two riders' 20 each: not a chunk's tail, not
-    the refused request's solo tokens); no utilisation gauge and no
-    cost-model family is registered at all."""
+    the pool delivered (the two riders' 20 each and the 8 of the request
+    that waited for a seat: not a chunk's tail, not the seeded request's
+    solo tokens); no utilisation gauge and no cost-model family is
+    registered at all."""
     text = held_pool.dev.metrics.expose()
     count = {
         op: float(next(
@@ -369,7 +411,7 @@ def test_metrics_count_tokens_and_export_no_utilisation(held_pool):
         ).rsplit(" ", 1)[1])
         for op in ("prefill", "decode")
     }
-    assert count == {"prefill": 2 * 5 + 3 + 4, "decode": 2 * 20}
+    assert count == {"prefill": 2 * 5 + 3 + 3 + 4, "decode": 2 * 20 + 8}
     for family in ("gofr_tpu_mfu", "gofr_tpu_mbu",
                    "gofr_tpu_dispatch_residual_ratio",
                    "gofr_tpu_dispatch_anomalies_total"):
