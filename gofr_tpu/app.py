@@ -56,7 +56,7 @@ from gofr_tpu.http.middleware import (
     tracer_middleware,
 )
 from gofr_tpu.http.router import Router
-from gofr_tpu.http.server import HTTPServer
+from gofr_tpu.http.server import HTTPServer, LoopClock
 from gofr_tpu.tracing import init_tracer
 
 DEFAULT_HTTP_PORT = 8000  # parity: pkg/gofr/default.go:3-6
@@ -239,7 +239,9 @@ class App:
         the reference achieves the same with goroutines + WaitGroup,
         gofr.go:109-125)."""
         self._install_default_routes()
-        self.http_server = HTTPServer(self.router, self.http_port, self.logger)
+        self.http_server = HTTPServer(
+            self.router, self.http_port, self.logger, loop_clock=self._loop_clock()
+        )
         self.http_server.run_in_thread()
         if (
             self._grpc_registrations
@@ -257,6 +259,32 @@ class App:
             )
             self._grpc_server.start()
         return self
+
+    def _loop_clock(self) -> LoopClock:
+        """The server's event-loop clock, wired to what reads it: the
+        metrics registry, the flight recorder (a record's loop lag over its
+        own life, ``http`` on /admin/engine) and, for the line a late tick
+        logs, the device dispatches then in flight."""
+        container = self.container
+
+        def running() -> Any:
+            timeline = getattr(container.tpu, "timeline", None)
+            return timeline.running() if timeline is not None else None
+
+        clock = LoopClock(
+            histogram=container.metrics.histogram(
+                "gofr_tpu_http_loop_lag_seconds",
+                "how late the HTTP server's event loop woke from a 50 ms "
+                "sleep: the wait of every frame and request behind "
+                "whatever held the loop's thread",
+                buckets=(0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05,
+                         0.1, 0.25, 0.5, 1.0, 2.5, 5.0),
+            ),
+            logger=self.logger, running=running,
+            ready=lambda: container.tpu is None or container.tpu.ready(),
+        )
+        container.telemetry.loop_clock = clock
+        return clock
 
     def shutdown(self) -> None:
         # visible to in-flight stream teardown: asyncio acloses every

@@ -365,6 +365,9 @@ def engine_admin_handler(ctx: Context) -> Any:
     if slo is not None:
         snap["slo"] = slo.headline()
     snap["tenants"] = ctx.container.tenants.overview()
+    # the transport's own clock: how late the server's event loop runs
+    # and how many token frames it has written
+    snap["http"] = ctx.container.telemetry.transport()
     return snap
 
 

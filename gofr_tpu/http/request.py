@@ -34,6 +34,10 @@ class Request:
         self.body = body
         self.remote_addr = remote_addr
         self.path_params: dict[str, str] = path_params or {}
+        # ``perf_counter`` when the server had read the whole request (set
+        # by HTTPServer; None from any other transport): a FlightRecord's
+        # ``accept_s`` runs from here to the record's own start
+        self.t_received: Optional[float] = None
 
     # -- the Request interface (parity: pkg/gofr/request.go:10-16) ----------
     def param(self, key: str) -> str:

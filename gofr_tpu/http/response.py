@@ -67,7 +67,8 @@ class Stream:
     ``on_write`` (optional callable) fires each time a frame was handed
     to the socket — the server's write of the previous frame returned
     and it asked for the next. The flight recorder uses it to mark when
-    the first token's frame left (``FlightRecord.t_first_frame``)."""
+    the first token's frame left (``FlightRecord.t_first_frame``) and to
+    add each token frame's wait since its delivery (``frame_lag_*``)."""
 
     events: Union[Iterator[Any], AsyncIterator[Any]]
     sse: bool = True

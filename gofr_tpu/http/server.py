@@ -8,7 +8,8 @@ destroying p50 TTFT).
 
 Features: keep-alive, Content-Length and chunked request bodies, chunked
 streaming responses (SSE), HEAD handling, header-size limits, per-connection
-read timeouts.
+read timeouts. Every stream's frames pass through this ONE loop, so the
+server also times how late the loop runs (``LoopClock``).
 """
 
 from __future__ import annotations
@@ -16,11 +17,14 @@ from __future__ import annotations
 import asyncio
 import socket
 import threading
-from typing import Any, Optional
+import time
+from collections import deque
+from typing import Any, Callable, Optional
 
 from gofr_tpu.http.request import Request
 from gofr_tpu.http.response import Response
 from gofr_tpu.http.router import Router
+from gofr_tpu.profiling import HTTP_LOOP_TICK, instant
 
 MAX_HEADER_BYTES = 64 * 1024
 MAX_BODY_BYTES = 64 * 1024 * 1024
@@ -46,16 +50,94 @@ _STATUS_TEXT = {
 }
 
 
+class LoopClock:
+    """How late the server's event loop runs. ``run`` is one task that
+    sleeps ``TICK_S`` and notes how far past its due time it woke: the wait
+    of any callback behind whatever held the loop's thread (a long callback,
+    another thread holding the interpreter lock, a machine that did not run
+    the process). It keeps the largest lag since the server began and a
+    bounded ring of ``(perf_counter at the wake, lag)``, from which a
+    FlightRecord takes the ticks of its own life and ``GET /admin/engine``
+    a p99; count and sum are the histogram's. Each tick is also an instant
+    ``gofr.http.loop_tick`` on the loop's line of a profiler trace.
+    Written by the loop's thread, read by any."""
+
+    TICK_S = 0.05
+    LATE_S = 0.25  # a tick this late is logged, with the device dispatches then in flight
+    RING = 2400  # two minutes of ticks
+
+    def __init__(self, histogram: Any = None, logger: Any = None,
+                 running: Optional[Callable[[], Any]] = None,
+                 ready: Optional[Callable[[], bool]] = None):
+        self.max_s = 0.0
+        self._ring: "deque[tuple[float, float]]" = deque(maxlen=self.RING)
+        self._lock = threading.Lock()
+        self._histogram = histogram
+        self._logger = logger
+        self._running = running  # () -> the device dispatches in flight
+        # () -> the engine has booted: imports and warm-up compiles hold the
+        # interpreter lock for seconds, and a tick late behind them is no news
+        self._ready = ready
+
+    async def run(self) -> None:
+        loop = asyncio.get_running_loop()
+        while True:
+            due = loop.time() + self.TICK_S
+            await asyncio.sleep(self.TICK_S)
+            self.note(max(0.0, loop.time() - due))
+
+    def note(self, lag: float) -> None:
+        instant(HTTP_LOOP_TICK)
+        with self._lock:
+            self.max_s = max(self.max_s, lag)
+            self._ring.append((time.perf_counter(), lag))
+        if self._histogram is not None:
+            self._histogram.observe(lag)
+        if (lag >= self.LATE_S and self._logger is not None
+                and (self._ready is None or self._ready())):
+            self._logger.warn({
+                "event": "http_loop_late", "lag_s": round(lag, 4),
+                "running": self._running() if self._running is not None else None,
+            })
+
+    def lags(self, t0: float, t1: float) -> tuple[Optional[float], Optional[float]]:
+        """(mean, max) lag of the ticks that woke in ``t0..t1``
+        (``perf_counter`` marks); (None, None) where none did."""
+        lags = []
+        with self._lock:
+            for t, lag in reversed(self._ring):  # newest first: a life is the ring's end
+                if t < t0:
+                    break
+                if t <= t1:
+                    lags.append(lag)
+        if not lags:
+            return None, None
+        return sum(lags) / len(lags), max(lags)
+
+    def snapshot(self) -> dict[str, Any]:
+        """p99 over the ring's ticks (the last two minutes) and the max
+        since the server began, in ms."""
+        with self._lock:
+            lags = sorted(lag for _, lag in self._ring)
+            worst = self.max_s
+        if not lags:
+            return {"loop_lag_p99_ms": None, "loop_lag_max_ms": None}
+        p99 = lags[max(0, -(-99 * len(lags) // 100) - 1)]  # nearest rank
+        return {"loop_lag_p99_ms": 1e3 * p99, "loop_lag_max_ms": 1e3 * worst}
+
+
 class HTTPServer:
     """Serves a Router on a port. ``run()`` blocks; ``run_in_thread()``
     starts a daemon thread and returns once the socket is listening (the
     test-friendly shape the reference gets from httptest)."""
 
-    def __init__(self, router: Router, port: int, logger: Any = None, host: str = "0.0.0.0"):
+    def __init__(self, router: Router, port: int, logger: Any = None, host: str = "0.0.0.0",
+                 loop_clock: Optional[LoopClock] = None):
         self.router = router
         self.port = port
         self.host = host
         self.logger = logger
+        self.loop_clock = loop_clock if loop_clock is not None else LoopClock()
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._server: Optional[asyncio.AbstractServer] = None
         self._ready = threading.Event()
@@ -74,8 +156,12 @@ class HTTPServer:
         self._ready.set()
         if self.logger:
             self.logger.infof("starting HTTP server on port %s", self.port)
-        async with self._server:
-            await self._server.serve_forever()
+        ticks = asyncio.create_task(self.loop_clock.run())
+        try:
+            async with self._server:
+                await self._server.serve_forever()
+        finally:
+            ticks.cancel()
 
     def run_in_thread(self) -> "HTTPServer":
         self._thread = threading.Thread(target=self._run_quiet, daemon=True, name="gofr-http")
@@ -187,6 +273,7 @@ class HTTPServer:
                         return False
 
         request = Request(method, target, headers, body, remote)
+        request.t_received = time.perf_counter()
         try:
             response = await self.router.dispatcher()(request)
         except Exception:  # last-resort guard; logging middleware recovers first

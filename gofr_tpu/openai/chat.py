@@ -81,12 +81,14 @@ def _stream_chat(
         )
 
     from gofr_tpu.openai.parse import _abortable
+    from gofr_tpu.telemetry import current_record
 
     cancel, on_abort = _abortable(ctx)
     stream_iter = ctx.tpu.generate_stream(
         prompt_ids, max_tokens, sampler=sampler, stop_tokens=stop_ids,
         adapter=adapter, logprobs=want_logprobs, cancel=cancel,
     )
+    record = current_record()  # here, on the handler's thread: events() runs elsewhere
 
     def events():
         emitted = 0
@@ -110,6 +112,10 @@ def _stream_chat(
                         break
                 if text or lp is not None:
                     yield chunk({"content": text}, lp=lp, token_id=token)
+                elif record is not None:
+                    record.note_unframed()  # its text rides a later frame
+            if record is not None:
+                record.end_token_frames()  # a stop may leave tokens unframed
             tail = dec.flush()
             if finish is None:
                 if scan is not None:
@@ -161,9 +167,13 @@ def _stream_chat_fanout(
         _stream_candidates,
     )
     from gofr_tpu.openai.parse import _abortable, _StopScanner
+    from gofr_tpu.telemetry import current_record
 
     replicate = sampler.greedy
     cancel, on_abort = _abortable(ctx)
+    record = current_record()
+    if replicate and record is not None:
+        record.frames_per_token = n  # one stream's token, a frame an index
     iters = _stream_candidates(
         ctx, body, prompt_ids, max_tokens, sampler, stop_ids, adapter,
         want_logprobs, 1 if replicate else n, cancel=cancel,
@@ -187,6 +197,8 @@ def _stream_chat_fanout(
         if text or lp is not None:
             return [chunk({"content": text}, lp=lp, token_id=token,
                           index=i)]
+        if record is not None:
+            record.note_unframed()  # its text rides a later frame
         return []
 
     def tail(i):
@@ -208,7 +220,7 @@ def _stream_chat_fanout(
     return Stream(
         _drive_stream_fanout(
             iters, replicate, n, finish, want_logprobs, open_frames, feed,
-            tail, error_frame, usage_frames,
+            tail, error_frame, usage_frames, record,
         ),
         on_abort=on_abort,
     )
@@ -252,6 +264,7 @@ def chat_completions(ctx: Any) -> Any:
         model=model, endpoint="/v1/chat/completions",
         trace_id=ctx.trace_id or "", tokens_in=len(prompt_ids),
         stream=bool(body.get("stream")),
+        t_received=getattr(ctx.request, "t_received", None),
     ) as fl:
         if body.get("stream"):
             # defer: the record completes when the stream ends

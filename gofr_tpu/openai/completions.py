@@ -117,6 +117,7 @@ def _stream_completion(
     # clamped to the token budget: a client interrupted between the
     # last token frame and [DONE] resumes straight into the tail
     from gofr_tpu.openai.parse import _abortable
+    from gofr_tpu.telemetry import current_record
 
     cancel, on_abort = _abortable(ctx)
     stream_iter = ctx.tpu.generate_stream(
@@ -124,6 +125,7 @@ def _stream_completion(
         adapter=adapter, logprobs=want_logprobs,
         resume_from=min(resume_from, max_tokens), cancel=cancel,
     )
+    record = current_record()  # here, on the handler's thread: events() runs elsewhere
 
     def events():
         # a resumed stream's token iterator starts at the resume
@@ -162,6 +164,8 @@ def _stream_completion(
                         finish = "stop"
                         break
                 yield chunk(text, lp)
+            if record is not None:
+                record.end_token_frames()  # a stop may leave tokens unframed
             tail = dec.flush() if dec is not None else ""
             if finish is None:
                 if scan is not None:
@@ -211,9 +215,13 @@ def _stream_completion_fanout(
         _stream_candidates,
     )
     from gofr_tpu.openai.parse import _abortable, _StopScanner
+    from gofr_tpu.telemetry import current_record
 
     replicate = sampler.greedy
     cancel, on_abort = _abortable(ctx)
+    record = current_record()
+    if replicate and record is not None:
+        record.frames_per_token = n  # one stream's token, a frame an index
     iters = _stream_candidates(
         ctx, body, prompt_ids, max_tokens, sampler, stop_ids, adapter,
         want_logprobs, 1 if replicate else n, cancel=cancel,
@@ -260,7 +268,7 @@ def _stream_completion_fanout(
     return Stream(
         _drive_stream_fanout(
             iters, replicate, n, finish, want_logprobs, open_frames, feed,
-            tail, error_frame, usage_frames,
+            tail, error_frame, usage_frames, record,
         ),
         on_abort=on_abort,
     )
@@ -300,6 +308,7 @@ def completions(ctx: Any) -> Any:
         model=model, endpoint="/v1/completions",
         trace_id=ctx.trace_id or "", tokens_in=len(prompt_ids),
         stream=bool(body.get("stream")),
+        t_received=getattr(ctx.request, "t_received", None),
     ) as fl:
         if body.get("stream"):
             # X-Resume-From: the fleet router (or a reconnecting

@@ -203,7 +203,7 @@ def _index_tail_text(
 def _drive_stream_fanout(
     iters: list, replicate: bool, n: int, finish: list,
     want_logprobs: bool, open_frames: Any, feed: Any, tail: Any,
-    error_frame: Any, usage_frames: Any = None,
+    error_frame: Any, usage_frames: Any = None, record: Any = None,
 ) -> Any:
     """The ONE interleaved-SSE driver both endpoints share: replicate
     mode consumes a single iterator and fans frames across indexes;
@@ -214,7 +214,9 @@ def _drive_stream_fanout(
     cancellation itself — is dropped rather than aborting the healthy
     candidates. Errors from UNFINISHED indexes abort the whole stream
     with one error frame (the transport cannot re-status a committed
-    200)."""
+    200). ``record`` (the request's FlightRecord) is told when the last
+    index's tokens are over, so that the frames after them are not counted
+    as some token's."""
     cancels: list = []
     try:
         yield from open_frames()
@@ -226,6 +228,8 @@ def _drive_stream_fanout(
                         yield from feed(i, token, lp)
                 if all(f is not None for f in finish):
                     break
+            if record is not None:
+                record.end_token_frames()
             for i in range(n):
                 yield from tail(i)
         else:
@@ -236,6 +240,8 @@ def _drive_stream_fanout(
                 i, item = q.get()
                 if item is STREAM_END:
                     active -= 1
+                    if not active and record is not None:
+                        record.end_token_frames()
                     yield from tail(i)
                     continue
                 if finish[i] is not None:

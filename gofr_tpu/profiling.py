@@ -8,9 +8,12 @@ the admin endpoints (handler.py: POST /admin/profiler/start|stop, GET
 /admin/profiler) drive it on a live serving process, so a production TTFT
 regression can be traced without redeploying.
 
-Per-batch device time is additionally recorded as a span tag on every
-dispatched batch (tpu/device.py ``tpu-batch`` spans) — the always-on,
-cheap signal; full traces are the on-demand deep dive.
+What is always on and cheap is the records: a ``DispatchRecord`` a
+device dispatch and a ``FlightRecord`` a request, each with its
+``perf_counter`` marks (``cadence_s`` on a pooled chunk is the one
+in-program estimate of a chunk's device time); utilisation is read from a
+trace against the benchmark's work sheets and from nowhere in here. Full
+traces are the on-demand deep dive.
 
 ``phase`` puts the program's own phase boundaries on both clocks at one
 line of code: a ``jax.profiler.TraceAnnotation`` (the ``/host:CPU`` plane
@@ -19,11 +22,16 @@ of the same ``.xplane.pb`` the device's ``XLA Ops`` land in) and the
 phase belongs to. The names are LEAVES — no ``phase`` encloses another —
 because a trace reducer that attributes a device gap to the host event
 covering most of it would hand every gap to an enclosing span.
+``instant`` is the same annotation with no extent and no record: the HTTP
+server's event loop ticks one every 50 ms (``gofr.http.loop_tick``), so a
+hole in them on the loop's thread is a loop that did not run, on the
+device trace's clock.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -43,6 +51,8 @@ POOL_SEAT_WAIT = "gofr.pool.seat_wait"
 SOLO_ISSUE = "gofr.solo.issue"
 SOLO_FETCH_WAIT = "gofr.solo.fetch_wait"
 SSE_FIRST_FRAME = "gofr.sse.first_frame"
+HTTP_LOOP_TICK = "gofr.http.loop_tick"
+
 
 def _annotation(name: str, dispatch_id: Optional[int]) -> Any:
     """The profiler's host-plane event for one phase. With no profiler
@@ -81,6 +91,15 @@ class phase:
         if self._end is not None:
             _stamp(self._record, self._end)
         return False
+
+
+def instant(name: str) -> None:
+    """An event of no extent on the calling thread's line of the host
+    plane. A process that never imported jax (an app serving no model)
+    has no profiler to write to, and is not made to import one."""
+    if "jax" in sys.modules:
+        with _annotation(name, None):
+            pass
 
 
 def between(a: Optional[float], b: Optional[float]) -> Optional[float]:

@@ -265,6 +265,10 @@ class FlightRecord:
         "t_pool_admit", "t_first_frame",
         "t_state_insert", "t_state_inserted",
         "t_seat_wait", "t_seated",
+        "t_received", "frames", "frame_lag_max_s", "deliver_gap_max_s",
+        "loop_lag_mean_s", "loop_lag_max_s",
+        "frames_per_token",
+        "_frame_lag_sum", "_deliveries", "_head_t", "_head_left", "_t_delivered",
         # the recorder's in-flight index holds records WEAKLY (an
         # abandoned record must vanish with its request, not leak)
         "__weakref__",
@@ -282,6 +286,7 @@ class FlightRecord:
         trace_id: str = "",
         tokens_in: int = 0,
         stream: bool = False,
+        t_received: Optional[float] = None,
     ):
         self.trace_id = trace_id
         # fleet origin: the router-stamped request id + hop block, read
@@ -347,6 +352,10 @@ class FlightRecord:
         # gofrlint: wall-clock — /admin/requests display ts (durations use t_*)
         self.wall_start = time.time()
         self.t_start = time.perf_counter()
+        # the HTTP server had read the whole request (its Request's
+        # t_received): what lies between is middleware, routing and the
+        # hop to the handler's thread, ``accept_s``
+        self.t_received = t_received
         self.t_enqueue: Optional[float] = None
         self.t_dispatch: Optional[float] = None
         self.t_first_token: Optional[float] = None
@@ -372,6 +381,30 @@ class FlightRecord:
         self.t_last_token: Optional[float] = None
         self.t_done: Optional[float] = None
         self.wall_done: Optional[float] = None
+        # the frame's wait, "gofr.sse.frame" (on the record alone: no
+        # profiler event a frame). Where tokens become the stream's to
+        # send, their producer notes (when, how many); each token frame
+        # the server has written takes the oldest noted token and adds
+        # its wait. Producers append, the one consumer is the stream's
+        # own turn on the event loop: no lock on either side.
+        self.frames = 0  # token frames written
+        # a greedy n > 1 stream replicates ONE generation's tokens into a
+        # frame an index (the fan-out builders set n)
+        self.frames_per_token = 1
+        self._frame_lag_sum = 0.0
+        self.frame_lag_max_s = 0.0
+        # (None: nothing frames this request's tokens, or no longer)
+        self._deliveries: "Optional[deque[tuple[float, int]]]" = deque() if stream else None
+        self._head_t = 0.0  # the delivery being drawn from, and
+        self._head_left = 0  # its tokens not yet framed
+        # the longest interval between two deliveries to this request:
+        # the producer's side of a silence
+        self._t_delivered: Optional[float] = None
+        self.deliver_gap_max_s = 0.0
+        # the event loop's lag over the ticks of this record's life
+        # (FlightRecorder.finish reads the server's LoopClock)
+        self.loop_lag_mean_s: Optional[float] = None
+        self.loop_lag_max_s: Optional[float] = None
         self._lock = threading.Lock()
 
     # -- marks (called from batcher / pool / device) -------------------------
@@ -387,8 +420,66 @@ class FlightRecord:
             self.batch_size = cohort
 
     def mark_first_token(self) -> None:
+        """A generation's first token exists on the host: the mark is the
+        first candidate's; every candidate's token is one delivery."""
+        now = time.perf_counter()
         if self.t_first_token is None:
-            self.t_first_token = time.perf_counter()
+            self.t_first_token = now
+        self.note_delivered(1, now)
+
+    def note_delivered(self, n: int, now: Optional[float] = None) -> None:
+        """``n`` tokens became the stream's to send (the pool's burst put,
+        a solo chunk's fetch, the first token). Noted BEFORE they are
+        handed on, so a frame never precedes its delivery."""
+        if n < 1:
+            return
+        if now is None:
+            now = time.perf_counter()
+        last, self._t_delivered = self._t_delivered, now
+        if last is not None and now - last > self.deliver_gap_max_s:
+            self.deliver_gap_max_s = now - last
+        deliveries = self._deliveries
+        if deliveries is not None:
+            deliveries.append((now, n * self.frames_per_token))
+
+    def _take_delivered(self) -> Optional[float]:
+        """When the oldest noted token not yet framed was delivered (and
+        it is taken); None with none left."""
+        if not self._head_left:
+            if not self._deliveries:
+                return None
+            self._head_t, self._head_left = self._deliveries.popleft()
+        self._head_left -= 1
+        return self._head_t
+
+    def note_frame(self, now: float) -> bool:
+        """The server has written a frame (``Stream.on_write``, the event
+        loop's thread). Counted while a noted token is left to have been
+        in it: the role, echo, usage and terminal frames find none."""
+        delivered = self._take_delivered()
+        if delivered is None:
+            return False
+        lag = now - delivered
+        self.frames += 1
+        self._frame_lag_sum += lag
+        if lag > self.frame_lag_max_s:
+            self.frame_lag_max_s = lag
+        return True
+
+    def note_unframed(self) -> None:
+        """A token left the stream without a frame of its own (a chat
+        delta that decoded to no text yet): taken off the oldest delivery
+        so that later frames stay matched to theirs."""
+        self._take_delivered()
+
+    def end_token_frames(self) -> None:
+        """The stream's token loop is over; its handler says so between
+        two frames, so the loop's thread is not in ``note_frame``. What
+        was noted and never framed (tokens past a stop, the rest of a
+        cancelled burst) is nobody's: the terminal, usage and ``[DONE]``
+        frames find none left, and a producer still running notes no more."""
+        self._deliveries = None
+        self._head_left = 0
 
     def mark_pool_admit(self) -> None:
         if self.t_pool_admit is None:
@@ -590,7 +681,9 @@ class FlightRecord:
             "queue_wait_s": self.queue_wait,
             # the server-side TTFT, partitioned (each term from marks on
             # this one clock): parse_s + queue_wait_s + sched_defer_s +
-            # prefill_s + first_frame_s = server_ttft_s on a stream
+            # prefill_s + first_frame_s = server_ttft_s on a stream, and
+            # accept_s before them all = received -> first frame
+            "accept_s": between(self.t_received, self.t_start),
             "parse_s": between(self.t_start, self.t_enqueue),
             "prefill_s": self.prefill,
             "first_frame_s": between(self.t_first_token, self.t_first_frame),
@@ -598,6 +691,14 @@ class FlightRecord:
             "pool_seat_wait_s": between(self.t_seat_wait, self.t_seated),
             "state_insert_s": between(self.t_state_insert, self.t_state_inserted),
             "server_ttft_s": between(self.t_start, self.t_first_frame),
+            "frames": self.frames if self.stream else None,
+            "frame_lag_mean_s": (
+                self._frame_lag_sum / self.frames if self.frames else None
+            ),
+            "frame_lag_max_s": self.frame_lag_max_s if self.frames else None,
+            "deliver_gap_max_s": self.deliver_gap_max_s or None,
+            "loop_lag_mean_s": self.loop_lag_mean_s,
+            "loop_lag_max_s": self.loop_lag_max_s,
             "ttft_s": self.ttft,
             "tpot_s": self.tpot,
             "duration_s": self.duration,
@@ -969,15 +1070,18 @@ def flight(
     trace_id: str = "",
     tokens_in: int = 0,
     stream: bool = False,
+    t_received: Optional[float] = None,
 ) -> Flight:
     """Start (and contextvar-activate) a FlightRecord under a ``Flight``
     lifecycle guard; recorder None (bare test containers) yields an
-    inert guard whose ``defer`` passes results through untouched."""
+    inert guard whose ``defer`` passes results through untouched.
+    ``t_received`` is the transport's mark of the request read whole
+    (``Request.t_received``)."""
     record = None
     if recorder is not None:
         record = recorder.start(
             model=model, endpoint=endpoint, trace_id=trace_id,
-            tokens_in=tokens_in, stream=stream,
+            tokens_in=tokens_in, stream=stream, t_received=t_received,
         )
     return Flight(recorder, record)
 
@@ -1181,6 +1285,12 @@ class FlightRecorder:
         self.slow_threshold_s = slow_threshold_s
         self.logger = logger
         self.tenants = tenants
+        # the HTTP server's LoopClock, once an App has one (app.py): a
+        # finishing record takes the loop's lag over its own life from it
+        self.loop_clock: Any = None
+        # token frames written on every stream (the event loop's thread
+        # alone adds to it)
+        self.frames_total = 0
         self._ring: "deque[FlightRecord]" = deque(maxlen=max(1, capacity))
         self._notable: "deque[FlightRecord]" = deque(maxlen=max(1, keep))
         # records started but not yet finished — the postmortem bundle
@@ -1202,10 +1312,11 @@ class FlightRecorder:
         tokens_in: int = 0,
         stream: bool = False,
         activate: bool = True,
+        t_received: Optional[float] = None,
     ) -> FlightRecord:
         record = FlightRecord(
             model=model, endpoint=endpoint, trace_id=trace_id,
-            tokens_in=tokens_in, stream=stream,
+            tokens_in=tokens_in, stream=stream, t_received=t_received,
         )
         with self._lock:
             self._active[id(record)] = record
@@ -1226,6 +1337,10 @@ class FlightRecorder:
             return
         record.t_done = time.perf_counter()
         record.wall_done = time.time()  # gofrlint: wall-clock — /admin/requests display timestamp
+        if self.loop_clock is not None:
+            record.loop_lag_mean_s, record.loop_lag_max_s = self.loop_clock.lags(
+                record.t_start, record.t_done
+            )
         if error is not None:
             record.note_error(error)
         elif record.status == "in_flight":
@@ -1292,12 +1407,26 @@ class FlightRecorder:
             if record.t_first_frame is None and record.t_first_token is not None:
                 with phase(SSE_FIRST_FRAME, record, end="t_first_frame"):
                     pass  # an instant on both clocks: the frame has left
+                now = record.t_first_frame  # so its lag IS first_frame_s
+            else:
+                now = time.perf_counter()
+            if record.note_frame(now):
+                self.frames_total += 1
 
         result.events = guarded()
         result.on_write = frame_written
         return result
 
     # -- read side (admin API / postmortem) ----------------------------------
+    def transport(self) -> dict[str, Any]:
+        """The ``http`` block of ``GET /admin/engine``: the event loop's
+        lag (None without a server's clock) and the token frames written."""
+        lag = (
+            self.loop_clock.snapshot() if self.loop_clock is not None
+            else {"loop_lag_p99_ms": None, "loop_lag_max_ms": None}
+        )
+        return dict(lag, frames_total=self.frames_total)
+
     def active_count(self) -> int:
         """In-flight request count — the cheap read for rollups that
         only need the number, not the serialized records."""

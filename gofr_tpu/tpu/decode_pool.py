@@ -1570,6 +1570,8 @@ class DecodePool:
                     break
                 emit.append(t)
             if emit:
+                if req.record is not None:
+                    req.record.note_delivered(len(emit))
                 req.out_queue.put(list(emit))
         # committed tokens: everything emitted (the stop token itself is
         # never emitted nor committed — the request ends at it)
@@ -1784,6 +1786,10 @@ class DecodePool:
                 req, index, toks[index], lps[index], tvals, tids, take
             )
             if burst:
+                if req.record is not None:
+                    # from here the tokens are the stream's to send:
+                    # each one's frame waits from this stamp
+                    req.record.note_delivered(len(burst))
                 req.out_queue.put(burst)
                 delivered = len(burst)  # only tokens a request received
             if req.spec is not None:

@@ -2977,6 +2977,8 @@ class _EchoRunner:
             if logprobs:
                 lps.append(0.0)
                 tops.append([(token, 0.0)])
+            if record is not None and len(out) > 1:
+                record.note_delivered(1)  # the first was mark_first_token's
             if on_token:
                 on_token((token, 0.0) if logprobs else token)
             if self.step_s:
@@ -3065,6 +3067,9 @@ class _EchoRunner:
                 if len(burst) > n_acc:
                     self.paged.append(seq, burst[-1])
             cancelled = False
+            if record is not None:
+                # the stream's first token was mark_first_token's delivery
+                record.note_delivered(len(burst) - (0 if out else 1))
             for t in burst:
                 out.append(t)
                 if logprobs:
@@ -4139,9 +4144,12 @@ class _TransformerRunner:
                     self._shed_solo_decode(deadline, len(out))
                 take = min(n, max_new_tokens - len(out))
                 for j, t in enumerate(chunk[:take]):
-                    if t in stop_tokens:
-                        stopped = True
+                    if t in stop_tokens:  # the request ends before it
+                        take, stopped = j, True
                         break
+                if record is not None:
+                    record.note_delivered(take)  # what is handed on, no more
+                for j, t in enumerate(chunk[:take]):
                     out.append(t)
                     if chunk_lps is not None:
                         lps.append(chunk_lps[j])
@@ -4649,7 +4657,15 @@ class _TransformerRunner:
         """The one emit helper both spec paths share: append tokens,
         honoring stop tokens / budget / cancellation; True = keep going."""
 
+        record = telemetry_record()
+
         def emit(tokens_host: list[int]) -> bool:
+            if record is not None:
+                # what is handed on, no more: up to a stop token or the budget
+                ends = [j for j, t in enumerate(tokens_host) if t in stop_tokens]
+                record.note_delivered(
+                    min(ends[0] if ends else len(tokens_host), max_new_tokens - len(out))
+                )
             for t in tokens_host:
                 if t in stop_tokens:
                     return False

@@ -192,6 +192,19 @@ class DispatchRecord:
             return None
         return self.t_done - (self.t_running or self.t_queued)
 
+    @property
+    def phase(self) -> str:
+        """Which part of its life the dispatch is in, by its marks."""
+        if self.t_done is not None:
+            return "done"
+        if self.t_running is None:
+            return "queued"
+        if self.t_issued is None:
+            return "issue"
+        if self.t_fetch is None:
+            return "in_flight"
+        return "fetch_wait" if self.t_fetched is None else "deliver"
+
     def to_dict(self) -> dict[str, Any]:
         return {
             "dispatch_id": self.dispatch_id,
@@ -300,6 +313,17 @@ class DispatchTimeline:
             if len(out) >= limit:
                 break
         return out
+
+    def running(self) -> list[dict[str, Any]]:
+        """The dispatches in flight right now, oldest first, each with the
+        phase it is in: what the device's threads were doing when
+        something else (the HTTP loop's late tick) asks."""
+        with self._lock:
+            records = list(self._in_flight.values())
+        return [
+            {"dispatch_id": r.dispatch_id, "kind": r.kind, "phase": r.phase}
+            for r in records
+        ]
 
     def stats(self) -> dict[str, Any]:
         with self._lock:

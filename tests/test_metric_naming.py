@@ -117,6 +117,9 @@ def test_scanner_sees_the_known_registrations():
     assert {"gofr_tpu_queue_wait_seconds",
             "gofr_tpu_decode_slots_active",
             "gofr_tpu_pool_seat_wait_seconds"} <= names
+    # the transport's own clock: how late the HTTP server's event loop
+    # runs (app.py wires http/server.py's LoopClock)
+    assert "gofr_tpu_http_loop_lag_seconds" in names
     # crash-recovery surfaces: engine recovery outcomes (tpu/recovery.py),
     # journal resume modes (telemetry.py), and the fleet's replica
     # restart / stream-resume ledgers (fleet/router.py)

@@ -1,12 +1,15 @@
 """Phase marks inside a dispatch and a request (gofr_tpu/profiling.py
 ``phase``): the DispatchRecord's split of running -> done into issue / in
 flight / fetch wait / deliver, ``chunks_ahead``, the ``decode_solo`` kind,
-the FlightRecord's partition of the server-side TTFT, and the rule that the
+the FlightRecord's partition of the server-side TTFT, the transport's own
+clock (a request's wait before its record, every token frame's wait between
+its delivery and its write, the event loop's lag), and the rule that the
 profiler annotations are leaves. The request path runs on the no-JAX echo
 model over HTTP; the decode pool, the wait for a seat in it and the solo
 decode on the tiny transformer (ONE compiled bucket, two slots: a few
 seconds of CPU compiles, the price of keeping the pool's marks in tier-1)."""
 
+import asyncio
 import json
 import os
 import socket
@@ -34,7 +37,7 @@ ALL_NAMES = {
     profiling.POOL_ISSUE, profiling.POOL_FETCH_WAIT, profiling.POOL_DELIVER,
     profiling.POOL_WAIT_WORK, profiling.SOLO_ISSUE, profiling.SOLO_FETCH_WAIT,
     profiling.SSE_FIRST_FRAME, profiling.POOL_STATE_INSERT,
-    profiling.POOL_SEAT_WAIT,
+    profiling.POOL_SEAT_WAIT, profiling.HTTP_LOOP_TICK,
 }
 
 
@@ -216,6 +219,243 @@ def test_request_path_annotations_are_leaves(echo_app, monkeypatch):
     assert stub.names <= ALL_NAMES
 
 
+# -- the transport's own clock: echo model over HTTP ---------------------------
+
+def _stream_frames(base, path, body):
+    """POST a streamed request; (its trace id, the decoded ``data:`` frames
+    the client read, ``[DONE]`` left out)."""
+    req = urllib.request.Request(
+        base + path, data=json.dumps(dict(body, stream=True)).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=30) as resp:
+        lines = resp.read().decode().splitlines()
+        trace_id = resp.headers["X-Correlation-ID"]
+    datas = [ln[len("data: "):] for ln in lines if ln.startswith("data: ")]
+    return trace_id, [json.loads(d) for d in datas if d != "[DONE]"]
+
+
+def _token_frames(frames):
+    """Completions frames that carry a token: a choice that is not the
+    terminal one."""
+    return [f for f in frames if f["choices"] and f["choices"][0]["finish_reason"] is None]
+
+
+def _live_record(app, trace_id):
+    _flight(app, trace_id)  # finished
+    return next(r for r in app.container.telemetry.finished_since(0.0) if r.trace_id == trace_id)
+
+
+def test_stream_counts_its_token_frames_and_their_wait(echo_app):
+    app, base = echo_app
+    trace_id, frames = _stream_frames(
+        base, "/v1/completions", {"model": "echo", "prompt": "count me", "max_tokens": 7})
+    flight = _flight(app, trace_id)
+    assert flight["frames"] == len(_token_frames(frames)) == flight["tokens_out"] == 7
+    assert flight["frame_lag_max_s"] >= flight["frame_lag_mean_s"] >= 0
+    assert flight["frame_lag_max_s"] >= flight["first_frame_s"]  # the first frame's lag IS first_frame_s
+    # ECHO_STEP_MS between two tokens: the producer's side of the stream
+    assert 0.002 <= flight["deliver_gap_max_s"] < 1.0
+
+
+def test_accept_and_the_six_terms_sum_to_received_to_first_frame(echo_app):
+    app, base = echo_app
+    trace_id, _ = _stream_frames(
+        base, "/v1/completions", {"model": "echo", "prompt": "six terms", "max_tokens": 3})
+    record = _live_record(app, trace_id)
+    flight = record.to_dict()
+    assert flight["accept_s"] is not None and 0 <= flight["accept_s"] < 5.0
+    terms = ("accept_s", "parse_s", "queue_wait_s", "prefill_s", "first_frame_s")
+    total = sum(flight[name] for name in terms) + (flight["sched_defer_s"] or 0.0)
+    assert total == pytest.approx(record.t_first_frame - record.t_received, abs=1e-3)
+    assert record.t_received <= record.t_start  # the server's mark, one clock
+
+
+def test_non_streamed_request_counts_no_frame(echo_app):
+    app, base = echo_app
+    flight = _flight(app, _complete(base, stream=False, prompt="no frames"))
+    assert flight["frames"] is None
+    assert flight["frame_lag_mean_s"] is None and flight["frame_lag_max_s"] is None
+    assert flight["accept_s"] >= 0 and flight["deliver_gap_max_s"] >= 0.002
+
+
+@pytest.mark.parametrize("temperature, streams", [(0.7, 2), (0, 1)],
+                         ids=["sampled_two_streams", "greedy_one_stream_replicated"])
+def test_fanout_counts_every_candidates_frames_on_its_one_record(echo_app, temperature, streams):
+    app, base = echo_app
+    trace_id, frames = _stream_frames(
+        base, "/v1/completions",
+        {"model": "echo", "prompt": "two of us", "max_tokens": 5, "n": 2,
+         "temperature": temperature})
+    flight = _flight(app, trace_id)
+    token_frames = _token_frames(frames)
+    assert {f["choices"][0]["index"] for f in token_frames} == {0, 1}
+    assert flight["frames"] == len(token_frames) == 10
+    assert flight["tokens_out"] == 5 * streams
+    assert flight["frame_lag_max_s"] >= flight["frame_lag_mean_s"] >= 0
+
+
+def test_chat_token_without_a_frame_is_taken_off_the_count(echo_app):
+    """Two-byte characters under the byte tokenizer: the first byte of each
+    decodes to no text and makes no frame; the role and terminal frames
+    carry no token."""
+    app, base = echo_app
+    trace_id, frames = _stream_frames(
+        base, "/v1/chat/completions",
+        {"model": "echo", "messages": [{"role": "user", "content": "\u00e9\u00e9\u00e9\u00e9"}],
+         "max_tokens": 80})  # the echo cycles the rendered prompt: the template, then these
+    flight = _flight(app, trace_id)
+    with_text = [f for f in frames if f["choices"][0]["delta"].get("content")]
+    assert flight["tokens_out"] == 80
+    assert 0 < flight["frames"] == len(with_text) < 80
+    assert flight["frame_lag_max_s"] < 1.0  # later frames stay matched to their own delivery
+
+
+def test_blocked_loop_shows_on_the_request_the_histogram_the_page_and_one_log_line(
+        echo_app, monkeypatch):
+    app, base = echo_app
+    clock = app.http_server.loop_clock
+    logger = MockLogger(Level.WARN)
+    monkeypatch.setattr(clock, "_logger", logger)
+
+    async def hold_the_loop():
+        time.sleep(0.3)  # on the loop's own thread: nothing else runs
+
+    done: list = []
+    stream = threading.Thread(target=lambda: done.append(_stream_frames(
+        base, "/v1/completions", {"model": "echo", "prompt": "across it", "max_tokens": 400})))
+    stream.start()
+    time.sleep(0.15)  # the stream is under way (400 tokens of 2 ms)
+    asyncio.run_coroutine_threadsafe(hold_the_loop(), app.http_server._loop).result(10.0)
+    stream.join(30.0)
+    assert not stream.is_alive() and done
+    flight = _flight(app, done[0][0])
+    assert flight["frames"] == 400
+    assert 0.2 <= flight["loop_lag_max_s"] < 5.0
+    assert flight["loop_lag_max_s"] >= flight["loop_lag_mean_s"] > 0
+    # tokens went on being delivered while nothing was written: the frame's
+    # wait holds the loop's, the producer's gap does not
+    assert flight["frame_lag_max_s"] >= 0.2
+    assert flight["frame_lag_max_s"] > flight["deliver_gap_max_s"]
+    page = json.loads(urllib.request.urlopen(base + "/admin/engine", timeout=10).read())["data"]
+    assert page["http"]["loop_lag_max_ms"] >= 200.0
+    assert page["http"]["loop_lag_max_ms"] >= page["http"]["loop_lag_p99_ms"] >= 0
+    assert page["http"]["frames_total"] >= 400
+    text = app.container.metrics.expose()
+    buckets = {
+        ln.split('le="')[1].split('"')[0]: float(ln.rsplit(" ", 1)[1])
+        for ln in text.splitlines()
+        if ln.startswith("gofr_tpu_http_loop_lag_seconds_bucket")
+    }
+    assert buckets["+Inf"] > buckets["0.1"]  # an observation past 100 ms
+    late = [json.loads(ln) for ln in logger.lines if "http_loop_late" in ln]
+    # one line a late tick (pinned below); a loaded machine may add a tick of its own
+    assert late and max(ln["message"]["lag_s"] for ln in late) >= 0.2
+    assert all("running" in ln["message"] for ln in late)
+
+
+def test_loop_clock_reads_the_ticks_of_a_life_and_says_what_ran(monkeypatch):
+    from gofr_tpu.http.server import LoopClock
+
+    timeline = DispatchTimeline(capacity=4)
+    rec = timeline.begin("decode_chunk", batch_size=2)
+    with profiling.phase(profiling.POOL_ISSUE, rec, end="t_issued"):
+        pass
+    logger = MockLogger(Level.WARN)
+    clock = LoopClock(logger=logger, running=timeline.running)
+    assert clock.lags(0.0, time.perf_counter()) == (None, None)
+    assert clock.snapshot() == {"loop_lag_p99_ms": None, "loop_lag_max_ms": None}
+    t0 = time.perf_counter()
+    for lag in (0.001, 0.003, 0.002):
+        clock.note(lag)
+    t1 = time.perf_counter()
+    clock.note(0.4)  # late: logged, and after t1
+    mean, worst = clock.lags(t0, t1)
+    assert mean == pytest.approx(0.002) and worst == pytest.approx(0.003)
+    assert clock.lags(t1, time.perf_counter()) == (pytest.approx(0.4), pytest.approx(0.4))
+    assert clock.snapshot() == {"loop_lag_p99_ms": pytest.approx(400.0),
+                                "loop_lag_max_ms": pytest.approx(400.0)}
+    (line,) = [json.loads(ln) for ln in logger.lines]
+    assert line["message"] == {
+        "event": "http_loop_late", "lag_s": 0.4,
+        "running": [{"dispatch_id": rec.dispatch_id, "kind": "decode_chunk",
+                     "phase": "in_flight"}],
+    }
+    # a tick late while the engine boots (imports, warm-up compiles) is no news
+    booting = LoopClock(logger=logger, ready=lambda: False)
+    booting.note(0.9)
+    assert len(logger.lines) == 1 and booting.snapshot()["loop_lag_max_ms"] == pytest.approx(900.0)
+    timeline.finish(rec)
+    assert timeline.running() == [] and rec.phase == "done"
+
+
+def test_frames_are_matched_to_the_oldest_delivery_with_tokens_left():
+    """The record's own arithmetic, no server: a burst of 3 then a burst of
+    2, five token frames and a terminal one."""
+    record = FlightRecorder().start("m", "/t", stream=True, activate=False)
+    assert not record.note_frame(1.0)  # the role frame: nothing delivered yet
+    record.note_delivered(3, now=10.0)
+    record.note_delivered(2, now=10.5)
+    record.note_delivered(0, now=99.0)  # an empty burst is no delivery
+    for now in (10.1, 10.2, 10.7, 10.8, 10.9):
+        assert record.note_frame(now)
+    assert not record.note_frame(11.0)  # the terminal frame: none left
+    flight = record.to_dict()
+    assert flight["frames"] == 5
+    assert flight["frame_lag_mean_s"] == pytest.approx((0.1 + 0.2 + 0.7 + 0.3 + 0.4) / 5)
+    assert flight["frame_lag_max_s"] == pytest.approx(0.7)
+    assert flight["deliver_gap_max_s"] == pytest.approx(0.5)
+    assert flight["accept_s"] is None and flight["loop_lag_max_s"] is None  # no server
+
+
+def test_end_of_the_token_frames_drops_what_was_never_framed():
+    """A stop string ends the token loop with tokens delivered and never
+    framed: the terminal, usage and [DONE] frames must not take them, and
+    a producer that runs on until it sees the cancel notes no more."""
+    record = FlightRecorder().start("m", "/t", stream=True, activate=False)
+    record.note_delivered(3, now=10.0)
+    assert record.note_frame(10.1) and record.note_frame(10.2)
+    record.end_token_frames()
+    record.note_delivered(4, now=10.5)  # the pool's next burst, before the cancel lands
+    assert not record.note_frame(10.6)  # the terminal frame
+    assert not record.note_frame(10.7)  # [DONE]
+    flight = record.to_dict()
+    assert flight["frames"] == 2 and flight["frame_lag_max_s"] == pytest.approx(0.2)
+    assert flight["deliver_gap_max_s"] == pytest.approx(0.5)  # the producer's side is kept
+
+
+_CHAT_ABC = [{"role": "user", "content": "abcdefgh"}]
+
+
+@pytest.mark.parametrize("path, body, slack", [
+    ("/v1/completions", {"prompt": "abcdefgh", "stop": ["ef"]}, 0),
+    ("/v1/completions", {"prompt": "abcdefgh", "stop_token_ids": [ord("f")]}, 0),
+    ("/v1/completions", {"prompt": "abcdefgh", "stop": ["ef"], "n": 2, "temperature": 0}, 0),
+    ("/v1/chat/completions", {"messages": _CHAT_ABC, "stop": ["ef"]}, 0),
+    ("/v1/chat/completions",
+     {"messages": _CHAT_ABC, "stop": ["ef"], "n": 2, "temperature": 0,
+      "stream_options": {"include_usage": True}}, 0),
+    # two generations on one record share its deliveries: the terminal
+    # frame of the one that stops first may take a token of the other's
+    ("/v1/completions", {"prompt": "abcdefgh", "stop": ["ef"], "n": 2, "temperature": 0.7}, 1),
+], ids=["completions_stop_string", "completions_stop_token", "completions_stop_string_n2",
+        "chat_stop_string", "chat_stop_string_n2_usage", "completions_stop_string_n2_sampled"])
+def test_stream_ended_by_a_stop_counts_only_its_token_frames(echo_app, path, body, slack):
+    """The frames behind a stop (terminal, usage) carry no token, though
+    tokens were delivered past it before the decode was cancelled."""
+    app, base = echo_app
+    trace_id, frames = _stream_frames(base, path, dict(body, model="echo", max_tokens=200))
+    flight = _flight(app, trace_id)
+    chosen = [f["choices"][0] for f in frames if f["choices"]]
+    assert {c["finish_reason"] for c in chosen if c["finish_reason"]} == {"stop"}
+    if path.endswith("/chat/completions"):
+        tokens = [c for c in chosen if c["delta"].get("content")]
+    else:
+        tokens = [c for c in chosen if c["finish_reason"] is None]
+    assert 0 < len(tokens) <= flight["frames"] <= len(tokens) + slack < 200
+    assert flight["frame_lag_max_s"] >= flight["frame_lag_mean_s"] >= 0
+
+
 # -- the decode pool, the wait for a seat and the solo decode: tiny transformer ---
 
 _TINY = {
@@ -251,6 +491,19 @@ def held_pool():
         return real_fetch(in_flight, last_fetch_done)
 
     pool._fetch_and_deliver = held_fetch
+    # the worker's first chunk waits (the pool's lock let go meanwhile) until
+    # BOTH riders are seated: they ride the same chunks and end together,
+    # whichever thread reached submit first
+    aboard = threading.Event()
+    real_dispatch = pool._dispatch_chunk
+
+    def dispatch_both_aboard(in_flight):
+        if not aboard.is_set():
+            pool._work.wait_for(lambda: len(pool._active) == 2, timeout=60.0)
+            aboard.set()
+        return real_dispatch(in_flight)
+
+    pool._dispatch_chunk = dispatch_both_aboard
     recorder = FlightRecorder()
 
     def serve(prompt, n, out, sampler=None):
@@ -290,6 +543,7 @@ def held_pool():
         for thread in threads:
             thread.join(60.0)
         pool._fetch_and_deliver = real_fetch
+        pool._dispatch_chunk = real_dispatch
     assert not any(thread.is_alive() for thread in threads)
     assert len(riders) == 2 and len(waited) == 1
     deadline = time.monotonic() + 10.0
@@ -418,6 +672,81 @@ def test_metrics_count_tokens_and_export_no_utilisation(held_pool):
         assert family not in text
 
 
+def test_delivery_held_back_shows_in_the_riders_deliver_gap(held_pool):
+    """The riders' first tokens were out when the worker was held at its
+    first fetch; their next delivery came once it was let go, after the
+    prefill, the solo decode and the 20 ms a waiter stood: the gap is the
+    pool's side of that silence, on a record that was never streamed."""
+    for rider in held_pool.riders:
+        assert rider["deliver_gap_max_s"] > 0.02
+        assert rider["frames"] is None
+    # one delivery only (a single token): no gap to speak of
+    assert held_pool.prefill_only["deliver_gap_max_s"] is None
+
+
+def test_pooled_stream_frames_each_token_of_each_burst(held_pool):
+    """A stream over the pool, served as the HTTP server serves it (the
+    responder's iterator pulled on an event loop, ``on_write`` after each
+    frame): the first token and two bursts of DECODE_CHUNK tokens make nine
+    token frames, the terminal frame none."""
+    from gofr_tpu.http.responder import _sse_iter
+    from gofr_tpu.http.response import Stream
+
+    recorder = FlightRecorder()
+    record = recorder.start(model="tiny", endpoint="/t", stream=True)
+    try:
+        tokens = held_pool.dev.generate_stream([5, 3, 5, 8], 9)
+    finally:
+        activate_record(None)
+
+    def events():
+        for token in tokens:
+            yield {"token": token}
+        yield {"finish": True}
+
+    async def serve():
+        return [frame async for frame in _sse_iter(recorder.finish_stream(Stream(events()), record))]
+
+    frames = asyncio.run(serve())
+    flight = record.to_dict()
+    assert flight["status"] == "ok" and flight["tokens_out"] == 9
+    assert flight["frames"] == len(frames) - 1 == 9
+    assert flight["frame_lag_max_s"] >= flight["frame_lag_mean_s"] >= 0
+    assert flight["frame_lag_max_s"] >= flight["first_frame_s"] > 0
+    assert flight["deliver_gap_max_s"] > 0  # first token -> first burst -> second
+    assert flight["pool_admit_s"] is not None  # it rode the pool
+
+
+def test_solo_chunk_notes_only_the_tokens_before_its_stop(held_pool):
+    """A seeded request decodes solo, DECODE_CHUNK tokens a fetch. With a
+    stop token in the middle of a chunk the tokens noted as delivered are
+    those handed on, not the chunk's."""
+    dev = held_pool.dev
+
+    def stream(stop_tokens):
+        record = FlightRecorder().start(model="tiny", endpoint="/t", stream=True)
+        try:
+            tokens = list(dev.generate_stream(
+                [6, 2, 8], 9, sampler=Sampler(temperature=1.0, seed=5),
+                stop_tokens=stop_tokens))
+        finally:
+            activate_record(None)
+        noted = record._head_left + sum(n for _, n in record._deliveries)
+        return tokens, noted, record
+
+    free, noted, record = stream(frozenset())
+    assert len(free) == noted == 9 and record.pool_cohort == 0  # never rode the pool
+    # a token first seen inside a chunk (the first token is the prefill's,
+    # then chunks of 4: positions 1-4, 5-8), with more of the chunk behind it
+    at = next(j for j in range(1, 9) if j % 4 and free[j] not in free[:j])
+    stopped, noted, _ = stream(frozenset({free[at]}))
+    assert stopped == free[:at]
+    assert noted == at
+
+
 def test_pool_and_solo_annotations_are_leaves(held_pool):
     assert not held_pool.stub.nested
-    assert held_pool.stub.names == ALL_NAMES - {profiling.SSE_FIRST_FRAME}
+    # the echo app's server, where this module's other fixture is alive,
+    # ticks through the same stub
+    assert held_pool.stub.names - {profiling.HTTP_LOOP_TICK} == ALL_NAMES - {
+        profiling.SSE_FIRST_FRAME, profiling.HTTP_LOOP_TICK}
