@@ -615,9 +615,20 @@ def _attention_mixer(
     else:
         with jax.named_scope("attn.qkv"):
             h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-            q = _mm(h, p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-            k = _mm(h, p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-            v = _mm(h, p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            # the three products exist as [B, S, width] before anything views
+            # them by head. Left to itself the chip's compiler folds the
+            # reshape into the product (a convolution ``bf0_0oi->b0f``), which
+            # then wants its weight as [heads, head_dim, dim], the stored one
+            # transposed: a relaid copy of the whole stack a program run, or
+            # of a layer's slab a layer, and the product fed from that copy.
+            # Behind the barrier each is ``bf_io->bf`` over the stack as it
+            # is stored, the layer index fused in, like every other weight
+            # (tests/test_tpu_compile.py holds the compiled text to it)
+            q, k, v = jax.lax.optimization_barrier(
+                (_mm(h, p["wq"]), _mm(h, p["wk"]), _mm(h, p["wv"])))
+            q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     if kind == "retention":
         with jax.named_scope("attn.qk_norm"):
             q = rms_norm(q, p["q_norm"], cfg.norm_eps)

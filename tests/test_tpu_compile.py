@@ -71,6 +71,58 @@ def _compiled(fn, donate, one_chip, *trees):
     return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
 
 
+@pytest.fixture(scope="module")
+def texts() -> dict[tuple, str]:
+    """Compiled text by (program, configuration): the cases that read what
+    a program does with its WEIGHTS read the text the case about its cache
+    compiled (one file, one worker, one process: the module's docstring)."""
+    return {}
+
+
+def _pooled_chunk(texts, one_chip, name, cfg, slots, params):
+    """The pooled decode chunk of 8 steps over ``slots`` rows, as the pool
+    calls it: cache and key donated."""
+    if ("chunk", name) not in texts:
+        texts["chunk", name] = _compiled(
+            lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
+                p, t, c, cfg, 8, key, temp, tk, tp, mp),
+            (2, 3), one_chip,
+            params, jnp.zeros((slots, 1), jnp.int32),
+            lambda: T.init_cache(cfg, slots), lambda: jax.random.key(0),
+            jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.float32),
+        )
+    return texts["chunk", name]
+
+
+def _prefill(texts, one_chip, name, cfg, rows, bucket, params, donated=False):
+    """A prefill bucket as the server calls it (``donated=False``: the
+    caller keeps the cache it passed)."""
+    key = ("prefill", name, rows, bucket, donated)
+    if key not in texts:
+        texts[key] = _compiled(
+            lambda p, t, c, l: T.prefill(p, t, c, cfg, l), (2,) if donated else (), one_chip,
+            params, jnp.zeros((rows, bucket), jnp.int32),
+            lambda: T.init_cache(cfg, rows), jnp.zeros((rows,), jnp.int32),
+        )
+    return texts[key]
+
+
+def _instructions(hlo: str):
+    """(computation, name, result shape, its layout's minor-to-major order,
+    opcode) of every instruction with an array result; the computation is
+    "ENTRY" or the name of a loop body, a fused computation, a called one."""
+    computation = ""
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            computation = "ENTRY" if head.group(1) else head.group(2)
+            continue
+        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{([\d,]*)[^ ]* ([\w\-]+)\(", line)
+        if match:
+            yield (computation, *match.groups())
+
+
 def _cache_movers(hlo: str, batch: int, cfg=CFG) -> dict[str, list[str]]:
     """computation name -> the instructions in it that write a result of
     the whole cache's or one layer slab's shape by moving data: in the
@@ -81,15 +133,9 @@ def _cache_movers(hlo: str, batch: int, cfg=CFG) -> dict[str, list[str]]:
                  f"{batch},{cfg.max_seq},{cfg.n_kv_heads},{cfg.head_dim}"):
         shapes |= {f"bf16[{cfg.n_layers},{slab}]", f"bf16[1,{slab}]", f"bf16[{slab}]"}
     found: dict[str, list[str]] = {}
-    computation = ""
-    for line in hlo.splitlines():
-        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
-        if head:
-            computation = "ENTRY" if head.group(1) else head.group(2)
-            continue
-        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
-        if match and match.group(2) in shapes and match.group(3) in _MOVERS:
-            found.setdefault(computation, []).append(match.group(1))
+    for computation, name, shape, _, opcode in _instructions(hlo):
+        if shape in shapes and opcode in _MOVERS:
+            found.setdefault(computation, []).append(name)
     return found
 
 
@@ -97,16 +143,8 @@ def _abstract_params():
     return lambda: T.init_transformer(jax.random.key(0), CFG)
 
 
-def test_pooled_chunk_moves_its_cache_nowhere(one_chip, as_on_tpu):
-    hlo = _compiled(
-        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
-            p, t, c, CFG, 8, key, temp, tk, tp, mp),
-        (2, 3), one_chip,
-        _abstract_params(), jnp.zeros((SLOTS, 1), jnp.int32),
-        lambda: T.init_cache(CFG, SLOTS), lambda: jax.random.key(0),
-        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
-        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.float32),
-    )
+def test_pooled_chunk_moves_its_cache_nowhere(texts, one_chip, as_on_tpu):
+    hlo = _pooled_chunk(texts, one_chip, "internlm2-bf16", CFG, SLOTS, _abstract_params())
     assert "tpu_custom_call" in hlo  # the Mosaic kernel, not interpret mode
     # the cache is stored in the order the kernel reads and donated through
     # the chunk: the token writes and the kernel work on the buffer itself
@@ -152,16 +190,15 @@ def test_pooled_chunk_compiles_for_four_chips_under_tp(topo, as_on_tpu):
     assert cache_specs(cache)["k"][2] == "tp"
     per_chip = dataclasses.replace(CFG, n_kv_heads=CFG.n_kv_heads // 4)
     assert _cache_movers(hlo, SLOTS, per_chip) == {}
+    # each shard's q, k and v are products over its columns of the stacks as
+    # they are stored (the last section of this file)
+    assert _qkv_products(hlo) == ["io"] * 3
 
 
 @pytest.mark.parametrize("donated", [True, False])
-def test_prefill_moves_its_cache_nowhere(one_chip, as_on_tpu, donated):
+def test_prefill_moves_its_cache_nowhere(texts, one_chip, as_on_tpu, donated):
     rows = 2
-    hlo = _compiled(
-        lambda p, t, c, l: T.prefill(p, t, c, CFG, l), (2,) if donated else (), one_chip,
-        _abstract_params(), jnp.zeros((rows, 512), jnp.int32),
-        lambda: T.init_cache(CFG, rows), jnp.zeros((rows,), jnp.int32),
-    )
+    hlo = _prefill(texts, one_chip, "internlm2-bf16", CFG, rows, 512, _abstract_params(), donated)
     assert "tpu_custom_call" in hlo
     movers = _cache_movers(hlo, rows)
     if donated:
@@ -189,15 +226,9 @@ def _state_writers(hlo: str, batch: int) -> dict[str, list[str]]:
     row = f"{batch},{RCFG.n_kv_heads},{RCFG.head_dim},8320"
     shapes = (f"f32[{RCFG.n_layers},{row}]", f"f32[1,{row}]", f"f32[{row}]")
     found: dict[str, list[str]] = {}
-    computation = ""
-    for line in hlo.splitlines():
-        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
-        if head:
-            computation = "ENTRY" if head.group(1) else head.group(2)
-            continue
-        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
-        if match and match.group(2) in shapes and match.group(3) in _MOVERS:
-            found.setdefault(computation, []).append(match.group(1))
+    for computation, name, shape, _, opcode in _instructions(hlo):
+        if shape in shapes and opcode in _MOVERS:
+            found.setdefault(computation, []).append(name)
     return found
 
 
@@ -205,16 +236,8 @@ def _retention_params():
     return lambda: T.init_transformer(jax.random.key(0), RCFG)
 
 
-def test_pooled_chunk_of_a_retention_model_leaves_its_state_to_the_kernel(one_chip, as_on_tpu):
-    hlo = _compiled(
-        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
-            p, t, c, RCFG, 8, key, temp, tk, tp, mp),
-        (2, 3), one_chip,
-        _retention_params(), jnp.zeros((SLOTS, 1), jnp.int32),
-        lambda: T.init_cache(RCFG, SLOTS), lambda: jax.random.key(0),
-        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
-        jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.float32),
-    )
+def test_pooled_chunk_of_a_retention_model_leaves_its_state_to_the_kernel(texts, one_chip, as_on_tpu):
+    hlo = _pooled_chunk(texts, one_chip, "brumby", RCFG, SLOTS, _retention_params())
     assert "retention_step" in hlo and "tpu_custom_call" in hlo
     # the state is donated through the chunk: the kernel reads and writes
     # its layer of it in place, and nothing copies or slices it anywhere
@@ -255,12 +278,8 @@ def _expert_movers(hlo: str) -> list[str]:
     shapes = {f"bf16[{ZCFG.n_layers},{ZCFG.n_experts},{shape}]",
               f"bf16[{ZCFG.n_experts},{shape}]", f"bf16[1,{ZCFG.n_experts},{shape}]",
               f"bf16[{shape}]"}
-    found = []
-    for line in hlo.splitlines():
-        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
-        if match and match.group(2) in shapes and match.group(3) in _MOVERS:
-            found.append(match.group(1))
-    return found
+    return [name for _, name, shape, _, opcode in _instructions(hlo)
+            if shape in shapes and opcode in _MOVERS]
 
 
 def _zaya_params():
@@ -312,9 +331,8 @@ JCFG = T.TransformerConfig(
     layer_kinds=tuple("softmax" if i % 14 == 7 else "ssm" for i in range(28)),
 )
 JSLOTS = 64
-# a weight stack of either kind, and one layer of it (not the two attention
-# layers' q and o projections, 26 MB a stack: the compiler relays those once
-# where a program begins and parks a layer of them in the fast memory)
+# a weight stack of either kind's SwiGLU or state-space projections, and one
+# layer of it (the two attention layers' own projections: the last section)
 _JWEIGHTS = {f"bf16[{n},{shape}]" for n in (26, 2, 1) for shape in (
     "2560,10240", "5120,2560", "2560,8192", "8192,2560")}
 
@@ -332,19 +350,11 @@ def _hybrid_movers(hlo: str, batch: int) -> dict[str, list[str]]:
               f"bf16[26,{batch},{3 * JCFG.d_inner}]",
               f"bf16[2,{batch},1,{JCFG.max_seq},128]"} | _JWEIGHTS
     found: dict[str, list[str]] = {}
-    computation = ""
-    for line in hlo.splitlines():
-        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
-        if head:
-            computation = "ENTRY" if head.group(1) else head.group(2)
-            continue
-        match = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+?)\{[^ ]* ([\w\-]+)\(", line)
+    for computation, name, shape, _, opcode in _instructions(hlo):
         # inside a fused computation nothing is written out: a layer's weights
         # sliced there feed the product they are fused with
-        if (not match or match.group(2) not in leaves
-                or computation.startswith("fused_computation")):
+        if shape not in leaves or computation.startswith("fused_computation"):
             continue
-        name, opcode = match.group(1), match.group(3)
         if opcode in ("copy", "transpose", "reshape", "slice", "dynamic-slice") or (
                 opcode == "fusion" and not _updates_in_place(hlo, name)):
             found.setdefault(computation, []).append(name)
@@ -367,16 +377,8 @@ def _jamba_params():
 
 
 def test_pooled_chunk_of_a_hybrid_model_leaves_state_tail_and_weights_where_they_lie(
-        one_chip, as_on_tpu):
-    hlo = _compiled(
-        lambda p, t, c, key, temp, tk, tp, mp: T.decode_chunk_pool(
-            p, t, c, JCFG, 8, key, temp, tk, tp, mp),
-        (2, 3), one_chip,
-        _jamba_params, jnp.zeros((JSLOTS, 1), jnp.int32),
-        lambda: T.init_cache(JCFG, JSLOTS), lambda: jax.random.key(0),
-        jnp.zeros((JSLOTS,), jnp.float32), jnp.zeros((JSLOTS,), jnp.int32),
-        jnp.zeros((JSLOTS,), jnp.float32), jnp.zeros((JSLOTS,), jnp.float32),
-    )
+        texts, one_chip, as_on_tpu):
+    hlo = _pooled_chunk(texts, one_chip, "jamba2", JCFG, JSLOTS, _jamba_params)
     # one state-space body a run of the PERIOD (7 and 6 layers: two step
     # kernels) and the decode form of flash attention at a group of 20
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 3
@@ -403,3 +405,134 @@ def test_prefill_of_a_hybrid_model_compiles_at_the_cells_buckets(one_chip, as_on
     # tail, 0.8 MB, twice) and in no loop
     movers = _hybrid_movers(hlo, rows)
     assert set(movers) <= {"ENTRY"} and len(movers.get("ENTRY", [])) <= 6, movers
+
+
+# -- a layer's weights are read where they lie, inside their matmul, once -----------------------
+# The chip's compiler may fold the reshape by head that follows a product
+# into the product (a convolution ``bf0_0oi->b0f``, the result [B, heads,
+# head_dim]); that form wants its weight as [heads, head_dim, dim], the stored
+# one transposed, so the program relays the whole stack once a run
+# (``copy(%p__layers____wq…)``) or a layer's slab once a layer, copies each
+# layer's slab out as an operation of its own (``constant_dynamic-slice_fusion``:
+# the weight's whole HBM read) and runs the product from the copy.
+# ``_attention_mixer`` holds the three products 2-D until they exist.
+
+# Mistral-7B-v0.3's widths and depth (benchmark/configs), int8 as the cell
+# serves it, 6 slots; a vocabulary whose head and table share no shape
+# with a layer's weights
+MCFG = T.TransformerConfig(
+    vocab_size=8192, dim=4096, n_layers=32, n_heads=32, n_kv_heads=8,
+    hidden_dim=14336, max_seq=2048, rope_theta=1e6,
+)
+MSLOTS = 6
+_HLO_TYPES = {"bfloat16": "bf16", "int8": "s8"}
+
+
+def _mistral_params():
+    from gofr_tpu.models import quant
+
+    return quant.quantize_params(T.init_transformer(jax.random.key(0), MCFG), "int8")
+
+
+def _weight_stacks(layers) -> set[tuple[str, int, int, int]]:
+    """(type, layers, in, out) of every stacked matmul leaf under ``layers``
+    (a narrow leaf, the retention gate's 40 columns or a convolution's 4
+    taps, is no weight stream)."""
+    return {
+        (_HLO_TYPES[leaf.dtype.name], *leaf.shape)
+        for leaf in jax.tree.leaves(jax.eval_shape(layers))
+        if leaf.ndim == 3 and leaf.dtype.name in _HLO_TYPES and min(leaf.shape[1:]) >= 128
+    }
+
+
+def _weight_reads(hlo: str, stacks) -> dict[str, list]:
+    """What the compiled text does with the weight ``stacks``:
+
+    ``moved``: (computation, instruction) outside the fused computations
+    whose result has the shape of a stack, of one layer's slab or of that
+    slab as a matrix, in the stored type or dequantised, written by moving
+    data (a fusion with such a result is rooted in a slice or a copy:
+    ``constant_dynamic-slice_fusion``); ``prefetched``: the compiler's own
+    asynchronous fetches of a small stack into the fast memory, in the
+    layout it has (``copy-done``, ``slice-done``: no relayout, and beside
+    the weight stream, not in it); ``relaid``: the shapes that appear
+    anywhere in a layout other than the one the program was handed."""
+    shapes = set()
+    for kind, n, i, o in stacks:
+        for t in {kind, "bf16"}:
+            shapes |= {f"{t}[{n},{i},{o}]", f"{t}[1,{i},{o}]", f"{t}[{i},{o}]"}
+    reads: dict[str, list] = {"moved": [], "prefetched": [], "relaid": []}
+    for computation, name, shape, layout, opcode in _instructions(hlo):
+        if shape not in shapes:
+            continue
+        if layout != ",".join(str(d) for d in reversed(range(shape.count(",") + 1))):
+            reads["relaid"].append((shape, layout))
+        if computation.startswith("fused_computation"):
+            continue  # nothing is written out there: the slice feeds its product
+        if opcode in ("copy-done", "slice-done"):
+            reads["prefetched"].append((computation, name))
+        elif opcode in _MOVERS:
+            reads["moved"].append((computation, name))
+    return reads
+
+
+def _qkv_products(hlo: str) -> list[str]:
+    """The kernel side of the ``dim_labels`` of every product of the
+    ``attn.qkv`` scope, less the batch axis a prefill adds (``io0``): ``io``
+    is the weight as it is stored, [dim, width]; ``0oi`` (``1oi``) is the
+    fold, [heads, head_dim, dim]."""
+    return [
+        labels.split("_")[1].split("->")[0].rstrip("0")
+        for labels in re.findall(
+            r" convolution\(.*dim_labels=([\w>\-]+).*op_name=\"[^\"]*attn\.qkv/dot_general", hlo)
+    ]
+
+
+def _reads_weights_in_place(hlo: str, stacks, prefetches: int = 0) -> None:
+    reads = _weight_reads(hlo, stacks)
+    assert reads["moved"] == [] and reads["relaid"] == [], reads
+    assert len(reads["prefetched"]) <= prefetches, reads
+    products = _qkv_products(hlo)
+    assert len(products) >= 3 and set(products) == {"io"}, products
+
+
+@pytest.mark.parametrize("program", ["chunk", "prefill"])
+@pytest.mark.parametrize("model", ["internlm2-bf16", "mistral-int8"])
+def test_dense_programs_read_every_weight_stack_where_it_lies(texts, one_chip, as_on_tpu, model, program):
+    """On the tree before this test, three of the four fail: both pooled
+    chunks copy the ``wq`` and ``wk`` stacks in ENTRY and slice a layer of
+    each in the layer loop (``constant_dynamic-slice_fusion.19 =
+    s8[1,4096,4096]``, ``.4 = bf16[1,2048,2048]``), the bf16 prefill slices
+    and copies all three slabs in its loop; the int8 prefill held no fold
+    (the scale's multiply stands between product and reshape)."""
+    cfg, slots, params = ((CFG, SLOTS, _abstract_params()) if model == "internlm2-bf16"
+                          else (MCFG, MSLOTS, _mistral_params))
+    hlo = (_pooled_chunk(texts, one_chip, model, cfg, slots, params) if program == "chunk"
+           else _prefill(texts, one_chip, model, cfg, 2, 512, params))
+    assert "tpu_custom_call" in hlo
+    _reads_weights_in_place(hlo, _weight_stacks(lambda: params()["layers"]))
+
+
+def test_pooled_chunk_of_a_retention_model_reads_every_weight_stack_where_it_lies(
+        texts, one_chip, as_on_tpu):
+    """Brumby's q and k norms stand between product and rotary and its
+    kernel takes v by head: the tree before this test folded all THREE
+    products here (``copy`` of the ``wq``, ``wk`` and ``wv`` stacks in
+    ENTRY). What is left is the compiler's prefetch of the two small stacks
+    (``wk``, ``wv``: 21 MB each at this test's two layers) as they lie."""
+    hlo = _pooled_chunk(texts, one_chip, "brumby", RCFG, SLOTS, _retention_params())
+    _reads_weights_in_place(
+        hlo, _weight_stacks(lambda: _retention_params()()["layers"]), prefetches=2)
+
+
+def test_pooled_chunk_of_a_hybrid_model_reads_its_attention_weights_where_they_lie(
+        texts, one_chip, as_on_tpu):
+    """Jamba's two attention layers have no rotary, and the tree before this
+    test folded their ``wq`` all the same (a ``copy`` of the 26 MB stack in
+    ENTRY, a slice of a layer of it in the loop): the fold is the reshape's
+    whoever follows it. Left: the compiler's prefetches, as they lie, of
+    the ``wk`` and ``wv`` stacks (0.65 MB a layer) and of each attention
+    layer's ``w_down`` slab while the layers before it run."""
+    hlo = _pooled_chunk(texts, one_chip, "jamba2", JCFG, JSLOTS, _jamba_params)
+    _reads_weights_in_place(
+        hlo, _weight_stacks(lambda: _jamba_params()["layers"]["softmax"]), prefetches=4)
