@@ -156,6 +156,73 @@ JAMBA2_3B = TransformerConfig(
     tie_embeddings=True,
 )
 
+# LongCat-Flash's block at test size: latent attention (MLA: 4 heads, a q
+# bottleneck of 32, a cached latent of 16 and one shared rotated key of 8)
+# in the shortcut-connected double layer (two (attention, dense SwiGLU)
+# sublayers and one expert product added two sublayers later); a linear
+# router over 8 routed and 4 identity experts, top-3, of which this chip
+# holds experts 0-1 (rank 0 of ep = 4); untied head. CPU tests.
+TINY_LONGCAT = TransformerConfig(
+    vocab_size=256,
+    dim=64,
+    n_layers=2,
+    n_heads=4,
+    n_kv_heads=1,
+    hidden_dim=128,
+    max_seq=128,
+    rope_theta=10000.0,
+    dtype=jnp.float32,
+    attn_impl="xla",
+    attn_kind="mla",
+    q_lora_rank=32,
+    kv_lora_rank=16,
+    qk_nope_dim=16,
+    qk_rope_dim=8,
+    v_head_dim=16,
+    ffn_kind="scmoe",
+    router_kind="linear",
+    n_experts=2,
+    n_routed_experts=8,
+    n_identity_experts=4,
+    top_k=3,
+    routed_scale=6.0,
+    expert_dim=32,
+)
+
+# LongCat-Flash-Chat (huggingface.co/meituan-longcat/LongCat-Flash-Chat
+# config.json): 28 double layers of MLA (64 heads, q rank 1536, latent 512,
+# rope 64, nope 128, v 128) and dense SwiGLUs of 12288 around one product of
+# 512 experts of 2048 and 256 identity experts, top-12 by a linear gate,
+# scores x 6; untied 131,072-row head; bf16. As rank 0 of an ep = 32
+# deployment holds it: 16 of each layer's experts (a layer's 512 are 38.7 GB).
+# All 28 layers of that share are some 72 GB: no one chip boots this entry (as
+# none boots `zaya1-8b` or `llama3-70b`); it holds the published widths, which
+# the benchmark cuts to 4 layers (`longcat-flash-ep32-bf16`) and the tests read
+LONGCAT_FLASH = TransformerConfig(
+    vocab_size=131072,
+    dim=6144,
+    n_layers=28,
+    n_heads=64,
+    n_kv_heads=1,
+    hidden_dim=12288,
+    max_seq=131072,
+    rope_theta=10000000.0,
+    attn_kind="mla",
+    q_lora_rank=1536,
+    kv_lora_rank=512,
+    qk_nope_dim=128,
+    qk_rope_dim=64,
+    v_head_dim=128,
+    ffn_kind="scmoe",
+    router_kind="linear",
+    n_experts=16,
+    n_routed_experts=512,
+    n_identity_experts=256,
+    top_k=12,
+    routed_scale=6.0,
+    expert_dim=2048,
+)
+
 # Small-but-realistic single-chip bench model (fits v5e-1 in bf16 and
 # exercises the same kernels/shapes class as 8B)
 SMALL = TransformerConfig(
@@ -176,6 +243,8 @@ CONFIGS: dict[str, TransformerConfig] = {
     "zaya1-8b": ZAYA1_8B,
     "tiny-jamba": TINY_JAMBA,
     "jamba2-3b": JAMBA2_3B,
+    "tiny-longcat": TINY_LONGCAT,
+    "longcat-flash-ep32": LONGCAT_FLASH,
     "small": SMALL,
     "llama3-8b": LLAMA3_8B,
     "llama3-70b": LLAMA3_70B,

@@ -152,9 +152,10 @@ def routed_mlp(
     cfg: TransformerConfig, p: dict, h: jnp.ndarray, r_before: jnp.ndarray,
     experts: dict, layer: jnp.ndarray, token_mask: Any = None,
 ) -> tuple[jnp.ndarray, dict]:
-    """The serving path's expert layer (``cfg.ffn_kind == "moe"``): an MLP
-    router whose state passes down the stack, top-1, and the routed product
-    (ops/experts.py), which reads only the experts that got a token.
+    """The serving path's expert layer (``cfg.routed``): a router and the
+    routed product (ops/experts.py), which reads only the experts that got
+    a token. Under ``cfg.router_kind == "linear"``: ``_linear_routed``. Else
+    an MLP router whose state passes down the stack, top-1:
 
     ``h`` [B, S, D] is the normed input, ``r_before`` [B, S, R] float32 the
     router state of the layer above (zeros at the first), ``experts`` the
@@ -172,6 +173,8 @@ def routed_mlp(
 
     b, s, d = h.shape
     f32 = jnp.float32
+    if cfg.router_kind == "linear":
+        return _linear_routed(cfg, p, h, r_before, experts, layer, token_mask)
     with jax.named_scope("moe.router"):
         # float32 throughout: a choice between two near experts should not
         # turn on the rounding of a 256-wide MLP
@@ -194,6 +197,53 @@ def routed_mlp(
     with jax.named_scope("moe.combine"):
         y = (y.reshape(b, s, d).astype(f32) * weight[..., None]).astype(h.dtype)
     return y, {"router_state": r, "expert_counts": counts}
+
+
+def _linear_routed(
+    cfg: TransformerConfig, p: dict, h: jnp.ndarray, r_before: jnp.ndarray,
+    experts: dict, layer: jnp.ndarray, token_mask: Any,
+) -> tuple[jnp.ndarray, dict]:
+    """``routed_mlp`` under the linear router (``cfg.router_kind``): a gate
+    over the deployment's routed experts and the identity experts, top-k by
+    score + bias, the scores themselves (times ``routed_scale``) the weights::
+
+        p = softmax(h Wr);  E = top-k of (p + bias)
+        y = scale * sum_{e in E} p_e f_e(h)
+
+    ``f_e`` is SwiGLU expert e for a routed e, ``h`` itself for an identity
+    one. This chip holds the routed experts ``ep_rank * n_experts`` onwards,
+    ``n_experts`` of them: a pair whose expert another chip holds adds
+    nothing here, an identity pair is computed here (a token's home chip
+    needs no exchange for it). ``expert_counts`` [n_experts + 2]: the pairs
+    each held expert got, then the identity pairs, then the absent ones."""
+    from gofr_tpu.ops.experts import routed_experts
+
+    b, s, d = h.shape
+    f32 = jnp.float32
+    with jax.named_scope("moe.router"):
+        probs = jax.nn.softmax(jnp.einsum(
+            "...i,io->...o", h.astype(f32), p["router"].astype(f32),
+            precision=lax.Precision.HIGHEST), axis=-1)
+        _, choice = lax.top_k(probs + p["router_bias"].astype(f32), cfg.top_k)
+        weight = jnp.take_along_axis(probs, choice, axis=-1) * cfg.routed_scale
+        real = jnp.ones(choice.shape, bool) if token_mask is None else token_mask[..., None]
+        local = choice - cfg.ep_rank * cfg.n_experts
+        held = real & (local >= 0) & (local < cfg.n_experts)
+        identity = real & (choice >= cfg.n_routed_experts)
+        absent = real & ~held & ~identity
+        expert = jnp.where(held, local, cfg.n_experts).astype(jnp.int32)
+    y, counts = routed_experts(
+        h.reshape(b * s, d), expert.reshape(b * s, cfg.top_k), experts["w_gate"],
+        experts["w_up"], experts["w_down"], layer, weight=weight.reshape(b * s, cfg.top_k),
+    )
+    with jax.named_scope("moe.identity"):
+        own = jnp.sum(jnp.where(identity, weight, 0.0), axis=-1)
+        y = y.reshape(b, s, d) + own[..., None] * h.astype(f32)
+    with jax.named_scope("moe.combine"):
+        y = y.astype(h.dtype)
+        counts = jnp.concatenate([counts, jnp.stack([jnp.sum(identity), jnp.sum(absent)])
+                                  .astype(jnp.int32)])
+    return y, {"router_state": r_before, "expert_counts": counts}
 
 
 def moe_block(

@@ -74,9 +74,28 @@ class TransformerConfig:
     # "moe": ``n_experts`` SwiGLU experts of width ``hidden_dim``, one a
     # token, chosen by an MLP router of width ``router_dim`` whose state
     # passes from layer to layer (models/moe.py::routed_mlp).
+    # "scmoe": the shortcut-connected double layer (``_shortcut_block``):
+    # two (mixer, dense SwiGLU of width ``hidden_dim``) sublayers and one
+    # product of ``n_experts`` SwiGLU experts of width ``expert_dim`` that
+    # takes the first sublayer's normed input and is added after the second.
     ffn_kind: str = "dense"
     n_experts: int = 0
     router_dim: int = 0
+    # who chooses the experts. "mlp": the MLP router above, one expert a
+    # token. "linear": a gate of ``n_routed_experts + n_identity_experts``
+    # outputs in float32, softmax, the ``top_k`` best by score + bias, each
+    # weighted by its score times ``routed_scale`` (not renormalised). The
+    # routed experts are the deployment's; this chip holds ``n_experts`` of
+    # them, those from ``ep_rank * n_experts`` on, and a choice of another
+    # chip's adds nothing here. An identity expert gives its input back and
+    # holds no weights.
+    router_kind: str = "mlp"
+    n_routed_experts: int = 0
+    n_identity_experts: int = 0
+    top_k: int = 1
+    routed_scale: float = 1.0
+    ep_rank: int = 0
+    expert_dim: int = 0  # 0: ``hidden_dim``
     # the mixer of each layer, one name a layer, where the layers are not
     # all alike; empty means every layer is ``attn_kind``. Beside the three
     # attention kinds above a layer may be "ssm": a selective state-space
@@ -85,6 +104,17 @@ class TransformerConfig:
     # row. Parameters and cache are stacked per kind (``MIXERS``) and the
     # layer loop scans the pattern's period (``layer_period``).
     layer_kinds: tuple = ()
+    # "mla" (an ``attn_kind``): latent attention (``_mla_mixer``, ops/mla.py).
+    # q through a bottleneck of ``q_lora_rank``; what a token leaves in the
+    # cache is one latent of ``kv_lora_rank`` and one rotated key of
+    # ``qk_rope_dim`` shared by all heads, from which each head's key
+    # (``qk_nope_dim`` wide beside the shared part) and value
+    # (``v_head_dim``) are made. ``head_dim`` is the query-key width.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # an "ssm" layer: ``ssm_expand * dim`` channels, ``ssm_state`` entries
     # of state a channel, a causal depthwise convolution of ``ssm_conv``
     # taps, a step size projected through ``ssm_dt_rank``. The state is
@@ -107,6 +137,10 @@ class TransformerConfig:
                     f"kind is one of {MIXER_KINDS}")
             if self.ffn_kind != "dense":
                 raise ValueError("layers of more than one kind take the dense feed-forward")
+        if self.attn_kind == "mla":
+            object.__setattr__(self, "head_dim", self.qk_nope_dim + self.qk_rope_dim)
+        if not self.expert_dim:
+            object.__setattr__(self, "expert_dim", self.hidden_dim)
 
     @property
     def kinds(self) -> tuple:
@@ -126,6 +160,23 @@ class TransformerConfig:
 
     def n_of(self, kind: str) -> int:
         return self.kinds.count(kind)
+
+    @property
+    def routed(self) -> bool:
+        """Whether a layer has routed experts (``ffn_kind`` "moe", "scmoe")."""
+        return self.ffn_kind in ("moe", "scmoe")
+
+    @property
+    def mixers_per_layer(self) -> int:
+        """Mixer sublayers a layer has, each with its place in the cache."""
+        return 2 if self.ffn_kind == "scmoe" else 1
+
+    @property
+    def routing_width(self) -> int:
+        """Columns of a layer's routing counts: the experts held and, under
+        the linear router, the pairs that chose an identity expert and those
+        that chose an expert of another chip."""
+        return self.n_experts + (2 if self.router_kind == "linear" else 0)
 
     @property
     def layer_period(self) -> tuple:
@@ -158,7 +209,7 @@ class TransformerConfig:
 
     @property
     def rope_dim(self) -> int:
-        return int(self.head_dim * self.rope_fraction)
+        return self.qk_rope_dim or int(self.head_dim * self.rope_fraction)
 
     @property
     def tail_dim(self) -> int:
@@ -250,7 +301,7 @@ def init_transformer(
         return jnp.zeros(shape, x.dtype, device=NamedSharding(mesh, spec))
 
     quantizer_for(quantize)  # validate the mode eagerly
-    if quantize and cfg.ffn_kind == "moe":
+    if quantize and cfg.routed:
         raise ValueError("the quantiser does not take expert-stacked leaves")
     if quantize and cfg.mixed:
         raise ValueError("the quantiser does not take layers stacked per kind")
@@ -299,8 +350,20 @@ def init_transformer(
             "cca_temp": jnp.ones((cfg.n_kv_heads,), cfg.dtype),
         }
 
+    def expert_leaves(i: int) -> dict:
+        e, f = cfg.n_experts, cfg.expert_dim
+        return {
+            "w_gate": extra(i, 7, (e, cfg.dim, f), cfg.dim),
+            "w_up": extra(i, 8, (e, cfg.dim, f), cfg.dim),
+            "w_down": extra(i, 9, (e, f, cfg.dim), f),
+        }
+
     def moe_leaves(i: int) -> dict:
-        r, e, f = cfg.router_dim, cfg.n_experts, cfg.hidden_dim
+        r, e = cfg.router_dim, cfg.n_experts
+        if cfg.router_kind == "linear":
+            outputs = cfg.n_routed_experts + cfg.n_identity_experts
+            return {"router": extra(i, 3, (cfg.dim, outputs), cfg.dim),
+                    "router_bias": jnp.zeros((outputs,), jnp.float32), **expert_leaves(i)}
         return {
             "router_down": extra(i, 3, (cfg.dim, r), cfg.dim),
             "router_down_b": jnp.zeros((r,), cfg.dtype),
@@ -311,9 +374,30 @@ def init_transformer(
             "router_w2": extra(i, 5, (r, r), r),
             "router_b2": jnp.zeros((r,), cfg.dtype),
             "router_w3": extra(i, 6, (r, e), r),
-            "w_gate": extra(i, 7, (e, cfg.dim, f), cfg.dim),
-            "w_up": extra(i, 8, (e, cfg.dim, f), cfg.dim),
-            "w_down": extra(i, 9, (e, f, cfg.dim), f),
+            **expert_leaves(i),
+        }
+
+    def mla_leaves(i: int, j: int) -> dict:
+        h, rq, rc = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        at = 30 + 8 * j
+        return {
+            "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
+            "wq_a": extra(i, at, (cfg.dim, rq), cfg.dim),
+            "q_norm": jnp.ones((rq,), cfg.dtype),
+            "wq_b": extra(i, at + 1, (rq, h * cfg.head_dim), rq),
+            "wkv_a": extra(i, at + 2, (cfg.dim, rc + cfg.qk_rope_dim), cfg.dim),
+            "kv_norm": jnp.ones((rc,), cfg.dtype),
+            "wkv_b": extra(i, at + 3, (rc, h * (cfg.qk_nope_dim + cfg.v_head_dim)), rc),
+            "wo": extra(i, at + 4, (h * cfg.v_head_dim, cfg.dim), h * cfg.v_head_dim),
+            "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
+        }
+
+    def dense_leaves(i: int, j: int) -> dict:
+        at = 35 + 8 * j
+        return {
+            "w_gate": extra(i, at, (cfg.dim, cfg.hidden_dim), cfg.dim),
+            "w_up": extra(i, at + 1, (cfg.dim, cfg.hidden_dim), cfg.dim),
+            "w_down": extra(i, at + 2, (cfg.hidden_dim, cfg.dim), cfg.hidden_dim),
         }
 
     def retention_leaves(i: int) -> dict:
@@ -357,9 +441,17 @@ def init_transformer(
         }
 
     def make_layer(kind: str, i: int) -> dict:
+        if cfg.ffn_kind == "scmoe":
+            # the two (mixer, dense) sublayers under "sub" (stacked over all
+            # sublayers in the tree); the router and the experts are the layer's
+            subs = [{**mla_leaves(i, j), **dense_leaves(i, j)} for j in range(2)]
+            return {"sub": jax.tree.map(lambda a, b: jnp.stack([a, b]), *subs),
+                    **moe_leaves(i)}
         if kind == "ssm":
             layer = {"attn_norm": jnp.ones((cfg.dim,), cfg.dtype), **ssm_leaves(i),
                      "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype)}
+        elif kind == "mla":
+            layer = mla_leaves(i, 0)
         else:
             layer = {
                 "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
@@ -406,6 +498,10 @@ def init_transformer(
         placed[kind] = at + 1
         del layer
     params["layers"] = stacks if cfg.mixed else stacks[cfg.kinds[0]]
+    if cfg.ffn_kind == "scmoe":
+        # [L, 2, ...] -> [2 L, ...]: sublayer j of layer i at 2 i + j
+        params["layers"]["sub"] = jax.tree.map(
+            lambda x: x.reshape((-1,) + x.shape[2:]), params["layers"]["sub"])
     return params
 
 
@@ -449,7 +545,7 @@ _L2_EPS = 1e-6  # under the root of the "cca" L2 norm: a dead row's q is 0
 
 def _split_experts(cfg: TransformerConfig, layers: dict) -> tuple[dict, Optional[dict]]:
     """(what the layer loop scans, the expert stacks it closes over)."""
-    if cfg.ffn_kind != "moe":
+    if not cfg.routed:
         return layers, None
     return ({k: v for k, v in layers.items() if k not in EXPERT_LEAVES},
             {k: layers[k] for k in EXPERT_LEAVES})
@@ -560,14 +656,55 @@ def _block(
     is ``_ssm_mixer``. ``freqs`` None: no rotary (``cfg.rope_dim == 0``).
     """
     kind = kind or cfg.attn_kind
-    x, merged = MIXERS[kind].mix(
-        cfg, kind, p, x, kv_cache, layer,
-        _Call(freqs, positions, starts, kv_lens, attn_fn, valid, live))
+    call = _Call(freqs, positions, starts, kv_lens, attn_fn, valid, live)
+    if cfg.ffn_kind == "scmoe":
+        return _shortcut_block(cfg, kind, p, x, kv_cache, layer, call, mlp_fn)
+    x, merged = MIXERS[kind].mix(cfg, kind, p, x, kv_cache, layer, call)
     with jax.named_scope("mlp"):
         h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
         y, aux = (mlp_fn or _default_mlp)(p, h)
         x = x + y
     return x, merged, aux
+
+
+def _shortcut_block(
+    cfg: TransformerConfig, kind: str, p: dict, x: jnp.ndarray,
+    kv_cache: Optional[tuple[jnp.ndarray, ...]], layer: Optional[jnp.ndarray], call: "_Call",
+    mlp_fn: Any,
+) -> tuple[jnp.ndarray, tuple[jnp.ndarray, ...], dict]:
+    """The shortcut-connected double layer (``cfg.ffn_kind == "scmoe"``): two
+    (mixer, dense SwiGLU) sublayers, and one routed expert product that
+    takes the first sublayer's normed input and is added two sublayers
+    later, after the second dense one::
+
+        x1 = x  + mix_0(x);    a = rms(x1);   m = experts(a)
+        x2 = x1 + swiglu_0(a)
+        x3 = x2 + mix_1(x2);   b = rms(x3)
+        y  = x3 + swiglu_1(b) + m
+
+    ``p["sub"]`` is the pair of the two sublayers' leaves (``_scan_layers``
+    reads them out of the stack over all sublayers, which it keeps out of
+    the scan's xs); the mixers' places in the cache are ``2 * layer`` and
+    ``2 * layer + 1``; ``mlp_fn(p, a)`` is the routed product."""
+    def place(j: int) -> Optional[jnp.ndarray]:
+        return None if layer is None else 2 * layer + j
+
+    mix = MIXERS[kind].mix
+    p0, p1 = p["sub"]
+    x, cache = mix(cfg, kind, p0, x, kv_cache, place(0), call)
+    with jax.named_scope("mlp"):
+        a = rms_norm(x, p0["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("moe.shortcut"):
+        m, aux = mlp_fn(p, a)
+    with jax.named_scope("mlp"):
+        x = x + _default_mlp(p0, a)[0]
+    x, cache = mix(cfg, kind, p1, x, cache, place(1), call)
+    with jax.named_scope("mlp"):
+        b = rms_norm(x, p1["mlp_norm"], cfg.norm_eps)
+        x = x + _default_mlp(p1, b)[0]
+    with jax.named_scope("moe.shortcut"):
+        x = x + m
+    return x, cache, aux
 
 
 class _Call(NamedTuple):
@@ -780,6 +917,80 @@ def _ssm_mixer(
     return x, (None if cache is None else (conv_stack, ssm_stack))
 
 
+def _pairs_apart(x: jnp.ndarray) -> jnp.ndarray:
+    """Interleaved rotary pairs (2i, 2i + 1) laid as ``apply_rope`` takes
+    them: the even dims, then the odd ones. Queries and keys are laid alike,
+    so every score is what turning the pairs in place would give."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _mla_mixer(
+    cfg: TransformerConfig, kind: str, p: dict, x: jnp.ndarray,
+    cache: Optional[tuple[jnp.ndarray, jnp.ndarray]], layer: Optional[jnp.ndarray], call: _Call,
+) -> tuple[jnp.ndarray, Optional[tuple[jnp.ndarray, jnp.ndarray]]]:
+    """Latent attention (MLA) for this call's tokens ``x`` [B, S, D] -> (x
+    with the mixer's output added, the stacks (k_rope, latent) that came in
+    with this call's tokens written at place ``layer``)::
+
+        h = rms(x);  q = rms(h Wq_a) Wq_b * sqrt(D / q_rank)   [H, nope | rope]
+        [c | kr] = h Wkv_a;  c = rms(c) * sqrt(D / kv_rank);  kr = rope(kr)
+        [k_nope_i | v_i] = c Wkv_b  (head i);  softmax((q_nope_i . k_nope_i
+        + rope(q_rope_i) . kr) / sqrt(nope + rope)) v_i;  concat_i(...) Wo
+
+    The cache keeps ``c`` (normed and scaled) and ``kr`` (rotated; one head,
+    shared by all, not scaled) and nothing else: ``latent`` [L, B, S, rank]
+    and ``k_rope`` [L, B, rope, S]. The two forms of the attention itself
+    are ops/mla.py's."""
+    from gofr_tpu.ops.mla import latent_attention
+
+    freqs, positions, starts, kv_lens = call.freqs, call.positions, call.starts, call.kv_lens
+    b, s, _ = x.shape
+    h, rc, nope = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim
+    with jax.named_scope("attn.mla.q"):
+        hid = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        # the product exists [B, S, width] before it is viewed by head (as
+        # ``_attention_mixer``'s do: a reshape folded into the product would
+        # want the weight stack relaid)
+        q = jax.lax.optimization_barrier(
+            _mm(rms_norm(_mm(hid, p["wq_a"]), p["q_norm"], cfg.norm_eps), p["wq_b"]))
+        q = (q * (cfg.dim / cfg.q_lora_rank) ** 0.5).reshape(b, s, h, cfg.head_dim)
+        q_nope = q[..., :nope]
+        q_rope = apply_rope(_pairs_apart(q[..., nope:]), freqs, positions)
+    with jax.named_scope("attn.mla.latent"):
+        ckr = _mm(hid, p["wkv_a"])
+        c = rms_norm(ckr[..., :rc], p["kv_norm"], cfg.norm_eps)
+        c = (c.astype(jnp.float32) * (cfg.dim / rc) ** 0.5).astype(x.dtype)
+        kr = apply_rope(_pairs_apart(ckr[..., rc:])[:, :, None], freqs, positions)[:, :, 0]
+        kr = jnp.swapaxes(kr, 1, 2)  # [B, rope, S]
+        if cache is None:
+            # this call's own tokens, as a stack of one place
+            k_rope_stack, latent_stack, layer = kr[None], c[None], jnp.int32(0)
+            starts = jnp.zeros((b,), jnp.int32)
+            kv_lens = jnp.full((b,), s, jnp.int32)
+        else:
+            k_rope_stack, latent_stack = cache
+            for row in range(b):
+                latent_stack = jax.lax.dynamic_update_slice(
+                    latent_stack, c[row][None, None].astype(latent_stack.dtype),
+                    (layer, row, starts[row], 0))
+                k_rope_stack = jax.lax.dynamic_update_slice(
+                    k_rope_stack, kr[row][None, None].astype(k_rope_stack.dtype),
+                    (layer, row, 0, starts[row]))
+    attn = latent_attention(q_nope, q_rope, latent_stack, k_rope_stack, p["wkv_b"], starts,
+                            kv_lens, layer, impl=cfg.attn_impl)
+    with jax.named_scope("attn.mla.out"):
+        x = x + _mm(attn.reshape(b, s, h * cfg.v_head_dim), p["wo"])
+    return x, (None if cache is None else (k_rope_stack, latent_stack))
+
+
+def _latent_rows(cfg: TransformerConfig, n: int, batch: int, max_seq: int) -> dict:
+    # one latent and one rotated key a token, whatever the heads; the key's
+    # 64 dims stand second and the positions minor (64 in the minor place
+    # would be padded to 128 by the chip's tiling)
+    return {"latent": jnp.zeros((n, batch, max_seq, cfg.kv_lora_rank), cfg.dtype),
+            "k_rope": jnp.zeros((n, batch, cfg.qk_rope_dim, max_seq), cfg.dtype)}
+
+
 def _kv_rows(cfg: TransformerConfig, n: int, batch: int, max_seq: int) -> dict:
     shape = (n, batch, cfg.n_kv_heads, max_seq, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.kv_dtype or cfg.dtype),
@@ -833,6 +1044,7 @@ MIXERS: dict[str, Mixer] = {
     "cca": Mixer(_attention_mixer, ("k", "tail", "v"), _cca_cache),
     "retention": Mixer(_attention_mixer, ("s", "z"), _retention_state, state=("s", "z")),
     "ssm": Mixer(_ssm_mixer, ("conv", "ssm"), _ssm_state, state=("conv", "ssm")),
+    "mla": Mixer(_mla_mixer, ("k_rope", "latent"), _latent_rows),
 }
 MIXER_KINDS = tuple(MIXERS)  # what ``TransformerConfig.layer_kinds`` may name
 STATE_LEAVES = tuple(name for m in MIXERS.values() for name in m.state)
@@ -910,9 +1122,19 @@ def _scan_layers(
 
     from gofr_tpu.models.moe import routed_mlp
 
+    # a double layer's sublayers are stacked [2 L, ...], sublayer j of layer
+    # i at 2 i + j. The loop closes over the stacks and a layer reads its
+    # two out of them (a slice [2, ...] handed in by the scan is copied
+    # whole: 1.3 GB a layer at LongCat-Flash's widths)
+    subs = scanned.pop("sub", None)
+
     def body(carry, inputs):
         x, stacks, r = carry
         layer_params, layer = inputs
+        if subs is not None:
+            layer_params = {**layer_params, "sub": tuple(
+                jax.tree.map(lambda leaf, at=2 * layer + j: jax.lax.dynamic_index_in_dim(
+                    leaf, at, 0, keepdims=False), subs) for j in range(2))}
         mlp_fn = lambda p, h: routed_mlp(  # noqa: E731
             cfg, p, h, r, experts, layer, token_mask)
         y, stacks, aux = block(layer_params, x, stacks, layer, mlp_fn, kind)
@@ -968,7 +1190,8 @@ def init_cache(cfg: TransformerConfig, batch: int, max_seq: int | None = None) -
         )
     stacks: dict = {}
     for kind in cfg.kinds_present:
-        stacks.update(MIXERS[kind].make_cache(cfg, cfg.n_of(kind), batch, max_seq))
+        stacks.update(MIXERS[kind].make_cache(
+            cfg, cfg.n_of(kind) * cfg.mixers_per_layer, batch, max_seq))
     # ``live``: the rows that hold a request. The decode pool keeps it to
     # its active slots; every row of a prefill's or a solo cache is live,
     # and so is every row of a cache that lacks the leaf.
@@ -992,6 +1215,16 @@ def state_row_bytes(cache: dict) -> int:
     token); 0 for a cache of K/V rows alone."""
     return sum(leaf.size // leaf.shape[1] * leaf.dtype.itemsize  # (a shape has no nbytes)
                for name, leaf in cache.items() if name in STATE_LEAVES)
+
+
+def latent_token_bytes(cache: dict) -> int:
+    """What one token holds in a latent cache over all its places, in bytes
+    (its latent and the shared rotated key); 0 for any other cache."""
+    if "latent" not in cache:
+        return 0
+    latent, k_rope = cache["latent"], cache["k_rope"]
+    return (latent.shape[0] * latent.shape[3] * latent.dtype.itemsize
+            + k_rope.shape[0] * k_rope.shape[2] * k_rope.dtype.itemsize)
 
 
 def _run_cached(
@@ -1027,11 +1260,11 @@ def _run_cached(
     valid = None
     # K/V rows have a "past the length" for padding to be dead in; a state,
     # a tail and an expert's tokens have not
-    masks_pads = cfg.ffn_kind == "moe" or any(k != "softmax" for k in cfg.kinds_present)
+    masks_pads = cfg.routed or any(k != "softmax" for k in cfg.kinds_present)
     if masks_pads and lengths is not None:
         valid = jnp.arange(s)[None, :] < lengths[:, None]
     token_mask = None
-    if cfg.ffn_kind == "moe":
+    if cfg.routed:
         # a pad token and the row of a slot without a request go to no expert
         token_mask = jnp.ones((b, s), bool) if valid is None else valid
         if live is not None:
@@ -1380,7 +1613,8 @@ def pack_expert_counts(ids: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
 def unpack_expert_counts(ids: Any, rows: int, n_experts: int) -> tuple[Any, Optional[Any]]:
     """A fetched (numpy) ``pack_expert_counts`` array, rows first ([rows + L
     * E] or, a chunk's, [rows + L * E, steps]) -> (ids, counts [..., L, E]
-    with a chunk's steps first); counts None where nothing rode along."""
+    with a chunk's steps first); counts None where nothing rode along.
+    ``n_experts`` is the counts' width (``TransformerConfig.routing_width``)."""
     if ids.shape[0] == rows:
         return ids, None
     packed = ids[rows:].T if ids.ndim > 1 else ids[rows:]
@@ -1437,7 +1671,7 @@ def decode_chunk_pool(
     from gofr_tpu.ops.sampling import sample_logits_rows
 
     key, sub = jax.random.split(key)
-    routed = cfg.ffn_kind == "moe"
+    routed = cfg.routed
 
     def body(carry, _):
         tok, c, k = carry

@@ -1,9 +1,11 @@
-"""The routed expert product: every token through the ONE SwiGLU expert it
-was sent to, reading only the experts that got a token.
+"""The routed expert product: every token through the SwiGLU expert (one, or
+its top-k) it was sent to, reading only the experts that got a token.
 
 ``routed_experts(x, expert, w_gate, w_up, w_down, layer)``: ``x`` [T, D]
 tokens, ``expert`` [T] int32 the expert of each (``n_experts`` for a token
-that goes to none: bucket padding, the row of a slot without a request),
+that goes to none: bucket padding, the row of a slot without a request; or
+``expert`` [T, k] with ``weight`` [T, k], k (token, expert) pairs a token and
+their weighted sum on the way back: ``_routed_pairs``),
 the weights as the model holds them, stacked over layers
 ([L, E, D, F], [L, E, D, F], [L, E, F, D]) with ``layer`` an int32 scalar,
 or one layer's ([E, ...], ``layer`` None). Returns ``y`` [T, D] (zeros for
@@ -137,34 +139,97 @@ def _use_pallas(impl: str, x: jnp.ndarray, w: jnp.ndarray) -> bool:
     return impl == "pallas"
 
 
+def _sorted_product(xs: jnp.ndarray, counts: jnp.ndarray, w_gate: jnp.ndarray,
+                    w_up: jnp.ndarray, w_down: jnp.ndarray, layer: jnp.ndarray,
+                    impl: str) -> jnp.ndarray:
+    """``xs`` [M, D] rows sorted by expert, ``counts`` [E] rows a group (the
+    rows past the last group belong to none and come back as whatever the
+    kernel's buffer held) -> each row through its group's expert, [M, D]."""
+    t = xs.shape[0]
+    if _use_pallas(impl, xs, w_gate):
+        tile = _row_tile(t)
+        padded = -(-t // tile) * tile
+        xs = jnp.pad(xs, ((0, padded - t), (0, 0)))
+        schedule = visit_schedule(counts, padded // tile, tile)
+        interpret = jax.default_backend() != "tpu"
+        hidden = _grouped(xs, (w_gate, w_up), layer, schedule, tile, interpret)
+        return _grouped(hidden, (w_down,), layer, schedule, tile, interpret)[:t]
+    wg, wu, wd = (jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+                  for w in (w_gate, w_up, w_down))
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=counts,
+                            preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(dot(xs, wg)) * dot(xs, wu)).astype(xs.dtype)
+    return dot(hidden, wd).astype(xs.dtype)
+
+
+def pair_capacity(tokens: int) -> int:
+    """Rows of sorted pairs one pass of the pair form multiplies: every
+    token's worth for a step's few rows, half of it for a prefill's many
+    (a chip of a wide deployment holds a small share of the experts, so
+    the pairs that land here are a fraction of a pair a token)."""
+    return tokens if tokens <= ROW_TILE else tokens // 2
+
+
+def _routed_pairs(x, expert, weight, w_gate, w_up, w_down, layer, impl):
+    """The top-k form: ``expert`` [T, k] (``n`` for a pair that goes to no
+    expert HERE: an identity expert, another chip's, a pad's) and ``weight``
+    [T, k] -> (sum over a token's pairs of weight x expert(x), float32
+    [T, D]; counts [E] of PAIRS). The cost follows the pairs that landed on
+    an expert held here, not the T x k drawn: the pairs are sorted (ints
+    alone), and only those with an expert are gathered, ``pair_capacity``
+    rows a pass, as many passes as they need (one, but for a skewed batch)."""
+    t, k = expert.shape
+    n = w_gate.shape[1]
+    f32 = jnp.float32
+    cap = pair_capacity(t)
+    with jax.named_scope("moe.dispatch"):
+        pairs = expert.reshape(-1)
+        counts = jnp.sum(pairs[:, None] == jnp.arange(n)[None, :], axis=0).astype(jnp.int32)
+        passes = -(-t * k // cap)
+        order = jnp.pad(jnp.argsort(pairs, stable=True).astype(jnp.int32),
+                        (0, passes * cap - t * k))
+        ends = jnp.cumsum(counts)
+        landed = ends[-1]
+        flat_weight = weight.reshape(-1).astype(f32)
+
+    def one_pass(i, y):
+        lo = i * cap
+        with jax.named_scope("moe.dispatch"):
+            idx = jax.lax.dynamic_slice_in_dim(order, lo, cap)
+            token = idx // k
+            xs = x[token]
+            here = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - counts, lo, lo + cap)
+        with jax.named_scope("moe.experts"):
+            ys = _sorted_product(xs, here, w_gate, w_up, w_down, layer, impl)
+        with jax.named_scope("moe.combine"):
+            real = lo + jnp.arange(cap) < landed
+            scaled = jnp.where(real[:, None], ys.astype(f32) * flat_weight[idx][:, None], 0.0)
+            # back to the tokens, a token's pairs summed: a 0/1 product
+            to_token = (token[None, :] == jnp.arange(t)[:, None]) & real[None, :]
+            return y + jnp.einsum("tc,cd->td", to_token.astype(x.dtype), scaled.astype(x.dtype),
+                                  preferred_element_type=f32)
+
+    y = jax.lax.fori_loop(0, -(-landed // cap), one_pass, jnp.zeros(x.shape, f32))
+    return y, counts
+
+
 def routed_experts(
     x: jnp.ndarray, expert: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
     w_down: jnp.ndarray, layer: Optional[jnp.ndarray] = None, impl: str = "auto",
+    weight: Optional[jnp.ndarray] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     if layer is None:
         w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
         layer = jnp.int32(0)
+    if expert.ndim == 2:
+        return _routed_pairs(x, expert, weight, w_gate, w_up, w_down, layer, impl)
     t, n = x.shape[0], w_gate.shape[1]
     with jax.named_scope("moe.dispatch"):
         counts = jnp.sum(expert[:, None] == jnp.arange(n)[None, :], axis=0).astype(jnp.int32)
         order = jnp.argsort(expert, stable=True)
         xs = x[order]
     with jax.named_scope("moe.experts"):
-        if _use_pallas(impl, x, w_gate):
-            tile = _row_tile(t)
-            padded = -(-t // tile) * tile
-            xs = jnp.pad(xs, ((0, padded - t), (0, 0)))
-            schedule = visit_schedule(counts, padded // tile, tile)
-            interpret = jax.default_backend() != "tpu"
-            hidden = _grouped(xs, (w_gate, w_up), layer, schedule, tile, interpret)
-            ys = _grouped(hidden, (w_down,), layer, schedule, tile, interpret)[:t]
-        else:
-            wg, wu, wd = (jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
-                          for w in (w_gate, w_up, w_down))
-            dot = functools.partial(jax.lax.ragged_dot, group_sizes=counts,
-                                    preferred_element_type=jnp.float32)
-            hidden = (jax.nn.silu(dot(xs, wg)) * dot(xs, wu)).astype(x.dtype)
-            ys = dot(hidden, wd).astype(x.dtype)
+        ys = _sorted_product(xs, counts, w_gate, w_up, w_down, layer, impl)
     with jax.named_scope("moe.combine"):
         # rows of no expert hold whatever the kernel's buffer held
         back = jnp.zeros((t,), jnp.int32).at[order].set(jnp.arange(t, dtype=jnp.int32))

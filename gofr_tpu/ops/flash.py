@@ -48,7 +48,10 @@ read from its shapes alone:
 
 Layouts match gofr_tpu.ops.attention: q [B, Sq, Hq, D]; k, v [B, Skv,
 Hkv, D], or with ``layer`` the stacked cache [L, B, Hkv, Skv, D];
-Hq % Hkv == 0. On non-TPU backends the kernel runs in pallas
+Hq % Hkv == 0. The forward (both forms, a stacked k and v) takes a value
+narrower than its key, ``v`` [..., Dv] beside ``q`` and ``k`` [..., D]:
+latent attention's expanded form (``ops/mla.py``: 192 and 128). The output
+is as wide as the value; the backward is written for one width. On non-TPU backends the kernel runs in pallas
 interpret mode (tests exercise the real kernel logic on the CPU mesh, the
 way the reference tests run against in-process fakes, SURVEY.md §4).
 """
@@ -150,7 +153,7 @@ def _kernel(
     kv_len = lens_ref[b]
 
     qb = q_ref[0, 0, :, :]  # [block_q, D]
-    d = qb.shape[-1]
+    d = v_ref.shape[-1]  # the value width: the output's (D, or Dv where it differs)
 
     # absolute positions of this query block's rows (2D iota: TPU rule)
     q_pos = (
@@ -216,7 +219,7 @@ def _decode_kernel(
     lay = layer_ref[0]
 
     qb = q_ref[0, 0, :, :]  # [rows, D]
-    rows, d = qb.shape
+    rows, d = qb.shape[0], v_buf.shape[-1]
     if sq == 1:
         q_pos = offset
     else:
@@ -261,6 +264,11 @@ def _decode_kernel(
 
     carry = jax.lax.fori_loop(0, hi, body, _softmax_init(rows, d))
     _softmax_store(carry, out_ref, lse_ref)
+
+
+def _lanes(d: int) -> int:
+    """``d`` as fast memory holds a minor axis: whole tiles of 128."""
+    return -(-d // 128) * 128
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, to: int) -> jnp.ndarray:
@@ -310,6 +318,7 @@ def _flash_fwd_impl(
     stack in every layer."""
     b, sq, hq, d = q.shape
     n_layers, _, hkv, skv, _ = k.shape
+    dv = v.shape[-1]  # == d but for latent attention's expanded form (192 and 128)
     groups = hq // hkv
 
     block_kv = min(block_kv, skv)
@@ -320,7 +329,7 @@ def _flash_fwd_impl(
     vt = _pad_axis(v, 3, skv_pad)
     num_kv_blocks = skv_pad // block_kv
     cost = dict(
-        flops=4 * b * hq * sq * skv * d,
+        flops=2 * b * hq * sq * skv * (d + dv),
         transcendentals=b * hq * sq * skv,
     )
 
@@ -349,11 +358,11 @@ def _flash_fwd_impl(
                 (1, 1, block_q, d), lambda bi, h, qi, *_: (bi, h, qi, 0)
             ),
             pl.BlockSpec((1, 1, 1, skv_pad, d), kv_block),
-            pl.BlockSpec((1, 1, 1, skv_pad, d), kv_block),
+            pl.BlockSpec((1, 1, 1, skv_pad, dv), kv_block),
         ],
         out_specs=[
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda bi, h, qi, *_: (bi, h, qi, 0)
+                (1, 1, block_q, dv), lambda bi, h, qi, *_: (bi, h, qi, 0)
             ),
             pl.BlockSpec(
                 (1, 1, block_q, 1), lambda bi, h, qi, *_: (bi, h, qi, 0)
@@ -373,7 +382,7 @@ def _flash_fwd_impl(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, sq_pad, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sq_pad, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -383,6 +392,11 @@ def _flash_fwd_impl(
             ) * q.dtype.itemsize,
             **cost,
         ),
+        # K and V of one (row, kv head) whole, double-buffered: past the
+        # default scoped limit where a key is wider than 128
+        compiler_params=(None if d == dv else pltpu.CompilerParams(
+            vmem_limit_bytes=int(
+                4 * skv_pad * (_lanes(d) + _lanes(dv)) * q.dtype.itemsize + (8 << 20)))),
     )(offsets, kv_lens, layer, qt, kt, vt)
     return jnp.swapaxes(out[:, :, :sq, :], 1, 2), lse[:, :, :sq, 0]
 
@@ -396,7 +410,7 @@ def _decode_form(
     block, K and V ``kt``, ``vt`` [L, B, Hkv, Skv_pad, D] left in HBM and
     copied in by ``_decode_kernel`` up to each row's length."""
     b, sq, hq, d = q.shape
-    hkv = kt.shape[2]
+    hkv, dv = kt.shape[2], vt.shape[-1]
     groups = hq // hkv
     rows = sq * groups
     # sublane floor 16 covers the bf16 min tile (f32 needs only 8)
@@ -417,12 +431,12 @@ def _decode_form(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rows_pad, d), q_block),
+            pl.BlockSpec((1, 1, rows_pad, dv), q_block),
             pl.BlockSpec((1, 1, rows_pad, 1), q_block),
         ],
         scratch_shapes=[
             pltpu.VMEM((_FETCH_BUFFERS, block_kv, d), kt.dtype),
-            pltpu.VMEM((_FETCH_BUFFERS, block_kv, d), vt.dtype),
+            pltpu.VMEM((_FETCH_BUFFERS, block_kv, dv), vt.dtype),
             pltpu.SemaphoreType.DMA((2, _FETCH_BUFFERS)),
         ],
     )
@@ -433,7 +447,7 @@ def _decode_form(
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rows_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, rows_pad, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hkv, rows_pad, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -446,8 +460,8 @@ def _decode_form(
             **cost,
         ),
     )(offsets, kv_lens, layer, qg, kt, vt)
-    out = out[:, :, :rows].reshape(b, hkv, groups, sq, d)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
+    out = out[:, :, :rows].reshape(b, hkv, groups, sq, dv)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, dv)
     return out, lse[:, :, :rows, 0].reshape(b, hq, sq)
 
 

@@ -289,9 +289,11 @@ class DecodePool:
         # model's S and z; a state-space layer's state and convolution
         # tail), in bytes: what a live row reads and writes a step; 0 for
         # a cache of K/V rows alone
-        from gofr_tpu.models.transformer import state_row_bytes
+        from gofr_tpu.models.transformer import latent_token_bytes, state_row_bytes
 
         self._state_row_bytes = state_row_bytes(self.cache)
+        # a latent cache: what one token holds over all its places
+        self._latent_token_bytes = latent_token_bytes(self.cache)
         # the positions of K/V the attention kernel fetches at a time
         # (ops/flash.py, the decode form); 0 for a state
         from gofr_tpu.ops.flash import DEFAULT_BLOCK_KV
@@ -1680,9 +1682,9 @@ class DecodePool:
                 # an expert model's routing counts ride behind the rows
                 toks, routing = unpack_expert_counts(
                     np.asarray(toks_dev), self.n_slots,
-                    getattr(self.cfg, "n_experts", 0))
+                    getattr(self.cfg, "routing_width", 0))
                 if drec is not None and routing is not None:
-                    drec.note_routing(routing)
+                    drec.note_routing(routing, self.cfg.n_experts)
                 lps = np.asarray(lps_dev)
                 tvals = (
                     np.asarray(tvals_dev) if tvals_dev is not None else None
@@ -1732,6 +1734,10 @@ class DecodePool:
                 elapsed if self._chunk_ema_s <= 0
                 else 0.8 * self._chunk_ema_s + 0.2 * elapsed
             )
+        if drec is not None and self._latent_token_bytes:
+            drec.latent_bytes = self._latent_token_bytes * sum(
+                min(req.cache_len + step + 1, self.max_len)
+                for _, req in records if req is not None for step in range(self.chunk))
         if drec is not None and self._kv_block:
             drec.kv_blocks_read = self._kv_blocks_read(records)
             drec.kv_blocks_held = (

@@ -1011,66 +1011,80 @@ class TPUDevice:
         served by what aliases, rolls back, ships or shards K/V rows: a
         fixed-size state per row (every layer power retention) has no rows
         at all, a "cca" cache keeps a fixed tail per row beside its K and
-        V, and a model with state-space layers among its attention layers
+        V, a model with state-space layers among its attention layers
         keeps a state and a convolution tail per row beside the K/V rows of
-        its attention layers; none of those settings would carry the state
-        or the tail along. What the cache holds is asked of the kinds of
-        layer the model has (``models/transformer.py::MIXERS``). Each such
-        setting is refused here, by name, at boot: none of them may give a
-        wrong answer instead. So is ``MODEL_QUANT`` for a model whose
-        experts are stacked leaves, or whose layers are stacked per kind,
-        neither of which the quantiser takes."""
+        its attention layers, and a latent cache (MLA) keeps one latent and
+        one rotated key a token that all heads share, not (kv heads, head
+        size) rows; none of those settings would carry the state or the
+        tail along, or knows the latent's shape. What the cache holds is
+        asked of the kinds of layer the model has
+        (``models/transformer.py::MIXERS``). Each such setting is refused
+        here, by name, at boot: none of them may give a wrong answer
+        instead. So is ``MODEL_QUANT`` for a model whose experts are
+        stacked leaves, or whose layers are stacked per kind, neither of
+        which the quantiser takes."""
         from gofr_tpu.models.llama import CONFIGS
         from gofr_tpu.models.transformer import MIXERS
 
         cfg = CONFIGS.get(self.model_name)
         kinds = tuple(getattr(cfg, "kinds_present", ("softmax",)))
         mixed = len(kinds) > 1
-        if self.quant and (getattr(cfg, "ffn_kind", "dense") == "moe" or mixed):
+        paired = getattr(cfg, "mixers_per_layer", 1) > 1
+        if self.quant and (getattr(cfg, "routed", False) or mixed):
             raise ValueError(
                 f"MODEL_QUANT is not supported for MODEL_NAME '{self.model_name}': "
                 "the quantiser does not take "
                 + ("layers stacked per kind" if mixed else "expert-stacked leaves")
             )
-        if mixed and self._lora_adapters:
+        if (mixed or paired) and self._lora_adapters:
             raise ValueError(
                 f"LORA_ADAPTERS is not supported for MODEL_NAME '{self.model_name}': "
-                "an adapter wraps one stack of layers, and this model's are stacked per kind"
+                "an adapter wraps one stack of layers, and this model's are stacked "
+                + ("per kind" if mixed else "in pairs of sublayers")
             )
         leaves = {name for kind in kinds for name in MIXERS[kind].cache}
         if leaves <= {"k", "v"}:
             return
-        # which of the three reasons applies: 0 a state and no K/V rows,
-        # 1 a tail beside K/V rows, 2 a state beside K/V rows
-        case = 0 if "k" not in leaves else 1 if kinds == ("cca",) else 2
+        # which of the four reasons applies: 0 a state and no K/V rows,
+        # 1 a tail beside K/V rows, 2 a state beside K/V rows, 3 latent rows
+        case = (3 if "latent" in leaves else 0 if "k" not in leaves
+                else 1 if kinds == ("cca",) else 2)
         stated = (config.get("KV_TRANSFER") or "").strip().lower()
         blocks = ("the paged arena holds K/V blocks",
                   "the paged arena holds K/V blocks and would drop the tail",
-                  "the paged arena holds K/V blocks and would drop the state")
+                  "the paged arena holds K/V blocks and would drop the state",
+                  "the paged arena holds blocks of (kv heads, head size) rows; a latent "
+                  "is one vector a token")
         rollback = ("speculation rolls a cache back by length; a state has no length",
                     "speculation rolls a cache back by length; the tail of the token "
                     "rolled back to is gone",
                     "speculation rolls a cache back by length; the state of the token "
-                    "rolled back to is gone")
+                    "rolled back to is gone",
+                    "speculation over a latent cache (a verify chunk through the "
+                    "absorbed form, two leaves rolled back) is not covered yet")
         wire = ("the wire format carries K/V blocks",
                 "the wire format carries K/V blocks, not the tail",
-                "the wire format carries K/V blocks, not the state")
-        moves = ("prefill/decode disaggregation moves K/V over the wire",) * 3
-        refused = {  # setting -> (is it on, why each of the three cases cannot serve it)
+                "the wire format carries K/V blocks, not the state",
+                "the wire format carries K/V blocks, not latents")
+        moves = ("prefill/decode disaggregation moves K/V over the wire",) * 4
+        refused = {  # setting -> (is it on, why each of the four cases cannot serve it)
             "PREFIX_CACHE": (self._prefix_cache_size > 0, (
                 "prefix sharing aliases K/V rows; a state would need snapshots",
                 "prefix sharing aliases K/V rows; a shared prefix would need the tail "
                 "at its last token",
                 "prefix sharing aliases K/V rows; a shared prefix would need the state "
-                "at its last token")),
+                "at its last token",
+                "prefix sharing aliases and copies K/V rows by their (kv heads, head "
+                "size) shape; a latent row has another")),
             "KV_BLOCKS": (self._kv_paged and self._kv_blocks_cfg > 0, blocks),
             "KV_HBM_BUDGET_MB": (self._kv_paged and self._kv_budget_mb > 0, blocks),
             "DRAFT_MODEL_NAME": (bool(self._draft_name), rollback),
             "SPEC_POOLED": (self._spec_pooled, rollback),
             # float8 is a K/V type: K and V beside a tail or a state take
             # it (the tail stays in the model's type, the state float32)
-            "MODEL_KV_DTYPE": (case == 0 and self._kv_dtype == jnp.float8_e4m3fn, (
-                "f8 is a K/V type; a state takes float32 (unset) or bf16", "", "")),
+            "MODEL_KV_DTYPE": (case in (0, 3) and self._kv_dtype == jnp.float8_e4m3fn, (
+                "f8 is a K/V type; a state takes float32 (unset) or bf16", "", "",
+                "f8 is a K/V type; the latent is normed and scaled for the model's type")),
             "KV_TRANSFER": (stated not in ("", "off"), wire),
             "KV_TRANSFER_TRUST_HINT": (self.kv_hint_trusted, wire),
             "FLEET_ROLE": (self.role != "mixed", moves),
@@ -1078,11 +1092,14 @@ class TPUDevice:
                 "the state is not yet sharded over a mesh (by kv head under tp)",
                 "neither the tail nor the expert stacks are sharded over a mesh yet",
                 "neither the state nor the per-kind parameter stacks are sharded "
-                "over a mesh yet")),
+                "over a mesh yet",
+                "neither the latent (which has no head axis) nor the expert stacks "
+                "are sharded over a mesh yet")),
         }
         what = ("whose cache is a retention state",
                 "whose cache keeps a tail per row beside its K/V rows",
-                "whose cache holds a state per row beside its K/V rows")[case]
+                "whose cache holds a state per row beside its K/V rows",
+                "whose cache holds a latent a token, not K/V rows")[case]
         for name, (on, why) in refused.items():
             if on:
                 raise ValueError(
@@ -3286,9 +3303,12 @@ class _TransformerRunner:
         # a row that holds a large fixed-size state (Brumby's 0.27 GB; a
         # state-space row's 9 MB is none) has its chunked prefills gated
         # (``_chunked_prefill``)
-        from gofr_tpu.models.transformer import state_row_bytes
+        from gofr_tpu.models.transformer import latent_token_bytes, state_row_bytes
 
-        row_state = state_row_bytes(jax.eval_shape(lambda: init_cache(self.cfg, 1)))
+        one_row = jax.eval_shape(lambda: init_cache(self.cfg, 1))
+        row_state = state_row_bytes(one_row)
+        # what a token's latent takes over all its places (0: no latent cache)
+        self._latent_token_bytes = latent_token_bytes(one_row)
         self._state_prefill_gate = (
             threading.BoundedSemaphore(2) if row_state >= _STATE_GATE_BYTES else None
         )
@@ -3570,13 +3590,13 @@ class _TransformerRunner:
         # prefill also argmaxes on device: the hot /infer path fetches [B]
         # int32 next-token ids, never the [B, V] logits
         def _prefill_fn(p, t, c, l):
-            if cfg.ffn_kind == "moe":
+            if cfg.routed:
                 logits, new_cache, aux = prefill(p, t, c, cfg, l, with_aux=True)
             else:
                 logits, new_cache = prefill(p, t, c, cfg, l)
             with jax.named_scope("sample"):
                 next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            if cfg.ffn_kind == "moe":
+            if cfg.routed:
                 # what routing did rides the ids: one fetch (_note_routing)
                 next_ids = pack_expert_counts(next_ids, aux["expert_counts"])
             return logits, next_ids, new_cache
@@ -3733,7 +3753,7 @@ class _TransformerRunner:
             tokens, lengths = pack_token_rows(payloads, bsz, bucket)
             full_lengths = np.maximum(lengths, 1)  # padded rows need length>=1
             cache = self._zero_cache(bsz)
-            if self.cfg.ffn_kind == "moe":
+            if self.cfg.routed:
                 # a row the batch was padded with holds no request: its
                 # tokens go to no expert (models/transformer.py::_run_cached)
                 cache = {**cache, "live": jnp.asarray(lengths > 0, jnp.int32)}
@@ -3754,6 +3774,8 @@ class _TransformerRunner:
         with phase(PREFILL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"):
             next_ids = np.asarray(next_ids)
         next_ids = _note_routing(drec, next_ids, bsz, self.cfg)
+        if drec is not None and self._latent_token_bytes:
+            drec.latent_bytes = int(lengths.sum()) * self._latent_token_bytes
         return [
             _PrefillState(
                 cache, logits, i,
@@ -4275,6 +4297,9 @@ class _TransformerRunner:
                     )
                     drec.chunks_ahead = _chunks_ahead(self)
                     drec.carried = total > 0
+                    if self._latent_token_bytes:
+                        # the carried latent and this slice's own
+                        drec.latent_bytes = (total + size) * self._latent_token_bytes
                     if record is not None:
                         record.note_dispatch_id(drec.dispatch_id)
                 with phase(PREFILL_ISSUE, drec, end="t_issued"):
@@ -4307,7 +4332,7 @@ class _TransformerRunner:
             ):
                 next_ids = np.asarray(next_ids)
             next_token = int(_note_routing(drec, next_ids, 1, self.cfg)[0])
-            if self.cfg.ffn_kind == "moe":
+            if self.cfg.routed:
                 # the earlier slices are done: their ids are there to read
                 for earlier, ids in routed:
                     _note_routing(earlier, np.asarray(ids), 1, self.cfg)
@@ -5477,9 +5502,9 @@ def _note_routing(drec: Any, ids: np.ndarray, rows: int, cfg: Any) -> np.ndarray
     dispatch's record."""
     from gofr_tpu.models.transformer import unpack_expert_counts
 
-    ids, counts = unpack_expert_counts(ids, rows, getattr(cfg, "n_experts", 0))
+    ids, counts = unpack_expert_counts(ids, rows, getattr(cfg, "routing_width", 0))
     if drec is not None and counts is not None:
-        drec.note_routing(counts)
+        drec.note_routing(counts, cfg.n_experts)
     return ids
 
 
@@ -5507,9 +5532,12 @@ def _slice_cache(cache: dict, i: int) -> dict:
 
 def _cache_max_len(cache: dict, cfg: Any) -> int:
     """Positions a row can hold: the length axis of its K/V rows (a cache
-    that holds a state beside them is bound by them too), or for a cache
+    that holds a state beside them is bound by them too) or of its latent
+    rows, or for a cache
     that is a state alone, which has none, the model's ``max_seq`` (the
     rotary table)."""
+    if "latent" in cache:
+        return int(cache["latent"].shape[2])
     return int(cache["k"].shape[3]) if "k" in cache else int(cfg.max_seq)
 
 

@@ -102,6 +102,7 @@ class DispatchRecord:
         "t_done", "t_issued", "t_fetch", "t_fetched", "cadence_s",
         "chunks_ahead", "state_bytes", "kv_blocks_read", "kv_blocks_held",
         "carried", "expert_tokens", "experts_read", "expert_tokens_max",
+        "identity_tokens", "absent_tokens", "latent_bytes",
     )
 
     def __init__(
@@ -167,10 +168,27 @@ class DispatchRecord:
         self.expert_tokens: Optional[int] = None
         self.experts_read: Optional[int] = None
         self.expert_tokens_max: Optional[int] = None
+        # under a top-k router over a deployment's experts, of which this
+        # chip holds some, the three above count (token, expert) PAIRS that
+        # landed on an expert held here; these two count the pairs that
+        # chose an identity expert and those that chose another chip's.
+        # The three pair counts add up to top-k x real tokens x layers
+        self.identity_tokens: Optional[int] = None
+        self.absent_tokens: Optional[int] = None
+        # a model with a latent cache: the bytes of latent (and shared
+        # rotated key) the dispatch's attention had to read, by its rows'
+        # lengths over all its places; None for any other cache
+        self.latent_bytes: Optional[int] = None
 
-    def note_routing(self, counts: Any) -> None:
+    def note_routing(self, counts: Any, held: int = 0) -> None:
         """``counts`` [..., layers, experts]: the tokens each expert of each
-        layer got in each step of this dispatch (a numpy array)."""
+        layer got in each step of this dispatch (a numpy array); where it
+        is two columns wider than the ``held`` experts, those are the
+        identity pairs and the absent ones."""
+        if held and counts.shape[-1] == held + 2:
+            self.identity_tokens = int(counts[..., held].sum())
+            self.absent_tokens = int(counts[..., held + 1].sum())
+            counts = counts[..., :held]
         self.expert_tokens = int(counts.sum())
         self.experts_read = int((counts > 0).sum())
         self.expert_tokens_max = int(counts.max(axis=-1).sum())
@@ -231,6 +249,9 @@ class DispatchRecord:
             "expert_tokens": self.expert_tokens,
             "experts_read": self.experts_read,
             "expert_tokens_max": self.expert_tokens_max,
+            "identity_tokens": self.identity_tokens,
+            "absent_tokens": self.absent_tokens,
+            "latent_bytes": self.latent_bytes,
         }
 
 

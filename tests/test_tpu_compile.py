@@ -536,3 +536,77 @@ def test_pooled_chunk_of_a_hybrid_model_reads_its_attention_weights_where_they_l
     hlo = _pooled_chunk(texts, one_chip, "jamba2", JCFG, JSLOTS, _jamba_params)
     _reads_weights_in_place(
         hlo, _weight_stacks(lambda: _jamba_params()["layers"]["softmax"]), prefetches=4)
+
+
+# -- a latent cache, a top-k gate over a share of the experts, the double layer -------------------
+# LongCat-Flash-Chat's widths as the benchmark's cell cuts it (4 layers, 16
+# of 512 routed experts held, MODEL_MAX_SEQ 7168, the cell's 40 slots) with a
+# small vocabulary
+
+LCFG = T.TransformerConfig(
+    vocab_size=4096, dim=6144, n_layers=4, n_heads=64, n_kv_heads=1, hidden_dim=12288,
+    max_seq=7168, rope_theta=1e7, attn_kind="mla", q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, ffn_kind="scmoe", router_kind="linear",
+    n_experts=16, n_routed_experts=512, n_identity_experts=256, top_k=12, routed_scale=6.0,
+    expert_dim=2048,
+)
+LSLOTS = 40
+# a sublayer stack's big leaves, one sublayer of one, or the pair a scan would
+# hand in; the expert stacks, one layer's experts, one expert
+_LWEIGHTS = {f"bf16[{lead}{shape}]" for lead in ("8,", "2,", "1,", "") for shape in (
+    "6144,12288", "12288,6144", "8192,6144", "1536,12288", "6144,1536")} | {
+    f"bf16[{lead}{shape}]" for lead in ("4,16,", "1,16,", "16,", "")
+    for shape in ("6144,2048", "2048,6144")}
+
+
+def _latent_movers(hlo: str, batch: int) -> dict[str, list[str]]:
+    """computation name -> the instructions in it that write, by moving data,
+    a result of the shape of a latent cache leaf (or of one place of it), of
+    a sublayer weight stack or of the expert stacks. In-place row writes and
+    what happens inside a fused computation are no moves (``_hybrid_movers``)."""
+    leaves = {f"bf16[{lead}{batch},7168,512]" for lead in ("8,", "1,", "")} | {
+        f"bf16[{lead}{batch},64,7168]" for lead in ("8,", "1,", "")} | _LWEIGHTS
+    found: dict[str, list[str]] = {}
+    for computation, name, shape, _, opcode in _instructions(hlo):
+        if shape not in leaves or computation.startswith("fused_computation"):
+            continue
+        if opcode in ("copy", "transpose", "reshape", "slice", "dynamic-slice") or (
+                opcode == "fusion" and not _updates_in_place(hlo, name)):
+            found.setdefault(computation, []).append(name)
+    return found
+
+
+def _longcat_params():
+    return T.init_transformer(jax.random.key(0), LCFG)
+
+
+def test_pooled_chunk_of_a_latent_model_leaves_latent_weights_and_experts_where_they_lie(
+        texts, one_chip, as_on_tpu):
+    """The absorbed form reads a place's latent out of the stack inside its
+    products; the two sublayers of a layer are read out of the [2 L, ...]
+    stacks the loop closes over (handed in by the scan as a [2, ...] slice
+    they were copied whole: 1.3 GB a layer and step); the pair form's two
+    Pallas calls index [layer, expert] of the stacks themselves."""
+    hlo = _pooled_chunk(texts, one_chip, "longcat", LCFG, LSLOTS, _longcat_params)
+    assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
+    assert _latent_movers(hlo, LSLOTS) == {}
+
+
+def test_prefill_of_a_latent_model_compiles_at_the_cells_widest_bucket(one_chip, as_on_tpu):
+    """Two rows of 1024: the expanded form through the flash kernel's prefill
+    form at a key of 192 and a value of 128, and the pair form of the routed
+    product at 2048 tokens (1024 rows of sorted pairs a pass)."""
+    hlo = _compiled(
+        lambda p, t, c, l: T.prefill(p, t, c, LCFG, l, with_aux=True), (), one_chip,
+        _longcat_params, jnp.zeros((2, 1024), jnp.int32),
+        lambda: T.init_cache(LCFG, 2), jnp.zeros((2,), jnp.int32),
+    )
+    # the flash forward in both sublayers and the two expert products
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 4
+    assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
+    # each head's keys and values exist expanded, in the order the kernel reads
+    assert "bf16[1,2,64,7168,192]" in hlo and "bf16[1,2,64,7168,128]" in hlo
+    # called as the server calls it, the caller keeping its cache: the two
+    # leaves are copied where they enter and in no loop; no weight is moved
+    movers = _latent_movers(hlo, 2)
+    assert set(movers) <= {"ENTRY"} and len(movers.get("ENTRY", [])) <= 4, movers
