@@ -223,6 +223,80 @@ LONGCAT_FLASH = TransformerConfig(
     expert_dim=2048,
 )
 
+# Moonlight's block at test size (the DeepSeek-V3 family): latent attention
+# with no query bottleneck and no scale factors (4 heads, a cached latent of
+# 16 and one shared rotated key of 8), a leading dense layer of 96 before two
+# expert layers: a sigmoid gate over 8 routed experts that this chip holds
+# whole, the 3 best by score + bias weighted by their scores normalised over
+# the three, times 2.5, beside two shared experts every token takes (one
+# SwiGLU of 2 x 32); untied head. CPU tests.
+TINY_MOONLIGHT = TransformerConfig(
+    vocab_size=256,
+    dim=64,
+    n_layers=3,
+    n_heads=4,
+    n_kv_heads=1,
+    hidden_dim=96,
+    max_seq=128,
+    rope_theta=50000.0,
+    dtype=jnp.float32,
+    attn_impl="xla",
+    attn_kind="mla",
+    kv_lora_rank=16,
+    qk_nope_dim=16,
+    qk_rope_dim=8,
+    v_head_dim=16,
+    mla_scale=False,
+    ffn_kinds=("dense", "moe", "moe"),
+    router_kind="linear",
+    gate_scoring="sigmoid",
+    norm_topk=True,
+    n_experts=8,
+    n_routed_experts=8,
+    n_shared_experts=2,
+    top_k=3,
+    routed_scale=2.5,
+    expert_dim=32,
+)
+
+# Moonlight-16B-A3B (huggingface.co/moonshotai/Moonlight-16B-A3B config.json,
+# model_type deepseek_v3): MLA (16 heads, q projected directly, latent 512,
+# rope 64, nope 128, v 128), one dense SwiGLU layer of 11264, then expert
+# layers: 64 routed experts of 1408, 6 a token by a sigmoid gate (score +
+# bias chooses, the scores normalised over the six weigh, x 2.446), 2 shared
+# experts; untied 163,840-row head; bf16. The published model has 27 layers
+# (16 B parameters, 32 GB) and no one chip holds them; this entry is what ONE
+# v5e holds and boots, every expert and the whole vocabulary with it: the
+# dense layer and the first 8 expert layers, 5.43 B parameters, 10.87 GB (the
+# benchmark's `moonlight-16b-a3b-bf16`). The other 18 are further stages of
+# a pipeline.
+MOONLIGHT_16B_9L = TransformerConfig(
+    vocab_size=163840,
+    dim=2048,
+    n_layers=9,
+    n_heads=16,
+    n_kv_heads=1,
+    hidden_dim=11264,
+    max_seq=8192,
+    rope_theta=50000.0,
+    attn_kind="mla",
+    kv_lora_rank=512,
+    qk_nope_dim=128,
+    qk_rope_dim=64,
+    v_head_dim=128,
+    mla_scale=False,
+    ffn_kinds=("dense",) + ("moe",) * 8,
+    router_kind="linear",
+    gate_scoring="sigmoid",
+    norm_topk=True,
+    n_experts=64,
+    n_routed_experts=64,
+    n_shared_experts=2,
+    top_k=6,
+    routed_scale=2.446,
+    expert_dim=1408,
+)
+
 # Small-but-realistic single-chip bench model (fits v5e-1 in bf16 and
 # exercises the same kernels/shapes class as 8B)
 SMALL = TransformerConfig(
@@ -245,6 +319,8 @@ CONFIGS: dict[str, TransformerConfig] = {
     "jamba2-3b": JAMBA2_3B,
     "tiny-longcat": TINY_LONGCAT,
     "longcat-flash-ep32": LONGCAT_FLASH,
+    "tiny-moonlight": TINY_MOONLIGHT,
+    "moonlight-16b-a3b-9l": MOONLIGHT_16B_9L,
     "small": SMALL,
     "llama3-8b": LLAMA3_8B,
     "llama3-70b": LLAMA3_70B,

@@ -211,9 +211,15 @@ def _linear_routed(
         y = scale * sum_{e in E} p_e f_e(h)
 
     ``f_e`` is SwiGLU expert e for a routed e, ``h`` itself for an identity
-    one. This chip holds the routed experts ``ep_rank * n_experts`` onwards,
-    ``n_experts`` of them: a pair whose expert another chip holds adds
-    nothing here, an identity pair is computed here (a token's home chip
+    one. Two facts of the configuration change the gate, and nothing else
+    reads them: ``cfg.gate_scoring`` "sigmoid" gives ``p = sigmoid(h Wr)``,
+    and under ``cfg.norm_topk`` the chosen scores are divided by their sum
+    (+ 1e-20) before the scale; the bias chooses and never weighs. With
+    ``cfg.n_shared_experts`` every token also takes one SwiGLU of the shared
+    experts' joint width (the layer's leaves ``shared_gate | up | down``),
+    added unweighted and unscaled. This chip holds the routed experts
+    ``ep_rank * n_experts`` onwards, ``n_experts`` of them: a pair whose
+    expert another chip holds adds nothing here, an identity pair is computed here (a token's home chip
     needs no exchange for it). ``expert_counts`` [n_experts + 2]: the pairs
     each held expert got, then the identity pairs, then the absent ones."""
     from gofr_tpu.ops.experts import routed_experts
@@ -221,11 +227,15 @@ def _linear_routed(
     b, s, d = h.shape
     f32 = jnp.float32
     with jax.named_scope("moe.router"):
-        probs = jax.nn.softmax(jnp.einsum(
-            "...i,io->...o", h.astype(f32), p["router"].astype(f32),
-            precision=lax.Precision.HIGHEST), axis=-1)
+        logits = jnp.einsum("...i,io->...o", h.astype(f32), p["router"].astype(f32),
+                            precision=lax.Precision.HIGHEST)
+        probs = (jax.nn.sigmoid(logits) if cfg.gate_scoring == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))
         _, choice = lax.top_k(probs + p["router_bias"].astype(f32), cfg.top_k)
-        weight = jnp.take_along_axis(probs, choice, axis=-1) * cfg.routed_scale
+        weight = jnp.take_along_axis(probs, choice, axis=-1)
+        if cfg.norm_topk:
+            weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+        weight = weight * cfg.routed_scale
         real = jnp.ones(choice.shape, bool) if token_mask is None else token_mask[..., None]
         local = choice - cfg.ep_rank * cfg.n_experts
         held = real & (local >= 0) & (local < cfg.n_experts)
@@ -235,10 +245,17 @@ def _linear_routed(
     y, counts = routed_experts(
         h.reshape(b * s, d), expert.reshape(b * s, cfg.top_k), experts["w_gate"],
         experts["w_up"], experts["w_down"], layer, weight=weight.reshape(b * s, cfg.top_k),
+        gate_outputs=cfg.n_routed_experts + cfg.n_identity_experts,
     )
-    with jax.named_scope("moe.identity"):
-        own = jnp.sum(jnp.where(identity, weight, 0.0), axis=-1)
-        y = y.reshape(b, s, d) + own[..., None] * h.astype(f32)
+    y = y.reshape(b, s, d)
+    if cfg.n_identity_experts:
+        with jax.named_scope("moe.identity"):
+            own = jnp.sum(jnp.where(identity, weight, 0.0), axis=-1)
+            y = y + own[..., None] * h.astype(f32)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            gated = jax.nn.silu(_mm(h, p["shared_gate"])) * _mm(h, p["shared_up"])
+            y = y + _mm(gated, p["shared_down"]).astype(f32)
     with jax.named_scope("moe.combine"):
         y = y.astype(h.dtype)
         counts = jnp.concatenate([counts, jnp.stack([jnp.sum(identity), jnp.sum(absent)])

@@ -79,6 +79,14 @@ class TransformerConfig:
     # product of ``n_experts`` SwiGLU experts of width ``expert_dim`` that
     # takes the first sublayer's normed input and is added after the second.
     ffn_kind: str = "dense"
+    # the feed-forward of each layer, one name a layer ("dense" | "moe"),
+    # where the layers do not all take the same; empty means every layer is
+    # ``ffn_kind``. Parameters are then stacked per feed-forward kind
+    # (``params["layers"]["dense" | "moe"]``) and the layer loop takes the
+    # runs of equal layers in turn (``ffn_runs``): a leading dense layer,
+    # then a scan over the expert layers. The cache is one stack over all
+    # layers, whatever follows a layer's mixer.
+    ffn_kinds: tuple = ()
     n_experts: int = 0
     router_dim: int = 0
     # who chooses the experts. "mlp": the MLP router above, one expert a
@@ -88,12 +96,19 @@ class TransformerConfig:
     # routed experts are the deployment's; this chip holds ``n_experts`` of
     # them, those from ``ep_rank * n_experts`` on, and a choice of another
     # chip's adds nothing here. An identity expert gives its input back and
-    # holds no weights.
+    # holds no weights. ``gate_scoring`` "sigmoid" scores each output on its
+    # own in place of the softmax; ``norm_topk`` divides the chosen scores by
+    # their sum before ``routed_scale`` (the bias chooses and weighs nothing
+    # either way). ``n_shared_experts`` experts of width ``expert_dim`` every
+    # token takes beside its routed ones: one SwiGLU of their joint width.
     router_kind: str = "mlp"
     n_routed_experts: int = 0
     n_identity_experts: int = 0
     top_k: int = 1
     routed_scale: float = 1.0
+    gate_scoring: str = "softmax"
+    norm_topk: bool = False
+    n_shared_experts: int = 0
     ep_rank: int = 0
     expert_dim: int = 0  # 0: ``hidden_dim``
     # the mixer of each layer, one name a layer, where the layers are not
@@ -105,7 +120,10 @@ class TransformerConfig:
     # layer loop scans the pattern's period (``layer_period``).
     layer_kinds: tuple = ()
     # "mla" (an ``attn_kind``): latent attention (``_mla_mixer``, ops/mla.py).
-    # q through a bottleneck of ``q_lora_rank``; what a token leaves in the
+    # q through a bottleneck of ``q_lora_rank`` (0: projected directly, one
+    # ``wq``); ``mla_scale``: the query is multiplied by sqrt(dim /
+    # q_lora_rank) and the normed latent by sqrt(dim / kv_lora_rank)
+    # (LongCat-Flash's two ``mla_scale`` factors). What a token leaves in the
     # cache is one latent of ``kv_lora_rank`` and one rotated key of
     # ``qk_rope_dim`` shared by all heads, from which each head's key
     # (``qk_nope_dim`` wide beside the shared part) and value
@@ -115,6 +133,7 @@ class TransformerConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    mla_scale: bool = True
     # an "ssm" layer: ``ssm_expand * dim`` channels, ``ssm_state`` entries
     # of state a channel, a causal depthwise convolution of ``ssm_conv``
     # taps, a step size projected through ``ssm_dt_rank``. The state is
@@ -135,8 +154,21 @@ class TransformerConfig:
                     f"layer_kinds names {len(self.layer_kinds)} layers of kinds "
                     f"{sorted(set(self.layer_kinds))}; the model has {self.n_layers} and a "
                     f"kind is one of {MIXER_KINDS}")
-            if self.ffn_kind != "dense":
+            if self.ffn_kind != "dense" or self.ffn_kinds:
                 raise ValueError("layers of more than one kind take the dense feed-forward")
+        if self.ffn_kinds:
+            ffns = tuple(self.ffn_kinds)
+            if len(ffns) != self.n_layers or set(ffns) - {"dense", "moe"}:
+                raise ValueError(
+                    f"ffn_kinds names {len(ffns)} layers of kinds {sorted(set(ffns))}; the "
+                    f"model has {self.n_layers} and a kind is \"dense\" or \"moe\"")
+            if "moe" in ffns and self.router_kind != "linear":
+                raise ValueError("a router whose state passes from layer to layer takes "
+                                 "expert layers alone: ffn_kinds takes the linear router")
+            # layers that are all alike are one stack, scanned as it always was
+            alike = len(set(ffns)) == 1
+            object.__setattr__(self, "ffn_kind", ffns[0] if alike else "dense")
+            object.__setattr__(self, "ffn_kinds", () if alike else ffns)
         if self.attn_kind == "mla":
             object.__setattr__(self, "head_dim", self.qk_nope_dim + self.qk_rope_dim)
         if not self.expert_dim:
@@ -162,9 +194,33 @@ class TransformerConfig:
         return self.kinds.count(kind)
 
     @property
+    def ffns(self) -> tuple:
+        """The feed-forward of every layer, first to last."""
+        return self.ffn_kinds or (self.ffn_kind,) * self.n_layers
+
+    @property
+    def ffn_stacked(self) -> bool:
+        """Whether parameters are stacked per feed-forward kind."""
+        return bool(self.ffn_kinds)
+
+    @property
+    def ffn_runs(self) -> tuple:
+        """The runs of equal layers down the stack as (feed-forward kind,
+        first index among the layers of that kind, first layer, layers)."""
+        runs: list = []
+        seen: dict = {}
+        for i, ffn in enumerate(self.ffns):
+            if runs and runs[-1][0] == ffn:
+                runs[-1][3] += 1
+            else:
+                runs.append([ffn, seen.get(ffn, 0), i, 1])
+            seen[ffn] = seen.get(ffn, 0) + 1
+        return tuple(tuple(r) for r in runs)
+
+    @property
     def routed(self) -> bool:
-        """Whether a layer has routed experts (``ffn_kind`` "moe", "scmoe")."""
-        return self.ffn_kind in ("moe", "scmoe")
+        """Whether a layer has routed experts (feed-forward "moe", "scmoe")."""
+        return any(ffn in ("moe", "scmoe") for ffn in self.ffns)
 
     @property
     def mixers_per_layer(self) -> int:
@@ -303,7 +359,7 @@ def init_transformer(
     quantizer_for(quantize)  # validate the mode eagerly
     if quantize and cfg.routed:
         raise ValueError("the quantiser does not take expert-stacked leaves")
-    if quantize and cfg.mixed:
+    if quantize and (cfg.mixed or cfg.ffn_stacked):
         raise ValueError("the quantiser does not take layers stacked per kind")
     n_keys = cfg.n_layers * 7 + 3
     keys = iter(jax.random.split(key, n_keys))
@@ -362,8 +418,13 @@ def init_transformer(
         r, e = cfg.router_dim, cfg.n_experts
         if cfg.router_kind == "linear":
             outputs = cfg.n_routed_experts + cfg.n_identity_experts
+            shared = cfg.n_shared_experts * cfg.expert_dim
             return {"router": extra(i, 3, (cfg.dim, outputs), cfg.dim),
-                    "router_bias": jnp.zeros((outputs,), jnp.float32), **expert_leaves(i)}
+                    "router_bias": jnp.zeros((outputs,), jnp.float32), **expert_leaves(i),
+                    **({"shared_gate": extra(i, 15, (cfg.dim, shared), cfg.dim),
+                        "shared_up": extra(i, 16, (cfg.dim, shared), cfg.dim),
+                        "shared_down": extra(i, 17, (shared, cfg.dim), shared)}
+                       if shared else {})}
         return {
             "router_down": extra(i, 3, (cfg.dim, r), cfg.dim),
             "router_down_b": jnp.zeros((r,), cfg.dtype),
@@ -380,11 +441,13 @@ def init_transformer(
     def mla_leaves(i: int, j: int) -> dict:
         h, rq, rc = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
         at = 30 + 8 * j
+        query = {"wq_a": extra(i, at, (cfg.dim, rq), cfg.dim),
+                 "q_norm": jnp.ones((rq,), cfg.dtype),
+                 "wq_b": extra(i, at + 1, (rq, h * cfg.head_dim), rq)} if rq else {
+                     "wq": extra(i, at, (cfg.dim, h * cfg.head_dim), cfg.dim)}
         return {
             "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
-            "wq_a": extra(i, at, (cfg.dim, rq), cfg.dim),
-            "q_norm": jnp.ones((rq,), cfg.dtype),
-            "wq_b": extra(i, at + 1, (rq, h * cfg.head_dim), rq),
+            **query,
             "wkv_a": extra(i, at + 2, (cfg.dim, rc + cfg.qk_rope_dim), cfg.dim),
             "kv_norm": jnp.ones((rc,), cfg.dtype),
             "wkv_b": extra(i, at + 3, (rc, h * (cfg.qk_nope_dim + cfg.v_head_dim)), rc),
@@ -441,7 +504,8 @@ def init_transformer(
         }
 
     def make_layer(kind: str, i: int) -> dict:
-        if cfg.ffn_kind == "scmoe":
+        ffn = cfg.ffns[i]
+        if ffn == "scmoe":
             # the two (mixer, dense) sublayers under "sub" (stacked over all
             # sublayers in the tree); the router and the experts are the layer's
             subs = [{**mla_leaves(i, j), **dense_leaves(i, j)} for j in range(2)]
@@ -465,9 +529,9 @@ def init_transformer(
             layer.update(retention_leaves(i))
         if kind == "cca":
             layer.update(cca_leaves(i))
-        if cfg.ffn_kind == "moe":
+        if ffn == "moe":
             layer.update(moe_leaves(i))
-        if cfg.ffn_kind == "dense":
+        if ffn == "dense":
             layer.update({
                 "w_gate": dense(next(keys), (cfg.dim, cfg.hidden_dim), cfg.dim),
                 "w_up": dense(next(keys), (cfg.dim, cfg.hidden_dim), cfg.dim),
@@ -482,22 +546,24 @@ def init_transformer(
     # such copies holds the whole model twice until the old tree is
     # dropped — at 8B int8 that is ~14 GB of a 16 GB chip during boot.
     # (Quantized {"q","scale"} dicts thread per-field through the tree maps.)
-    # A model whose layers are not all alike has one such stack a kind,
-    # ``params["layers"][kind]``, a layer at its index among its kind.
+    # A model whose layers are not all alike has one such stack a kind (of
+    # mixer, or of feed-forward where those differ), ``params["layers"][kind]``,
+    # a layer at its index among its kind.
+    groups = cfg.ffns if cfg.ffn_stacked else cfg.kinds
     stacks: dict[str, Any] = {}
     placed: dict[str, int] = {}
-    for i, kind in enumerate(cfg.kinds):
+    for i, (kind, group) in enumerate(zip(cfg.kinds, groups)):
         layer = put(make_layer(kind, i))
-        at = placed.get(kind, 0)
-        if kind not in stacks:
-            stacks[kind] = jax.tree.map(
-                lambda x, n=cfg.n_of(kind): stack_like(x, n), layer)
-        stacks[kind] = jax.tree.map(
-            lambda s, x, at=at: _place_layer(s, x, at), stacks[kind], layer
+        at = placed.get(group, 0)
+        if group not in stacks:
+            stacks[group] = jax.tree.map(
+                lambda x, n=groups.count(group): stack_like(x, n), layer)
+        stacks[group] = jax.tree.map(
+            lambda s, x, at=at: _place_layer(s, x, at), stacks[group], layer
         )
-        placed[kind] = at + 1
+        placed[group] = at + 1
         del layer
-    params["layers"] = stacks if cfg.mixed else stacks[cfg.kinds[0]]
+    params["layers"] = stacks if cfg.mixed or cfg.ffn_stacked else stacks[groups[0]]
     if cfg.ffn_kind == "scmoe":
         # [L, 2, ...] -> [2 L, ...]: sublayer j of layer i at 2 i + j
         params["layers"]["sub"] = jax.tree.map(
@@ -543,9 +609,9 @@ EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 _L2_EPS = 1e-6  # under the root of the "cca" L2 norm: a dead row's q is 0
 
 
-def _split_experts(cfg: TransformerConfig, layers: dict) -> tuple[dict, Optional[dict]]:
+def _split_experts(layers: dict, routed: bool) -> tuple[dict, Optional[dict]]:
     """(what the layer loop scans, the expert stacks it closes over)."""
-    if not cfg.routed:
+    if not routed:
         return layers, None
     return ({k: v for k, v in layers.items() if k not in EXPERT_LEAVES},
             {k: layers[k] for k in EXPERT_LEAVES})
@@ -934,6 +1000,7 @@ def _mla_mixer(
 
         h = rms(x);  q = rms(h Wq_a) Wq_b * sqrt(D / q_rank)   [H, nope | rope]
         [c | kr] = h Wkv_a;  c = rms(c) * sqrt(D / kv_rank);  kr = rope(kr)
+        (``q_lora_rank`` 0: q = h Wq; neither factor without ``mla_scale``)
         [k_nope_i | v_i] = c Wkv_b  (head i);  softmax((q_nope_i . k_nope_i
         + rope(q_rope_i) . kr) / sqrt(nope + rope)) v_i;  concat_i(...) Wo
 
@@ -952,14 +1019,18 @@ def _mla_mixer(
         # ``_attention_mixer``'s do: a reshape folded into the product would
         # want the weight stack relaid)
         q = jax.lax.optimization_barrier(
-            _mm(rms_norm(_mm(hid, p["wq_a"]), p["q_norm"], cfg.norm_eps), p["wq_b"]))
-        q = (q * (cfg.dim / cfg.q_lora_rank) ** 0.5).reshape(b, s, h, cfg.head_dim)
+            _mm(rms_norm(_mm(hid, p["wq_a"]), p["q_norm"], cfg.norm_eps), p["wq_b"])
+            if cfg.q_lora_rank else _mm(hid, p["wq"]))
+        if cfg.mla_scale:
+            q = q * (cfg.dim / cfg.q_lora_rank) ** 0.5
+        q = q.reshape(b, s, h, cfg.head_dim)
         q_nope = q[..., :nope]
         q_rope = apply_rope(_pairs_apart(q[..., nope:]), freqs, positions)
     with jax.named_scope("attn.mla.latent"):
         ckr = _mm(hid, p["wkv_a"])
         c = rms_norm(ckr[..., :rc], p["kv_norm"], cfg.norm_eps)
-        c = (c.astype(jnp.float32) * (cfg.dim / rc) ** 0.5).astype(x.dtype)
+        if cfg.mla_scale:
+            c = (c.astype(jnp.float32) * (cfg.dim / rc) ** 0.5).astype(x.dtype)
         kr = apply_rope(_pairs_apart(ckr[..., rc:])[:, :, None], freqs, positions)[:, :, 0]
         kr = jnp.swapaxes(kr, 1, 2)  # [B, rope, S]
         if cache is None:
@@ -1109,7 +1180,9 @@ def _scan_layers(
                 a_period, (x, stacks), jnp.arange(reps, dtype=jnp.int32))
         return x, stacks, {}
     kind = cfg.kinds[0]
-    scanned, experts = _split_experts(cfg, params["layers"])
+    if cfg.ffn_stacked:
+        return _scan_ffn_runs(cfg, params, x, stacks, block, token_mask)
+    scanned, experts = _split_experts(params["layers"], cfg.routed)
     layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
     if experts is None:
         def body(carry, inputs):
@@ -1144,6 +1217,47 @@ def _scan_layers(
     (x, stacks, _), counts = jax.lax.scan(
         body, (x, stacks, r0), (scanned, layer_ids))
     return x, stacks, {"expert_counts": counts}
+
+
+def _scan_ffn_runs(
+    cfg: TransformerConfig, params: dict, x: jnp.ndarray, stacks: Optional[dict],
+    block: Any, token_mask: Optional[jnp.ndarray],
+) -> tuple[jnp.ndarray, Optional[dict], dict]:
+    """``_scan_layers`` for a model whose layers do not all take the same
+    feed-forward (``cfg.ffn_kinds``): the runs of equal layers in turn
+    (``cfg.ffn_runs``: Moonlight's leading dense layer inline, then one scan
+    over its expert layers), each over its kind's parameter stack, which
+    the loop closes over and a layer reads at its place among its kind. One
+    switch over the kinds inside one scan would hand every layer both
+    kinds' stacks, copied whole. A layer's place in the cache is its place
+    in the model; ``aux["expert_counts"]`` [expert layers, width]."""
+    from gofr_tpu.models.moe import routed_mlp
+
+    kind = cfg.kinds[0]
+    r0 = jnp.zeros(x.shape[:-1] + (cfg.router_dim,), jnp.float32)  # a linear gate keeps none
+    counts = []
+    for ffn, first, at, count in cfg.ffn_runs:
+        scanned, experts = _split_experts(params["layers"][ffn], ffn == "moe")
+
+        def one(carry, j, scanned=scanned, experts=experts, first=first, at=at):
+            x, stacks = carry
+            layer_params = jax.tree.map(
+                lambda leaf: jax.lax.dynamic_index_in_dim(leaf, first + j, 0, keepdims=False),
+                scanned)
+            mlp_fn = None if experts is None else lambda p, h: routed_mlp(  # noqa: E731
+                cfg, p, h, r0, experts, first + j, token_mask)
+            y, stacks, aux = block(layer_params, x, stacks, at + j, mlp_fn, kind)
+            return (y, stacks), aux.get("expert_counts")
+
+        if count == 1:
+            (x, stacks), got = one((x, stacks), jnp.int32(0))
+            got = None if got is None else got[None]
+        else:
+            (x, stacks), got = jax.lax.scan(
+                one, (x, stacks), jnp.arange(count, dtype=jnp.int32))
+        if got is not None:
+            counts.append(got)
+    return x, stacks, {"expert_counts": jnp.concatenate(counts)}
 
 
 def transformer_forward(

@@ -5,7 +5,8 @@ its top-k) it was sent to, reading only the experts that got a token.
 tokens, ``expert`` [T] int32 the expert of each (``n_experts`` for a token
 that goes to none: bucket padding, the row of a slot without a request; or
 ``expert`` [T, k] with ``weight`` [T, k], k (token, expert) pairs a token and
-their weighted sum on the way back: ``_routed_pairs``),
+their weighted sum on the way back: ``_routed_pairs``, whose rows a pass
+follow the share of the gate's ``gate_outputs`` that is held here),
 the weights as the model holds them, stacked over layers
 ([L, E, D, F], [L, E, D, F], [L, E, F, D]) with ``layer`` an int32 scalar,
 or one layer's ([E, ...], ``layer`` None). Returns ``y`` [T, D] (zeros for
@@ -162,26 +163,32 @@ def _sorted_product(xs: jnp.ndarray, counts: jnp.ndarray, w_gate: jnp.ndarray,
     return dot(hidden, wd).astype(xs.dtype)
 
 
-def pair_capacity(tokens: int) -> int:
-    """Rows of sorted pairs one pass of the pair form multiplies: every
-    token's worth for a step's few rows, half of it for a prefill's many
-    (a chip of a wide deployment holds a small share of the experts, so
-    the pairs that land here are a fraction of a pair a token)."""
-    return tokens if tokens <= ROW_TILE else tokens // 2
+def pair_capacity(tokens: int, k: int = 1, held: int = 0, outputs: int = 1) -> int:
+    """Rows of sorted pairs one pass of the pair form multiplies. It follows
+    the share of the gate's ``outputs`` that are experts ``held`` here
+    (``tokens * k * held / outputs`` pairs are expected to land): twice the
+    expectation, and never under every token's worth for a step's few
+    rows, half of it for a prefill's many. A chip of a wide deployment
+    holds a small share, so the pairs that land are a fraction of a pair a
+    token and one pass of that floor takes them; a chip that holds every
+    expert gets all ``tokens * k`` pairs, and one pass takes those."""
+    floor = tokens if tokens <= ROW_TILE else tokens // 2
+    return min(tokens * k, max(floor, 2 * tokens * k * held // outputs))
 
 
-def _routed_pairs(x, expert, weight, w_gate, w_up, w_down, layer, impl):
+def _routed_pairs(x, expert, weight, w_gate, w_up, w_down, layer, impl, gate_outputs=0):
     """The top-k form: ``expert`` [T, k] (``n`` for a pair that goes to no
     expert HERE: an identity expert, another chip's, a pad's) and ``weight``
     [T, k] -> (sum over a token's pairs of weight x expert(x), float32
     [T, D]; counts [E] of PAIRS). The cost follows the pairs that landed on
     an expert held here, not the T x k drawn: the pairs are sorted (ints
     alone), and only those with an expert are gathered, ``pair_capacity``
-    rows a pass, as many passes as they need (one, but for a skewed batch)."""
+    rows a pass, as many passes as they need (one, but for a skewed batch
+    on a chip that holds a small share of the experts)."""
     t, k = expert.shape
     n = w_gate.shape[1]
     f32 = jnp.float32
-    cap = pair_capacity(t)
+    cap = pair_capacity(t, k, n if gate_outputs else 0, gate_outputs or 1)
     with jax.named_scope("moe.dispatch"):
         pairs = expert.reshape(-1)
         counts = jnp.sum(pairs[:, None] == jnp.arange(n)[None, :], axis=0).astype(jnp.int32)
@@ -216,13 +223,13 @@ def _routed_pairs(x, expert, weight, w_gate, w_up, w_down, layer, impl):
 def routed_experts(
     x: jnp.ndarray, expert: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
     w_down: jnp.ndarray, layer: Optional[jnp.ndarray] = None, impl: str = "auto",
-    weight: Optional[jnp.ndarray] = None,
+    weight: Optional[jnp.ndarray] = None, gate_outputs: int = 0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     if layer is None:
         w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
         layer = jnp.int32(0)
     if expert.ndim == 2:
-        return _routed_pairs(x, expert, weight, w_gate, w_up, w_down, layer, impl)
+        return _routed_pairs(x, expert, weight, w_gate, w_up, w_down, layer, impl, gate_outputs)
     t, n = x.shape[0], w_gate.shape[1]
     with jax.named_scope("moe.dispatch"):
         counts = jnp.sum(expert[:, None] == jnp.arange(n)[None, :], axis=0).astype(jnp.int32)

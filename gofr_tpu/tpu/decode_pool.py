@@ -1684,7 +1684,8 @@ class DecodePool:
                     np.asarray(toks_dev), self.n_slots,
                     getattr(self.cfg, "routing_width", 0))
                 if drec is not None and routing is not None:
-                    drec.note_routing(routing, self.cfg.n_experts)
+                    drec.note_routing(routing, self.cfg.n_experts, self.cfg.top_k,
+                                      self.cfg.n_shared_experts)
                 lps = np.asarray(lps_dev)
                 tvals = (
                     np.asarray(tvals_dev) if tvals_dev is not None else None

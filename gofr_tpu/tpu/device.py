@@ -1021,8 +1021,10 @@ class TPUDevice:
         (``models/transformer.py::MIXERS``). Each such setting is refused
         here, by name, at boot: none of them may give a wrong answer
         instead. So is ``MODEL_QUANT`` for a model whose experts are
-        stacked leaves, or whose layers are stacked per kind, neither of
-        which the quantiser takes."""
+        stacked leaves, or whose layers are stacked per kind (of mixer, or
+        of feed-forward: a dense layer before expert layers), neither of
+        which the quantiser takes, and ``LORA_ADAPTERS`` for a tree that is
+        not one stack of layers."""
         from gofr_tpu.models.llama import CONFIGS
         from gofr_tpu.models.transformer import MIXERS
 
@@ -1030,17 +1032,19 @@ class TPUDevice:
         kinds = tuple(getattr(cfg, "kinds_present", ("softmax",)))
         mixed = len(kinds) > 1
         paired = getattr(cfg, "mixers_per_layer", 1) > 1
+        by_ffn = getattr(cfg, "ffn_stacked", False)
+        stacking = ("per kind" if mixed else "per feed-forward kind (a dense layer "
+                    "before expert layers)" if by_ffn else "in pairs of sublayers")
         if self.quant and (getattr(cfg, "routed", False) or mixed):
             raise ValueError(
                 f"MODEL_QUANT is not supported for MODEL_NAME '{self.model_name}': "
                 "the quantiser does not take "
-                + ("layers stacked per kind" if mixed else "expert-stacked leaves")
+                + (f"layers stacked {stacking}" if mixed or by_ffn else "expert-stacked leaves")
             )
-        if (mixed or paired) and self._lora_adapters:
+        if (mixed or paired or by_ffn) and self._lora_adapters:
             raise ValueError(
                 f"LORA_ADAPTERS is not supported for MODEL_NAME '{self.model_name}': "
-                "an adapter wraps one stack of layers, and this model's are stacked "
-                + ("per kind" if mixed else "in pairs of sublayers")
+                f"an adapter wraps one stack of layers, and this model's are stacked {stacking}"
             )
         leaves = {name for kind in kinds for name in MIXERS[kind].cache}
         if leaves <= {"k", "v"}:
@@ -5504,7 +5508,7 @@ def _note_routing(drec: Any, ids: np.ndarray, rows: int, cfg: Any) -> np.ndarray
 
     ids, counts = unpack_expert_counts(ids, rows, getattr(cfg, "routing_width", 0))
     if drec is not None and counts is not None:
-        drec.note_routing(counts, cfg.n_experts)
+        drec.note_routing(counts, cfg.n_experts, cfg.top_k, cfg.n_shared_experts)
     return ids
 
 

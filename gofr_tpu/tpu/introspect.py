@@ -102,7 +102,7 @@ class DispatchRecord:
         "t_done", "t_issued", "t_fetch", "t_fetched", "cadence_s",
         "chunks_ahead", "state_bytes", "kv_blocks_read", "kv_blocks_held",
         "carried", "expert_tokens", "experts_read", "expert_tokens_max",
-        "identity_tokens", "absent_tokens", "latent_bytes",
+        "identity_tokens", "absent_tokens", "latent_bytes", "shared_tokens",
     )
 
     def __init__(
@@ -179,12 +179,19 @@ class DispatchRecord:
         # rotated key) the dispatch's attention had to read, by its rows'
         # lengths over all its places; None for any other cache
         self.latent_bytes: Optional[int] = None
+        # a model with shared experts, which every token takes beside its
+        # routed ones: real tokens x expert layers (x steps); None without
+        self.shared_tokens: Optional[int] = None
 
-    def note_routing(self, counts: Any, held: int = 0) -> None:
+    def note_routing(self, counts: Any, held: int = 0, top_k: int = 1, shared: int = 0) -> None:
         """``counts`` [..., layers, experts]: the tokens each expert of each
         layer got in each step of this dispatch (a numpy array); where it
         is two columns wider than the ``held`` experts, those are the
-        identity pairs and the absent ones."""
+        identity pairs and the absent ones. ``shared``: the model's shared
+        experts (0: none): every real token of an expert layer drew ``top_k``
+        pairs and took the shared expert once."""
+        if shared:
+            self.shared_tokens = int(counts.sum()) // top_k
         if held and counts.shape[-1] == held + 2:
             self.identity_tokens = int(counts[..., held].sum())
             self.absent_tokens = int(counts[..., held + 1].sum())
@@ -252,6 +259,7 @@ class DispatchRecord:
             "identity_tokens": self.identity_tokens,
             "absent_tokens": self.absent_tokens,
             "latent_bytes": self.latent_bytes,
+            "shared_tokens": self.shared_tokens,
         }
 
 

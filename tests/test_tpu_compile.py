@@ -559,13 +559,10 @@ _LWEIGHTS = {f"bf16[{lead}{shape}]" for lead in ("8,", "2,", "1,", "") for shape
     for shape in ("6144,2048", "2048,6144")}
 
 
-def _latent_movers(hlo: str, batch: int) -> dict[str, list[str]]:
+def _movers_of(hlo: str, leaves: set) -> dict[str, list[str]]:
     """computation name -> the instructions in it that write, by moving data,
-    a result of the shape of a latent cache leaf (or of one place of it), of
-    a sublayer weight stack or of the expert stacks. In-place row writes and
-    what happens inside a fused computation are no moves (``_hybrid_movers``)."""
-    leaves = {f"bf16[{lead}{batch},7168,512]" for lead in ("8,", "1,", "")} | {
-        f"bf16[{lead}{batch},64,7168]" for lead in ("8,", "1,", "")} | _LWEIGHTS
+    a result of one of the shapes ``leaves``. In-place row writes and what
+    happens inside a fused computation are no moves (``_hybrid_movers``)."""
     found: dict[str, list[str]] = {}
     for computation, name, shape, _, opcode in _instructions(hlo):
         if shape not in leaves or computation.startswith("fused_computation"):
@@ -574,6 +571,13 @@ def _latent_movers(hlo: str, batch: int) -> dict[str, list[str]]:
                 opcode == "fusion" and not _updates_in_place(hlo, name)):
             found.setdefault(computation, []).append(name)
     return found
+
+
+def _latent_movers(hlo: str, batch: int) -> dict[str, list[str]]:
+    """What moves a latent cache leaf (or one place of it), a sublayer weight
+    stack or the expert stacks."""
+    return _movers_of(hlo, {f"bf16[{lead}{batch},7168,512]" for lead in ("8,", "1,", "")} | {
+        f"bf16[{lead}{batch},64,7168]" for lead in ("8,", "1,", "")} | _LWEIGHTS)
 
 
 def _longcat_params():
@@ -609,4 +613,71 @@ def test_prefill_of_a_latent_model_compiles_at_the_cells_widest_bucket(one_chip,
     # called as the server calls it, the caller keeping its cache: the two
     # leaves are copied where they enter and in no loop; no weight is moved
     movers = _latent_movers(hlo, 2)
+    assert set(movers) <= {"ENTRY"} and len(movers.get("ENTRY", [])) <= 4, movers
+
+
+# -- a dense layer before expert layers, every expert held, shared experts, no q bottleneck ------
+# Moonlight-16B-A3B's widths as the benchmark's cell holds it (1 dense + 8
+# expert layers, all 64 routed experts, MODEL_MAX_SEQ 2048, the cell's 48
+# slots) with a small vocabulary
+
+MOONCFG = T.TransformerConfig(
+    vocab_size=4096, dim=2048, n_layers=9, n_heads=16, n_kv_heads=1, hidden_dim=11264,
+    max_seq=2048, rope_theta=50000.0, attn_kind="mla", kv_lora_rank=512, qk_nope_dim=128,
+    qk_rope_dim=64, v_head_dim=128, mla_scale=False, ffn_kinds=("dense",) + ("moe",) * 8,
+    router_kind="linear", gate_scoring="sigmoid", norm_topk=True, n_experts=64,
+    n_routed_experts=64, n_shared_experts=2, top_k=6, routed_scale=2.446, expert_dim=1408,
+)
+MOONSLOTS = 48
+# the expert stacks, one layer's experts, one expert; the shared experts' and
+# the attention's stacks over the expert layers, and the dense layer's
+_MOONWEIGHTS = {f"bf16[{lead}{shape}]" for lead in ("8,64,", "1,64,", "64,", "")
+             for shape in ("2048,1408", "1408,2048")} | {
+    f"bf16[{lead}{shape}]" for lead in ("8,", "1,") for shape in (
+        "2048,2816", "2816,2048", "2048,3072", "2048,2048", "2048,11264", "11264,2048")}
+
+
+def _moonlight_movers(hlo: str, batch: int) -> dict[str, list[str]]:
+    """What moves the latent cache over nine places, the expert stacks, the
+    shared experts' stacks or the attention's."""
+    return _movers_of(hlo, {f"bf16[{lead}{batch},2048,512]" for lead in ("9,", "1,", "")} | {
+        f"bf16[{lead}{batch},64,2048]" for lead in ("9,", "1,", "")} | _MOONWEIGHTS)
+
+
+def _moonlight_params():
+    return T.init_transformer(jax.random.key(0), MOONCFG)
+
+
+def test_pooled_chunk_of_a_model_with_a_leading_dense_layer_takes_one_pass_of_pairs_a_layer(
+        texts, one_chip, as_on_tpu):
+    """The dense layer inline and one scan over the eight expert layers, each
+    reading its kind's stack where it lies; all 48 x 6 pairs of a step in ONE
+    pass of the pair form (288 sorted rows, three row tiles of 128), whose
+    two Pallas calls index [layer, expert] of the stacks themselves; the
+    absorbed attention's kernel in both bodies."""
+    hlo = _pooled_chunk(texts, one_chip, "moonlight", MOONCFG, MOONSLOTS, _moonlight_params)
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 4
+    assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
+    assert hlo.count("mla_absorbed_decode") >= 2
+    assert "bf16[384,2048]" in hlo and "bf16[384,1408]" in hlo  # a step's every pair, padded
+    assert _moonlight_movers(hlo, MOONSLOTS) == {}
+
+
+def test_prefill_of_a_model_with_a_leading_dense_layer_compiles_at_the_cells_bucket(
+        one_chip, as_on_tpu):
+    """Two rows of 256: the expanded form through the flash kernel at a key
+    of 192 and a value of 128, and all 512 x 6 pairs in one pass of the pair
+    form (3072 sorted rows)."""
+    hlo = _compiled(
+        lambda p, t, c, l: T.prefill(p, t, c, MOONCFG, l, with_aux=True), (), one_chip,
+        _moonlight_params, jnp.zeros((2, 256), jnp.int32),
+        lambda: T.init_cache(MOONCFG, 2), jnp.zeros((2,), jnp.int32),
+    )
+    # the flash forward in the dense layer and in the scanned one, the two expert products
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 4
+    assert "moe_experts_gated" in hlo and "moe_experts_down" in hlo
+    assert "bf16[3072,2048]" in hlo and "bf16[3072,1408]" in hlo
+    # called as the server calls it, the caller keeping its cache: the two
+    # leaves are copied where they enter and in no loop; no weight is moved
+    movers = _moonlight_movers(hlo, 2)
     assert set(movers) <= {"ENTRY"} and len(movers.get("ENTRY", [])) <= 4, movers
