@@ -13,7 +13,7 @@ import json
 from typing import Any, AsyncIterator, Optional
 
 from gofr_tpu.errors import status_from_error
-from gofr_tpu.http.response import File, Raw, Response, Stream
+from gofr_tpu.http.response import File, Held, Raw, Response, Stream
 
 _JSON = "application/json"
 
@@ -69,10 +69,10 @@ async def _sse_iter(stream: Stream, executor: Any = None) -> AsyncIterator[bytes
                     yield _to_bytes(item)
                 # resumed: the server wrote that frame and asked for more
                 if stream.on_write is not None:
-                    stream.on_write()
+                    stream.on_write(1)
         else:
             # Sync generators (e.g. blocking token decode) must not stall the
-            # event loop between yields; pull each item on a worker thread —
+            # event loop between yields; pull on a worker thread —
             # the CALLER-provided pool (container.handler_executor), because a
             # stream's blocking next() holds its thread for the full
             # inter-token wait and asyncio's cpu_count+4 default executor
@@ -81,20 +81,45 @@ async def _sse_iter(stream: Stream, executor: Any = None) -> AsyncIterator[bytes
 
             loop = asyncio.get_running_loop()
             iterator = iter(events)  # type: ignore[arg-type]
+            ready = stream.ready
             sentinel = object()
+
+            def pull() -> list:
+                # ONE trip to the thread for whatever the stream has: it
+                # waits for the first item as ever, then takes each further
+                # one that is ALREADY there (``Stream.ready`` says so) and
+                # never waits for a second. Framed here, off the loop.
+                nonlocal next_id
+                frames: list = []
+                item = next(iterator, sentinel)
+                while item is not sentinel:
+                    if stream.sse:
+                        frames.append(_frame_sse(item, next_id))
+                        if next_id is not None:
+                            next_id += 1
+                    else:
+                        frames.append(_to_bytes(item))
+                    if ready is None or not ready():
+                        return frames
+                    item = next(iterator, sentinel)
+                frames.append(sentinel)
+                return frames
+
             while True:
-                item = await loop.run_in_executor(executor, next, iterator, sentinel)
-                if item is sentinel:
+                frames = await loop.run_in_executor(executor, pull)
+                ended = frames[-1] is sentinel
+                if ended:
+                    frames.pop()
+                # all but the last are Held: the server writes them with it
+                for frame in frames[:-1]:
+                    yield Held(frame)
+                if frames:
+                    yield frames[-1]
+                    # resumed: the server wrote them and asked for more
+                    if stream.on_write is not None:
+                        stream.on_write(len(frames))
+                if ended:
                     break
-                if stream.sse:
-                    yield _frame_sse(item, next_id)
-                    if next_id is not None:
-                        next_id += 1
-                else:
-                    yield _to_bytes(item)
-                # resumed: the server wrote that frame and asked for more
-                if stream.on_write is not None:
-                    stream.on_write()
         completed = True
     finally:
         if not completed and stream.on_abort is not None:

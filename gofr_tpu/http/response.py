@@ -21,8 +21,20 @@ class Response:
     headers: dict[str, str] = field(default_factory=dict)
     body: bytes = b""
     # When set, body is ignored and chunks are written as they arrive
-    # (chunked transfer encoding; used for SSE token streaming).
+    # (chunked transfer encoding; used for SSE token streaming). A chunk
+    # that is ``Held`` waits for the chunks right behind it: one write.
     stream: Optional[Union[Iterator[bytes], AsyncIterator[bytes]]] = None
+
+
+class Held(bytes):
+    """A chunk of a streamed body that was ready TOGETHER with the chunks
+    right behind it (the responder pulled them off the stream in one go):
+    the server encodes it as an HTTP chunk of its own, as ever, and keeps
+    it for the write that carries the first plain chunk after it. Whatever
+    wraps a body and yields plain ``bytes`` loses only the sharing: every
+    chunk is then written on its own, as before."""
+
+    __slots__ = ()
 
 
 @dataclass
@@ -64,11 +76,20 @@ class Stream:
     the generation's stop event so an abandoned stream frees its
     decode slot and paged-KV blocks within one chunk.
 
-    ``on_write`` (optional callable) fires each time a frame was handed
-    to the socket — the server's write of the previous frame returned
-    and it asked for the next. The flight recorder uses it to mark when
-    the first token's frame left (``FlightRecord.t_first_frame``) and to
-    add each token frame's wait since its delivery (``frame_lag_*``)."""
+    ``on_write(frames)`` (optional callable) fires each time a write of
+    ``frames`` frames was handed to the socket — the server's write
+    returned and it asked for more. The flight recorder uses it to mark
+    when the first token's frame left (``FlightRecord.t_first_frame``),
+    to add each token frame's wait since its delivery (``frame_lag_*``)
+    and to count the writes that carried them (``frame_writes``).
+
+    ``ready`` (optional callable) says whether the NEXT item of a sync
+    ``events`` is there to be had without waiting. With it, the thread
+    the responder sends for an item waits for the first as ever and then
+    takes every further one while ``ready()`` holds: what was ready
+    together is framed (one SSE frame, one ``id:``, one HTTP chunk an
+    item, as ever), written and drained together. It never waits for a
+    second item, and without ``ready`` each item is pulled on its own."""
 
     events: Union[Iterator[Any], AsyncIterator[Any]]
     sse: bool = True
@@ -77,3 +98,4 @@ class Stream:
     id_offset: int = 0
     on_abort: Optional[Any] = None
     on_write: Optional[Any] = None
+    ready: Optional[Any] = None

@@ -22,7 +22,7 @@ from collections import deque
 from typing import Any, Callable, Optional
 
 from gofr_tpu.http.request import Request
-from gofr_tpu.http.response import Response
+from gofr_tpu.http.response import Held, Response
 from gofr_tpu.http.router import Router
 from gofr_tpu.profiling import HTTP_LOOP_TICK, instant
 
@@ -319,16 +319,27 @@ class HTTPServer:
             return
         if response.stream is not None:
             try:
+                # a Held chunk (what the responder pulled off the stream
+                # together with the chunks behind it) waits for the next
+                # plain one: one write and one drain for the burst, an HTTP
+                # chunk a frame on the wire as ever
+                held: list[bytes] = []
                 async for chunk in response.stream:
-                    if not chunk:
+                    if chunk:
+                        held += (b"%x\r\n" % len(chunk), chunk, b"\r\n")
+                    if isinstance(chunk, Held) or not held:
                         continue
-                    writer.write(f"{len(chunk):x}\r\n".encode() + chunk + b"\r\n")
+                    burst = b"".join(held)
+                    held.clear()
+                    writer.write(burst)
                     await writer.drain()
             except Exception as exc:
                 # Abort WITHOUT the terminal chunk so the client sees a
                 # truncated chunked body (distinguishable from completion).
                 if self.logger:
                     self.logger.errorf("response stream aborted: %r", exc)
+                if held:  # the body failed behind them: they leave, as ever
+                    writer.write(b"".join(held))
                 transport = writer.transport
                 if transport is not None:
                     transport.abort()
@@ -344,7 +355,8 @@ class HTTPServer:
                     except Exception:
                         pass  # teardown best-effort; the abort already won
                 return
-            writer.write(b"0\r\n\r\n")
+            # (held: a body that ended on a Held chunk, cut by a wrapper)
+            writer.write(b"".join(held) + b"0\r\n\r\n")
             await writer.drain()
         else:
             writer.write(response.body)

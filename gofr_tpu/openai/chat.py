@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import time
 import uuid
+from collections import deque
 from typing import Any
 
 from gofr_tpu.openai.fanout import _fanout_generate
@@ -89,6 +90,11 @@ def _stream_chat(
         adapter=adapter, logprobs=want_logprobs, cancel=cancel,
     )
     record = current_record()  # here, on the handler's thread: events() runs elsewhere
+    # token frames built and not yet pulled (``Stream.ready``): the tokens
+    # that are there together become frames before the first is handed on,
+    # so that one pull of the responder takes them all and none waits (a
+    # token that decodes to no text leaves no frame to wait behind)
+    held: deque = deque()
 
     def events():
         emitted = 0
@@ -107,13 +113,18 @@ def _stream_chat(
                         if text:
                             # no lp: the matched token's text is
                             # excluded from the stream
-                            yield chunk({"content": text})
+                            held.append(chunk({"content": text}))
                         finish = "stop"
                         break
                 if text or lp is not None:
-                    yield chunk({"content": text}, lp=lp, token_id=token)
+                    held.append(chunk({"content": text}, lp=lp, token_id=token))
                 elif record is not None:
                     record.note_unframed()  # its text rides a later frame
+                if not stream_iter.ready():  # the next token is a wait away
+                    while held:
+                        yield held.popleft()
+            while held:  # a stop leaves the loop with its frames built
+                yield held.popleft()
             if record is not None:
                 record.end_token_frames()  # a stop may leave tokens unframed
             tail = dec.flush()
@@ -143,7 +154,7 @@ def _stream_chat(
     # resume a deterministic chat stream by replaying from zero and
     # filtering already-delivered frames (chat frames are not 1:1 with
     # tokens, so there is no replica-side X-Resume-From shortcut here)
-    return Stream(events(), ids=True, on_abort=on_abort)
+    return Stream(events(), ids=True, on_abort=on_abort, ready=held.__len__)
 
 
 def _stream_chat_fanout(

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import time
 import uuid
+from collections import deque
 from typing import Any
 
 from gofr_tpu.openai.fanout import _fanout_generate
@@ -126,6 +127,10 @@ def _stream_completion(
         resume_from=min(resume_from, max_tokens), cancel=cancel,
     )
     record = current_record()  # here, on the handler's thread: events() runs elsewhere
+    # token frames built and not yet pulled (``Stream.ready``): the tokens
+    # that are there together become frames before the first is handed on,
+    # so that one pull of the responder takes them all and none waits
+    held: deque = deque()
 
     def events():
         # a resumed stream's token iterator starts at the resume
@@ -150,20 +155,25 @@ def _stream_completion(
                 token, lp = item if want_logprobs else (item, None)
                 emitted += 1
                 if dec is None:
-                    yield chunk("", lp, token=token)
-                    continue
-                text = dec.feed(token)
-                if scan is not None:
-                    text, done = scan.feed(text)
-                    if done:
-                        # matched mid-stream: emit up to the stop and
-                        # cancel the decode (frees the pool slot). No
-                        # lp: the matched token's text is excluded, so
-                        # its logprob must not ride this chunk either
-                        yield chunk(text, None)
-                        finish = "stop"
-                        break
-                yield chunk(text, lp)
+                    held.append(chunk("", lp, token=token))
+                else:
+                    text = dec.feed(token)
+                    if scan is not None:
+                        text, done = scan.feed(text)
+                        if done:
+                            # matched mid-stream: emit up to the stop and
+                            # cancel the decode (frees the pool slot). No
+                            # lp: the matched token's text is excluded, so
+                            # its logprob must not ride this chunk either
+                            held.append(chunk(text, None))
+                            finish = "stop"
+                            break
+                    held.append(chunk(text, lp))
+                if not stream_iter.ready():  # the next token is a wait away
+                    while held:
+                        yield held.popleft()
+            while held:  # a stop leaves the loop with its frames built
+                yield held.popleft()
             if record is not None:
                 record.end_token_frames()  # a stop may leave tokens unframed
             tail = dec.flush() if dec is not None else ""
@@ -191,7 +201,7 @@ def _stream_completion(
     # the resume offset), making the stream resumable through the fleet
     # router's journal — see docs/advanced-guide/fleet.md
     return Stream(events(), ids=True, id_offset=resume_from,
-                  on_abort=on_abort)
+                  on_abort=on_abort, ready=held.__len__)
 
 
 def _stream_completion_fanout(

@@ -265,7 +265,7 @@ class FlightRecord:
         "t_pool_admit", "t_first_frame",
         "t_state_insert", "t_state_inserted",
         "t_seat_wait", "t_seated",
-        "t_received", "frames", "frame_lag_max_s", "deliver_gap_max_s",
+        "t_received", "frames", "frame_writes", "frame_lag_max_s", "deliver_gap_max_s",
         "loop_lag_mean_s", "loop_lag_max_s",
         "frames_per_token",
         "_frame_lag_sum", "_deliveries", "_head_t", "_head_left", "_t_delivered",
@@ -388,6 +388,9 @@ class FlightRecord:
         # its wait. Producers append, the one consumer is the stream's
         # own turn on the event loop: no lock on either side.
         self.frames = 0  # token frames written
+        # the writes that carried them: frames over writes is how many
+        # tokens were ready together when the stream was pulled
+        self.frame_writes = 0
         # a greedy n > 1 stream replicates ONE generation's tokens into a
         # frame an index (the fan-out builders set n)
         self.frames_per_token = 1
@@ -465,6 +468,15 @@ class FlightRecord:
         if lag > self.frame_lag_max_s:
             self.frame_lag_max_s = lag
         return True
+
+    def note_write(self, now: float, frames: int) -> int:
+        """One write of the server carried ``frames`` frames and has
+        returned: each is noted with its own wait, and a write that
+        carried a token frame is counted. Returns the token frames."""
+        counted = sum(self.note_frame(now) for _ in range(frames))
+        if counted:
+            self.frame_writes += 1
+        return counted
 
     def note_unframed(self) -> None:
         """A token left the stream without a frame of its own (a chat
@@ -692,6 +704,7 @@ class FlightRecord:
             "state_insert_s": between(self.t_state_insert, self.t_state_inserted),
             "server_ttft_s": between(self.t_start, self.t_first_frame),
             "frames": self.frames if self.stream else None,
+            "frame_writes": self.frame_writes if self.stream else None,
             "frame_lag_mean_s": (
                 self._frame_lag_sum / self.frames if self.frames else None
             ),
@@ -1288,9 +1301,10 @@ class FlightRecorder:
         # the HTTP server's LoopClock, once an App has one (app.py): a
         # finishing record takes the loop's lag over its own life from it
         self.loop_clock: Any = None
-        # token frames written on every stream (the event loop's thread
-        # alone adds to it)
+        # token frames written on every stream, and the writes that
+        # carried them (the event loop's thread alone adds to them)
         self.frames_total = 0
+        self.frame_writes_total = 0
         self._ring: "deque[FlightRecord]" = deque(maxlen=max(1, capacity))
         self._notable: "deque[FlightRecord]" = deque(maxlen=max(1, keep))
         # records started but not yet finished — the postmortem bundle
@@ -1401,17 +1415,20 @@ class FlightRecorder:
             else:
                 self.finish(record)
 
-        def frame_written() -> None:
-            # the responder calls this once a frame's write returned; the
-            # first one after the first token existed carried that token
+        def frame_written(frames: int) -> None:
+            # the responder calls this once a write of ``frames`` frames
+            # returned; the first one after the first token existed
+            # carried that token
             if record.t_first_frame is None and record.t_first_token is not None:
                 with phase(SSE_FIRST_FRAME, record, end="t_first_frame"):
                     pass  # an instant on both clocks: the frame has left
                 now = record.t_first_frame  # so its lag IS first_frame_s
             else:
                 now = time.perf_counter()
-            if record.note_frame(now):
-                self.frames_total += 1
+            counted = record.note_write(now, frames)
+            if counted:
+                self.frames_total += counted
+                self.frame_writes_total += 1
 
         result.events = guarded()
         result.on_write = frame_written
@@ -1420,12 +1437,14 @@ class FlightRecorder:
     # -- read side (admin API / postmortem) ----------------------------------
     def transport(self) -> dict[str, Any]:
         """The ``http`` block of ``GET /admin/engine``: the event loop's
-        lag (None without a server's clock) and the token frames written."""
+        lag (None without a server's clock), the token frames written and
+        the writes that carried them."""
         lag = (
             self.loop_clock.snapshot() if self.loop_clock is not None
             else {"loop_lag_p99_ms": None, "loop_lag_max_ms": None}
         )
-        return dict(lag, frames_total=self.frames_total)
+        return dict(lag, frames_total=self.frames_total,
+                    frame_writes_total=self.frame_writes_total)
 
     def active_count(self) -> int:
         """In-flight request count — the cheap read for rollups that

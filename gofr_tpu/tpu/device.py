@@ -221,6 +221,30 @@ class TPULog:
         }
 
 
+class TokenStream:
+    """What ``generate_stream`` returns: a generation's tokens one at a
+    time to whoever iterates (gRPC streaming, the fan-out's pumps, the
+    non-stream consumers), and ``ready()`` for a transport that sends what
+    is there together: true while a TOKEN can be had without waiting (the
+    stream's end is not one). ``close()`` cancels the decode behind it, as
+    dropping the last reference does."""
+
+    __slots__ = ("_tokens", "ready")
+
+    def __init__(self, tokens: Any, ready: Any):
+        self._tokens = tokens
+        self.ready = ready
+
+    def __iter__(self) -> "TokenStream":
+        return self
+
+    def __next__(self) -> Any:
+        return next(self._tokens)
+
+    def close(self) -> None:
+        self._tokens.close()
+
+
 def _checkpoint_eos_ids(model_path, tokenizer) -> set:
     """EOS ids for default stopping: the checkpoint's
     generation_config.json (eos_token_id int or list — Llama-3 instruct
@@ -1893,7 +1917,10 @@ class TPUDevice:
         bridge for SSE and gRPC streaming transports. With ``logprobs=True``
         each item is a (token, raw_logprob) pair instead of a bare id.
         Closing the iterator (client disconnect) cancels the background
-        decode instead of letting it run to completion unread.
+        decode instead of letting it run to completion unread. The
+        iterator is a ``TokenStream``: its ``ready()`` says a token is
+        there to be had without waiting, so that the SSE transport sends
+        a decode chunk's tokens together (``Stream.ready``).
 
         ``cancel`` (a ``threading.Event``) is an EXTERNALLY-trippable
         stop: the SSE responder's client-abort hook sets it the moment
@@ -2070,8 +2097,10 @@ class TPUDevice:
     ) -> Any:
         import queue as queue_mod
         import threading
+        from collections import deque
 
-        out: "queue_mod.Queue" = queue_mod.Queue()
+        out: "queue_mod.SimpleQueue" = queue_mod.SimpleQueue()
+        taken: deque = deque()  # off the queue by ready(), not yet yielded
         done = object()
         failure: list[BaseException] = []
         # the caller's cancel event (SSE abort hook) doubles as the
@@ -2099,20 +2128,31 @@ class TPUDevice:
             finally:
                 out.put(done)
 
-        target = (lambda: snapshot.run(run)) if snapshot is not None else run
-        threading.Thread(
-            target=target, daemon=True, name="gofr-stream-producer"
-        ).start()
-        try:
-            while True:
-                item = out.get()
-                if item is done:
-                    break
-                yield item
-            if failure:
-                raise failure[0]
-        finally:
-            stop.set()
+        def ready() -> bool:
+            # the consumer's own thread, between two of its next()s: what
+            # the producer has put by now is what was ready together (a
+            # chunk's burst is put token by token in one go)
+            while not out.empty():  # one consumer: what is there stays there
+                taken.append(out.get_nowait())
+            return bool(taken) and taken[0] is not done
+
+        def tokens_iter() -> Any:
+            target = (lambda: snapshot.run(run)) if snapshot is not None else run
+            threading.Thread(
+                target=target, daemon=True, name="gofr-stream-producer"
+            ).start()
+            try:
+                while True:
+                    item = taken.popleft() if taken else out.get()
+                    if item is done:
+                        break
+                    yield item
+                if failure:
+                    raise failure[0]
+            finally:
+                stop.set()
+
+        return TokenStream(tokens_iter(), ready)
 
     # -- internals -----------------------------------------------------------
     def _prepare(self, payload: Any) -> Any:

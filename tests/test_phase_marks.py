@@ -341,6 +341,9 @@ def test_blocked_loop_shows_on_the_request_the_histogram_the_page_and_one_log_li
     assert page["http"]["loop_lag_max_ms"] >= 200.0
     assert page["http"]["loop_lag_max_ms"] >= page["http"]["loop_lag_p99_ms"] >= 0
     assert page["http"]["frames_total"] >= 400
+    # a write carries what its stream had ready: never more writes than frames
+    assert 1 <= page["http"]["frame_writes_total"] <= page["http"]["frames_total"]
+    assert 1 <= flight["frame_writes"] <= flight["frames"]
     text = app.container.metrics.expose()
     buckets = {
         ln.split('le="')[1].split('"')[0]: float(ln.rsplit(" ", 1)[1])
@@ -686,11 +689,12 @@ def test_delivery_held_back_shows_in_the_riders_deliver_gap(held_pool):
 
 def test_pooled_stream_frames_each_token_of_each_burst(held_pool):
     """A stream over the pool, served as the HTTP server serves it (the
-    responder's iterator pulled on an event loop, ``on_write`` after each
-    frame): the first token and two bursts of DECODE_CHUNK tokens make nine
-    token frames, the terminal frame none."""
+    responder's iterator pulled on an event loop, what the stream has ready
+    taken in one pull, ``on_write`` after each pull's frames): the first
+    token and two bursts of DECODE_CHUNK tokens make nine token frames, the
+    terminal frame none, and a write for every pull that carried a token."""
     from gofr_tpu.http.responder import _sse_iter
-    from gofr_tpu.http.response import Stream
+    from gofr_tpu.http.response import Held, Stream
 
     recorder = FlightRecorder()
     record = recorder.start(model="tiny", endpoint="/t", stream=True)
@@ -705,12 +709,18 @@ def test_pooled_stream_frames_each_token_of_each_burst(held_pool):
         yield {"finish": True}
 
     async def serve():
-        return [frame async for frame in _sse_iter(recorder.finish_stream(Stream(events()), record))]
+        return [frame async for frame in _sse_iter(
+            recorder.finish_stream(Stream(events(), ready=tokens.ready), record))]
 
     frames = asyncio.run(serve())
     flight = record.to_dict()
     assert flight["status"] == "ok" and flight["tokens_out"] == 9
     assert flight["frames"] == len(frames) - 1 == 9
+    # a pull ends on the one frame that is not Held; the last pull is the
+    # terminal frame's alone (the stream's end is no token: ready() is false)
+    assert not isinstance(frames[-1], Held) and not isinstance(frames[-2], Held)
+    assert flight["frame_writes"] == sum(not isinstance(f, Held) for f in frames[:-1])
+    assert 1 <= flight["frame_writes"] <= 9
     assert flight["frame_lag_max_s"] >= flight["frame_lag_mean_s"] >= 0
     assert flight["frame_lag_max_s"] >= flight["first_frame_s"] > 0
     assert flight["deliver_gap_max_s"] > 0  # first token -> first burst -> second
