@@ -10,8 +10,10 @@ regression can be traced without redeploying.
 
 What is always on and cheap is the records: a ``DispatchRecord`` a
 device dispatch and a ``FlightRecord`` a request, each with its
-``perf_counter`` marks (``cadence_s`` on a pooled chunk is the one
-in-program estimate of a chunk's device time); utilisation is read from a
+``perf_counter`` marks (``cadence_s`` on a pooled chunk is the in-program
+estimate of the interval between two deliveries, whatever the device ran
+in it; the pool's ``chunk_run_s`` on ``GET /admin/engine`` is the second,
+of a chunk's own device time); utilisation is read from a
 trace against the benchmark's work sheets and from nowhere in here. Full
 traces are the on-demand deep dive.
 
@@ -46,6 +48,7 @@ POOL_ISSUE = "gofr.pool.issue"
 POOL_FETCH_WAIT = "gofr.pool.fetch_wait"
 POOL_DELIVER = "gofr.pool.deliver"
 POOL_WAIT_WORK = "gofr.pool.wait_work"
+POOL_HOLD = "gofr.pool.hold"
 POOL_STATE_INSERT = "gofr.pool.state_insert"
 POOL_SEAT_WAIT = "gofr.pool.seat_wait"
 SOLO_ISSUE = "gofr.solo.issue"

@@ -336,7 +336,8 @@ class DynamicBatcher:
         # interleave with.
         if self.bucket_fn is not None:
             defer = (
-                self.scheduler.admit_prefill(bucket * len(batch))
+                self.scheduler.admit_prefill(
+                    bucket * len(batch), program=("prefill", bucket, len(batch)))
                 if self.scheduler is not None else 0.0
             )
             for item in batch:

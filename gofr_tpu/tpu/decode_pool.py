@@ -12,18 +12,30 @@ ON DEVICE (``_last_tokens``, fed forward chunk-to-chunk exactly like the
 in-chunk scan feeds itself), so chunk N+1 dispatches immediately after
 chunk N — its inputs are N's output futures — and the host fetch of chunk
 N's tokens overlaps chunk N+1's execution. Without this, the device idles
-one host round trip per chunk. Two chunks are kept in flight, one running
-and one queued: the host's part of a chunk is 1-2 ms against chunks of
-21-290 ms (PERF.md section 6, PR 31), and every further chunk in flight
-is a chunk a prefill, and a newly seated row's first decode, queue behind.
+one host round trip per chunk. At most two chunks are in flight, one
+running and one queued: the host's part of a chunk is 1-2 ms against
+chunks of 21-290 ms (PERF.md section 6, PR 31), and every further chunk in
+flight is a chunk a prefill, and a newly seated row's first decode, queue
+behind. For the same reason the queued chunk is issued when the device is
+about to need it, not when the host can (PR 47): with a chunk running and
+its own device time known (``run_s``), the worker HOLDS the next one until
+``lead`` before the running one is due to end, ``lead`` being what the
+host has needed to get a held chunk onto the device's queue. A short
+prefill issued meanwhile runs behind the chunk that is running and not
+behind the queued one too, and a row seated meanwhile rides the held
+chunk (``_hold``; the interference scheduler decides which prefills go
+ahead of the held chunk, tpu/scheduler.py).
 
 Mechanics:
 - a finished prefill row is copied into a free slot (one jitted
   dynamic_update_slice per cache field) and its first token is written
   into the device-resident token row;
-- the worker keeps ``pipeline_depth`` chunks in flight (2); each
-  dispatch snapshots (slot index -> request) so a slot freed and reused
-  mid-pipeline never leaks garbage tokens to the new request;
+- the worker keeps up to ``pipeline_depth`` chunks in flight (2), the
+  last of them from ``lead`` before the device needs it where the
+  chunk's run is known and long against the lead, else from the moment
+  the fetch before it returned; each dispatch snapshots (slot index ->
+  request) so a slot freed and reused mid-pipeline never leaks garbage
+  tokens to the new request;
 - inactive slots decode garbage in lockstep (fixed shapes = one compiled
   executable) and are overwritten on reuse;
 - per-request host-tracked lengths stop a request at the cache bound;
@@ -48,6 +60,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import queue
+import sys
 import threading
 from collections import deque
 from time import perf_counter as _perf_counter
@@ -67,6 +80,7 @@ from gofr_tpu.errors import DeadlineExceeded
 from gofr_tpu.profiling import (
     POOL_DELIVER,
     POOL_FETCH_WAIT,
+    POOL_HOLD,
     POOL_ISSUE,
     POOL_SEAT_WAIT,
     POOL_STATE_INSERT,
@@ -74,6 +88,7 @@ from gofr_tpu.profiling import (
     phase,
 )
 from gofr_tpu.telemetry import current_journal_entry, current_record
+from gofr_tpu.tpu.scheduler import RUN_SAMPLES
 
 DONE = object()  # end-of-stream marker on a slot's token queue
 # precedes DONE on a slot queue whose request's end-to-end deadline
@@ -84,6 +99,10 @@ DEADLINE = object()
 # prefill, looks at its stop event and its deadline (a seat itself wakes it
 # at once)
 _WAIT_POLL_S = 0.05
+# a chunk is held back only where it runs this many times the lead: under
+# that the hold gains a prefill little and a late wake-up costs every row
+# (chunks of a few milliseconds are issued the moment the host can)
+_HOLD_MIN_RUNS_PER_LEAD = 4.0
 
 
 class PoolFailure:
@@ -222,9 +241,30 @@ class DecodePool:
         # prefill (PERF.md section 6, PR 31); at 1 the device idles one
         # host round trip a chunk by construction
         self.pipeline_depth = pipeline_depth
+        # WHEN the last place of the pipeline is filled (``_hold``). The
+        # two estimates, both the worker's own: a chunk's device time
+        # (the shortest of the last few delivery intervals with the
+        # device busy throughout and no prefill admitted between the two
+        # issues; forgotten when the pool drains), and the peak, slowly let down, of what the host
+        # has needed to put a chunk on the device's queue (a held one:
+        # how far after its due time it was there, the wake-up's lateness
+        # included)
+        self._run_samples: deque = deque(maxlen=RUN_SAMPLES)
+        self._lead_peak_s = 0.0
+        # a hold is on (read without the lock by whoever issues a program
+        # of its own: that program goes ahead of the held chunk); the
+        # scheduler's guard ended it early; what the hold hands the issue
+        # it precedes: (when it began, its due time or None if ended early)
+        self.holding = False
+        self._hold_released = False
+        self._held: Optional[tuple] = None
+        self.held_issues = 0
+        # of them, those that found the running chunk done: the device
+        # had waited
+        self.held_issues_late = 0
         # interference scheduler (tpu/scheduler.py): the pool NOTES each
-        # chunk dispatch (never throttled) so prefill chunks can
-        # interleave between decode turns instead of stalling them
+        # chunk dispatch and each hold (never throttled) so prefill chunks
+        # can interleave between decode turns instead of stalling them
         self._sched = scheduler
         # paged-KV admission (tpu/kv_blocks.py BlockPool, shared with
         # the prefix cache): submit reserves a request's block budget —
@@ -1189,6 +1229,8 @@ class DecodePool:
             with self._work:
                 self._seat_waiters()
                 while not self._active and not in_flight and not self._closed:
+                    # the rows that come next make another chunk
+                    self._run_samples.clear()
                     with phase(POOL_WAIT_WORK):  # parked: no live slot
                         self._work.wait()
                 if self._closed:
@@ -1224,12 +1266,21 @@ class DecodePool:
                     # BOTH speculation and pipelining forever was the
                     # worst of both worlds (a new submit re-opens the
                     # window: fresh context may draft).
+                    # The LAST place is filled when the device is about
+                    # to need it (``_hold``), a pool that closes meanwhile
+                    # fails its rows at the top of the loop.
                     depth = (
                         1 if spec_armed and self._spec_idle < 4
                         else self.pipeline_depth
                     )
                     while self._active and len(in_flight) < depth:
+                        if in_flight and len(in_flight) == depth - 1:
+                            self._hold(in_flight[-1], last_fetch_done)
+                            if self._closed:
+                                break
                         self._dispatch_chunk(in_flight)
+                    if self._closed:
+                        continue
             if cycle is not None:
                 last_fetch_done = self._spec_fetch_deliver(
                     cycle, last_fetch_done
@@ -1238,6 +1289,110 @@ class DecodePool:
                 last_fetch_done = self._fetch_and_deliver(
                     in_flight, last_fetch_done
                 )
+
+    def _chunk_run_s(self) -> float:
+        """A chunk's own device time, 0.0 while unknown: the SHORTEST of
+        the last few clean delivery intervals, because the device cannot
+        need the next chunk sooner than that after it began this one. A
+        chunk's run moves by some 5% from one to the next (which experts
+        its rows hit, a row gone), and the median made one held chunk in
+        fifty find the device waiting (PERF.md section 6, PR 47); an
+        interval cut short by a late fetch errs to the early side."""
+        return min(self._run_samples, default=0.0)
+
+    def _hold_terms(self) -> Optional[tuple]:
+        """(a chunk's own device time, the lead the host is given), or
+        None where the next chunk is issued at once: no clean interval
+        since the pool last drained, or a chunk too short against the
+        lead. The lead is the interpreter's switch interval, which a
+        wake-up can lose to another thread whatever was observed, plus
+        the peak of what the host has needed."""
+        run_s = self._chunk_run_s()
+        if not run_s:
+            return None
+        lead_s = sys.getswitchinterval() + self._lead_peak_s
+        if run_s < _HOLD_MIN_RUNS_PER_LEAD * lead_s:
+            return None
+        return run_s, lead_s
+
+    def _hold(self, running: tuple, last_fetch_done: float) -> None:
+        """Hold the chunk that would queue behind ``running`` (pool lock
+        held; released while waiting): until ``lead`` before the running
+        chunk is due to end, which is ``run_s`` after it began, i.e. after
+        the fetch before it returned or, with nothing ahead of it, after
+        its own issue. The wait is on ``_work``, so a row seated
+        meanwhile (``submit`` takes the lock) rides the held chunk;
+        ``close`` ends it, and so does the scheduler for a prefill that
+        is to go behind the chunk (``_release_hold``). Whatever the
+        device runs meanwhile that the pool did not issue is ahead of the
+        held chunk and behind the running one. No hold without an
+        estimate, past the due time, or with the running chunk done."""
+        terms = self._hold_terms()
+        if terms is None:
+            return
+        run_s, lead_s = terms
+        due = max(last_fetch_done, running[5]) + run_s - lead_s
+        began = _perf_counter()
+        if due <= began or running[1].is_ready():
+            return
+        self._hold_released = False
+        self.holding = True
+        if self._sched is not None:
+            self._sched.note_hold(run_s, self._release_hold)
+        try:
+            with phase(POOL_HOLD):
+                while not (self._closed or self._hold_released):
+                    remaining = due - _perf_counter()
+                    if remaining <= 0:
+                        break
+                    self._work.wait(remaining)
+        finally:
+            self.holding = False
+        self._held = (began, None if self._hold_released else due)
+
+    def _release_hold(self) -> None:
+        """The scheduler's guard (a prefill thread, no lock of the
+        scheduler's held): issue the held chunk now, the prefill that
+        asks goes behind it."""
+        with self._work:
+            self._hold_released = True
+            self._work.notify_all()
+
+    def _note_issued(self, drec: Any, started: float, behind_busy: bool) -> None:
+        """The lead's bookkeeping once a chunk is on the device's queue
+        (pool lock held): what the host needed for it (a held chunk: from
+        its due time, the wake-up's lateness included; any other: the
+        issue alone) raises the peak at once and lets it down slowly, and
+        a held chunk that found the running one done (the device waited
+        for the host, or the chunk was shorter than ``run_s``) doubles it."""
+        now = _perf_counter()
+        held, self._held = self._held, None
+        needed_s = now - started
+        if held is not None:
+            began, due = held
+            self.held_issues += 1
+            if drec is not None:
+                drec.held_s = started - began
+            if due is not None:
+                needed_s = now - due
+                if drec is not None:
+                    drec.held_late_s = needed_s
+                if not behind_busy:
+                    self.held_issues_late += 1
+                    needed_s = max(needed_s, 2.0 * self._lead_peak_s)
+        self._lead_peak_s = max(
+            needed_s, self._lead_peak_s + 0.1 * (needed_s - self._lead_peak_s))
+
+    def _note_interval(self, interval_s: float, admitted: list) -> None:
+        """A delivery interval with the device busy throughout (pool lock
+        held): with no prefill admitted between the two issues
+        it is a reading of a chunk's own device time, else what it holds
+        beyond a chunk is the scheduler's reading of a prefill's run."""
+        if not admitted:
+            self._run_samples.append(interval_s)
+        elif self._run_samples and self._sched is not None:
+            self._sched.note_interval(
+                admitted, interval_s - self._chunk_run_s())
 
     def _dispatch_chunk(self, in_flight: deque) -> None:
         """Dispatch ONE pipelined chunk (pool lock held): timeline
@@ -1296,16 +1451,24 @@ class DecodePool:
         if want_top:
             tvals_dev.copy_to_host_async()
             tids_dev.copy_to_host_async()
+        # was the chunk before this one still running when this one
+        # reached the device's queue: then the device goes from one to the
+        # other (and to whatever was issued between them) without a gap,
+        # and the interval between their deliveries is device time
+        behind_busy = bool(in_flight) and not in_flight[-1][1].is_ready()
+        self._note_issued(drec, dispatch_start, behind_busy)
+        # decode keeps its cadence; prefill chunks take the gaps between
+        # these notes, and the note says which were admitted in this one
+        admitted = (
+            self._sched.note_decode_chunk(len(records))
+            if self._sched is not None else None
+        )
         in_flight.append(
             (records, toks_dev, lps_dev, tvals_dev, tids_dev,
-             dispatch_start, drec)
+             dispatch_start, drec, admitted or [], behind_busy)
         )
         self._pending_chunk_drec = None  # owned by in_flight now
         self.chunks_in_flight += 1
-        if self._sched is not None:
-            # decode keeps its cadence; prefill chunks take the gaps
-            # between these notes
-            self._sched.note_decode_chunk(len(records))
 
     def _sync_live(self) -> None:
         """Keep ``cache["live"]`` to the active slots (pool lock held): a
@@ -1663,7 +1826,7 @@ class DecodePool:
         from gofr_tpu.models.transformer import unpack_expert_counts
 
         (records, toks_dev, lps_dev, tvals_dev, tids_dev,
-         dispatch_start, drec) = in_flight.popleft()
+         dispatch_start, drec, admitted, behind_busy) = in_flight.popleft()
         # the blocking host fetch is WHERE a wedged device manifests:
         # it runs under the stall watchdog's deadline so a hang flips
         # the engine state instead of silently parking this worker
@@ -1712,6 +1875,9 @@ class DecodePool:
                 # the device ran in between (a prefill, solo chunks)
                 drec.cadence_s = dispatch_elapsed
             with phase(POOL_DELIVER, drec), self._work:
+                if behind_busy and last_fetch_done:
+                    # the fetch before this one was of the chunk before it
+                    self._note_interval(fetch_done - last_fetch_done, admitted)
                 self._deliver(records, toks, lps, tvals, tids,
                               dispatch_elapsed, drec)
         except BaseException:
@@ -1958,6 +2124,7 @@ class DecodePool:
 
     def occupancy(self) -> dict:
         """Point-in-time slot occupancy for ``GET /admin/engine``."""
+        guard = getattr(self._sched, "stats", {})
         with self._work:
             return {
                 "slots": self.n_slots,
@@ -1974,6 +2141,17 @@ class DecodePool:
                 # the deadline admission gate's unit: what one more
                 # chunk of decode costs right now (0 = not yet observed)
                 "chunk_cadence_s": self._chunk_ema_s,
+                # the hold (``_hold``): a chunk's own device time and the
+                # lead the host is given (0 / the floor before a reading),
+                # chunks that were held and those of them that came late,
+                # and the scheduler's two answers to a prefill admitted
+                # during a hold
+                "chunk_run_s": self._chunk_run_s(),
+                "issue_lead_s": sys.getswitchinterval() + self._lead_peak_s,
+                "held_issues": self.held_issues,
+                "held_issues_late": self.held_issues_late,
+                "prefills_ahead_of_held": guard.get("prefills_ahead_of_held", 0),
+                "prefills_kept_behind": guard.get("prefills_kept_behind", 0),
                 "kv": self._kv.stats() if self._kv is not None else None,
                 # pooled speculative decoding: armed + its width bound
                 # (per-request accept/width state lives on the flight

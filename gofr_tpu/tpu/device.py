@@ -168,12 +168,17 @@ WATCHDOG_AUTO_TIMEOUT_S = 120.0
 _NULLCTX = contextlib.nullcontext()
 
 
-def _chunks_ahead(runner: Any) -> int:
-    """Pool dispatches issued and not yet fetched, as the runner is about
-    to issue one of its own: what that dispatch queues behind on the
-    device (``DispatchRecord.chunks_ahead``). A plain int read, no lock."""
+def _note_queue_ahead(drec: Any, runner: Any) -> None:
+    """As the runner is about to issue a program of its own: the pool
+    dispatches issued and not yet fetched, which is what that program
+    queues behind on the device (``DispatchRecord.chunks_ahead``: one
+    running and one queued, or the running one alone where the pool holds
+    its next chunk back until the device is about to need it, and then
+    ``ahead_of_held``). Plain reads, no lock."""
     pool = runner.decode_pool
-    return pool.chunks_in_flight if pool is not None else 0
+    drec.chunks_ahead = pool.chunks_in_flight if pool is not None else 0
+    if pool is not None and pool.holding:
+        drec.ahead_of_held = True
 
 
 def configure_compile_cache() -> str:
@@ -2851,7 +2856,7 @@ class _EchoRunner:
             if self._closed:
                 raise RuntimeError("echo runner closed (engine recovering)")
             if drec is not None:
-                drec.chunks_ahead = _chunks_ahead(self)
+                _note_queue_ahead(drec, self)
         with phase(PREFILL_FETCH_WAIT, drec, start="t_fetch", end="t_fetched"):
             if self.step_s:
                 time.sleep(self.step_s)
@@ -3806,7 +3811,7 @@ class _TransformerRunner:
                 tokens_dev = jax.device_put(tokens_dev, self._token_sharding)
                 lengths_dev = jax.device_put(lengths_dev, self._row_sharding)
             if drec is not None:
-                drec.chunks_ahead = _chunks_ahead(self)
+                _note_queue_ahead(drec, self)
             logits, next_ids, cache = self._prefill(
                 self.params, tokens_dev, cache, lengths_dev
             )
@@ -4157,7 +4162,7 @@ class _TransformerRunner:
                         issued = timeline.begin(
                             "decode_solo", batch_size=1, tokens=n,
                         )
-                        issued.chunks_ahead = _chunks_ahead(self)
+                        _note_queue_ahead(issued, self)
                         if record is not None:
                             record.note_dispatch_id(issued.dispatch_id)
                     with phase(SOLO_ISSUE, issued, end="t_issued"):
@@ -4322,7 +4327,8 @@ class _TransformerRunner:
         try:
             for tokens, lengths, size in _prompt_chunks(ids, bucket):
                 if scheduler is not None:
-                    wait = scheduler.admit_prefill(bucket)
+                    wait = scheduler.admit_prefill(
+                        bucket, program=("prefill_chunk", bucket))
                     if record is not None and wait:
                         record.note_sched_defer(wait)
                 if self.timeline is not None:
@@ -4339,7 +4345,7 @@ class _TransformerRunner:
                         "prefill_chunk", bucket=bucket, batch_size=1,
                         tokens=size,
                     )
-                    drec.chunks_ahead = _chunks_ahead(self)
+                    _note_queue_ahead(drec, self)
                     drec.carried = total > 0
                     if self._latent_token_bytes:
                         # the carried latent and this slice's own

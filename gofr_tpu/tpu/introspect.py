@@ -103,6 +103,7 @@ class DispatchRecord:
         "chunks_ahead", "state_bytes", "kv_blocks_read", "kv_blocks_held",
         "carried", "expert_tokens", "experts_read", "expert_tokens_max",
         "identity_tokens", "absent_tokens", "latent_bytes", "shared_tokens",
+        "held_s", "held_late_s", "ahead_of_held",
     )
 
     def __init__(
@@ -182,6 +183,15 @@ class DispatchRecord:
         # a model with shared experts, which every token takes beside its
         # routed ones: real tokens x expert layers (x steps); None without
         self.shared_tokens: Optional[int] = None
+        # a decode chunk the pool held back until the device was about to
+        # need it (tpu/decode_pool.py::_hold): how long, and how far after
+        # its due time it was on the device's queue (None: the scheduler
+        # ended the hold for a prefill that went behind the chunk); any
+        # other dispatch: it was issued during such a hold, so it runs
+        # ahead of the held chunk
+        self.held_s: Optional[float] = None
+        self.held_late_s: Optional[float] = None
+        self.ahead_of_held: Optional[bool] = None
 
     def note_routing(self, counts: Any, held: int = 0, top_k: int = 1, shared: int = 0) -> None:
         """``counts`` [..., layers, experts]: the tokens each expert of each
@@ -260,6 +270,9 @@ class DispatchRecord:
             "absent_tokens": self.absent_tokens,
             "latent_bytes": self.latent_bytes,
             "shared_tokens": self.shared_tokens,
+            "held_s": self.held_s,
+            "held_late_s": self.held_late_s,
+            "ahead_of_held": self.ahead_of_held,
         }
 
 

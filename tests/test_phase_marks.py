@@ -37,7 +37,7 @@ ALL_NAMES = {
     profiling.POOL_ISSUE, profiling.POOL_FETCH_WAIT, profiling.POOL_DELIVER,
     profiling.POOL_WAIT_WORK, profiling.SOLO_ISSUE, profiling.SOLO_FETCH_WAIT,
     profiling.SSE_FIRST_FRAME, profiling.POOL_STATE_INSERT,
-    profiling.POOL_SEAT_WAIT, profiling.HTTP_LOOP_TICK,
+    profiling.POOL_SEAT_WAIT, profiling.HTTP_LOOP_TICK, profiling.POOL_HOLD,
 }
 
 
@@ -197,7 +197,7 @@ def test_prefill_records_the_pool_chunks_it_was_issued_behind(echo_app):
     app, base = echo_app
     runner = app.container.tpu.runner
     runner.stall_hook = lambda: setattr(
-        runner, "decode_pool", SimpleNamespace(chunks_in_flight=2))
+        runner, "decode_pool", SimpleNamespace(chunks_in_flight=2, holding=False))
     try:
         flight = _flight(app, _complete(base, stream=False, prompt="behind two"))
     finally:
@@ -758,5 +758,8 @@ def test_pool_and_solo_annotations_are_leaves(held_pool):
     assert not held_pool.stub.nested
     # the echo app's server, where this module's other fixture is alive,
     # ticks through the same stub
-    assert held_pool.stub.names - {profiling.HTTP_LOOP_TICK} == ALL_NAMES - {
-        profiling.SSE_FIRST_FRAME, profiling.HTTP_LOOP_TICK}
+    # (and the tiny model's chunks of a few milliseconds are held only where
+    # this CPU was slow enough to make them long: tests/test_pool_hold.py)
+    sometimes = {profiling.HTTP_LOOP_TICK, profiling.POOL_HOLD}
+    assert held_pool.stub.names - sometimes == ALL_NAMES - sometimes - {
+        profiling.SSE_FIRST_FRAME}
